@@ -221,8 +221,9 @@ class Query:
         sum/count/mean aggregates; rows with keys outside [0, K) are
         dropped.  Output is range-partitioned and ordered by the key.
 
-        Dense-path precision: counts are exact (int32 across the mesh;
-        per-partition capacity is guarded at 2^24).  SUM columns
+        Dense-path precision: counts are exact at any row count (the
+        kernel folds a partition in blocks of 2^24 rows, int32 between
+        blocks and across the mesh).  SUM columns
         accumulate on the MXU via split-bf16 terms
         (``ops/pallas_bucket.py``): integer values use 3 terms and stay
         EXACT up to 2^24 per value (totals still accumulate in f32, so

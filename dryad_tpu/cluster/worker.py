@@ -422,8 +422,8 @@ def main(argv=None) -> int:
 
     # Backend setup MUST precede any backend query: pin CPU with K local
     # devices, select gloo for cross-process CPU collectives, then join
-    # the multi-controller runtime.  (On real TPU pods the distributed
-    # runtime is joined the same way with the default backend.)
+    # the multi-controller runtime.  Gang workers run on CPU devices
+    # only today (one worker per chip is ROADMAP S6).
     from dryad_tpu.parallel.mesh import force_cpu_backend
 
     force_cpu_backend(args.devices_per_proc)
@@ -434,10 +434,7 @@ def main(argv=None) -> int:
         # initializes one (init_distributed no-ops at nproc<=1), and
         # some jaxlibs refuse gloo without it — so only select it when
         # cross-process collectives will actually exist.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # older jaxlib: single CPU collective impl
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     from dryad_tpu.parallel.multihost import ControlPlane, init_distributed
 

@@ -372,28 +372,50 @@ def _k_group_combine(ctx: StageContext, p) -> None:
     )
 
 
+# Rows one bucket-kernel call may fold: its counts accumulate in f32 on
+# the MXU, exact only below 2^24 per bucket.  Larger partitions fold in
+# static blocks of this many rows (tests patch it down).
+_DENSE_BLOCK_ROWS = 1 << 24
+
+
+def _bucket_fold(key, vals, in_range, num_buckets: int):
+    """``bucket_sum_count`` over a partition of ANY capacity: static
+    blocks of at most ``_DENSE_BLOCK_ROWS`` rows, each block's f32
+    counts rounded to int32 and added as integers (so counts stay exact
+    at every row count), f32 sums added block by block.  Returns
+    ``(sums, int32 counts)``."""
+    from dryad_tpu.ops.pallas_bucket import bucket_sum_count
+
+    n = key.shape[0]
+    cnt = None
+    sums: List[jax.Array] = []
+    for lo in range(0, max(n, 1), _DENSE_BLOCK_ROWS):
+        hi = min(n, lo + _DENSE_BLOCK_ROWS)
+        bs, bc = bucket_sum_count(
+            key[lo:hi], [v[lo:hi] for v in vals], in_range[lo:hi],
+            num_buckets,
+        )
+        bc = jnp.round(bc).astype(jnp.int32)
+        cnt = bc if cnt is None else cnt + bc
+        sums = bs if not sums else [a + b for a, b in zip(sums, bs)]
+    return sums, cnt
+
+
 def _k_group_reduce_dense(ctx: StageContext, p) -> None:
     """Dense-key GroupBy: per-partition MXU bucket reduce (Pallas on
     TPU, ``ops/pallas_bucket.py``) + one ``psum_scatter`` over the mesh.
 
     Output partition i holds buckets [i*per, (i+1)*per); rows for keys
     outside [0, K) are dropped (API contract).  Per-partition counts
-    accumulate in f32 on the MXU (exact below 2^24 rows/bucket/partition
-    — statically guaranteed by the capacity guard below) and cross the
-    mesh as int32, so the global count is exact.  SUM columns accumulate
-    in f32 end-to-end: integer sums silently lose exactness once a
-    per-bucket total exceeds 2^24 (documented at the API, query.py
-    ``dense=``); the sort-based path is the exact alternative.
+    accumulate in f32 on the MXU, exact below 2^24 rows per kernel call
+    — ``_bucket_fold`` keeps every call under that and adds the blocks
+    as int32 — and cross the mesh as int32, so the global count is
+    exact at any row count the int32 guard below admits.  SUM columns
+    accumulate in f32 end-to-end: integer sums silently lose exactness
+    once a per-bucket total exceeds 2^24 (documented at the API,
+    query.py ``dense=``); the sort-based path is the exact alternative.
     """
-    from dryad_tpu.ops.pallas_bucket import bucket_sum_count
-
     b = ctx.slots[p["slot"]]
-    if b.capacity > (1 << 24):
-        raise ValueError(
-            f"dense group_by: partition capacity {b.capacity} exceeds the "
-            "f32-exact accumulation range (2^24 rows/partition); use the "
-            "sort-based group_by path"
-        )
     if ctx.P * b.capacity > 0x7FFFFFFF:
         raise ValueError(
             f"dense group_by: global capacity {ctx.P * b.capacity} exceeds "
@@ -419,7 +441,7 @@ def _k_group_reduce_dense(ctx: StageContext, p) -> None:
     for a in p["aggs"]:
         if a.op in ("sum", "mean") and a.col not in val_cols:
             val_cols.append(a.col)
-    sums, cnt = bucket_sum_count(
+    sums, cnt = _bucket_fold(
         key, [b.data[c] for c in val_cols], in_range, Kp
     )
     by_col = dict(zip(val_cols, sums))
@@ -427,10 +449,10 @@ def _k_group_reduce_dense(ctx: StageContext, p) -> None:
     scat = lambda x: jax.lax.psum_scatter(
         x, ctx.axes, scatter_dimension=0, tiled=True
     )
-    # Counts cross the mesh as int32: each per-partition partial is f32-
-    # exact (capacity guard above), and integer reduce-scatter keeps the
-    # global total exact past 2^24.
-    cnt = scat(jnp.round(cnt).astype(jnp.int32))
+    # Counts cross the mesh as int32: each per-partition partial is
+    # exact (_bucket_fold), and integer reduce-scatter keeps the global
+    # total exact past 2^24.
+    cnt = scat(cnt)
     by_col = {c: scat(s) for c, s in by_col.items()}
 
     me = jax.lax.axis_index(ctx.axes)

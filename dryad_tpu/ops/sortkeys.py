@@ -47,7 +47,7 @@ def sort_order(
     ``ops.sort.sort_batch_by_operands`` / ``sort_carry`` — applying a
     permutation with ``take()`` costs ~42 ms per gathered column at
     n=4M on v5e, while carrying columns through ``lax.sort`` is free
-    (BASELINE.md round-4).  Use the permutation form only when the
+    (round-4 ``probe_sortops.py``).  Use the permutation form only when the
     order must be applied to something that cannot ride the sort.
     """
     n = valid.shape[0]

@@ -10,8 +10,8 @@ with no shuffle — the reference pays a full hash repartition for the
 same query (``DryadLinqQueryNode.cs:3581``).
 
 The mapping table is host-built open addressing over the 64-bit hash
-(linear probing, power-of-two slots, load <= 0.5); lookup is an
-unrolled vectorized gather loop.  Tables are wrapped in VALUE-equal
+(linear probing, power-of-two slots, load <= 0.5); lookup is a
+vectorized gather loop of ``probe_bound`` rounds.  Tables are wrapped in VALUE-equal
 objects so the executor's structural compile cache can key on table
 *content* (the legacy baked-constant path), or — with
 ``stringcode_runtime_tables`` — on the table's **shape palette tier**
@@ -56,7 +56,7 @@ class CodeTable:
     out-of-range drop in BOTH palette modes).
 
     Shape palette: ``num_slots`` is ``2 * palette_domain(K)`` (load
-    <= 0.5) and the unrolled probe loop runs ``probe_bound`` (the
+    <= 0.5) and the probe loop runs ``probe_bound`` (the
     observed max probe rounded up to a power of two) iterations, so the
     traced lookup depends only on the ``(num_slots, probe_bound)`` tier
     — two tables of the same tier produce byte-identical traces and the
@@ -150,6 +150,7 @@ class CodeTable:
         arrays when the tables travel as runtime operands; None bakes
         them into the trace as constants (legacy path).  Either way the
         trace depends only on ``operand_signature()`` values."""
+        import jax
         import jax.numpy as jnp
 
         S = self.num_slots
@@ -161,11 +162,20 @@ class CodeTable:
             tco = jnp.asarray(self.slots_code)
         idx = (h0 ^ (h1 * jnp.uint32(0x9E3779B9))).astype(jnp.uint32) & jnp.uint32(S - 1)
         idx = idx.astype(jnp.int32)
-        code = jnp.full(h0.shape, -1, jnp.int32)
-        for p in range(self.probe_bound):
+
+        # A real loop, not an unrolled one: each probe gathers three
+        # row-sized arrays, and XLA:TPU keeps every unrolled probe's
+        # gathers live at once (2^26 rows x 16 probes asked for 24 GB of
+        # HBM temp on a v5e).  One probe's temporaries at a time.
+        def probe(p, code):
             j = (idx + p) & (S - 1)
-            hit = (th0[j] == h0) & (th1[j] == h1) & (tco[j] >= 0)
-            code = jnp.where(hit & (code < 0), tco[j], code)
+            slot_code = tco[j]
+            hit = (th0[j] == h0) & (th1[j] == h1) & (slot_code >= 0)
+            return jnp.where(hit & (code < 0), slot_code, code)
+
+        code = jax.lax.fori_loop(
+            0, self.probe_bound, probe, jnp.full(h0.shape, -1, jnp.int32)
+        )
         return jnp.where(code < 0, jnp.int32(self.num_codes_padded), code)
 
 

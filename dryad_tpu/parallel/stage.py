@@ -24,25 +24,16 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from dryad_tpu.parallel.mesh import mesh_axes
 
-# jax >= 0.5 exposes shard_map at the top level with check_vma=; older
-# jax ships it under jax.experimental with the check_rep= spelling.
-if hasattr(jax, "shard_map"):
-    _shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-else:  # pragma: no cover - exercised only on older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 
 def compile_stage(mesh: Mesh, fn: Callable[[Any, Any], Tuple[Any, Any]]):
     """Compile a per-partition stage fn into a jitted SPMD callable."""
     axes = mesh_axes(mesh)
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(axes), P()),
         out_specs=(P(axes), P()),
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     return jax.jit(mapped)
 

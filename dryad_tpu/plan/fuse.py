@@ -3,8 +3,8 @@
 Phase-2 lowering (``plan/lower.py``) already fuses maximal *operator
 chains* into stages, but the executor still dispatches every stage as
 its own compiled program with the driver mediating each boundary — one
-compile key, one dispatch latency, and (through a TPU tunnel) one
-control round-trip per stage.  The reference Dryad pays a process +
+compile key, one dispatch latency, and one control round-trip per
+stage.  The reference Dryad pays a process +
 channel boundary between every stage pair (N*M file/HTTP channels per
 exchange, ``channelinterface.h``); our intra-stage shuffles are already
 on-device ``all_to_all`` ops (``ops/shuffle.py``), so the remaining

@@ -1,10 +1,12 @@
 """ctypes bindings for the native runtime, with pure-Python fallbacks.
 
 The shared library is built from ``runtime/native`` with the checked-in
-Makefile; if it is missing we attempt one build, then fall back to
-Python implementations (correct, slower).  Every native function has an
-identical-semantics Python twin so the engine never *requires* the
-native library.
+Makefile.  Every load runs ``make`` first (a no-op when the library is
+up to date), so what is loaded is what the committed source builds —
+never a stale binary left on disk.  Every native function has an
+identical-semantics Python twin for machines with no toolchain; taking
+them is logged once at WARNING with the compiler's message, and
+callers that need the native speed check :func:`native_available`.
 """
 
 from __future__ import annotations
@@ -30,21 +32,32 @@ _lib_tried = False
 _lock = threading.Lock()
 
 
+def build_native(force: bool = False) -> Optional[str]:
+    """Run the checked-in Makefile (``force``: rebuild unconditionally).
+    Returns None on success, else the toolchain's message."""
+    cmd = ["make", "-C", _NATIVE_DIR] + (["-B"] if force else [])
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, timeout=300)
+    except subprocess.CalledProcessError as e:
+        err = (e.stderr or b"").decode("utf-8", "replace").strip()
+        return f"{' '.join(cmd)} exited {e.returncode}: {err}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{' '.join(cmd)}: {e!r}"
+    return None
+
+
 def _load() -> Optional[ctypes.CDLL]:
     global _lib, _lib_tried
     with _lock:
         if _lib is not None or _lib_tried:
             return _lib
         _lib_tried = True
-        if not os.path.exists(_LIB_PATH):
-            try:
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR],
-                    check=True, capture_output=True, timeout=120,
-                )
-            except Exception as e:  # no toolchain: fall back
-                log.warning("native build failed (%s); using Python fallbacks", e)
-                return None
+        err = build_native()
+        if err is not None:
+            log.warning(
+                "native build failed; using Python fallbacks: %s", err
+            )
+            return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError as e:
@@ -109,14 +122,13 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_uint16), ctypes.POINTER(ctypes.c_uint64),
             ctypes.POINTER(ctypes.c_uint32),
         ]
-        if hasattr(lib, "dn_decompress_batch"):  # rebuilt lib only
-            lib.dn_decompress_batch.restype = ctypes.c_int32
-            lib.dn_decompress_batch.argtypes = [
-                ctypes.c_size_t, ctypes.POINTER(ctypes.c_void_p),
-                ctypes.POINTER(ctypes.c_uint64),
-                ctypes.POINTER(ctypes.c_void_p),
-                ctypes.POINTER(ctypes.c_uint64),
-            ]
+        lib.dn_decompress_batch.restype = ctypes.c_int32
+        lib.dn_decompress_batch.argtypes = [
+            ctypes.c_size_t, ctypes.POINTER(ctypes.c_void_p),
+            ctypes.POINTER(ctypes.c_uint64),
+            ctypes.POINTER(ctypes.c_void_p),
+            ctypes.POINTER(ctypes.c_uint64),
+        ]
         _lib = lib
         log.info("native runtime loaded from %s", _LIB_PATH)
         return _lib
@@ -133,7 +145,7 @@ def decompress_batch(srcs, dsts) -> bool:
     ``channelbuffernativereader.cpp`` analog).  Returns False when the
     native runtime is unavailable (caller falls back to zlib)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "dn_decompress_batch") or not srcs:
+    if lib is None or not srcs:
         return False
     n = len(srcs)
     src_ptrs = (ctypes.c_void_p * n)()

@@ -1,5 +1,5 @@
 """On-chip micro-probe for the sort-path segmented-reduce rewrite
-(BASELINE.md round-4 "sort-path optimization target").
+(round-4 sort-path optimization target).
 
 Compares, at n=4M sorted-keys shape, the CURRENT post-sort reduction
 (scatter-based ``segment_sum`` per agg) against the CANDIDATE

@@ -1,7 +1,7 @@
 """On-chip probe: carry value columns THROUGH lax.sort as variadic
 operands vs sort an index and gather columns afterwards (the current
 ``sort_order`` + ``take`` pattern).  Decides the `_segment_layout`
-rewrite (BASELINE.md round-4 sort-path target)."""
+rewrite (round-4 sort-path target)."""
 import sys
 import time
 

@@ -17,9 +17,13 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from dryad_tpu.parallel.mesh import force_cpu_backend
+# The CPU-mesh demo path: pin the virtual mesh before the first backend
+# query.  Without JAX_PLATFORMS=cpu the sample runs on jax's default
+# devices (the chip).
+if os.environ.get("JAX_PLATFORMS", "") == "cpu":
+    from dryad_tpu.parallel.mesh import force_cpu_backend
 
-force_cpu_backend(8)
+    force_cpu_backend(8)
 
 import numpy as np
 
@@ -33,7 +37,7 @@ def main() -> None:
     users = np.array([f"user{int(i):04d}" for i in rng.integers(0, 2000, n)], object)
     spend = (rng.gamma(2.0, 10.0, n)).astype(np.float32)
 
-    ctx = DryadContext(num_partitions_=8)
+    ctx = DryadContext()
     events = ctx.from_arrays({"user": users, "spend": spend})
 
     per_user = events.group_by(
@@ -65,7 +69,7 @@ def main() -> None:
         os.environ["DRYAD_TPU_DFS_GATEWAY"] = f"127.0.0.1:{svc.port}"
         agg.order_by([("total", True)]).to_store("hdfs://warehouse/per_user")
         back = (
-            DryadContext(num_partitions_=8)
+            DryadContext()
             .from_store("hdfs://warehouse/per_user")
             .count()
         )

@@ -15,9 +15,13 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from dryad_tpu.parallel.mesh import force_cpu_backend
+# The CPU-mesh demo path: pin the virtual mesh before the first backend
+# query.  Without JAX_PLATFORMS=cpu the sample runs on jax's default
+# devices (the chip).
+if os.environ.get("JAX_PLATFORMS", "") == "cpu":
+    from dryad_tpu.parallel.mesh import force_cpu_backend
 
-force_cpu_backend(8)
+    force_cpu_backend(8)
 
 import numpy as np
 
@@ -60,7 +64,7 @@ def main() -> None:
     src, dst = src[keep], dst[keep]
     deg = np.bincount(src, minlength=n_nodes).astype(np.float32)
 
-    ctx = DryadContext(num_partitions_=8)
+    ctx = DryadContext()
     edges = ctx.from_arrays(
         {
             "src": src,

@@ -15,9 +15,13 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from dryad_tpu.parallel.mesh import force_cpu_backend
+# The CPU-mesh demo path: pin the virtual mesh before the first backend
+# query.  Without JAX_PLATFORMS=cpu the sample runs on jax's default
+# devices (the chip).
+if os.environ.get("JAX_PLATFORMS", "") == "cpu":
+    from dryad_tpu.parallel.mesh import force_cpu_backend
 
-force_cpu_backend(8)
+    force_cpu_backend(8)
 
 import numpy as np
 
@@ -26,7 +30,7 @@ from dryad_tpu.tools.explain import explain
 
 
 def main() -> None:
-    ctx = DryadContext(num_partitions_=8)
+    ctx = DryadContext()
     if len(sys.argv) > 1:
         q = ctx.from_text(sys.argv[1])
     else:

@@ -276,8 +276,8 @@ def test_dict_miss_surfaced_not_dropped(rng):
 
 def test_int_group_by_auto_dense_no_shuffle(rng):
     """A plain group_by over an ingest-bounded INT32 key rides the MXU
-    bucket path: no exchange, no sort (VERDICT r3 item 3 — every
-    non-dense GroupBy used to pay the 12x-slower sort path)."""
+    bucket path: no exchange, no sort (every non-dense GroupBy used to
+    pay the slower sort path)."""
     ctx = DryadContext(num_partitions_=8)
     tbl = {
         "k": rng.integers(0, 50, 3000).astype(np.int32),

@@ -4,7 +4,7 @@ in ONE batched readback (the GM pump's concurrent vertex management,
 ``DrMessagePump.h:116-180``) — so through a high-latency control link a
 k-shuffle pipeline pays one round-trip of control latency, not k.
 
-Covers: >1 shuffle stages in flight (the VERDICT r3 item 7 done-gate),
+Covers: >1 shuffle stages in flight,
 correct recovery when a speculative stage overflows (suffix redo at a
 larger boost), depth=1 legacy behavior, and differential correctness.
 """

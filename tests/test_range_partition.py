@@ -156,13 +156,8 @@ def test_splitter_sample_count_scales_with_boost(mesh8):
     cap = 8 << 20  # per-partition 2^20: rate*cap = 1048 > 512 clamp
     from unittest import mock
 
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P_
-
-    from dryad_tpu.parallel.stage import _CHECK_KW, _shard_map
-
-    def shard_map(fn, **kw):
-        kw[_CHECK_KW] = kw.pop("check_vma")
-        return _shard_map(fn, **kw)
 
     mesh = mesh8
     with mock.patch.object(SORT, "sample_splitters", spy):

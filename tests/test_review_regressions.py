@@ -300,10 +300,10 @@ def test_deferred_abort_emits_job_failed(ctx, monkeypatch):
     )
 
     def boom(self, extra=()):
-        raise RuntimeError("tunnel died")
+        raise RuntimeError("transfer died")
 
     monkeypatch.setattr(ColumnBatch, "fetch_host", boom)
-    with pytest.raises(RuntimeError, match="tunnel died"):
+    with pytest.raises(RuntimeError, match="transfer died"):
         q.collect()
     kinds = [e["kind"] for e in ctx.executor.events.events()]
     assert "job_failed" in kinds

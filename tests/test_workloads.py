@@ -1,6 +1,6 @@
 """The five BASELINE.json workload shapes, end-to-end.
 
-Each test mirrors one reference workload config (BASELINE.md), runs it
+Each test mirrors one reference workload config (BASELINE.json), runs it
 through the distributed engine on the 8-device mesh AND through the
 LocalDebug NumPy interpreter, and differentially validates
 (the reference pattern: cluster run vs LINQ-to-Objects,
