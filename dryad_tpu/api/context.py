@@ -29,6 +29,7 @@ from dryad_tpu.columnar.schema import ColumnType, Schema, StringDictionary
 from dryad_tpu.exec.events import EventLog
 from dryad_tpu.exec.executor import GraphExecutor
 from dryad_tpu.obs import flightrec, tracectx
+from dryad_tpu.obs.span import Tracer
 from dryad_tpu.obs.diagnose import DiagnosisEngine
 from dryad_tpu.rewrite.controller import RewriteController
 from dryad_tpu.parallel import distribute as D
@@ -81,13 +82,15 @@ def _infer_schema(arrays: Dict[str, np.ndarray]) -> Schema:
     return Schema(fields)
 
 
-def _fetch_with_miss(batch, deferred):
+def _fetch_with_miss(batch, deferred, tracer):
     """Fetch a result batch host-side with the job's deferred dict-miss
     counters riding the same ``device_get``, resolve the deferred tail
     (raises on a nonzero counter), and return ``(valid, host_cols)``."""
     miss = deferred.miss_arrays()
     try:
-        valid, host_cols, miss_vals = batch.fetch_host(extra=miss)
+        valid, host_cols, miss_vals = batch.fetch_host(
+            extra=miss, tracer=tracer
+        )
     except Exception as e:  # transfer failure: close out the job
         deferred.abort(f"output transfer failed: {e!r}")
         raise
@@ -233,6 +236,12 @@ class DryadContext:
             )
             self.executor.rewriter = self.rewriter
             self.executor.headroom = self.headroom
+        # ONE tracer (one thread-local span stack) for the context and
+        # its executor, so a stage's span nests under the job's
+        self.tracer = (
+            self.executor.tracer if self.executor is not None
+            else Tracer(self.events)
+        )
 
     def rebuild_mesh(self, exclude_device_ids) -> None:
         """Elastic recovery: shrink the mesh past failed devices and
@@ -426,47 +435,43 @@ class DryadContext:
         str, or bytes."""
         from dryad_tpu.runtime import bindings as RB
 
-        if isinstance(data, (list, tuple)):
-            # Multi-file ingest: the native prefetch channel reads file
-            # i+1 while file i tokenizes (reference async channel
-            # buffer readers, channelbuffernativereader.cpp).
-            parts = []
-            with RB.PrefetchChannel(list(data), depth=4, threads=2) as ch:
-                for fbuf in ch:
-                    parts.append(self._tokenize_buf(fbuf))
-            if not parts:
-                cols = [np.zeros(0, np.uint32)] * 4
-            else:
-                cols = [
-                    np.concatenate([p[i] for p in parts]) for i in range(4)
-                ]
-            h0, h1, r0, r1 = cols
-            schema = Schema([(column, ColumnType.STRING)])
-            node = Node(
-                "input", [], schema, PartitionInfo.roundrobin(),
-                source="host_physical",
-                str_vocab={column: _word_vocab(h0, h1)},
-            )
-            self._bindings[node.id] = (
-                "host_physical",
-                {f"{column}#h0": h0, f"{column}#h1": h1,
-                 f"{column}#r0": r0, f"{column}#r1": r1},
-            )
-            return Query(self, node)
-
-        if isinstance(data, str) and os.path.exists(data):
-            with open(data, "rb") as fh:
-                buf = fh.read()
-        elif isinstance(data, str):
-            buf = data.encode("utf-8")
+        many = isinstance(data, (list, tuple))
+        on_disk = many or (isinstance(data, str) and os.path.exists(data))
+        if on_disk:
+            size = sum(os.path.getsize(d) for d in (data if many else [data]))
         else:
-            buf = bytes(data)
-        h0, h1, r0, r1 = self._tokenize_buf(buf)
+            data = data.encode("utf-8") if isinstance(data, str) else bytes(data)
+            size = len(data)
+        # bind time, before any collect(): no parent span and no qid
+        with self.tracer.span("tokenize", cat="ingest", bytes=size) as span:
+            if many:
+                # Multi-file ingest: the native prefetch channel reads
+                # file i+1 while file i tokenizes (reference async
+                # channel buffer readers, channelbuffernativereader.cpp).
+                parts = []
+                with RB.PrefetchChannel(list(data), depth=4, threads=2) as ch:
+                    for fbuf in ch:
+                        parts.append(self._tokenize_buf(fbuf))
+                if not parts:
+                    cols = [np.zeros(0, np.uint32)] * 4
+                else:
+                    cols = [
+                        np.concatenate([p[i] for p in parts])
+                        for i in range(4)
+                    ]
+                h0, h1, r0, r1 = cols
+            else:
+                if on_disk:
+                    with open(data, "rb") as fh:
+                        data = fh.read()
+                h0, h1, r0, r1 = self._tokenize_buf(data)
+            span.add(rows=len(h0))
+        with self.tracer.span("vocab", cat="ingest", rows=len(h0)):
+            vocab = _word_vocab(h0, h1)
         schema = Schema([(column, ColumnType.STRING)])
         node = Node(
             "input", [], schema, PartitionInfo.roundrobin(),
-            source="host_physical",
-            str_vocab={column: _word_vocab(h0, h1)},
+            source="host_physical", str_vocab={column: vocab},
         )
         self._bindings[node.id] = (
             "host_physical",
@@ -666,7 +671,8 @@ class DryadContext:
                 self._device_cache.move_to_end(node.id)
                 return batch
             del self._device_cache[node.id]
-        batch = self._ingest_binding(kind, rest, node)
+        with self.tracer.span("bind", cat="ingest", node=node.id):
+            batch = self._ingest_binding(kind, rest, node)
         if budget:
             nbytes = sum(
                 a.size * a.dtype.itemsize for a in batch.data.values()
@@ -684,12 +690,14 @@ class DryadContext:
             return D.from_host_table(
                 node.schema, arrays, self.mesh,
                 partition_capacity=cap, dictionary=self.dictionary,
+                tracer=self.tracer, metrics=self.executor.metrics,
             )
         if kind == "host_physical":
             phys, *opt = rest
             cap = opt[0] if opt else None
             return D.from_physical_table(
-                phys, self.mesh, partition_capacity=cap
+                phys, self.mesh, partition_capacity=cap,
+                tracer=self.tracer, metrics=self.executor.metrics,
             )
         if kind == "store":
             parts, schema = rest
@@ -709,19 +717,26 @@ class DryadContext:
             cap = math.ceil(max(max(rows_per, default=1), 1) / 8) * 8
             # Host-side (P * cap) layout + one device_put per column
             # (same no-jitted-ingest policy as from_physical_table).
-            data = {
-                c: np.zeros(P * cap, _phys_dtype(c, schema)) for c in phys
-            }
-            valid = np.zeros(P * cap, np.bool_)
-            for p, group in enumerate(folded):
-                at = p * cap
-                for cols in group:
-                    n = len(next(iter(cols.values()))) if cols else 0
-                    for c in phys:
-                        data[c][at : at + n] = cols[c]
-                    valid[at : at + n] = True
-                    at += n
-            return D.shard_host_padded(data, valid, self.mesh)
+            with self.tracer.span(
+                "encode", cat="ingest", rows=sum(rows_per), capacity=P * cap
+            ):
+                data = {
+                    c: np.zeros(P * cap, _phys_dtype(c, schema))
+                    for c in phys
+                }
+                valid = np.zeros(P * cap, np.bool_)
+                for p, group in enumerate(folded):
+                    at = p * cap
+                    for cols in group:
+                        n = len(next(iter(cols.values()))) if cols else 0
+                        for c in phys:
+                            data[c][at : at + n] = cols[c]
+                        valid[at : at + n] = True
+                        at += n
+            return D.shard_host_padded(
+                data, valid, self.mesh,
+                tracer=self.tracer, metrics=self.executor.metrics,
+            )
         if kind == "stream":
             raise RuntimeError(
                 "a chunk-stream input cannot bind as a device table; "
@@ -832,10 +847,12 @@ class DryadContext:
         return total
 
     def _execute_device(self, query: Query, defer_miss: bool = False):
-        graph = lower(
-            [query.node], self.config, self.dictionary,
-            P=num_partitions(self.mesh) if self.mesh is not None else None,
-        )
+        with self.tracer.span("lower", cat="plan") as span:
+            graph = lower(
+                [query.node], self.config, self.dictionary,
+                P=num_partitions(self.mesh) if self.mesh is not None else None,
+            )
+            span.add(stages=len(graph.stages))
         bindings = {
             nid: self._bind_device(n) for nid, n in graph.inputs.items()
         }
@@ -868,7 +885,8 @@ class DryadContext:
         # every span / exchange_round / dispatch_gap below carries the
         # minted (or inherited) context's qid
         with tracectx.activate(self._trace_ctx()):
-            return self._run_to_host(query)
+            with self.tracer.span("collect", cat="job"):
+                return self._run_to_host(query)
 
     def _run_to_host(self, query: Query) -> Dict[str, np.ndarray]:
         from dryad_tpu.exec.outofcore import StreamExecutor, has_stream_input
@@ -891,15 +909,28 @@ class DryadContext:
         # deferred check still raises before any result reaches the
         # caller.
         batch, deferred = self._execute_device(query, defer_miss=True)
-        valid, host_cols = _fetch_with_miss(batch, deferred)
-        self._account_d2h(valid, host_cols)
-        table = batch.to_numpy(
-            query.schema, self.dictionary, _host=(valid, host_cols)
-        )
-        if self._codecs:
-            from dryad_tpu.columnar.codecs import collapse_table
+        return self._fetch_table(query, batch, deferred)
 
-            table = collapse_table(table, self._codecs)
+    def _fetch_table(self, query: Query, batch, deferred=None):
+        """A result batch as the user's logical host table: the fetch
+        (``deferred``'s miss counters riding it), the byte accounting,
+        and the decode of the valid rows."""
+        if deferred is not None:
+            valid, host_cols = _fetch_with_miss(batch, deferred, self.tracer)
+        else:
+            valid, host_cols, _ = batch.fetch_host(tracer=self.tracer)
+        self._account_d2h(valid, host_cols)
+        with self.tracer.span(
+            "decode", cat="decode", rows=int(np.count_nonzero(valid)),
+            capacity=len(valid),
+        ):
+            table = batch.to_numpy(
+                query.schema, self.dictionary, _host=(valid, host_cols)
+            )
+            if self._codecs:
+                from dryad_tpu.columnar.codecs import collapse_table
+
+                table = collapse_table(table, self._codecs)
         return table
 
     def _account_d2h(self, valid, host_cols) -> None:
@@ -928,16 +959,7 @@ class DryadContext:
             # on another thread (DispatchWindow collector, serve
             # driver) still stamps readback spans with the right qid
             with tracectx.activate(tctx):
-                valid, host_cols = _fetch_with_miss(batch, deferred)
-                self._account_d2h(valid, host_cols)
-                table = batch.to_numpy(
-                    query.schema, self.dictionary, _host=(valid, host_cols)
-                )
-                if self._codecs:
-                    from dryad_tpu.columnar.codecs import collapse_table
-
-                    table = collapse_table(table, self._codecs)
-                return table
+                return self._fetch_table(query, batch, deferred)
 
         return fetch
 
@@ -979,20 +1001,11 @@ class DryadContext:
         def make_fetch(query, batch):
             def fetch() -> Dict[str, np.ndarray]:
                 with tracectx.activate(tctx):
-                    if not state["deferred_done"]:
-                        valid, host_cols = _fetch_with_miss(batch, deferred)
-                        state["deferred_done"] = True
-                    else:
-                        valid, host_cols, _ = batch.fetch_host(extra=[])
-                    self._account_d2h(valid, host_cols)
-                    table = batch.to_numpy(
-                        query.schema, self.dictionary,
-                        _host=(valid, host_cols),
+                    first = not state["deferred_done"]
+                    table = self._fetch_table(
+                        query, batch, deferred if first else None
                     )
-                    if self._codecs:
-                        from dryad_tpu.columnar.codecs import collapse_table
-
-                        table = collapse_table(table, self._codecs)
+                    state["deferred_done"] = True
                     return table
 
             return fetch
@@ -1043,7 +1056,7 @@ class DryadContext:
         cap = batch.capacity // P
         parts = []
         # overlapped d2h copies; miss counters ride the same transfer
-        valid, host_cols = _fetch_with_miss(batch, deferred)
+        valid, host_cols = _fetch_with_miss(batch, deferred, self.tracer)
         for i in range(P):
             sl = slice(i * cap, (i + 1) * cap)
             m = valid[sl]

@@ -27,6 +27,7 @@ from dryad_tpu.columnar.schema import (
     join64,
     split64,
 )
+from dryad_tpu.obs.span import UNTRACED, Tracer
 
 
 def encode_physical(
@@ -224,7 +225,9 @@ class ColumnBatch:
         valid[:n] = True
         return ColumnBatch(data, jnp.asarray(valid))
 
-    def fetch_host(self, extra: Sequence[jax.Array] = ()):
+    def fetch_host(
+        self, extra: Sequence[jax.Array] = (), tracer: Tracer = UNTRACED
+    ):
         """(valid, columns, extras) on the host, via ONE
         ``jax.device_get`` so PJRT overlaps all the device->host copies
         (copy_to_host_async then a single block).  A per-column
@@ -232,11 +235,23 @@ class ColumnBatch:
         per column, which dominates egress through a high-latency
         link.  ``extra`` arrays (e.g. deferred
         dict-miss counters) ride the same transfer; ``extras`` is empty
-        when none were passed."""
+        when none were passed.
+
+        The wait ``device_get`` would make itself is made first, so
+        "the program had not finished" (``fetch_wait``) and "the copy
+        took long" (``fetch_copy``) are two spans of ``tracer``;
+        ``fetch_copy``'s ``bytes`` is the batch's (``valid`` + columns,
+        what ``d2h_bytes`` counts), without the few bytes of ``extra``."""
         assert "#valid" not in self.data, "'#valid' is a reserved name"
-        host, extras = jax.device_get(
-            ({"#valid": self.valid, **self.data}, list(extra))
-        )
+        wanted = ({"#valid": self.valid, **self.data}, list(extra))
+        with tracer.span("fetch_wait", cat="readback"):
+            jax.block_until_ready(wanted)
+        nbytes = sum(a.size * a.dtype.itemsize for a in wanted[0].values())
+        with tracer.span(
+            "fetch_copy", cat="readback", bytes=nbytes,
+            capacity=self.capacity, columns=len(self.data),
+        ):
+            host, extras = jax.device_get(wanted)
         valid = host.pop("#valid")
         return valid, host, extras
 

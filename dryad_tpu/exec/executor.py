@@ -186,10 +186,11 @@ class _CompileTimed:
         if not self._pending:
             return self.fn(*args)
         self._pending = False
-        t0 = time.monotonic()
-        out = self.fn(*args)
-        dt = time.monotonic() - t0
         ex = self._exec
+        t0 = time.monotonic()
+        with ex.tracer.span(self._name, cat="compile"):
+            out = self.fn(*args)
+        dt = time.monotonic() - t0
         ex.metrics.add("xla_compiles", 1.0, stage=self._name)
         ex.metrics.add("xla_compile_s", dt, stage=self._name)
         ex.events.emit(
@@ -511,10 +512,13 @@ class GraphExecutor:
         # chunk plans) reuse fused programs across calls.  Off = the
         # legacy per-stage path, kept as the differential baseline.
         if getattr(self.config, "plan_fuse", True) and len(graph.stages) > 1:
-            graph, fuse_report = fuse_plan(
-                graph, self.config,
-                single_axis=len(mesh_axes(self.mesh)) == 1,
-            )
+            with self.tracer.span(
+                "fuse", cat="plan", stages=len(graph.stages)
+            ):
+                graph, fuse_report = fuse_plan(
+                    graph, self.config,
+                    single_axis=len(mesh_axes(self.mesh)) == 1,
+                )
             for br in fuse_report.breaks:
                 self.events.emit(
                     "fuse_break", after=br["after"], before=br["before"],
@@ -886,9 +890,12 @@ class GraphExecutor:
         )
         # observed row counts ride the SAME batched readback
         counted = [w for w in window if w.get("counts")]
-        combined_v, counts_v = jax.device_get(
-            (combined, [w["counts"] for w in counted])
-        )
+        with self.tracer.span(
+            "drain", cat="readback", inflight=len(window)
+        ):
+            combined_v, counts_v = jax.device_get(
+                (combined, [w["counts"] for w in counted])
+            )
         count_of = {id(w): cv for w, cv in zip(counted, counts_v)}
         if not bool(combined_v):
             for w in window:
@@ -1147,13 +1154,11 @@ class GraphExecutor:
                     stage, boost, shape_key,
                     fan=adapt_fan if boost < 4 else None,
                 )
-                # Per-stage step marker: stages show up as named steps in
-                # the XLA profiler timeline (SURVEY 5.1).  The obs span
-                # (cat=execute) is the jobview/Perfetto twin: dispatch +
-                # any rides-along readback, attributed to this attempt.
-                with jax.profiler.StepTraceAnnotation(
-                    stage.name, step_num=version
-                ), self.tracer.span(
+                # The obs span (cat=execute): dispatch + any
+                # rides-along readback, attributed to this attempt; in
+                # the XLA profiler timeline it is the annotation
+                # ``dryad:dispatch:<stage>`` (obs/span.py).
+                with self.tracer.span(
                     stage.name, cat="execute", stage=stage.id,
                     version=version, boost=boost,
                 ):
@@ -1210,16 +1215,24 @@ class GraphExecutor:
                     # message-pump concurrency, DrMessagePump.h:116).
                     if can_overflow and counts_dev is not None:
                         # ONE readback for flag + observed counts
-                        overflow, host_counts = jax.device_get(
-                            (overflow, counts_dev)
-                        )
+                        with self.tracer.span(
+                            "drain", cat="readback", inflight=1
+                        ):
+                            overflow, host_counts = jax.device_get(
+                                (overflow, counts_dev)
+                            )
                         overflow = bool(overflow)
                         self._record_observed(
                             stage, host_counts,
                             [o.capacity for o in outs],
                         )
+                    elif can_overflow:
+                        with self.tracer.span(
+                            "drain", cat="readback", inflight=1
+                        ):
+                            overflow = bool(overflow)
                     else:
-                        overflow = bool(overflow) if can_overflow else False
+                        overflow = False
             except faults.InjectedFault as e:
                 failures += 1
                 kind = classify(e, attempts)

@@ -116,7 +116,9 @@ def apply_op(ctx: StageContext, kind: str, p: Dict[str, Any]) -> None:
     fn = _KERNELS.get(kind)
     if fn is None:
         raise NotImplementedError(f"no kernel for stage op {kind!r}")
-    fn(ctx, p)
+    # names the operator in every device operation's ``tf_op`` path
+    with jax.named_scope(f"dryad.{kind}"):
+        fn(ctx, p)
 
 
 # -- row-wise --------------------------------------------------------------
@@ -391,10 +393,11 @@ def _bucket_fold(key, vals, in_range, num_buckets: int):
     sums: List[jax.Array] = []
     for lo in range(0, max(n, 1), _DENSE_BLOCK_ROWS):
         hi = min(n, lo + _DENSE_BLOCK_ROWS)
-        bs, bc = bucket_sum_count(
-            key[lo:hi], [v[lo:hi] for v in vals], in_range[lo:hi],
-            num_buckets,
-        )
+        with jax.named_scope("dryad.pallas_bucket"):
+            bs, bc = bucket_sum_count(
+                key[lo:hi], [v[lo:hi] for v in vals], in_range[lo:hi],
+                num_buckets,
+            )
         bc = jnp.round(bc).astype(jnp.int32)
         cnt = bc if cnt is None else cnt + bc
         sums = bs if not sums else [a + b for a, b in zip(sums, bs)]

@@ -17,8 +17,11 @@ Uncovered time before the first span is ``admission_wait`` (queueing
 behind other tenants); uncovered time elsewhere is ``other`` (honest
 residual, never silently redistributed).
 
-Phases (:data:`PHASES`): admission_wait / cache_probe / compile /
-ingest / dispatch / exchange / collective / readback / other.
+Phases (:data:`PHASES`): admission_wait / cache_probe / plan / compile /
+ingest / dispatch / exchange / collective / readback / decode / other.
+:func:`phase_of` is the one table from a span's name and category to
+its phase; ``obs/span`` names each span's profiler annotation
+``dryad:<phase>:<name>`` by it.
 Surfaces: ``Query.explain(analyze=True)``, the jobview ``-- queries --``
 panel, and ``QueryService.stats()["slo"]`` per-tenant phase totals.
 """
@@ -29,19 +32,21 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
     "PHASES", "QueryBreakdown", "fold_query", "fold_all",
-    "format_queries", "query_ids",
+    "format_queries", "phase_of", "query_ids",
 ]
 
 # canonical phase order (also the display order)
 PHASES: Tuple[str, ...] = (
-    "admission_wait", "cache_probe", "compile", "ingest", "dispatch",
-    "exchange", "collective", "readback", "other",
+    "admission_wait", "cache_probe", "plan", "compile", "ingest",
+    "dispatch", "exchange", "collective", "readback", "decode", "other",
 )
 
 # span category -> phase (name-based overrides win, below)
 _CAT_PHASE: Dict[str, str] = {
     "serve": "cache_probe",
+    "plan": "plan",
     "compile": "compile",
+    "ingest": "ingest",
     "prefetch": "ingest",
     "spill": "ingest",
     "execute": "dispatch",
@@ -50,21 +55,24 @@ _CAT_PHASE: Dict[str, str] = {
     "driver": "dispatch",
     "checkpoint": "other",
     "readback": "readback",
+    "decode": "decode",
+    "job": "other",
 }
 
 # specificity when intervals tie on span depth: a readback or compile
 # blocks the query outright; generic dispatch is the least specific
 # covered phase
 _PRIORITY: Dict[str, int] = {
-    "other": 0, "admission_wait": 0, "dispatch": 1, "ingest": 2,
-    "cache_probe": 3, "exchange": 4, "collective": 5, "compile": 6,
-    "readback": 7,
+    "other": 0, "admission_wait": 0, "dispatch": 1, "plan": 2,
+    "ingest": 2, "decode": 2, "cache_probe": 3, "exchange": 4,
+    "collective": 5, "compile": 6, "readback": 7,
 }
 
 _LIFECYCLE = ("query_admitted", "query_complete", "result_cache_hit")
 
 
-def _phase_of(name: str, cat: str) -> str:
+def phase_of(name: str, cat: str) -> str:
+    """The phase a span of this name and category is charged to."""
     n = name or ""
     if "exchange" in n:
         return "exchange"
@@ -196,7 +204,7 @@ def fold_query(
         ts = float(ev.get("ts", 0.0) or 0.0)
         if kind == "span":
             dur = float(ev.get("dur", 0.0) or 0.0)
-            phase = _phase_of(
+            phase = phase_of(
                 str(ev.get("name", "")), str(ev.get("cat", ""))
             )
             parents[ev.get("span_id")] = ev.get("parent_id")

@@ -21,6 +21,16 @@ span passes ``parent=`` explicitly (capture it with
 Span ids are unique process-wide (one shared counter), so any module
 may construct its own ``Tracer(events)`` over the same log and the
 hierarchy stays consistent.
+
+Every span is also a ``jax.profiler.TraceAnnotation`` over the same
+interval, named ``dryad:<phase>:<name>`` (``<phase>`` from
+:func:`dryad_tpu.obs.critpath.phase_of`) with ``span_id``,
+``parent_id``, ``qid`` and the numeric fields it was opened with as
+stats: in a profiler session (``config.profile_dir``, the benchmark's
+traced run) the program's spans lie on the device trace's clock.  With
+no session the annotation is a flag check.  Only fields known at open
+ride the annotation; what ``add()`` attaches later reaches the event
+alone.
 """
 
 from __future__ import annotations
@@ -31,9 +41,12 @@ import threading
 import time
 from typing import Any, Optional
 
-from dryad_tpu.obs import tracectx
+from jax.profiler import TraceAnnotation
 
-__all__ = ["Span", "Tracer"]
+from dryad_tpu.obs import tracectx
+from dryad_tpu.obs.critpath import phase_of
+
+__all__ = ["Span", "Tracer", "UNTRACED"]
 
 # process-wide id source: tracers are cheap per-module conveniences,
 # so ids must not collide across instances
@@ -57,7 +70,8 @@ class Span:
     """
 
     __slots__ = (
-        "_tracer", "name", "cat", "fields", "span_id", "parent_id", "_t0"
+        "_tracer", "name", "cat", "fields", "span_id", "parent_id", "_t0",
+        "_annotation",
     )
 
     def __init__(self, tracer, name, cat, parent_id, fields):
@@ -68,6 +82,7 @@ class Span:
         self.span_id = _next_id()
         self.parent_id = parent_id
         self._t0 = 0.0
+        self._annotation = None
 
     def add(self, **fields: Any) -> "Span":
         self.fields.update(fields)
@@ -75,11 +90,24 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._tracer._push(self)
+        stats = {
+            k: v for k, v in self.fields.items()
+            if isinstance(v, (int, float))
+        }
+        qid = self.fields.get("qid") or tracectx.current_qid()
+        if qid:  # the profiler keeps no empty stat
+            stats["qid"] = qid
+        self._annotation = TraceAnnotation(
+            f"dryad:{phase_of(self.name, self.cat)}:{self.name}",
+            span_id=self.span_id, parent_id=self.parent_id or 0, **stats,
+        )
+        self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         dur = time.monotonic() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._pop(self)
         if exc_type is not None and exc_type is not StopIteration:
             # StopIteration is iterator protocol, not a fault (the
@@ -179,3 +207,8 @@ class Tracer:
             return wrapper
 
         return deco
+
+
+# The default of a ``tracer=`` parameter (``columnar/batch.py``,
+# ``parallel/distribute.py``): no event log, so every span is ``_NULL``.
+UNTRACED = Tracer()

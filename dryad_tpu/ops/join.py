@@ -63,6 +63,7 @@ def _probe_ranges(
     return rs, lhash, start, counts
 
 
+@jax.named_scope("dryad.join.expand_pairs")
 def _expand_pairs(
     start: jax.Array, counts: jax.Array, out_capacity: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:

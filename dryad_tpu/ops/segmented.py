@@ -48,6 +48,7 @@ class AggSpec:
     out: str
 
 
+@jax.named_scope("dryad.group_reduce.layout")
 def _segment_layout(
     batch: ColumnBatch, key_cols: Sequence[str]
 ) -> Tuple[ColumnBatch, jax.Array, jax.Array, jax.Array, jax.Array]:
@@ -207,8 +208,18 @@ def group_reduce(
 
     if os.environ.get("DRYAD_TPU_SORT_FUSED") == "1":  # graftlint: disable=kernel-determinism -- opt-in experiment hatch, off by default; constant within a run
         return group_reduce_fused(batch, key_cols, aggs)
-    cap = batch.capacity
     sb, v, start, seg, nseg = _segment_layout(batch, key_cols)
+    return _segmented_fold(sb, v, start, seg, nseg, key_cols, aggs)
+
+
+@jax.named_scope("dryad.group_reduce.fold")
+def _segmented_fold(
+    sb: ColumnBatch, v, start, seg, nseg,
+    key_cols: Sequence[str], aggs: Sequence[AggSpec],
+) -> ColumnBatch:
+    """The fold of :func:`group_reduce` over rows already laid out by
+    segment (:func:`_segment_layout`): one output row a segment."""
+    cap = sb.capacity
     nsegments = cap + 1  # includes the invalid-row sentinel segment
 
     out: Dict[str, jax.Array] = {}

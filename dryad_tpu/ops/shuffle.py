@@ -67,6 +67,37 @@ def row_bytes(batch: ColumnBatch) -> int:
     return per
 
 
+# Operator scopes (``jax.named_scope``) inside an exchange: the bucket
+# layout (sort by destination, scatter into send buffers) and the
+# collective apart, so a device trace splits one from the other.
+LAYOUT_SCOPE = "dryad.exchange.layout"
+COLLECTIVE_SCOPE = "dryad.exchange.collective"
+
+
+def _bucket_layout(batch: ColumnBatch, dest: jax.Array, P: int, B: int):
+    """Rows stably sorted by destination, so each bucket's rows are
+    contiguous: ``(sorted batch, sorted dest, position within bucket,
+    ships, overflow)``.  Invalid rows take the sentinel ``P`` and never
+    ship; a valid row past its bucket's ``B`` sets ``overflow``."""
+    cap = batch.capacity
+    dest = jnp.where(batch.valid, dest, P)
+    operands = (dest, jnp.arange(cap, dtype=jnp.int32))
+    dsorted, order = jax.lax.sort(operands, num_keys=1, is_stable=True)
+    sb = batch.take(order)
+
+    counts = jnp.bincount(dsorted, length=P + 1)[:P]
+    offsets = jnp.concatenate(
+        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]]
+    )
+    within = jnp.arange(cap, dtype=jnp.int32) - jnp.where(
+        dsorted < P, offsets[jnp.clip(dsorted, 0, P - 1)], 0
+    ).astype(jnp.int32)
+
+    in_range = (dsorted < P) & (within < B)
+    overflow = jnp.any((dsorted < P) & (within >= B))
+    return sb, dsorted, within, in_range, overflow
+
+
 def exchange(
     batch: ColumnBatch,
     dest: jax.Array,
@@ -82,48 +113,35 @@ def exchange(
     and a scalar bool overflow flag (psum'd across devices).
     """
     P, B = num_partitions, bucket_cap
-    cap = batch.capacity
-    dest = jnp.where(batch.valid, dest, P)  # invalid rows -> sentinel
+    with jax.named_scope(LAYOUT_SCOPE):
+        sb, dsorted, within, in_range, overflow = _bucket_layout(
+            batch, dest, P, B
+        )
+        flat_idx = jnp.where(in_range, dsorted * B + within, P * B)
 
-    # Stable sort rows by destination so each bucket's rows are contiguous.
-    operands = (dest, jnp.arange(cap, dtype=jnp.int32))
-    dsorted, order = jax.lax.sort(operands, num_keys=1, is_stable=True)
-    sb = batch.take(order)
+        send = {}
+        for name, col in sb.data.items():
+            buf = jnp.zeros((P * B,) + col.shape[1:], col.dtype)
+            send[name] = buf.at[flat_idx].set(col, mode="drop").reshape((P, B) + col.shape[1:])
+        send_valid = (
+            jnp.zeros((P * B,), jnp.bool_)
+            .at[flat_idx]
+            .set(sb.valid & in_range, mode="drop")
+            .reshape(P, B)
+        )
 
-    counts = jnp.bincount(dsorted, length=P + 1)[:P]
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]]
-    )
-    within = jnp.arange(cap, dtype=jnp.int32) - jnp.where(
-        dsorted < P, offsets[jnp.clip(dsorted, 0, P - 1)], 0
-    ).astype(jnp.int32)
+    with jax.named_scope(COLLECTIVE_SCOPE):
+        recv = {
+            name: jax.lax.all_to_all(
+                buf, axis_name, split_axis=0, concat_axis=0, tiled=True
+            ).reshape((P * B,) + buf.shape[2:])
+            for name, buf in send.items()
+        }
+        recv_valid = jax.lax.all_to_all(
+            send_valid, axis_name, split_axis=0, concat_axis=0, tiled=True
+        ).reshape(P * B)
 
-    in_range = (dsorted < P) & (within < B)
-    overflow = jnp.any((dsorted < P) & (within >= B))
-    flat_idx = jnp.where(in_range, dsorted * B + within, P * B)
-
-    send = {}
-    for name, col in sb.data.items():
-        buf = jnp.zeros((P * B,) + col.shape[1:], col.dtype)
-        send[name] = buf.at[flat_idx].set(col, mode="drop").reshape((P, B) + col.shape[1:])
-    send_valid = (
-        jnp.zeros((P * B,), jnp.bool_)
-        .at[flat_idx]
-        .set(sb.valid & in_range, mode="drop")
-        .reshape(P, B)
-    )
-
-    recv = {
-        name: jax.lax.all_to_all(
-            buf, axis_name, split_axis=0, concat_axis=0, tiled=True
-        ).reshape((P * B,) + buf.shape[2:])
-        for name, buf in send.items()
-    }
-    recv_valid = jax.lax.all_to_all(
-        send_valid, axis_name, split_axis=0, concat_axis=0, tiled=True
-    ).reshape(P * B)
-
-    overflow = jax.lax.psum(overflow.astype(jnp.int32), axis_name) > 0
+        overflow = jax.lax.psum(overflow.astype(jnp.int32), axis_name) > 0
     return ColumnBatch(recv, recv_valid), overflow
 
 
@@ -153,25 +171,13 @@ def exchange_staged(
     overflow-palette retries).
     """
     P, B = num_partitions, bucket_cap
-    cap = batch.capacity
     D, ici = schedule.dcn_slices, schedule.ici_partitions
     assert P == schedule.num_partitions == D * ici
 
-    dest = jnp.where(batch.valid, dest, P)  # invalid rows -> sentinel
-    operands = (dest, jnp.arange(cap, dtype=jnp.int32))
-    dsorted, order = jax.lax.sort(operands, num_keys=1, is_stable=True)
-    sb = batch.take(order)
-
-    counts = jnp.bincount(dsorted, length=P + 1)[:P]
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]]
-    )
-    within = jnp.arange(cap, dtype=jnp.int32) - jnp.where(
-        dsorted < P, offsets[jnp.clip(dsorted, 0, P - 1)], 0
-    ).astype(jnp.int32)
-
-    in_range = (dsorted < P) & (within < B)
-    overflow = jnp.any((dsorted < P) & (within >= B))
+    with jax.named_scope(LAYOUT_SCOPE):
+        sb, dsorted, within, in_range, overflow = _bucket_layout(
+            batch, dest, P, B
+        )
 
     me = jax.lax.axis_index(axis_name)  # flattened, slice-major
     md, mp = me // ici, me % ici
@@ -207,8 +213,9 @@ def exchange_staged(
         return jax.lax.dynamic_update_slice(out_valid, bv, (start,))
 
     # Local bucket: zero network bytes, scatter straight into my slot.
-    blocks, bv = bucket_block(me)
-    out_valid = place(blocks, bv, me)
+    with jax.named_scope(LAYOUT_SCOPE):
+        blocks, bv = bucket_block(me)
+        out_valid = place(blocks, bv, me)
 
     for rnd in schedule.rounds:
         for sd, sp in rnd.hops:
@@ -218,15 +225,19 @@ def exchange_staged(
             ]
             tgt = ((md + sd) % D) * ici + (mp + sp) % ici
             src = ((md - sd) % D) * ici + (mp - sp) % ici
-            blocks, bv = bucket_block(tgt)
-            blocks = {
-                name: jax.lax.ppermute(blk, axis_name, perm)
-                for name, blk in blocks.items()
-            }
-            bv = jax.lax.ppermute(bv, axis_name, perm)
-            out_valid = place(blocks, bv, src)
+            with jax.named_scope(LAYOUT_SCOPE):
+                blocks, bv = bucket_block(tgt)
+            with jax.named_scope(COLLECTIVE_SCOPE):
+                blocks = {
+                    name: jax.lax.ppermute(blk, axis_name, perm)
+                    for name, blk in blocks.items()
+                }
+                bv = jax.lax.ppermute(bv, axis_name, perm)
+            with jax.named_scope(LAYOUT_SCOPE):
+                out_valid = place(blocks, bv, src)
 
-    overflow = jax.lax.psum(overflow.astype(jnp.int32), axis_name) > 0
+    with jax.named_scope(COLLECTIVE_SCOPE):
+        overflow = jax.lax.psum(overflow.astype(jnp.int32), axis_name) > 0
     return ColumnBatch(out, out_valid), overflow
 
 

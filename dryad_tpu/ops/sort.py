@@ -56,6 +56,7 @@ def _carry_profitable() -> bool:
     return _on_tpu()
 
 
+@jax.named_scope("dryad.sort.carry")
 def sort_carry(
     operands: Sequence[jax.Array],
     valid: jax.Array,
@@ -108,6 +109,7 @@ def sort_batch_by_operands(
     return ColumnBatch(dict(zip(names, carried)), valid)
 
 
+@jax.named_scope("dryad.sort.splitters")
 def sample_splitters(
     key_u32: jax.Array,
     valid: jax.Array,
@@ -157,6 +159,7 @@ def range_dest(key_u32: jax.Array, splitters: jax.Array) -> jax.Array:
 
 # -- skew-proof multi-word variant (automatic heavy-key mitigation) --------
 
+@jax.named_scope("dryad.sort.splitters")
 def sample_splitters_multi(
     words: Sequence[jax.Array],
     valid: jax.Array,

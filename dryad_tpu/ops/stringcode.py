@@ -173,9 +173,11 @@ class CodeTable:
             hit = (th0[j] == h0) & (th1[j] == h1) & (slot_code >= 0)
             return jnp.where(hit & (code < 0), slot_code, code)
 
-        code = jax.lax.fori_loop(
-            0, self.probe_bound, probe, jnp.full(h0.shape, -1, jnp.int32)
-        )
+        with jax.named_scope("dryad.string_code.probe"):
+            code = jax.lax.fori_loop(
+                0, self.probe_bound, probe,
+                jnp.full(h0.shape, -1, jnp.int32),
+            )
         return jnp.where(code < 0, jnp.int32(self.num_codes_padded), code)
 
 

@@ -17,27 +17,41 @@ from jax.sharding import Mesh
 
 from dryad_tpu.columnar.batch import ColumnBatch, encode_table
 from dryad_tpu.columnar.schema import Schema, StringDictionary
+from dryad_tpu.obs.span import UNTRACED, Tracer
 from dryad_tpu.parallel.mesh import num_partitions, partition_sharding
 
 
-def shard_batch(batch: ColumnBatch, mesh: Mesh) -> ColumnBatch:
+def shard_batch(
+    batch: ColumnBatch, mesh: Mesh, tracer: Tracer = UNTRACED, metrics=None
+) -> ColumnBatch:
     """Place a (global-capacity) batch onto the mesh, row-sharded."""
-    sh = partition_sharding(mesh)
-    data = {n: jax.device_put(v, sh) for n, v in batch.data.items()}
-    return ColumnBatch(data, jax.device_put(batch.valid, sh))
+    return shard_host_padded(batch.data, batch.valid, mesh, tracer, metrics)
 
 
 def shard_host_padded(
-    data: Dict[str, np.ndarray], valid: np.ndarray, mesh: Mesh
+    data: Dict[str, np.ndarray], valid: np.ndarray, mesh: Mesh,
+    tracer: Tracer = UNTRACED, metrics=None,
 ) -> ColumnBatch:
     """One device_put per already-laid-out (P * cap) host column onto
     the row sharding — the ingest edge for host-side layouts.  No
-    jitted concatenate/slice programs run, so ingest compiles nothing."""
+    jitted concatenate/slice programs run, so ingest compiles nothing.
+
+    The one H2D site: the copies sit inside ONE ``h2d`` span of
+    ``tracer`` whose ``bytes`` is also what the ``h2d_bytes`` counter of
+    ``metrics`` (the executor's registry) gains.  ``device_put`` returns
+    once the copies are enqueued, so the span times the enqueue, not
+    the transfer."""
     sh = partition_sharding(mesh)
-    return ColumnBatch(
-        {c: jax.device_put(v, sh) for c, v in data.items()},
-        jax.device_put(valid, sh),
-    )
+    nbytes = sum(v.size * v.dtype.itemsize for v in data.values())
+    nbytes += valid.size * valid.dtype.itemsize
+    with tracer.span("h2d", cat="ingest", bytes=nbytes):
+        out = ColumnBatch(
+            {c: jax.device_put(v, sh) for c, v in data.items()},
+            jax.device_put(valid, sh),
+        )
+    if metrics is not None:
+        metrics.add("h2d_bytes", nbytes)
+    return out
 
 
 def from_host_table(
@@ -46,6 +60,8 @@ def from_host_table(
     mesh: Mesh,
     partition_capacity: Optional[int] = None,
     dictionary: Optional[StringDictionary] = None,
+    tracer: Tracer = UNTRACED,
+    metrics=None,
 ) -> ColumnBatch:
     """Block-partition rows into P partitions of equal static capacity.
 
@@ -57,14 +73,20 @@ def from_host_table(
     # hashed / dictionary-registered), then block-partition the physical
     # columns through the shared path: one sharded device_put per
     # column, no full-size array on the default device.
-    phys, _n = encode_table(schema, arrays, dictionary)
-    return from_physical_table(phys, mesh, partition_capacity)
+    rows = len(next(iter(arrays.values()))) if arrays else 0
+    with tracer.span("encode", cat="ingest", rows=rows):
+        phys, _n = encode_table(schema, arrays, dictionary)
+    return from_physical_table(
+        phys, mesh, partition_capacity, tracer=tracer, metrics=metrics
+    )
 
 
 def from_physical_table(
     phys: Dict[str, np.ndarray],
     mesh: Mesh,
     partition_capacity: Optional[int] = None,
+    tracer: Tracer = UNTRACED,
+    metrics=None,
 ) -> ColumnBatch:
     """Block-partition already-encoded physical columns (no hashing).
 
@@ -85,18 +107,19 @@ def from_physical_table(
     sizes = [
         min((p + 1) * per, n) - min(p * per, n) for p in range(P)
     ]
-    data = {}
-    for c in names:
-        a = np.asarray(phys[c])
-        pad = np.zeros((P * cap,) + a.shape[1:], a.dtype)
+    with tracer.span("encode", cat="ingest", rows=n, capacity=P * cap):
+        data = {}
+        for c in names:
+            a = np.asarray(phys[c])
+            pad = np.zeros((P * cap,) + a.shape[1:], a.dtype)
+            for p, m in enumerate(sizes):
+                lo = min(p * per, n)
+                pad[p * cap : p * cap + m] = a[lo : lo + m]
+            data[c] = pad
+        valid = np.zeros(P * cap, np.bool_)
         for p, m in enumerate(sizes):
-            lo = min(p * per, n)
-            pad[p * cap : p * cap + m] = a[lo : lo + m]
-        data[c] = pad
-    valid = np.zeros(P * cap, np.bool_)
-    for p, m in enumerate(sizes):
-        valid[p * cap : p * cap + m] = True
-    return shard_host_padded(data, valid, mesh)
+            valid[p * cap : p * cap + m] = True
+    return shard_host_padded(data, valid, mesh, tracer, metrics)
 
 
 def to_host_table(

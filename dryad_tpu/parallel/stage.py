@@ -35,6 +35,14 @@ def compile_stage(mesh: Mesh, fn: Callable[[Any, Any], Tuple[Any, Any]]):
         out_specs=(P(axes), P()),
         check_vma=False,
     )
+    # The program's name (``jit_dryad_stage``: the profiler's ``XLA
+    # Modules`` line, the head of every operation's name path) is part
+    # of jax's compilation-cache key; the operator scopes inside
+    # (``exec/kernels.apply_op``) are not, the key strips names.  So
+    # the name says which generation of scopes a cached program
+    # carries: change it when scopes are added or renamed, or a stale
+    # cache hands back a program without them (benchmarks/TRACING.md).
+    mapped.__name__ = mapped.__qualname__ = "dryad_stage"
     return jax.jit(mapped)
 
 

@@ -1,0 +1,345 @@
+"""The program's spans on the profiler's clock (obs/span.py's
+``Span`` -> ``TraceAnnotation`` bridge) and the operator scopes on the
+device operations.
+
+ONE CPU ``jax.profiler`` session, recorded once a module, around a
+small ``order_by`` collect (fresh, then a requery), a two-stage plan
+and a ``from_text`` count; every test below reads that one trace and
+the context's event log.
+"""
+
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from dryad_tpu import DryadContext
+from dryad_tpu.obs import critpath
+
+ROWS = 1 << 16
+P = 8
+
+
+def _annotations(trace_dir):
+    """Every ``dryad:*`` event of the host plane: ``(name, start_ns,
+    end_ns, stats)``."""
+    found = []
+    for root, _dirs, files in os.walk(trace_dir):
+        found += [os.path.join(root, f) for f in files
+                  if f.endswith(".xplane.pb")]
+    assert len(found) == 1, found
+    data = jax.profiler.ProfileData.from_file(found[0])
+    out = []
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("dryad:"):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda a: a[1])
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    """The session: what ran, what it answered, the annotations the
+    profiler kept and the events the context kept."""
+    work = tmp_path_factory.mktemp("obs_profiler")
+    rng = np.random.default_rng(24)
+    table = {
+        "k": rng.integers(-(2**31), 2**31 - 1, ROWS).astype(np.int32),
+        "v": rng.standard_normal(ROWS).astype(np.float32),
+    }
+    text = work / "corpus.txt"
+    text.write_text(" ".join(f"w{i % 37}" for i in range(5000)))
+    ctx = DryadContext(num_partitions_=P)
+    metrics = ctx.executor.metrics
+
+    def counters():
+        return {n: metrics.total(n) for n in ("h2d_bytes", "d2h_bytes")}
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    trace_dir = str(work / "trace")
+    rec = {"ctx": ctx, "table": table}
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        sort = ctx.from_arrays(table).order_by([("k", False)])
+        c0 = counters()
+        rec["fresh"] = sort.collect()
+        c1 = counters()
+        rec["requery"] = sort.collect()
+        c2 = counters()
+        # several stages (an aggregate joined to a second input), so
+        # the executor has something to fuse
+        small = (table["k"] % 64).astype(np.int32)
+        rec["joined"] = (
+            ctx.from_arrays({"k": small})
+            .group_by("k", {"c": ("count", None)})
+            .join(ctx.from_arrays({"k": np.arange(-63, 64, dtype=np.int32)}), "k")
+            .collect()
+        )
+        rec["words"] = (
+            ctx.from_text(str(text))
+            .group_by("word", {"count": ("count", None)})
+            .collect()
+        )
+    finally:
+        jax.profiler.stop_trace()
+    rec["sort"] = sort
+    rec["fresh_counters"] = {n: c1[n] - c0[n] for n in c0}
+    rec["requery_counters"] = {n: c2[n] - c1[n] for n in c0}
+    rec["annotations"] = _annotations(trace_dir)
+    rec["spans"] = [e for e in ctx.events.events() if e["kind"] == "span"]
+    return rec
+
+
+def _by_name(recorded, name):
+    return [a for a in recorded["annotations"] if a[0] == name]
+
+
+def _job(recorded, index):
+    """The annotations of the ``index``-th collect: its root and
+    everything that reaches it by ``parent_id``."""
+    root = _by_name(recorded, "dryad:other:collect")[index]
+    parents = {a[3]["span_id"]: a[3]["parent_id"]
+               for a in recorded["annotations"]}
+
+    def under(span_id):
+        while span_id:
+            if span_id == root[3]["span_id"]:
+                return True
+            span_id = parents.get(span_id, 0)
+        return False
+
+    return root, [a for a in recorded["annotations"]
+                  if a is not root and under(a[3]["span_id"])]
+
+
+def test_the_answers_are_right(recorded):
+    order = np.argsort(recorded["table"]["k"], kind="stable")
+    for answer in (recorded["fresh"], recorded["requery"]):
+        np.testing.assert_array_equal(answer["k"], recorded["table"]["k"][order])
+        np.testing.assert_array_equal(answer["v"], recorded["table"]["v"][order])
+    assert sorted(recorded["words"]["count"]) == sorted(
+        np.bincount(np.arange(5000) % 37))
+    assert int(recorded["joined"]["c"].sum()) == ROWS
+
+
+def test_every_boundary_of_a_job_is_in_the_host_plane(recorded):
+    names = {a[0] for a in recorded["annotations"]}
+    assert {
+        "dryad:other:collect", "dryad:plan:lower", "dryad:plan:fuse",
+        "dryad:ingest:tokenize", "dryad:ingest:vocab", "dryad:ingest:bind",
+        "dryad:ingest:encode", "dryad:ingest:h2d",
+        "dryad:compile:input+order_by", "dryad:dispatch:input+order_by",
+        "dryad:readback:drain", "dryad:readback:fetch_wait",
+        "dryad:readback:fetch_copy", "dryad:decode:decode",
+    } <= names
+    # every phase is one of critpath's
+    assert {n.split(":")[1] for n in names} <= set(critpath.PHASES)
+
+
+def test_a_jobs_spans_nest_under_its_collect(recorded):
+    root, inside = _job(recorded, 0)
+    got = {a[0] for a in inside}
+    assert {
+        "dryad:plan:lower", "dryad:ingest:bind", "dryad:ingest:encode",
+        "dryad:ingest:h2d", "dryad:compile:input+order_by",
+        "dryad:dispatch:input+order_by", "dryad:readback:drain",
+        "dryad:readback:fetch_wait", "dryad:readback:fetch_copy",
+        "dryad:decode:decode",
+    } == got
+    for name, start, end, stats in inside:
+        assert root[1] <= start <= end <= root[2], name
+        assert stats["qid"] == root[3]["qid"]
+    by_id = {a[3]["span_id"]: a for a in inside}
+    h2d, = [a for a in inside if a[0] == "dryad:ingest:h2d"]
+    assert by_id[h2d[3]["parent_id"]][0] == "dryad:ingest:bind"
+    compiled, = [a for a in inside if a[0].startswith("dryad:compile:")]
+    assert by_id[compiled[3]["parent_id"]][0].startswith("dryad:dispatch:")
+    # the requery finds the table resident and the program compiled
+    _, again = _job(recorded, 1)
+    assert {a[0] for a in again} == got - {
+        "dryad:ingest:bind", "dryad:ingest:encode", "dryad:ingest:h2d",
+        "dryad:compile:input+order_by"}
+
+
+def test_bind_time_spans_have_no_parent_and_no_query(recorded):
+    for name in ("dryad:ingest:tokenize", "dryad:ingest:vocab"):
+        (_, _, _, stats), = _by_name(recorded, name)
+        assert stats["parent_id"] == 0 and "qid" not in stats
+    (_, _, _, stats), = _by_name(recorded, "dryad:ingest:tokenize")
+    assert stats["bytes"] == len(" ".join(f"w{i % 37}" for i in range(5000)))
+    (_, _, _, stats), = _by_name(recorded, "dryad:ingest:vocab")
+    assert stats["rows"] == 5000
+
+
+def test_every_annotation_has_its_span_event(recorded):
+    events = {e["span_id"]: e for e in recorded["spans"]}
+    assert len(recorded["annotations"]) >= 40
+    for name, start, end, stats in recorded["annotations"]:
+        ev = events[stats["span_id"]]
+        phase = critpath.phase_of(ev["name"], ev["cat"])
+        assert name == f"dryad:{phase}:{ev['name']}"
+        assert (ev["parent_id"] or 0) == stats["parent_id"]
+        assert ev["qid"] == stats.get("qid")
+        # one interval on two clocks
+        assert (end - start) * 1e-9 == pytest.approx(ev["dur"], abs=2e-3)
+    traced = {a[3]["span_id"] for a in recorded["annotations"]}
+    assert {e["span_id"] for e in recorded["spans"]} == traced
+
+
+def test_h2d_bytes_are_the_arrays_and_the_counter(recorded):
+    _, inside = _job(recorded, 0)
+    (_, _, _, stats), = [a for a in inside if a[0] == "dryad:ingest:h2d"]
+    table = recorded["table"]
+    # P partitions of ROWS / P rows: no padding, one validity byte a row
+    assert stats["bytes"] == table["k"].nbytes + table["v"].nbytes + ROWS
+    assert recorded["fresh_counters"]["h2d_bytes"] == stats["bytes"]
+    assert recorded["requery_counters"]["h2d_bytes"] == 0
+    encode = [a[3] for a in inside if a[0] == "dryad:ingest:encode"]
+    assert [s["rows"] for s in encode] == [ROWS, ROWS]
+    assert encode[-1]["capacity"] == ROWS
+
+
+def test_fetch_copy_bytes_are_the_arrays_and_the_counter(recorded):
+    for index, kind in ((0, "fresh_counters"), (1, "requery_counters")):
+        _, inside = _job(recorded, index)
+        (_, _, _, copy), = [a for a in inside
+                            if a[0] == "dryad:readback:fetch_copy"]
+        (_, _, _, decode), = [a for a in inside if a[0] == "dryad:decode:decode"]
+        assert copy["columns"] == 2
+        # int32 + float32 + the validity byte, for every slot
+        assert copy["bytes"] == 9 * copy["capacity"]
+        assert copy["bytes"] == recorded[kind]["d2h_bytes"]
+        assert decode["rows"] == ROWS == len(recorded["fresh"]["k"])
+        assert decode["capacity"] == copy["capacity"] >= ROWS
+
+
+def test_without_a_session_the_events_flow_and_the_answer_is_the_same(recorded):
+    ctx = recorded["ctx"]
+    before = len(ctx.events.events())
+    answer = recorded["sort"].collect()
+    for column in ("k", "v"):
+        np.testing.assert_array_equal(answer[column], recorded["fresh"][column])
+    new = [e for e in ctx.events.events()[before:] if e["kind"] == "span"]
+    assert [e["name"] for e in new] == [
+        "lower", "input+order_by", "drain", "fetch_wait", "fetch_copy",
+        "decode", "collect"]
+    root = new[-1]["span_id"]
+    assert all(e["parent_id"] == root for e in new[:-1])
+    copy = next(e for e in new if e["name"] == "fetch_copy")
+    assert copy["bytes"] == recorded["requery_counters"]["d2h_bytes"]
+
+
+def test_a_disabled_tracer_opens_no_annotation():
+    from dryad_tpu.obs.span import _NULL, Tracer
+
+    assert Tracer().span("h2d", cat="ingest", bytes=1) is _NULL
+    assert Tracer(None).current_id() is None
+
+
+def test_a_collect_is_no_longer_mostly_other(recorded):
+    events = recorded["ctx"].events.events()
+    root, _ = _job(recorded, 0)
+    fold = critpath.fold_query(events, root[3]["qid"])
+    assert fold.total_s > 0
+    assert fold.phases.get("other", 0.0) < 0.10 * fold.total_s
+    assert {"plan", "ingest", "readback", "decode"} <= set(fold.phases)
+    assert sum(fold.phases.values()) == pytest.approx(fold.total_s)
+
+
+def test_phase_table():
+    assert critpath.phase_of("lower", "plan") == "plan"
+    assert critpath.phase_of("decode", "decode") == "decode"
+    assert critpath.phase_of("collect", "job") == "other"
+    assert critpath.phase_of("h2d", "ingest") == "ingest"
+    assert critpath.phase_of("fetch_wait", "readback") == "readback"
+    assert critpath.phase_of("input+order_by", "compile") == "compile"
+    assert critpath.phase_of("input+order_by", "execute") == "dispatch"
+    assert "plan" in critpath.PHASES and "decode" in critpath.PHASES
+
+
+# -- operator scopes on the device operations -------------------------------
+
+def _plans(ctx, work):
+    rng = np.random.default_rng(7)
+    k = (rng.integers(0, 64, 4096) - 1).astype(np.int32)
+    v = rng.standard_normal(4096).astype(np.float32)
+    text = work / "scopes.txt"
+    text.write_text("a b c a b a " * 50)
+    return {
+        "sort": ctx.from_arrays({"k": k, "v": v}).order_by([("k", False)]),
+        "groupby": ctx.from_arrays({"k": k, "v": v}).group_by(
+            "k", {"c": ("count", None), "s": ("sum", "v")}),
+        "wordcount": ctx.from_text(str(text)).group_by(
+            "word", {"count": ("count", None)}).order_by(
+                [("count", True)]).take(2),
+    }
+
+
+INNER = {
+    "sort": {"dryad.exchange.layout", "dryad.exchange.collective",
+             "dryad.sort.splitters", "dryad.sort.carry"},
+    "groupby": {"dryad.exchange.layout", "dryad.exchange.collective",
+                "dryad.group_reduce.layout", "dryad.group_reduce.fold",
+                "dryad.sort.carry"},
+    "wordcount": {"dryad.string_code.probe", "dryad.pallas_bucket"},
+}
+
+
+@pytest.mark.parametrize("shape", sorted(INNER))
+def test_the_lowered_program_names_its_operators(shape, tmp_path, monkeypatch):
+    """Every kernel ``apply_op`` ran put ``dryad.<kind>`` into the
+    ``op_name`` of the compiled operations it produced, and the inner
+    scopes sit under their operator's."""
+    import re
+
+    from dryad_tpu.exec.executor import GraphExecutor
+    from dryad_tpu.plan.lower import lower
+
+    lowered = []
+    real = GraphExecutor._get_compiled
+
+    def spy(self, *args, **kwargs):
+        hit = real(self, *args, **kwargs)
+        fn = hit.fn
+
+        def lowering(*operands):
+            lowered.append(fn.lower(*operands).compile().as_text())
+            return fn(*operands)
+
+        hit.fn = lowering
+        return hit
+
+    monkeypatch.setattr(GraphExecutor, "_get_compiled", spy)
+    ctx = DryadContext(num_partitions_=4)
+    query = _plans(ctx, tmp_path)[shape]
+    query.collect()
+    graph = lower([query.node], ctx.config, ctx.dictionary, P=4)
+    kinds = {op.kind for stage in graph.stages for op in stage.ops}
+    assert len(lowered) == 1 and kinds
+    paths = re.findall(r'op_name="([^"]*)"', lowered[0])
+    scoped = [p for p in paths if "/dryad." in p]
+    for kind in kinds - {"project"}:  # picks columns: no operation
+        assert any(f"/dryad.{kind}/" in p + "/" for p in scoped), kind
+    for scope in INNER[shape]:
+        under = [p for p in scoped if f"/{scope}/" in p + "/"]
+        assert under, scope
+        # an inner scope lies inside an operator's scope
+        for p in under:
+            first = next(part for part in p.split("/")
+                         if part.startswith("dryad."))
+            assert first[len("dryad."):] in kinds, p
+    # what is left unnamed under shard_map is the stage's own plumbing
+    # (broadcasts and casts of its inputs, the overflow flag's psum)
+    plumbing = {p.rsplit("/", 1)[-1].split(".")[0]
+                for p in paths if "shard_map" in p and "/dryad." not in p}
+    assert plumbing <= {"shard_map", "broadcast", "convert_element_type",
+                        "gt", "psum", "or", "reduce_or", "ne", "reduce_sum",
+                        "concatenate", "squeeze", "reshape"}, plumbing
