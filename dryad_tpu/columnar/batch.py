@@ -155,11 +155,12 @@ class ColumnBatch:
 
         Sort-based compaction: key = !valid, stable, so valid rows keep
         their order at the front.  Invalid slots retain stale values but
-        their mask bits are off.
+        their mask bits are off.  The columns move as every sorted batch
+        does (``ops.sort.sort_carry``: riding the sort on TPU).
         """
-        order = jnp.argsort(jnp.logical_not(self.valid), stable=True)
-        data = {n: v[order] for n, v in self.data.items()}
-        return ColumnBatch(data, self.valid[order])
+        from dryad_tpu.ops.sort import sort_batch_by_operands
+
+        return sort_batch_by_operands(self, [])
 
     def take(self, order: jax.Array) -> "ColumnBatch":
         """Row gather by index array (caller manages mask semantics)."""

@@ -9,12 +9,26 @@ mesh devices with one ``all_to_all`` over ICI inside the compiled
 program.
 
 Static-shape strategy (XLA needs fixed shapes): each source device
-scatters its rows into a ``(P, B)`` send buffer — ``B`` is the
+lays its rows out in a ``(P, B)`` send buffer — ``B`` is the
 per-destination bucket capacity, uniform expectation times a slack
 factor — with a row-drop *overflow* flag when a bucket fills.  The
 executor treats overflow as a retryable fault and re-runs the stage with
 a larger ``B`` from a bounded shape palette (the adaptive analog of
 ``DrDynamicDistributor.h:26``'s data-size-driven fan-out).
+
+How rows move: one stable ``lax.sort`` by destination puts each
+bucket's rows side by side, and the columns go through that sort with
+the key (``ops.sort.sort_carry``: on the TPU as extra sort operands,
+elsewhere gathered by the sorted row index; the same permutation
+either way); one scatter a column then drops every row at
+``dest * B + position in bucket``.  ``resize`` compacts the same way,
+a stable sort on ``~valid`` alone with the columns carried.  No
+column is gathered by a sorted ``iota``: XLA's TPU ``gather`` runs at
+28 ns an element, which made that form 92.5% of the device time of a
+2^25-row ``order_by`` and 73% of a four-chip ``group_by``, against
+7.5% for the sort that carries every column of 2^26 slots
+(``PERF_LEDGER.jsonl``, PR 24, ``sort-1c`` and ``groupby-4c``:
+``gather_dev_share`` 77.3% and 61.8%).
 
 Under whole-DAG fusion (``plan/fuse.py``) these exchanges also serve as
 the SEAMS between fused member stages: the whole multi-stage region
@@ -35,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from dryad_tpu.columnar.batch import ColumnBatch
+from dryad_tpu.ops.sort import sort_carry
 
 
 def bucket_capacity(capacity: int, num_partitions: int, slack: float) -> int:
@@ -68,8 +83,9 @@ def row_bytes(batch: ColumnBatch) -> int:
 
 
 # Operator scopes (``jax.named_scope``) inside an exchange: the bucket
-# layout (sort by destination, scatter into send buffers) and the
-# collective apart, so a device trace splits one from the other.
+# layout (sort by destination with the columns carried, scatter into
+# send buffers) and the collective apart, so a device trace splits one
+# from the other.
 LAYOUT_SCOPE = "dryad.exchange.layout"
 COLLECTIVE_SCOPE = "dryad.exchange.collective"
 
@@ -77,13 +93,19 @@ COLLECTIVE_SCOPE = "dryad.exchange.collective"
 def _bucket_layout(batch: ColumnBatch, dest: jax.Array, P: int, B: int):
     """Rows stably sorted by destination, so each bucket's rows are
     contiguous: ``(sorted batch, sorted dest, position within bucket,
-    ships, overflow)``.  Invalid rows take the sentinel ``P`` and never
-    ship; a valid row past its bucket's ``B`` sets ``overflow``."""
+    ships, overflow)``.  Invalid rows take the sentinel ``P`` (after
+    every valid row, in their own order) and never ship; a valid row
+    past its bucket's ``B`` sets ``overflow``.  The columns ride the
+    sort (``ops.sort.sort_carry``)."""
     cap = batch.capacity
-    dest = jnp.where(batch.valid, dest, P)
-    operands = (dest, jnp.arange(cap, dtype=jnp.int32))
-    dsorted, order = jax.lax.sort(operands, num_keys=1, is_stable=True)
-    sb = batch.take(order)
+    names = batch.columns
+    svalid, (dsorted,), carried = sort_carry(
+        [jnp.where(batch.valid, dest, P)],
+        batch.valid,
+        [batch.data[n] for n in names],
+    )
+    sb = ColumnBatch(dict(zip(names, carried)), svalid)
+    dsorted = dsorted.astype(jnp.int32)
 
     counts = jnp.bincount(dsorted, length=P + 1)[:P]
     offsets = jnp.concatenate(
@@ -244,7 +266,9 @@ def exchange_staged(
 def resize(
     batch: ColumnBatch, capacity: int
 ) -> Tuple[ColumnBatch, jax.Array]:
-    """Compact valid rows to the front and resize to ``capacity``.
+    """Compact valid rows to the front (``ColumnBatch.compact``: a
+    stable sort on ``~valid`` with the columns carried) and resize to
+    ``capacity``.
 
     Returns (batch, overflow) — overflow set when valid rows exceed the
     new capacity (rows beyond it are dropped; the executor retries with

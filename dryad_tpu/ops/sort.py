@@ -27,13 +27,10 @@ def sort_order_by_operands(
 ) -> jax.Array:
     """Stable permutation: valid rows first, lexicographic by uint32 operands.
 
-    Prefer :func:`sort_batch_by_operands` / :func:`sort_carry` when the
-    goal is sorted DATA: applying this permutation with ``take()``
-    costs one gather per column (~42 ms/column at n=4M on v5e,
-    `probe_sortops.py`), while carrying the columns through
-    ``lax.sort`` as extra operands is free (~14.5 ms total vs 99 ms
-    for sort-index + 2 gathers).  Use the permutation form only when
-    the order must be applied to something that cannot ride the sort.
+    For an order that must be applied to something that cannot ride the
+    sort.  Sorted DATA comes from :func:`sort_carry` /
+    :func:`sort_batch_by_operands`, which every permutation of a whole
+    batch goes through.
     """
     n = valid.shape[0]
     ops: List[jax.Array] = [jnp.logical_not(valid).astype(jnp.uint32)]
@@ -72,36 +69,32 @@ def sort_carry(
     sort-index-then-gather for 2 payload columns at n=4M
     (`probe_sortops.py`: 14.5 ms vs 99 ms); elsewhere the payload is
     gathered by the sorted row index (cheaper off-TPU, bench round-4).
+    ``lax.sort`` operands share one shape, so a payload with trailing
+    dimensions never rides: it is gathered by one carried row index,
+    which is sorted only when such a payload is present.
     """
     inv = jnp.logical_not(valid).astype(jnp.uint32)
     ops = (inv,) + tuple(o.astype(jnp.uint32) for o in operands)
-    if not carry or _carry_profitable():
-        res = jax.lax.sort(
-            ops + tuple(carry), num_keys=len(ops), is_stable=True
-        )
-        return (
-            res[0] == 0,
-            list(res[1:len(ops)]),
-            list(res[len(ops):]),
-        )
-    n = valid.shape[0]
-    idx = jnp.arange(n, dtype=jnp.int32)
-    res = jax.lax.sort(ops + (idx,), num_keys=len(ops), is_stable=True)
-    order = res[-1]
+    profitable = bool(carry) and _carry_profitable()
+    rides = [profitable and c.ndim == 1 for c in carry]
+    riders = tuple(c for c, r in zip(carry, rides) if r)
+    if not all(rides):  # the row index, last, for what cannot ride
+        riders += (jnp.arange(valid.shape[0], dtype=jnp.int32),)
+    res = jax.lax.sort(ops + riders, num_keys=len(ops), is_stable=True)
+    rode, order = iter(res[len(ops):]), res[-1]
     return (
         res[0] == 0,
         list(res[1:len(ops)]),
-        [c[order] for c in carry],
+        [next(rode) if r else c[order] for c, r in zip(carry, rides)],
     )
 
 
 def sort_batch_by_operands(
     batch: ColumnBatch, operands: Sequence[jax.Array]
 ) -> ColumnBatch:
-    """Sort a whole batch by uint32 operands (valid rows first) — the
-    data-movement-optimal replacement for
-    ``batch.take(sort_order_by_operands(...))`` (strategy per
-    :func:`_carry_profitable`)."""
+    """Sort a whole batch by uint32 operands (valid rows first); with
+    no operands, stable compaction.  The one way a stable permutation
+    is applied to a batch (data movement per :func:`sort_carry`)."""
     names = batch.columns
     valid, _, carried = sort_carry(
         operands, batch.valid, [batch.data[n] for n in names]

@@ -1,17 +1,28 @@
 """Shuffle exchange tests on the virtual 8-device mesh."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dryad_tpu.columnar.batch import ColumnBatch
 from dryad_tpu.columnar.schema import ColumnType, Schema
 from dryad_tpu.ops.hash import partition_ids
 from dryad_tpu.ops.segmented import AggSpec, group_reduce
-from dryad_tpu.ops.shuffle import bucket_capacity, exchange, resize
+from dryad_tpu.ops import shuffle as SH
+from dryad_tpu.ops import sort as SORT
+from dryad_tpu.ops.shuffle import (
+    bucket_capacity,
+    exchange,
+    exchange_staged,
+    resize,
+)
 from dryad_tpu.parallel.distribute import from_host_table, to_host_table
 from dryad_tpu.parallel.mesh import AXIS
 from dryad_tpu.parallel.stage import compile_stage
+from dryad_tpu.plan.xchgplan import plan_exchange
 
 from oracle import check
 
@@ -105,3 +116,153 @@ def test_resize_shrink_and_overflow():
     big, ovf2 = resize(b, 32)
     assert not bool(ovf2)
     assert big.capacity == 32 and int(big.count()) == 10
+
+
+# -- a batch's rows ride the sort that permutes them (PR 25) ------------------
+#
+# The contract: whichever way the columns move (carried through
+# ``lax.sort`` as on the TPU, or gathered by a sorted row index as on
+# the CPU), every slot of the output, the invalid ones included, holds
+# what ``batch.take(order)`` put there before PR 25, and the overflow
+# flag is the same.
+
+_P, _CAP = 8, 64
+_B = bucket_capacity(_CAP, _P, 1.5)  # 12: a bucket fills in the all-valid batch only
+
+
+def _take_order_layout(batch, dest, P, B):
+    """``_bucket_layout`` as it was before PR 25: sort ``(dest, iota)``,
+    then one gather a column by the sorted ``iota``."""
+    cap = batch.capacity
+    dest = jnp.where(batch.valid, dest, P)
+    dsorted, order = jax.lax.sort(
+        (dest, jnp.arange(cap, dtype=jnp.int32)), num_keys=1, is_stable=True
+    )
+    sb = batch.take(order)
+    counts = jnp.bincount(dsorted, length=P + 1)[:P]
+    offsets = jnp.concatenate(
+        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]]
+    )
+    within = jnp.arange(cap, dtype=jnp.int32) - jnp.where(
+        dsorted < P, offsets[jnp.clip(dsorted, 0, P - 1)], 0
+    ).astype(jnp.int32)
+    in_range = (dsorted < P) & (within < B)
+    overflow = jnp.any((dsorted < P) & (within >= B))
+    return sb, dsorted, within, in_range, overflow
+
+
+def _take_order_compact(batch):
+    """``ColumnBatch.compact`` as it was before PR 25."""
+    return batch.take(jnp.argsort(jnp.logical_not(batch.valid), stable=True))
+
+
+def _permuted_batch(kind):
+    """``_P * _CAP`` rows with stale values under the invalid slots."""
+    n = _P * _CAP
+    rng = np.random.default_rng(25)
+    data = {
+        "k": jnp.asarray(rng.integers(-50, 50, n).astype(np.int32)),
+        "v": jnp.asarray(rng.standard_normal(n).astype(np.float32)),
+        "f": jnp.asarray(rng.random(n) < 0.5),
+    }
+    if kind == "col2d":
+        data["m"] = jnp.asarray(
+            rng.integers(0, 1 << 20, (n, 3)).astype(np.uint32)
+        )
+    valid = {
+        "all_valid": np.ones(n, np.bool_),
+        "half_valid": rng.random(n) < 0.5,
+        "none_valid": np.zeros(n, np.bool_),
+        "col2d": rng.random(n) < 0.7,
+    }[kind]
+    return ColumnBatch(data, jnp.asarray(valid))
+
+
+def _run_exchange(mesh, batch, staged):
+    def stage(sharded, _):
+        (b,) = sharded
+        dest = partition_ids([b["k"]], _P)
+        if staged:
+            out, ovf = exchange_staged(
+                b, dest, _P, _B, (AXIS,), plan_exchange(_P, 2, 1)
+            )
+        else:
+            out, ovf = exchange(b, dest, _P, _B, AXIS)
+        return (out,), (ovf,)
+
+    (out,), (ovf,) = compile_stage(mesh, stage)((batch,), ())
+    return out, bool(ovf)
+
+
+def _assert_same_slots(got, want):
+    np.testing.assert_array_equal(np.asarray(got.valid), np.asarray(want.valid))
+    assert got.columns == want.columns
+    for name in want.columns:
+        np.testing.assert_array_equal(
+            np.asarray(got[name]), np.asarray(want[name]), err_msg=name
+        )
+
+
+_RESIZE_TO = {"resize_equal": _P * _CAP, "resize_shrink": 100,
+              "resize_grow": _P * _CAP + 40}
+
+
+@pytest.mark.parametrize("kind", ["all_valid", "half_valid", "none_valid", "col2d"])
+@pytest.mark.parametrize(
+    "path", ["exchange", "exchange_staged", "compact", *_RESIZE_TO]
+)
+@pytest.mark.parametrize("carry", [True, False], ids=["carry", "gather"])
+def test_permuted_batch_equals_take_order(mesh8, monkeypatch, carry, path, kind):
+    batch = _permuted_batch(kind)
+    monkeypatch.setattr(SORT, "_carry_profitable", lambda: carry)
+    if path.startswith("exchange"):
+        staged = path == "exchange_staged"
+        got, got_ovf = _run_exchange(mesh8, batch, staged)
+        monkeypatch.setattr(SH, "_bucket_layout", _take_order_layout)
+        want, want_ovf = _run_exchange(mesh8, batch, staged)
+        assert want_ovf == (kind == "all_valid")  # rows drop at _B there
+    elif path == "compact":
+        got, got_ovf = batch.compact(), None
+        want, want_ovf = _take_order_compact(batch), None
+    else:
+        got, got_ovf = resize(batch, _RESIZE_TO[path])
+        monkeypatch.setattr(ColumnBatch, "compact", _take_order_compact)
+        want, want_ovf = resize(batch, _RESIZE_TO[path])
+        assert bool(want_ovf) == (
+            int(np.count_nonzero(np.asarray(batch.valid))) > _RESIZE_TO[path]
+        )
+        got_ovf, want_ovf = bool(got_ovf), bool(want_ovf)
+    assert got_ovf == want_ovf
+    _assert_same_slots(got, want)
+
+
+def _gathers_by_result_rows(mesh, carry, monkeypatch):
+    """Leading dimension of every ``stablehlo.gather`` result that reads
+    a table of more than ``_P`` rows, in ``exchange`` + ``resize``
+    lowered over 1-D columns."""
+    monkeypatch.setattr(SORT, "_carry_profitable", lambda: carry)
+    batch = _permuted_batch("half_valid")
+
+    def stage(sharded, _):
+        (b,) = sharded
+        out, o1 = exchange(b, partition_ids([b["k"]], _P), _P, _B, AXIS)
+        out, o2 = resize(out, 2 * _CAP)
+        return (out,), (o1 | o2,)
+
+    text = compile_stage(mesh, stage).lower((batch,), ()).as_text()
+    found = re.findall(
+        r'stablehlo\.gather.*:\s*\(tensor<(\d+)[x>].*->\s*tensor<(\d+)[x>]',
+        text,
+    )
+    return [int(rows) for table, rows in found if int(table) > _P]
+
+
+def test_carried_exchange_and_resize_lower_to_no_column_gather(
+    mesh8, monkeypatch
+):
+    """With the carry forced, the program gathers no column: the one
+    ``gather`` left reads the ``_P`` bucket offsets."""
+    capacities = {_CAP, _P * _B}
+    gathered = _gathers_by_result_rows(mesh8, False, monkeypatch)
+    assert capacities <= set(gathered), gathered  # the check can see them
+    assert _gathers_by_result_rows(mesh8, True, monkeypatch) == []

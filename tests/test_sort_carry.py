@@ -68,13 +68,26 @@ def test_sort_carry_invalid_rows_last(rng):
     assert got[:nv].all() and not got[nv:].any()
 
 
-def test_sort_batch_by_operands_matches_take(rng):
+@pytest.mark.parametrize(
+    "carry,wide",
+    [(None, False), (True, False), (True, True), (False, True)],
+    ids=["platform", "carry", "carry+2d", "gather+2d"],
+)
+def test_sort_batch_by_operands_matches_take(rng, monkeypatch, carry, wide):
+    """Either data movement (forced, or the platform's own), with and
+    without a column that cannot ride the sort (trailing dimension)."""
+    from dryad_tpu.ops import sort as SORT
+
+    if carry is not None:
+        monkeypatch.setattr(SORT, "_carry_profitable", lambda: carry)
     n = 2048
     data = {
         "k": jnp.asarray(rng.integers(-1000, 1000, n).astype(np.int32)),
         "v": jnp.asarray(rng.standard_normal(n).astype(np.float32)),
         "b": jnp.asarray(rng.random(n) < 0.5),
     }
+    if wide:
+        data["m"] = jnp.asarray(rng.integers(0, 99, (n, 2, 3)).astype(np.int32))
     valid = jnp.asarray(rng.random(n) < 0.9)
     b = ColumnBatch(data, valid)
     ops = [to_sortable_u32(b.data["k"]), to_sortable_u32(b.data["v"])]

@@ -234,6 +234,53 @@ def test_sort_carry_on_chip(jaxmod):
     np.testing.assert_array_equal(np.asarray(spf), np.asarray(pf)[order])
 
 
+def test_exchange_carry_on_chip(jaxmod):
+    """``order_by`` and a sort-path ``group_by`` through DryadContext
+    on the chip, where the exchange layout and ``resize`` carry the
+    columns through ``lax.sort`` (the default here, and only here),
+    against the LocalDebug interpreter (``exec/localdebug.py``)."""
+    from dryad_tpu import DryadContext
+    from dryad_tpu.ops.sort import _carry_profitable
+    from dryad_tpu.plan.lower import lower
+
+    assert _carry_profitable()
+    rng = np.random.default_rng(25)
+    n = 1 << 16
+    tbl = {
+        # one negative key keeps the dense rewrite off: the hash exchange
+        "k": (rng.integers(0, 3000, n) - 1).astype(np.int32),
+        "v": rng.standard_normal(n).astype(np.float32),
+    }
+    ctx, ref = DryadContext(), DryadContext(local_debug=True)
+
+    def queries(c):
+        t = c.from_arrays(tbl)
+        return (
+            t.order_by(["k", "v"]),
+            t.group_by("k", {"c": ("count", None), "s": ("sum", "v")}),
+        )
+
+    kinds = [
+        {op.kind for st in lower([q.node], ctx.config, ctx.dictionary).stages
+         for op in st.ops}
+        for q in queries(ctx)
+    ]
+    assert {"exchange_range", "resize"} <= kinds[0], kinds[0]
+    assert {"exchange_hash", "resize"} <= kinds[1], kinds[1]
+
+    (sorted_, grouped), (want_sorted, want_grouped) = (
+        [q.collect() for q in queries(c)] for c in (ctx, ref)
+    )
+    for name in ("k", "v"):  # same rows, same order, payload on its key
+        np.testing.assert_array_equal(sorted_[name], want_sorted[name])
+    at, want_at = np.argsort(grouped["k"]), np.argsort(want_grouped["k"])
+    np.testing.assert_array_equal(grouped["k"][at], want_grouped["k"][want_at])
+    np.testing.assert_array_equal(grouped["c"][at], want_grouped["c"][want_at])
+    np.testing.assert_allclose(
+        grouped["s"][at], want_grouped["s"][want_at], rtol=1e-4, atol=1e-4
+    )
+
+
 def _split_bf16_bound(k, v, K):
     """Per-bucket error bound of 2-term split-bf16 float sums: ~2^-16
     per ELEMENT, so it scales with the bucket's sum of |v|."""
