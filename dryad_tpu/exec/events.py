@@ -99,6 +99,9 @@ EVENT_KINDS: Dict[str, str] = {
     "span": "closed hierarchical span; name/cat/span_id/parent_id/dur",
     "metrics": "counter/histogram registry snapshot; counters/hists",
     "xla_compile": "stage (re)compiled; stage/key/trace_s/compile_s",
+    "join_plan": "one join kernel's trace-time decision, once a compile; "
+                 "strategy/est_right/broadcast_limit/out_capacity/"
+                 "left_capacity/right_capacity",
     "telemetry_merged": "driver absorbed worker span/counter batches",
     # -- diagnosis / flight recorder (obs.diagnose / exec.events) ---------
     "resource_sample": "continuous telemetry sample; hbm/rss/probes",
@@ -269,6 +272,11 @@ EVENT_PAYLOADS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
     ),
     "metrics": ((), ("counters", "hists")),
     "xla_compile": (("compile_s", "key", "stage", "trace_s"), ("qid",)),
+    "join_plan": (
+        ("broadcast_limit", "est_right", "key", "left_capacity",
+         "out_capacity", "right_capacity", "stage", "strategy"),
+        ("qid",),
+    ),
     "telemetry_merged": (("events", "offsets"), ()),
     "process_failed": (("computer", "error", "process"), ()),
     "process_stranded": (("computer", "process"), ()),

@@ -166,11 +166,11 @@ class _CompileTimed:
 
     __slots__ = (
         "fn", "_exec", "_name", "_key", "_build_s", "_pending",
-        "xchg_rounds",
+        "xchg_rounds", "join_plans",
     )
 
     def __init__(self, fn, executor, name, key_hash, build_s,
-                 xchg_rounds=None):
+                 xchg_rounds=None, join_plans=None):
         self.fn = fn
         self._exec = executor
         self._name = name
@@ -181,6 +181,10 @@ class _CompileTimed:
         # the stage builder's cell (kernels._exchange): one dict per
         # round, emitted as exchange_round events on every dispatch.
         self.xchg_rounds = xchg_rounds if xchg_rounds is not None else []
+        # What each join kernel of the stage decided at trace time
+        # (kernels._apply_join_strategy): emitted as join_plan events
+        # by the first call, which is the one that traces.
+        self.join_plans = join_plans if join_plans is not None else []
 
     def __call__(self, *args):
         if not self._pending:
@@ -198,6 +202,11 @@ class _CompileTimed:
             qid=tracectx.current_qid(),
             trace_s=round(self._build_s, 6), compile_s=round(dt, 6),
         )
+        for plan in self.join_plans:
+            ex.events.emit(
+                "join_plan", stage=self._name, key=self._key,
+                qid=tracectx.current_qid(), **plan,
+            )
         return out
 
 
@@ -413,24 +422,25 @@ class GraphExecutor:
             axes = mesh_axes(self.mesh)
             sizes = tuple(self.mesh.shape[a] for a in axes)
             cell: List[Dict[str, int]] = []
+            joins: List[Dict[str, Any]] = []
             if isinstance(run_stage, FusedStage):
                 fn = build_fused_fn(
                     run_stage, self.P, self.config.shuffle_slack, boost,
                     axes, sizes, operand_objs=objs,
-                    window=window, xchg_cell=cell,
+                    window=window, xchg_cell=cell, join_cell=joins,
                 )
                 compiled = compile_fused(self.mesh, fn)
             else:
                 fn = build_stage_fn(
                     run_stage, self.P, self.config.shuffle_slack, boost,
                     axes, sizes, operand_objs=objs,
-                    window=window, xchg_cell=cell,
+                    window=window, xchg_cell=cell, join_cell=joins,
                 )
                 compiled = compile_stage(self.mesh, fn)
             hit = _CompileTimed(
                 compiled, self, run_stage.name,
                 _lowering_key_hash(key), time.monotonic() - t0,
-                xchg_rounds=cell,
+                xchg_rounds=cell, join_plans=joins,
             )
             self._compiled[key] = hit
         return hit

@@ -86,6 +86,10 @@ class StageContext:
         # _exchange at trace time and surfaced by the executor as
         # exchange_round events (no device readback involved).
         self.xchg_log: List[Dict[str, int]] = []
+        # One record a join kernel of what ``_apply_join_strategy``
+        # decided at trace time; the executor emits each as a
+        # ``join_plan`` event, once a compile.
+        self.join_log: List[Dict[str, Any]] = []
         self.slots: Dict[int, ColumnBatch] = {}
         self.entry_caps: Dict[int, int] = {}
         # id(param object) -> tuple of traced operand arrays (bound
@@ -619,15 +623,18 @@ def _co_partition_for_join(ctx: StageContext, p) -> None:
 
 def _apply_join_strategy(ctx: StageContext, p) -> int:
     """Run the chosen placement (broadcast the right side, or the
-    deferred co-partition exchanges) and return the capacity base for
-    sizing candidate-pair buffers.  The base uses PRE-broadcast sizes:
-    replicating the right side multiplies its capacity by P but not the
-    match count."""
+    deferred co-partition exchanges) and return the capacity of the
+    candidate-pair buffer: ``expansion`` x ``boost`` x the larger side's
+    capacity.  That base uses PRE-broadcast sizes: replicating the
+    right side multiplies its capacity by P but not the match count.
+    What was decided goes on ``ctx.join_log``."""
     base = max(
         ctx.slots[p["left_slot"]].capacity, ctx.slots[p["right_slot"]].capacity
     )
+    broadcast = False
     if "strategy" in p:
-        if _join_strategy(ctx, p, ctx.slots[p["right_slot"]]):
+        broadcast = _join_strategy(ctx, p, ctx.slots[p["right_slot"]])
+        if broadcast:
             right = ctx.slots[p["right_slot"]]
             est = p.get("est_right")
             if est is not None:
@@ -648,14 +655,23 @@ def _apply_join_strategy(ctx: StageContext, p) -> int:
                 ctx.slots[p["left_slot"]].capacity,
                 ctx.slots[p["right_slot"]].capacity,
             )
-    return base
+    out_cap = _round8(base * p["expansion"] * ctx.boost)
+    ctx.join_log.append(dict(
+        strategy="broadcast" if broadcast else "shuffle",
+        est_right=p.get("est_right"),
+        broadcast_limit=p.get("broadcast_limit"),
+        out_capacity=out_cap,
+        # as the join kernel receives them, after the placement
+        left_capacity=ctx.slots[p["left_slot"]].capacity,
+        right_capacity=ctx.slots[p["right_slot"]].capacity,
+    ))
+    return out_cap
 
 
 def _k_join(ctx: StageContext, p) -> None:
-    base = _apply_join_strategy(ctx, p)
+    out_cap = _apply_join_strategy(ctx, p)
     left = ctx.slots[p["left_slot"]]
     right = ctx.slots[p["right_slot"]]
-    out_cap = _round8(base * p["expansion"] * ctx.boost)
     if p.get("outer"):
         out, ovf = J.hash_join_outer(
             left, right, p["left_keys"], p["right_keys"], out_cap,
@@ -670,10 +686,9 @@ def _k_join(ctx: StageContext, p) -> None:
 
 
 def _k_semi(ctx: StageContext, p) -> None:
-    base = _apply_join_strategy(ctx, p)
+    cap = _apply_join_strategy(ctx, p)
     left = ctx.slots[p["left_slot"]]
     right = ctx.slots[p["right_slot"]]
-    cap = _round8(base * p["expansion"] * ctx.boost)
     mask, ovf = J.exists_mask(
         left, right, p["left_keys"], p["right_keys"], cap
     )
@@ -691,10 +706,9 @@ def _k_concat(ctx: StageContext, p) -> None:
 
 
 def _k_group_join_count(ctx: StageContext, p) -> None:
-    base = _apply_join_strategy(ctx, p)
+    cap = _apply_join_strategy(ctx, p)
     left = ctx.slots[p["left_slot"]]
     right = ctx.slots[p["right_slot"]]
-    cap = _round8(base * p["expansion"] * ctx.boost)
     counts, ovf = J.group_join_counts(
         left, right, p["left_keys"], p["right_keys"], cap
     )
@@ -706,10 +720,9 @@ def _k_join_ranked(ctx: StageContext, p) -> None:
     """Inner join emitting a group-local match rank (full GroupJoin's
     enumerable group, reference ``DryadLinqQueryable.cs`` GroupJoin
     result-selector overloads)."""
-    base = _apply_join_strategy(ctx, p)
+    out_cap = _apply_join_strategy(ctx, p)
     left = ctx.slots[p["left_slot"]]
     right = ctx.slots[p["right_slot"]]
-    out_cap = _round8(base * p["expansion"] * ctx.boost)
     operands_fn = p.get("operands_fn")
     operands = operands_fn(right) if operands_fn is not None else ()
     out, ovf = J.hash_join_ranked(
@@ -1091,7 +1104,8 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
                    axis_sizes: "Tuple[int, ...]" = (),
                    operand_objs: "Tuple[Any, ...]" = (),
                    window: int = 0,
-                   xchg_cell: "List[Dict[str, int]]" = None):
+                   xchg_cell: "List[Dict[str, int]]" = None,
+                   join_cell: "List[Dict[str, Any]]" = None):
     """Compose a whole fused REGION (``plan.fuse.FusedStage``) into one
     per-partition function: the member stage fns chain device-resident
     — member i's output batches feed member j's slots directly in HBM,
@@ -1117,15 +1131,17 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
         tuple(stage_operand_objs(m)) if operand_objs else ()
         for m in members
     ]
-    # Per-member exchange-round accounting cells; each member fn
-    # rewrites its own cell idempotently at trace time, and the region
-    # fn flattens them in member order into the caller's cell.
+    # Per-member exchange-round accounting and join-plan cells; each
+    # member fn rewrites its own cells idempotently at trace time, and
+    # the region fn flattens them in member order into the caller's.
     member_cells = [[] for _ in members]
+    member_joins = [[] for _ in members]
     member_fns = [
         build_stage_fn(
             m, P, slack, boost, axes, axis_sizes,
             operand_objs=member_objs[i],
             window=window, xchg_cell=member_cells[i],
+            join_cell=member_joins[i],
         )
         for i, m in enumerate(members)
     ]
@@ -1165,6 +1181,8 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
         )
         if xchg_cell is not None:
             xchg_cell[:] = [r for c in member_cells for r in c]
+        if join_cell is not None:
+            join_cell[:] = [r for c in member_joins for r in c]
         return region_outs, (overflow, miss)
 
     return fn
@@ -1175,7 +1193,8 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
                    axis_sizes: "Tuple[int, ...]" = (),
                    operand_objs: "Tuple[Any, ...]" = (),
                    window: int = 0,
-                   xchg_cell: "List[Dict[str, int]]" = None):
+                   xchg_cell: "List[Dict[str, int]]" = None,
+                   join_cell: "List[Dict[str, Any]]" = None):
     """Compose the stage's ops into one per-partition function.
 
     ``operand_objs``: the stage's OPERAND-registered param objects (in
@@ -1212,6 +1231,8 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
             # Idempotent rewrite (not append): a retrace must not
             # double-count the static accounting.
             xchg_cell[:] = list(ctx.xchg_log)
+        if join_cell is not None:
+            join_cell[:] = list(ctx.join_log)
         return outs, (overflow, miss)
 
     return fn

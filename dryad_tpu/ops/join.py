@@ -33,6 +33,7 @@ def _suffixed(phys_name: str, suffix: str) -> str:
     return f"{phys_name}{suffix}"
 
 
+@jax.named_scope("dryad.join.probe")
 def _probe_ranges(
     left: ColumnBatch,
     right: ColumnBatch,
@@ -90,6 +91,34 @@ def _expand_pairs(
     return li, ri, pair_valid, overflow, offsets
 
 
+@jax.named_scope("dryad.join.materialize")
+def _materialize_pairs(
+    left: ColumnBatch,
+    rs: ColumnBatch,
+    right_keys: Sequence[str],
+    li: jax.Array,
+    ri: jax.Array,
+    suffix: str,
+) -> Tuple[Dict[str, jax.Array], Dict[str, str]]:
+    """Gather the pair slots' columns: every left column by ``li``,
+    every right column but the keys by ``ri`` (they equal the left's).
+
+    Returns (data, right_out): ``right_out`` maps a gathered right
+    column's name to its name in ``data`` (a name clashing with a left
+    column's takes ``suffix``)."""
+    data: Dict[str, jax.Array] = {}
+    for name, col in left.data.items():
+        data[name] = col[li]
+    rk = set(right_keys)
+    right_out: Dict[str, str] = {}
+    for name, col in rs.data.items():
+        if name in rk:
+            continue
+        right_out[name] = _suffixed(name, suffix) if name in data else name
+        data[right_out[name]] = col[ri]
+    return data, right_out
+
+
 def hash_join(
     left: ColumnBatch,
     right: ColumnBatch,
@@ -106,20 +135,12 @@ def hash_join(
     """
     rs, lhash, start, counts = _probe_ranges(left, right, left_keys, right_keys)
     li, ri, pair_valid, overflow, _ = _expand_pairs(start, counts, out_capacity)
-
-    data: Dict[str, jax.Array] = {}
-    for name, col in left.data.items():
-        data[name] = col[li]
-    rk = set(right_keys)
-    for name, col in rs.data.items():
-        if name in rk:
-            continue
-        data[_suffixed(name, suffix) if name in data else name] = col[ri]
-
+    data, _ = _materialize_pairs(left, rs, right_keys, li, ri, suffix)
     valid = _exact_pair_match(left, rs, left_keys, right_keys, li, ri, pair_valid)
     return ColumnBatch(data, valid), overflow
 
 
+@jax.named_scope("dryad.join.exact")
 def _exact_pair_match(
     left: ColumnBatch,
     rs: ColumnBatch,
@@ -168,19 +189,16 @@ def hash_join_outer(
     matched = _exact_per_left(li, exact, left.capacity)
     unmatched = left.valid & (matched == 0)
 
-    rk = set(right_keys)
-    data: Dict[str, jax.Array] = {}
+    data, right_out = _materialize_pairs(left, rs, right_keys, li, ri, suffix)
     for name, col in left.data.items():
-        data[name] = jnp.concatenate([col[li], col])
-    for name, col in rs.data.items():
-        if name in rk:
-            continue
-        out_name = _suffixed(name, suffix) if name in data else name
+        data[name] = jnp.concatenate([data[name], col])
+    for name, out_name in right_out.items():
+        col = rs.data[name]
         dflt = right_defaults.get(name, jnp.zeros((), col.dtype))
         tail = jnp.broadcast_to(
             jnp.asarray(dflt, col.dtype), (left.capacity,) + col.shape[1:]
         )
-        data[out_name] = jnp.concatenate([col[ri], tail])
+        data[out_name] = jnp.concatenate([data[out_name], tail])
     valid = jnp.concatenate([exact, unmatched])
     return ColumnBatch(data, valid), overflow
 
@@ -286,14 +304,7 @@ def hash_join_ranked(
         # the boost-widened window.
         exact = exact & (rank < jnp.int32(rank_limit))
 
-    data: Dict[str, jax.Array] = {}
-    for name, col in left.data.items():
-        data[name] = col[li]
-    rk = set(right_keys)
-    for name, col in rs.data.items():
-        if name in rk:
-            continue
-        data[_suffixed(name, suffix) if name in data else name] = col[ri]
+    data, _ = _materialize_pairs(left, rs, right_keys, li, ri, suffix)
     data[rank_name] = rank
     return ColumnBatch(data, exact), overflow
 

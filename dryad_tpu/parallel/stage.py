@@ -25,6 +25,18 @@ from jax.sharding import Mesh, PartitionSpec as P
 from dryad_tpu.parallel.mesh import mesh_axes
 
 
+# The stage program's name (``jit_<name>``: the profiler's ``XLA
+# Modules`` line, the head of every operation's name path) is part of
+# jax's compilation-cache key; the operator scopes inside
+# (``exec/kernels.apply_op`` and the kernels' inner scopes) are not,
+# the key strips names.  So the name says which generation of scopes a
+# cached program carries: count it up here when a scope is added or
+# renamed anywhere, or a stale cache hands back a program without them
+# (benchmarks/TRACING.md).  ``dryad_stage``: PR 24's scopes; ``_2``:
+# ``dryad.join.{probe,materialize,exact}``.
+PROGRAM_NAME = "dryad_stage_2"
+
+
 def compile_stage(mesh: Mesh, fn: Callable[[Any, Any], Tuple[Any, Any]]):
     """Compile a per-partition stage fn into a jitted SPMD callable."""
     axes = mesh_axes(mesh)
@@ -35,14 +47,7 @@ def compile_stage(mesh: Mesh, fn: Callable[[Any, Any], Tuple[Any, Any]]):
         out_specs=(P(axes), P()),
         check_vma=False,
     )
-    # The program's name (``jit_dryad_stage``: the profiler's ``XLA
-    # Modules`` line, the head of every operation's name path) is part
-    # of jax's compilation-cache key; the operator scopes inside
-    # (``exec/kernels.apply_op``) are not, the key strips names.  So
-    # the name says which generation of scopes a cached program
-    # carries: change it when scopes are added or renamed, or a stale
-    # cache hands back a program without them (benchmarks/TRACING.md).
-    mapped.__name__ = mapped.__qualname__ = "dryad_stage"
+    mapped.__name__ = mapped.__qualname__ = PROGRAM_NAME
     return jax.jit(mapped)
 
 
