@@ -4,11 +4,17 @@ The reference executes Join/GroupJoin inside vertices after co-hash-
 partitioning both inputs (``DryadLinqQueryNode.cs`` DLinqJoinNode;
 vertex-side implementations in ``LinqToDryad/DryadLinqVertex.cs``).
 The TPU-native version: both sides arrive co-partitioned by key hash;
-locally we sort the right side by a 32-bit key hash, probe with
-``searchsorted`` to get candidate ranges, expand candidate pairs into a
+locally we sort the right side by a 32-bit key hash, rank every left
+hash in it to get candidate ranges, expand candidate pairs into a
 fixed-capacity output via prefix sums, and mask to exact key equality
 (hash collisions only ever add masked-off candidates).  Output overflow
 is reported for executor retry, like the shuffle's padded buckets.
+
+Neither search is a binary search by ``gather``: the ranks come from
+one merge of the left hashes into the sorted right side
+(``ops/sort.py::sorted_ranks``), and a pair slot's left row from a
+scatter of the rows' first slots and a running maximum
+(:func:`_slot_owners`).
 """
 
 from __future__ import annotations
@@ -20,7 +26,11 @@ import jax.numpy as jnp
 
 from dryad_tpu.columnar.batch import ColumnBatch
 from dryad_tpu.ops.hash import hash_columns
-from dryad_tpu.ops.sort import sort_batch_by_operands, sort_carry
+from dryad_tpu.ops.sort import (
+    sort_batch_by_operands,
+    sort_carry,
+    sorted_ranks,
+)
 
 
 def _suffixed(phys_name: str, suffix: str) -> str:
@@ -42,9 +52,12 @@ def _probe_ranges(
 ) -> Tuple[ColumnBatch, jax.Array, jax.Array, jax.Array]:
     """Sort right by key hash; per valid left row the candidate range.
 
-    Returns (right_sorted, lhash, start, end). Invalid right rows sort
-    to the end with a sentinel hash that can never match a valid probe
-    (probe hashes have their top bit cleared; the sentinel is 2^32-1).
+    Returns (right_sorted, lhash, start, counts): left row i's
+    candidates are ``right_sorted[start[i] : start[i] + counts[i]]``,
+    the two ranks of its hash in the sorted right hashes
+    (``sorted_ranks``).  Invalid right rows sort to the end with a
+    sentinel hash that can never match a valid probe (probe hashes have
+    their top bit cleared; the sentinel is 2^32-1).
     """
     rhash = hash_columns([right.data[k] for k in right_keys]) >> 1
     rhash = jnp.where(right.valid, rhash, jnp.uint32(0xFFFFFFFF))
@@ -58,10 +71,25 @@ def _probe_ranges(
     rs = ColumnBatch(dict(zip(names, carried)), vs)
 
     lhash = hash_columns([left.data[k] for k in left_keys]) >> 1
-    start = jnp.searchsorted(rhash_sorted, lhash, side="left")
-    end = jnp.searchsorted(rhash_sorted, lhash, side="right")
+    start, end = sorted_ranks(rhash_sorted, lhash)
     counts = jnp.where(left.valid, end - start, 0)
     return rs, lhash, start, counts
+
+
+def _slot_owners(
+    offsets: jax.Array, counts: jax.Array, out_capacity: int
+) -> jax.Array:
+    """The left row each pair slot belongs to: the inverse of the prefix
+    sum ``offsets``.  Every row that owns slots writes its index at its
+    first one (the targets are distinct; a row with none, or with its
+    first slot past the capacity, drops) and a running maximum fills
+    each row's range.  On the slots under ``sum(counts)`` this is
+    ``searchsorted(offsets, slot, "right") - 1``; past them it is the
+    last row that owns a slot (0 when none does)."""
+    rows = jnp.arange(counts.shape[0], dtype=jnp.int32)
+    first = jnp.where(counts > 0, offsets, out_capacity)
+    heads = jnp.zeros((out_capacity,), jnp.int32).at[first].set(rows, mode="drop")
+    return jax.lax.cummax(heads)
 
 
 @jax.named_scope("dryad.join.expand_pairs")
@@ -72,9 +100,10 @@ def _expand_pairs(
 
     Returns (left_idx, right_idx, pair_valid, overflow, offsets) where
     ``offsets[i]`` is the first slot of left row i's candidate range
-    (slots for one left row are contiguous).
+    (slots for one left row are contiguous, rows in order) and
+    ``left_idx`` is :func:`_slot_owners`' answer.  Only the slots under
+    ``pair_valid`` mean anything.
     """
-    n = counts.shape[0]
     offsets = jnp.concatenate(
         [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]]
     )
@@ -82,9 +111,7 @@ def _expand_pairs(
     overflow = total > out_capacity
 
     slots = jnp.arange(out_capacity, dtype=jnp.int32)
-    # Which left row does slot j belong to?  offsets is non-decreasing.
-    li = jnp.searchsorted(offsets, slots, side="right").astype(jnp.int32) - 1
-    li = jnp.clip(li, 0, n - 1)
+    li = _slot_owners(offsets, counts, out_capacity)
     within = slots - offsets[li].astype(jnp.int32)
     pair_valid = slots < total
     ri = start[li].astype(jnp.int32) + within

@@ -102,6 +102,42 @@ def sort_batch_by_operands(
     return ColumnBatch(dict(zip(names, carried)), valid)
 
 
+def sorted_ranks(
+    sorted_u32: jax.Array, queries_u32: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """Both ranks of every query in an ascending uint32 array:
+    ``(searchsorted(..., "left"), searchsorted(..., "right"))``, int32,
+    in query order, by a merge and not by ``log n`` rounds of gather.
+    One stable ``lax.sort`` of the sorted side followed by the queries,
+    carrying each query's index, leaves the sorted side's elements first
+    inside every run of equal values.  There the inclusive running count
+    of sorted-side elements is the right rank, and the exclusive count
+    at a run's head, carried along the run by a running maximum, the
+    left rank; a second sort, on the carried index, brings both back to
+    query order.  Chip-measured on one v5e, seconds a call (PR 27): 2^23
+    queries into 2^16, the two ``jnp.searchsorted`` 2.0465,
+    ``searchsorted(method="sort")`` twice 0.2331, this 0.0672 (0.1445
+    with two scatters for the way back); 2^18 into 2^23, the largest
+    ratio measured, 0.1992, 0.1671 and 0.0656.  Far fewer queries into
+    a long array would be cheaper searched, by at most this merge, which
+    costs no more than the sort that made the array ascending: no shape
+    measured is there, so there is one form."""
+    n_s, n_q = sorted_u32.shape[0], queries_u32.shape[0]
+    keys = jnp.concatenate([sorted_u32, queries_u32])
+    # the query's index; -1 marks the sorted side and sorts it first on
+    # the way back
+    who = jnp.concatenate(
+        [jnp.full((n_s,), -1, jnp.int32), jnp.arange(n_q, dtype=jnp.int32)]
+    )
+    keys, who = jax.lax.sort((keys, who), num_keys=1, is_stable=True)
+    from_sorted = (who < 0).astype(jnp.int32)
+    right = jnp.cumsum(from_sorted)
+    head = jnp.concatenate([jnp.ones((1,), jnp.bool_), keys[1:] != keys[:-1]])
+    left = jax.lax.cummax(jnp.where(head, right - from_sorted, 0))
+    _, left, right = jax.lax.sort((who, left, right), num_keys=1)
+    return left[n_s:], right[n_s:]
+
+
 @jax.named_scope("dryad.sort.splitters")
 def sample_splitters(
     key_u32: jax.Array,

@@ -160,13 +160,9 @@ def test_the_join_plan_record(job):
     assert "xla_compile" in kinds and "join_plan" not in kinds
 
 
-def test_the_stage_program_names_the_joins_parts(job, monkeypatch):
-    """The lowered program of the cell's query carries the four join
-    scopes and the top-k's in its operations' metadata, under the
-    operator's scope, and its name is not the one the parent's cached
-    programs have."""
+def lowered_programs(job, monkeypatch, table, params, P):
+    """The cell's query collected once; every stage program it lowered."""
     from dryad_tpu.exec.executor import GraphExecutor
-    from dryad_tpu.parallel import stage
 
     lowered = []
     real = GraphExecutor._get_compiled
@@ -183,10 +179,20 @@ def test_the_stage_program_names_the_joins_parts(job, monkeypatch):
         return hit
 
     monkeypatch.setattr(GraphExecutor, "_get_compiled", spy)
+    job.bind(DryadContext(num_partitions_=P), table, params).collect()
+    return lowered
+
+
+def test_the_stage_program_names_the_joins_parts(job, monkeypatch):
+    """The lowered program of the cell's query carries the four join
+    scopes and the top-k's in its operations' metadata, under the
+    operator's scope, and its name is not the one the parent's cached
+    programs have."""
+    from dryad_tpu.parallel import stage
+
     params = {"rows": ROWS, "dim_rows": DIM_ROWS, "top": TOP, "expansion": 1.25}
     table = job.make_table(np.random.default_rng(26), params, None, 0)
-    job.bind(DryadContext(num_partitions_=4), table, params).collect()
-    program, = lowered
+    program, = lowered_programs(job, monkeypatch, table, params, 4)
     paths = re.findall(r'op_name="([^"]*)"', program.compile().as_text())
     for scope, operator in (
             ("dryad.join.probe", "dryad.join"),
@@ -206,3 +212,42 @@ def test_the_stage_program_names_the_joins_parts(job, monkeypatch):
     # new set of scopes takes a new name (benchmarks/TRACING.md)
     assert stage.PROGRAM_NAME != "dryad_stage"  # PR 24's and the parent's
     assert f"module @jit_{stage.PROGRAM_NAME} " in program.as_text()
+
+
+def whiles_by_scope(program):
+    """The ``op_name`` path of every ``while`` of a program.  Read from
+    the compiled HLO: a lowered ``stablehlo.while`` inside a nested
+    ``jit`` (``jnp.searchsorted`` is one) carries a path relative to
+    its own function, the compiled instruction the whole one."""
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in program.compile().as_text().splitlines()
+            if re.search(r"\bwhile\(", line)]
+
+
+def test_a_binary_search_would_be_seen():
+    """What the next test looks for is there to find when it is there."""
+    import jax
+    import jax.numpy as jnp
+
+    def search(a, q):
+        with jax.named_scope("dryad.join.probe"):
+            return jnp.searchsorted(a, q)
+
+    program = jax.jit(search).lower(jnp.arange(128, dtype=jnp.uint32),
+                                    jnp.arange(4, dtype=jnp.uint32))
+    path, = whiles_by_scope(program)
+    assert "dryad.join.probe" in path
+
+
+@pytest.mark.parametrize("rows", [ROWS, 4])
+def test_no_loop_under_the_joins_searches(job, monkeypatch, rows):
+    """A rank in a sorted array comes from a sort and a scan: the cell's
+    program holds no ``while`` under any ``dryad.join`` scope, nor does
+    the same query with four fact rows against the same dimension table
+    (a few left rows into a long right side)."""
+    params = {"rows": ROWS, "dim_rows": DIM_ROWS, "top": TOP, "expansion": 1.25}
+    table = job.make_table(np.random.default_rng(26), params, None, 0)
+    table["fact"] = {name: col[:rows] for name, col in table["fact"].items()}
+    program, = lowered_programs(job, monkeypatch, table, params, 1)
+    loops = [path for path in whiles_by_scope(program) if "dryad.join" in path]
+    assert loops == []
