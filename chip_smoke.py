@@ -42,7 +42,7 @@ import numpy as np
 
 LOG2_ROWS_PER_CHIP = 26
 SEED = 20260926
-VOCAB = 1 << 14  # bench.py's wordcount vocabulary
+VOCAB = 1 << 14  # the wordcount-1c cell's vocabulary
 DIM_ROWS = 1 << 16
 TOP_WORDS = 20
 TOP_JOIN = 100

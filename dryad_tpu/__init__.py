@@ -27,7 +27,7 @@ from dryad_tpu.columnar.schema import Schema, ColumnType, StringDictionary
 from dryad_tpu.columnar.batch import ColumnBatch
 
 from dryad_tpu.api.decomposable import Decomposable
-from dryad_tpu.api.context import DryadContext, PlatformKind
+from dryad_tpu.api.context import DryadContext
 from dryad_tpu.api.query import JobHandle, Query
 
 __version__ = "0.1.0"
@@ -41,7 +41,6 @@ __all__ = [
     "ColumnBatch",
     "Decomposable",
     "DryadContext",
-    "PlatformKind",
     "JobHandle",
     "Query",
     "__version__",

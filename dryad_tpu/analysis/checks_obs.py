@@ -31,7 +31,7 @@ emissions always name registered series.
 from __future__ import annotations
 
 import ast
-from typing import Iterator, Optional, Set, Tuple
+from typing import Dict, Iterator, Optional, Set, Tuple
 
 from dryad_tpu.analysis import astutil
 from dryad_tpu.analysis.core import Checker, Finding, Project, register
@@ -93,18 +93,20 @@ class SpanDisciplineChecker(Checker):
                     )
 
 
-def _config_fields(tree: ast.Module) -> Optional[Tuple[Set[str], Set[str]]]:
-    """(dataclass field names, method names) of DryadConfig."""
+def _config_fields(
+    tree: ast.Module,
+) -> Optional[Tuple[Dict[str, int], Set[str]]]:
+    """(dataclass field name -> its line, method names) of DryadConfig."""
     cls = astutil.find_class(tree, "DryadConfig")
     if cls is None:
         return None
-    fields: Set[str] = set()
+    fields: Dict[str, int] = {}
     methods: Set[str] = set()
     for stmt in cls.body:
         if isinstance(stmt, ast.AnnAssign) and isinstance(
             stmt.target, ast.Name
         ):
-            fields.add(stmt.target.id)
+            fields[stmt.target.id] = stmt.lineno
         elif isinstance(stmt, ast.FunctionDef):
             methods.add(stmt.name)
     return fields, methods
@@ -127,11 +129,13 @@ class ConfigKeyChecker(Checker):
     rule = "config-key"
     summary = (
         "CONFIG_KEYS mirrors DryadConfig fields both ways; every "
-        "config attribute read names a schema key"
+        "config attribute read names a schema key; every field is "
+        "read somewhere outside utils/config.py"
     )
     hint = (
         "add the field to DryadConfig AND document it in CONFIG_KEYS "
-        "(utils/config.py), or fix the attribute name"
+        "(utils/config.py), or fix the attribute name; delete a field "
+        "nothing reads"
     )
 
     def check(self, project: Project) -> Iterator[Finding]:
@@ -149,7 +153,8 @@ class ConfigKeyChecker(Checker):
                 hint="keep CONFIG_KEYS a plain literal dict",
             )
             return
-        fields, methods = parsed
+        field_lines, methods = parsed
+        fields = set(field_lines)
         stmt = astutil.find_assign(src.tree, "CONFIG_KEYS")
         keys_line = stmt.lineno if stmt is not None else 1
 
@@ -185,11 +190,16 @@ class ConfigKeyChecker(Checker):
             )
 
         allowed = set(keys) | fields | methods
+        read: Set[str] = set()
         for usage in project.package_files():
             if usage.rel == CONFIG_PATH:
                 continue
             for node in ast.walk(usage.tree):
                 if isinstance(node, ast.Attribute):
+                    # a read through ANY receiver counts (a field may
+                    # travel as ``self.ctx.config`` or a bare alias)
+                    if isinstance(node.ctx, ast.Load):
+                        read.add(node.attr)
                     if (
                         not node.attr.startswith("_")
                         and _is_config_receiver(node.value)
@@ -208,16 +218,29 @@ class ConfigKeyChecker(Checker):
                     and len(node.args) >= 2
                     and isinstance(node.args[1], ast.Constant)
                     and isinstance(node.args[1].value, str)
-                    and _is_config_receiver(node.args[0])
                 ):
                     key = node.args[1].value
-                    if not key.startswith("_") and key not in allowed:
+                    read.add(key)
+                    if (
+                        _is_config_receiver(node.args[0])
+                        and not key.startswith("_")
+                        and key not in allowed
+                    ):
                         yield self.finding(
                             usage.rel,
                             node.lineno,
                             f"getattr config key {key!r} is not a "
                             "DryadConfig field",
                         )
+
+        # an option nothing reads is not an option
+        for key in sorted(fields - read):
+            yield self.finding(
+                src.rel,
+                field_lines[key],
+                f"DryadConfig field {key!r} is read nowhere in the "
+                "package outside utils/config.py",
+            )
 
 
 TELEMETRY_PATH = "dryad_tpu/obs/telemetry.py"

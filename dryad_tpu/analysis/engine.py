@@ -3,8 +3,8 @@
 Locates the working tree from the installed package (the repo root is
 the parent of the ``dryad_tpu`` package directory), builds a
 :class:`~dryad_tpu.analysis.core.Project` over ``dryad_tpu/`` +
-``tests/``, and runs the registry.  This is what the CLI, the tier-1
-test, and ``bench.py --lint-gate`` all call.
+``tests/``, and runs the registry.  This is what the CLI and the
+tier-1 test both call.
 """
 
 from __future__ import annotations

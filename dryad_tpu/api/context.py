@@ -12,7 +12,6 @@ the GraphExecutor (replacing the GraphManager process tree).
 
 from __future__ import annotations
 
-import enum
 import itertools
 import math
 import os
@@ -42,13 +41,10 @@ from dryad_tpu.utils.logging import get_logger
 log = get_logger("dryad_tpu.api")
 
 
-class PlatformKind(enum.Enum):
-    """Reference ClusterType LOCAL/YARN_*; here the device platform."""
-
-    AUTO = "auto"
-    TPU = "tpu"
-    CPU_LOCAL = "cpu_local"
-
+# Ring-buffer cap for the context EventLog's in-memory mirror: long
+# out-of-core jobs emit per-chunk/span events without bound; the file
+# sink (event_log_dir) keeps the full stream.
+_EVENTS_MEM_CAP = 1 << 16
 
 _NP_TYPE_MAP = {
     np.dtype(np.int32): ColumnType.INT32,
@@ -104,14 +100,12 @@ class DryadContext:
         num_partitions_: Optional[int] = None,
         config: Optional[DryadConfig] = None,
         local_debug: bool = False,
-        platform: PlatformKind = PlatformKind.AUTO,
         dcn_slices: Optional[int] = None,
         mesh=None,
     ):
         self.config = config or DryadConfig()
         self.config.validate()
         self.local_debug = local_debug
-        self.platform = platform
         self.dictionary = StringDictionary()
         self._bindings: Dict[int, tuple] = {}
         # True once any from_stream binding exists: the fast gate for
@@ -170,9 +164,7 @@ class DryadContext:
                 path = os.path.join(
                     self.config.event_log_dir, f"job-{int(time.time()*1000)}.jsonl"
                 )
-            self.events = EventLog(
-                path, mem_cap=self.config.obs_events_mem_cap
-            )
+            self.events = EventLog(path, mem_cap=_EVENTS_MEM_CAP)
             # Flight recorder: always-on crash-forensics ring tapped
             # into this context's stream, dumped on JobFailedError /
             # unhandled exceptions (obs.flightrec).  The driver does
@@ -188,8 +180,6 @@ class DryadContext:
                     self.events.add_tap(rec.record)
                 else:
                     flightrec.install_recorder(
-                        capacity=self.config.flightrec_events,
-                        snapshot_s=self.config.flightrec_snapshot_s,
                         dump_dir=(
                             self.config.flightrec_dir
                             or self.config.event_log_dir
@@ -223,10 +213,7 @@ class DryadContext:
             if getattr(self.config, "obs_telemetry", True):
                 from dryad_tpu.obs.telemetry import ResourceMonitor
 
-                self.telemetry = ResourceMonitor(
-                    interval_s=self.config.telemetry_sample_s,
-                    events=self.events,
-                )
+                self.telemetry = ResourceMonitor(events=self.events)
                 self.headroom = self.telemetry.headroom
                 self.events.add_tap(self.telemetry.observe)
             self.executor = GraphExecutor(
@@ -875,7 +862,7 @@ class DryadContext:
         """The active trace context, or a fresh mint for a non-serve
         job (serve minted one at admission and it is already active).
         None — a true no-op under ``tracectx.activate`` — when
-        ``config.query_trace`` is off (the bench --obs-overhead A/B)."""
+        ``config.query_trace`` is off."""
         ctx = tracectx.current()
         if ctx is None and getattr(self.config, "query_trace", True):
             ctx = tracectx.mint()

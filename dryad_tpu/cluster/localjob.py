@@ -297,10 +297,6 @@ class LocalJobSubmission:
         self._gang_stats: Dict[Tuple, StageStatistics] = {}
         self._seq = 0
         self._cseq = 0  # unique per driver command; echoed in statuses
-        # mailbox round trips actually paid (one per command posted);
-        # the asyncpipe bench reads this to show command batching
-        # collapsing K round trips per worker into one
-        self.round_trips = 0
         self._handles: Dict[int, object] = {}
         self._logs: Dict[int, str] = {}
         self._registered: set = set()
@@ -447,7 +443,6 @@ class LocalJobSubmission:
         trips watch only their OWN worker, so an unrelated death leaves
         independent work running (re-execution handles the victim)."""
         mb = self.service.mailbox
-        self.round_trips += 1
         mb.set_prop(self.job_id, f"cmd/{i}", json.dumps(cmd).encode())
         deadline = time.monotonic() + self.timeout
         while not proc.cancelled:
@@ -950,7 +945,6 @@ class LocalJobSubmission:
                         "kind": "runbatch", "cmds": subs, "cseq": cseq,
                         "ack": ack, "skey": skey,
                     })
-                    self.round_trips += 1
                     mb.set_prop(
                         self.job_id, f"cmd/{i}", json.dumps(env).encode()
                     )
@@ -1430,6 +1424,7 @@ class LocalJobSubmission:
         when any worker's combine fails, where the caller assembles the
         original parts flat (byte-identical either way)."""
         from dryad_tpu.columnar.schema import ColumnType
+        from dryad_tpu.exec.combinetree import KEY_RANGES
         from dryad_tpu.exec.partial import state_reductions
 
         _kind, keys, plan, _out_schema = merge
@@ -1445,7 +1440,6 @@ class LocalJobSubmission:
             return None
         config = query.ctx.config
         red = state_reductions(plan)
-        ranges = int(getattr(config, "combine_tree_ranges", 64))
         cache_bytes = int(
             getattr(config, "gang_partition_cache_bytes", 0) or 0
         )
@@ -1462,7 +1456,7 @@ class LocalJobSubmission:
                     {"part": p, "fp": part_fps.get(p)}
                     for p in by_worker[w]
                 ],
-                "keys": list(keys), "red": red, "ranges": ranges,
+                "keys": list(keys), "red": red, "ranges": KEY_RANGES,
                 "wid": widx, "cache_bytes": cache_bytes,
                 "cseq": self._next_cseq(),
             })
@@ -1840,7 +1834,6 @@ class LocalJobSubmission:
         merged, info = merge_coded(
             [spec.row(j) for j in used], tables,
             list(decision.key_cols), list(decision.state_cols),
-            max_amplification=cfg.coded_max_amplification,
         )
         self.events.emit(
             "coded_reconstruct", seq=seq, used=used,
@@ -2193,12 +2186,11 @@ class LocalJobSubmission:
         a lower tree level (the gang workers' level-(-1) pre-merge
         ships them — same deterministic hash, same range space), which
         skip the driver-side hash + histogram pass."""
-        from dryad_tpu.exec.combinetree import plan_groups
+        from dryad_tpu.exec.combinetree import KEY_RANGES, plan_groups
         from dryad_tpu.exec.partial import state_reductions
         from dryad_tpu.obs.metrics import KeyRangeHistogram
 
         cols = {k: np.asarray(v) for k, v in table.items()}
-        ranges = int(getattr(config, "combine_tree_ranges", 64))
         bounds = np.cumsum([0] + list(part_rows))
         if (
             snaps is None
@@ -2208,7 +2200,7 @@ class LocalJobSubmission:
             h = _driver_key_hash(cols, keys)
             snaps = []
             for i in range(len(part_rows)):
-                kr = KeyRangeHistogram(ranges)
+                kr = KeyRangeHistogram(KEY_RANGES)
                 kr.observe(h[bounds[i]:bounds[i + 1]])
                 snaps.append(kr.snapshot())
         g = int(getattr(config, "combine_tree_groups", 0) or 0)

@@ -49,6 +49,14 @@ from dryad_tpu.parallel.mesh import (
     ici_partitions_per_slice,
 )
 
+# coarse key-range resolution of the placement/degrade histograms
+# (obs.metrics.KeyRangeHistogram): key hashes fold into this many
+# ranges.  Power of two.
+KEY_RANGES = 64
+# a range whose estimated distinct-key fraction (est. distinct / rows
+# seen) stays at or above this stops reducing on device and streams to
+# host accumulation; hot (reducing) ranges stay in the tree
+DEGRADE_RATIO = 0.75
 # evidence floor: a key range must have shown at least this many rows
 # before its reduction estimate may degrade it to host accumulation
 MIN_DEGRADE_ROWS = 512

@@ -239,7 +239,7 @@ class GraphExecutor:
         self.config = config or DryadConfig()
         self.events = events or EventLog(None)
         # structured tracing + counters (obs): spans serialize into the
-        # event stream; the registry feeds JobMetrics/bench attribution
+        # event stream; the registry feeds JobMetrics attribution
         self.tracer = Tracer(self.events)
         self.metrics = MetricsRegistry()
         self.P = num_partitions(mesh)
@@ -1360,17 +1360,13 @@ class GraphExecutor:
             raise RuntimeError("do_while requires a subquery_runner (use DryadContext)")
         p = stage.ops[0].params
         (current,) = self._resolve_inputs(stage, bindings, results)
-        # Device-side fixed point: with do_while_device_auto (default
-        # on) EVERY do_while first tries the lax.while_loop seam — the
-        # driver loop below costs one dispatch round trip per
-        # iteration, the device loop costs one total.  Ineligible
-        # subplans (multi-stage body/cond, carry-shape changes) fall
-        # back via the existing exception contract, so auto mode is
-        # behavior-preserving for plans the lowerer rejects.
-        device_auto = bool(
-            getattr(self.config, "do_while_device_auto", False)
-        )
-        if (p.get("device") or device_auto) and self.loop_lowerer is not None:
+        # Device-side fixed point: EVERY do_while first tries the
+        # lax.while_loop seam — the driver loop below costs one dispatch
+        # round trip per iteration, the device loop costs one total.
+        # Ineligible subplans (multi-stage body/cond, carry-shape
+        # changes) fall back via the exception contract below, so
+        # plans the lowerer rejects run exactly as before.
+        if self.loop_lowerer is not None:
             try:
                 results[(stage.id, 0)] = self._run_do_while_device(
                     stage, p, current

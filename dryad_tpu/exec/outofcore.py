@@ -34,6 +34,8 @@ import numpy as np
 
 from dryad_tpu.columnar.schema import ColumnType, Schema
 from dryad_tpu.exec.combinetree import (
+    DEGRADE_RATIO,
+    KEY_RANGES,
     CombineTreePlanner,
     TreeCombiner,
     TreeShape,
@@ -541,7 +543,6 @@ class StreamExecutor:
         self.pipeline_depth = max(
             1, int(getattr(cfg, "stream_pipeline_depth", 1))
         )
-        self.writer_queue = int(getattr(cfg, "stream_writer_queue", 8))
         # async device-paced dispatch: how many chunk dispatches stay
         # in flight (readbacks drained by the DispatchWindow collector
         # thread); 1 = today's serial driver, the differential
@@ -590,7 +591,7 @@ class StreamExecutor:
     def _spill_writer(self) -> Optional[SpillWriter]:
         if not self._pipelined:
             return None
-        return SpillWriter(events=self.events, queue_depth=self.writer_queue)
+        return SpillWriter(events=self.events)
 
     # ---- public --------------------------------------------------------
 
@@ -1237,11 +1238,8 @@ class StreamExecutor:
         mscope = self._scope()  # host-side combine plans
         pschema = None
         shape = TreeShape(self.ctx.mesh, cfg)
-        ranges = int(getattr(cfg, "combine_tree_ranges", 64))
-        planner = CombineTreePlanner(
-            ranges, float(getattr(cfg, "combine_tree_degrade_ratio", 0.75))
-        )
-        hist = KeyRangeHistogram(ranges)
+        planner = CombineTreePlanner(KEY_RANGES, DEGRADE_RATIO)
+        hist = KeyRangeHistogram(KEY_RANGES)
 
         def merge_local(batches):
             # every chunk's partial group_by hash-exchanged on the same
@@ -1273,14 +1271,14 @@ class StreamExecutor:
                 )
             snap = None
             if h is not None:
-                ch = KeyRangeHistogram(ranges)
+                ch = KeyRangeHistogram(KEY_RANGES)
                 ch.observe(h)
                 hist.merge(ch)
                 snap = ch.snapshot()
             nchunks += 1
             hot: Optional[Dict[str, Any]] = table
             if degraded and h is not None:
-                rid = KeyRangeHistogram.range_ids(h, ranges)
+                rid = KeyRangeHistogram.range_ids(h, KEY_RANGES)
                 cold_mask = np.isin(
                     rid, np.fromiter(degraded, np.int64, len(degraded))
                 )
@@ -1314,7 +1312,7 @@ class StreamExecutor:
                 self._emit(
                     "stream_chunk", rows=n, partial_cap=batch.capacity
                 )
-                comb.push(batch, snap or neutral_snapshot(ranges))
+                comb.push(batch, snap or neutral_snapshot(KEY_RANGES))
             else:
                 self._emit("stream_chunk", rows=n, partial_cap=0)
             if host_rows > self.combine_rows and len(host_acc) > 1:

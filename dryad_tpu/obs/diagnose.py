@@ -12,7 +12,7 @@ an evidence dict, and a remediation hint.
 Rules (``rule`` field of the emitted event):
 
 - ``recompile_storm`` — xla_compile rate per stage/lowering tier
-  exceeds ``diagnose_recompile_burst`` inside the sliding window (the
+  exceeds ``_RECOMPILE_BURST`` inside the sliding window (the
   palette exists so tiers compile once; a storm means shape-baking).
 - ``straggler`` — a completed vertex/stage duration is a z-score
   outlier vs its :class:`exec.stats.StageStatistics` family, or an
@@ -20,7 +20,7 @@ Rules (``rule`` field of the emitted event):
   proactive path — :meth:`DiagnosisEngine.note_inflight` — which
   feeds coded-parity pre-launch *before* the first failure).
 - ``partition_skew`` — per-bucket row imbalance (max/mean at or above
-  ``diagnose_skew_ratio``) folded live from ``stream_spill`` events
+  ``_SKEW_RATIO``) folded live from ``stream_spill`` events
   and from ``partition_rows`` histograms in ``metrics`` snapshots.
 - ``stall_dominance`` — cumulative ingest stall dominates execute
   time (the pipeline is IO-bound, not compute-bound).
@@ -37,10 +37,9 @@ Rules (``rule`` field of the emitted event):
 Each (rule, subject) pair re-announces at most once per
 ``diagnose_cooldown_s`` — a persistent pathology must not flood the
 very stream it is diagnosing.  The engine keeps every emitted
-diagnosis in :attr:`records` for ``Query.explain(analyze=True)``,
-the jobview health panel, and the bench ``diagnoses`` block; the
-module-level :func:`scan` re-runs the same folds over a RECORDED
-stream (loaded JSONL / blackbox dumps).
+diagnosis in :attr:`records` for ``Query.explain(analyze=True)``
+and the jobview health panel; the module-level :func:`scan` re-runs
+the same folds over a RECORDED stream (loaded JSONL / blackbox dumps).
 """
 
 from __future__ import annotations
@@ -53,19 +52,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from dryad_tpu.exec.stats import StageStatistics
 from dryad_tpu.obs import tracectx
 
-__all__ = ["DiagnosisEngine", "scan", "RULES", "drain_recent"]
-
-# Process-wide tail of emitted diagnoses (across ALL engines): the
-# bench harness drains this into each metric record's ``diagnoses``
-# block without holding a handle on every context it benchmarked.
-_RECENT: "deque" = deque(maxlen=256)
-
-
-def drain_recent() -> List[Dict[str, Any]]:
-    """Return and clear the process-wide recent-diagnosis tail."""
-    out = list(_RECENT)
-    _RECENT.clear()
-    return out
+__all__ = ["DiagnosisEngine", "scan", "RULES"]
 
 # rule id -> (severity, remediation hint)
 RULES: Dict[str, Tuple[str, str]] = {
@@ -81,13 +68,13 @@ RULES: Dict[str, Tuple[str, str]] = {
     ),
     "partition_skew": (
         "warn",
-        "key distribution is skewed — raise shuffle_slack, lower "
-        "combine_tree_degrade_ratio, or salt the hot keys",
+        "key distribution is skewed — raise shuffle_slack or salt the "
+        "hot keys",
     ),
     "stall_dominance": (
         "warn",
-        "the job is ingest-bound — raise stream_pipeline_depth / "
-        "io_threads or move inputs closer to the accelerator",
+        "the job is ingest-bound — raise stream_pipeline_depth or "
+        "move inputs closer to the accelerator",
     ),
     "quarantine_churn": (
         "error",
@@ -96,8 +83,8 @@ RULES: Dict[str, Tuple[str, str]] = {
     ),
     "combine_thrash": (
         "warn",
-        "degrade/reprobe oscillates — raise stream_host_reprobe or "
-        "adjust combine_tree_degrade_ratio so the decision sticks",
+        "degrade/reprobe oscillates — raise stream_host_reprobe so the "
+        "decision sticks",
     ),
     "overflow_loop": (
         "warn",
@@ -120,6 +107,10 @@ RULES: Dict[str, Tuple[str, str]] = {
 }
 
 _WINDOW_S = 60.0  # sliding window for rate-based rules
+_SKEW_RATIO = 4.0  # max/mean per-partition (or per-range) rows = skew
+# xla_compile events for ONE lowering tier inside the window = a storm
+# (the palette exists precisely so tiers compile once)
+_RECOMPILE_BURST = 4
 _MIN_STALL_S = 1.0  # ignore stall dominance below this absolute cost
 _HBM_PRESSURE_RATIO = 0.92  # used/limit at or above diagnoses pressure
 
@@ -128,12 +119,10 @@ class _Tuning:
     """Thresholds with config fallbacks (engine works config-less)."""
 
     def __init__(self, config):
-        g = lambda k, d: getattr(config, k, d) if config is not None else d  # noqa: E731
-        self.skew_ratio = float(g("diagnose_skew_ratio", 4.0))
-        self.recompile_burst = int(g("diagnose_recompile_burst", 4))
-        self.cooldown_s = float(g("diagnose_cooldown_s", 5.0))
-        self.floor_ratio = float(g("straggler_floor_ratio", 1.5))
-        self.sigmas = float(g("outlier_sigmas", 3.0))
+        # getattr(None, key, default) is the default: config-less works
+        self.cooldown_s = float(getattr(config, "diagnose_cooldown_s", 5.0))
+        self.floor_ratio = float(getattr(config, "straggler_floor_ratio", 1.5))
+        self.sigmas = float(getattr(config, "outlier_sigmas", 3.0))
 
 
 class DiagnosisEngine:
@@ -251,7 +240,6 @@ class DiagnosisEngine:
                 "hint": hint,
             }
             self.records.append(rec)
-            _RECENT.append(rec)
         if self.events is not None:
             extra: Dict[str, Any] = {}
             if stage is not None:
@@ -331,7 +319,7 @@ class DiagnosisEngine:
         dq.append((now, ev.get("key")))
         while dq and now - dq[0][0] > _WINDOW_S:
             dq.popleft()
-        if len(dq) >= self.tuning.recompile_burst:
+        if len(dq) >= _RECOMPILE_BURST:
             keys = sorted({str(k) for _, k in dq})
             self._diagnose(
                 "recompile_storm",
@@ -381,7 +369,7 @@ class DiagnosisEngine:
                 continue
             mean = h["sum"] / h["n"]
             mx = float(h.get("max", 0) or 0)
-            if mean > 0 and mx / mean >= self.tuning.skew_ratio:
+            if mean > 0 and mx / mean >= _SKEW_RATIO:
                 self._diagnose(
                     "partition_skew",
                     f"hist:{h.get('labels')}",
@@ -403,7 +391,7 @@ class DiagnosisEngine:
             return
         mean = total / len(rows)
         mx = max(rows.values())
-        if mean > 0 and mx / mean >= self.tuning.skew_ratio:
+        if mean > 0 and mx / mean >= _SKEW_RATIO:
             hot = max(rows, key=rows.get)  # type: ignore[arg-type]
             self._diagnose(
                 "partition_skew",

@@ -14,8 +14,7 @@ into job-level summaries the JobBrowser renders.  Here:
 - :class:`JobMetrics` — the programmatic time-attribution snapshot
   (compile vs execute vs ingest-stall vs spill), foldable from any
   event stream (live ``EventLog`` or a loaded JSONL file), which is
-  also what ``tools.jobview`` renders and ``bench.py`` attaches to
-  BENCH records.
+  also what ``tools.jobview`` renders.
 """
 
 from __future__ import annotations
@@ -392,7 +391,7 @@ class JobMetrics:
 
     def attribution(self) -> Dict[str, float]:
         """The compile/execute/stall/spill summary as a flat dict (the
-        BENCH-record / jobview rendering surface)."""
+        jobview rendering surface)."""
         return {
             "compile_s": round(self.compile_s, 4),
             "compile_count": self.compile_count,

@@ -38,7 +38,6 @@ reason to take the scan.
 
 from __future__ import annotations
 
-import os
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -98,12 +97,10 @@ def _stack_stride(a_pad: int) -> int:
 
 def _stacking_enabled(a_pad: int) -> bool:
     """Stacked-plane formulation applies below the 128-sublane pass
-    boundary; DRYAD_TPU_BUCKET_STACK=0 is the on-chip triage hatch
-    (per-term dots).  Shared by the kernel AND the VMEM sizing so the
-    hatch does not run an unstacked kernel against a stacked budget."""
-    # graftlint: disable=kernel-determinism -- triage hatch read at trace time; fleet-set, constant across a job's replays
-    return a_pad <= 128 and os.environ.get(
-        "DRYAD_TPU_BUCKET_STACK", "1") != "0"
+    boundary (per-term dots above it).  Shared by the kernel AND the
+    VMEM sizing so an unstacked kernel never runs against a stacked
+    budget."""
+    return a_pad <= 128
 
 
 def _row_block(a_pad: int, n_vals: int, total_planes: int) -> Optional[int]:
@@ -131,20 +128,7 @@ def _row_block(a_pad: int, n_vals: int, total_planes: int) -> Optional[int]:
     r = left // (4 * (hi_rows + _LO) + 5 + 4 * n_vals + 16)
     if r < 128:
         return None
-    r = min(8192, (r // 128) * 128)
-    # Experiment hatch: force the row block (rounded to 128, clamped to
-    # the VMEM-derived value) — for on-chip R sweeps (sweep_bucket.py).
-    # Read at trace time: a changed value only affects shapes not yet in
-    # the stage compile cache (sweep_bucket uses a fresh jit per case).
-    forced = os.environ.get("DRYAD_TPU_BUCKET_R")  # graftlint: disable=kernel-determinism -- R-sweep experiment hatch; only sweep_bucket.py sets it
-    if forced:
-        try:
-            forced_r = int(forced)
-        except ValueError:
-            forced_r = 0  # non-numeric: ignore the hatch
-        if forced_r > 0:
-            r = min(r, max(128, (forced_r // 128) * 128))
-    return r
+    return min(8192, (r // 128) * 128)
 
 
 def _split_terms(v, n: int):
@@ -285,48 +269,12 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-_PROBE_STRATEGY: dict = {}
-
-
-def _probed_strategy(platform: str) -> Optional[str]:
-    """Measured winner from ``probe_perf.py``'s persisted artifact
-    (PROBE_TPU.json at the repo root), cached per process."""
-    if platform in _PROBE_STRATEGY:
-        return _PROBE_STRATEGY[platform]
-    rec = None
-    try:
-        import json
-
-        # graftlint: disable=kernel-determinism -- points at the persisted probe artifact; strategy choice, not data
-        path = os.environ.get("DRYAD_TPU_PROBE_FILE") or os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), "PROBE_TPU.json")
-        if os.path.exists(path):
-            with open(path) as fh:
-                entry = json.load(fh).get(platform)
-            if entry and entry.get("recommend") in ("matmul", "scatter"):
-                rec = entry["recommend"]
-    except (OSError, ValueError):  # pragma: no cover - malformed artifact
-        rec = None
-    _PROBE_STRATEGY[platform] = rec  # graftlint: disable=kernel-determinism -- memo of the persisted probe artifact; same value on every read
-    return rec
-
-
 def _default_strategy() -> str:
-    """Bucket-reduce strategy: one-hot MXU matmul vs plain scatter-add
-    (``segment_sum`` on unsorted keys — no sort).  Priority: explicit
-    env ``DRYAD_TPU_BUCKET_STRATEGY=matmul|scatter`` > on TPU only,
-    the winner persisted by ``probe_perf.py`` (PROBE_TPU.json; off-TPU
-    records are ignored so a committed or stale file can never flip CPU
-    test runs) > platform default (matmul on TPU — scatters serialize
-    there; scatter elsewhere, where it beats the sort path on CPU)."""
-    env = os.environ.get("DRYAD_TPU_BUCKET_STRATEGY")  # graftlint: disable=kernel-determinism -- fleet-set strategy override, constant across a job's replays
-    if env in ("matmul", "scatter"):
-        return env
-    if _on_tpu():
-        probed = _probed_strategy("tpu")
-        return probed if probed is not None else "matmul"
-    return "scatter"
+    """Bucket-reduce strategy, from the platform alone: the one-hot MXU
+    matmul on TPU (scatters serialize there), plain scatter-add
+    (``segment_sum`` on unsorted keys — no sort) elsewhere, where it
+    beats the sort path."""
+    return "matmul" if _on_tpu() else "scatter"
 
 
 def _scatter_bucket(
@@ -368,7 +316,7 @@ def bucket_sum_count(
     picks the Pallas kernel on TPU and the XLA fallback elsewhere.
     ``block`` caps the rows-per-step of the XLA fallback's scan.
     ``strategy``: "matmul" (factorized one-hot, MXU) or "scatter"
-    (plain segment_sum) — default measured-per-backend
+    (plain segment_sum) — default from the platform
     (:func:`_default_strategy`).
     """
     n = keys.shape[0]

@@ -10,15 +10,15 @@ module, pre-shuffle) + post-shuffle final reduce — the
 Seed/Accumulate/RecursiveAccumulate/FinalReduce decomposition of
 ``LinqToDryad/IDecomposable.cs:35-71``.
 
-Kernel-strategy note (``probe_perf.py`` → ``PROBE_TPU.json``, round
-4; not re-measured on this code): raw scatter-adds serialize on
-TPU (7×10⁷ rows/s, 22× under the matmul bucket path), so the general
-path stays sort-based and the bounded-key fast path stays the MXU
-kernel (``group_by(dense=K)``, auto-selected for dictionary STRING
+Kernel-strategy note: raw scatter-adds serialize on TPU, so the
+general path stays sort-based and the bounded-key fast path stays the
+MXU kernel (``group_by(dense=K)``, auto-selected for dictionary STRING
 and ingest-bounded INT32 keys).  Within the sort path, the sort
-carries all columns as ``lax.sort`` operands (``ops/sort.py``) and
-counts come from one shared start-position scatter — the measured
-optimum of the round-4 rewrite (2.47→6.0 ×10⁷ rows/s on v5e).
+carries all columns as ``lax.sort`` operands (``ops/sort.py``: moving
+rows by XLA ``gather`` instead was 62-77% of the exchange cells' device
+time, ``PERF.md`` section 6, PR 25) and counts come from one shared
+start-position scatter.  What the fold costs on the chip is in
+``PERF.md`` section 5 (``group_reduce.fold``).
 """
 
 from __future__ import annotations
@@ -196,13 +196,13 @@ def group_reduce(
     the key columns plus one column per AggSpec.
 
     Two strategies share this entry point:
-    - the round-4 chip-measured per-agg path (segment_sum + shared
-      start-position count scatter) — the default;
+    - the per-agg path (segment_sum + shared start-position count
+      scatter) — the default;
     - :func:`group_reduce_fused` (env ``DRYAD_TPU_SORT_FUSED=1``): one
       multi-channel flagged scan + ONE stacked u32 scatter-set for
       every output, attacking the one-random-access-op-per-output-
-      column floor.  Flip the default once a chip run of
-      ``probe_fused.py`` settles it (ROADMAP S3).
+      column floor.  Never timed on a chip: ROADMAP S3 flips the
+      default or deletes it.
     """
     import os
 
@@ -230,14 +230,12 @@ def _segmented_fold(
     if any(a.op in ("count", "mean") for a in aggs):
         # Per-segment row counts WITHOUT a segment_sum: one shared
         # scatter of segment-start row positions, then adjacent
-        # differences.  Round-4 chip probe (n=4M, 4096 segments):
-        # ~14 ms vs ~40 ms for segment_sum of ones —
-        # scatter-ADD cost grows with same-address run length, while
-        # a scatter-set of distinct segment ids does not.  Non-start
-        # rows get an out-of-range index and are dropped
-        # (mode="drop"); the surviving in-bounds writes go to
-        # distinct slots, so no unique_indices promise is needed
-        # (chip-measured: the promise buys nothing here).
+        # differences.  Scatter-ADD cost grows with same-address
+        # run length on the TPU, while a scatter-set of distinct
+        # segment ids does not.  Non-start rows get an out-of-range
+        # index and are dropped (mode="drop"); the surviving
+        # in-bounds writes go to distinct slots, so no
+        # unique_indices promise is needed.
         nvalid = jnp.sum(v.astype(jnp.int32))
         idx = jnp.where(start, seg, cap + 2)
         start_pos = (
@@ -314,8 +312,8 @@ def group_reduce_fused(
     """Sort-path group reduce with ONE multi-channel flagged scan and
     ONE stacked u32 scatter-set for every output column.
 
-    The round-4 floor was one cap-sized random-access op per output
-    column (~14-30 ms each at 4M rows on v5e).  Here every aggregate that needs per-segment state rides
+    The per-agg path pays one cap-sized random-access op per output
+    column.  Here every aggregate that needs per-segment state rides
     a single segmented ``associative_scan`` (channels grouped by
     combine kind and dtype), counts come free from last-row POSITIONS
     (adjacent differences — segments are contiguous after the sort),
@@ -449,7 +447,6 @@ def group_reduce_fused(
     stacked = jnp.stack(chans, axis=1)  # (cap, C)
     # non-last rows take an OUT-OF-RANGE index and drop: a shared
     # in-range sentinel would serialize ~cap same-address writes
-    # (chip-measured in the round-4 count-scatter rewrite)
     idx = jnp.where(last, seg, cap + 1)
     out2d = (
         jnp.zeros((cap + 1, stacked.shape[1]), jnp.uint32)

@@ -42,12 +42,13 @@ def sort_order_by_operands(
 
 def _carry_profitable() -> bool:
     """Platform split for the payload-movement strategy.  On TPU,
-    carrying payload through ``lax.sort`` is free while each
-    post-sort gather costs ~42 ms/column at n=4M (`probe_sortops.py`);
-    on CPU it inverts — gathers are cheap and extra variadic sort
-    operands are not (bench round-4: the carry form cost the CPU
-    sort path ~1.4x).  Both forms produce the identical stable
-    permutation; only data movement differs."""
+    carrying payload through ``lax.sort`` is nearly free while a
+    post-sort XLA ``gather`` of each column is not (``PERF.md``
+    section 6, PR 25: ``sort-1c`` ``requery_s`` 6.14 -> 1.98 s when the
+    exchange layout's gathers became carried sorts); on CPU it
+    inverts — gathers are cheap and extra variadic sort operands are
+    not.  Both forms produce the identical stable permutation; only
+    data movement differs."""
     from dryad_tpu.ops.pallas_bucket import _on_tpu
 
     return _on_tpu()
@@ -65,10 +66,9 @@ def sort_carry(
     Returns ``(sorted_valid, sorted_operands, sorted_carry)``.  The
     permutation is identical to ``take(sort_order_by_operands(...))``
     (same stable key comparison).  On TPU the payload rides the sort
-    as extra ``lax.sort`` operands — chip-measured ~7x cheaper than
-    sort-index-then-gather for 2 payload columns at n=4M
-    (`probe_sortops.py`: 14.5 ms vs 99 ms); elsewhere the payload is
-    gathered by the sorted row index (cheaper off-TPU, bench round-4).
+    as extra ``lax.sort`` operands — cheaper there than
+    sort-index-then-gather (:func:`_carry_profitable`); elsewhere the
+    payload is gathered by the sorted row index (cheaper off-TPU).
     ``lax.sort`` operands share one shape, so a payload with trailing
     dimensions never rides: it is gathered by one carried row index,
     which is sorted only when such a payload is present.

@@ -44,11 +44,11 @@ def sort_order(
     gathered by this order is simultaneously compacted and sorted.
 
     NOTE: when the goal is sorted DATA, prefer
-    ``ops.sort.sort_batch_by_operands`` / ``sort_carry`` — applying a
-    permutation with ``take()`` costs ~42 ms per gathered column at
-    n=4M on v5e, while carrying columns through ``lax.sort`` is free
-    (round-4 ``probe_sortops.py``).  Use the permutation form only when the
-    order must be applied to something that cannot ride the sort.
+    ``ops.sort.sort_batch_by_operands`` / ``sort_carry`` — on the TPU
+    applying a permutation with ``take()`` is an XLA ``gather`` per
+    column, while carrying columns through ``lax.sort`` is nearly free
+    (``PERF.md`` section 6, PR 25).  Use the permutation form only when
+    the order must be applied to something that cannot ride the sort.
     """
     n = valid.shape[0]
     desc = list(descending) if descending is not None else [False] * len(key_cols)

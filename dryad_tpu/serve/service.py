@@ -26,7 +26,7 @@ facing happens there:
 
 Fair share is classic weighted deficit round robin: each visit to a
 tenant with queued work earns ``weight`` quantum units, a query costs
-``1 + input_bytes // config.serve_drr_quantum_bytes`` units, and an
+``1 + input_bytes // _DRR_QUANTUM_BYTES`` units, and an
 idle tenant forfeits its credit — so a heavy tenant cannot starve a
 light one, and a returning tenant cannot burst on banked idle time.
 """
@@ -56,6 +56,12 @@ from dryad_tpu.utils.logging import get_logger
 from dryad_tpu.views import ViewRegistry, finalize_query
 
 log = get_logger("dryad_tpu.serve")
+
+# Weighted deficit-round-robin cost quantum: one scheduling cost unit
+# per this many host-input bytes (a query always costs at least one
+# unit; each visit refills weight units), so a heavy tenant's big-input
+# queries consume deficit proportionally and cannot starve a light one.
+_DRR_QUANTUM_BYTES = 1 << 22
 
 
 class QueryFuture:
@@ -253,9 +259,7 @@ class QueryService:
         # percentiles and windowed admission/completion/rejection
         # counters over the telemetry rolling window — the metricsd
         # scrape surface and the ``stats()["slo"]`` block
-        self.slo = RollingStore(
-            window_s=getattr(self.config, "telemetry_window_s", 60.0)
-        )
+        self.slo = RollingStore()
         # driver-side serve spans (cache_probe etc) for the per-query
         # critical-path fold
         self.tracer = Tracer(self.events)
@@ -398,7 +402,7 @@ class QueryService:
                 st.seq += 1
                 item = _Queued(
                     st, qid, query, QueryFuture(st.name, qid), cost,
-                    1 + cost // self.config.serve_drr_quantum_bytes,
+                    1 + cost // _DRR_QUANTUM_BYTES,
                     st.epoch, time.monotonic(), tctx=tctx,
                 )
                 st.inflight += 1

@@ -297,37 +297,3 @@ def explain_diagnoses(ctx) -> str:
         )
         lines.append(f"      hint: {d['hint']}")
     return "\n".join(lines)
-
-
-def explain_lint(root=None) -> str:
-    """Static-analysis panel: per-rule finding counts and the tree's
-    reasoned suppressions, so lint state is visible alongside the
-    logical/fusion/SVG panels (and in bench provenance: ``bench.py
-    --lint-gate`` enforces the same registry before recording)."""
-    from dryad_tpu.analysis import engine
-
-    report = engine.run_repo(root=root)
-    sup_by_rule: Dict[str, int] = {}
-    for f in report.suppressed():
-        sup_by_rule[f.rule] = sup_by_rule.get(f.rule, 0) + 1
-    counts = report.counts()
-    lines = ["== static analysis (graftlint) =="]
-    for rule in sorted(set(report.rules_run) | set(counts) | set(sup_by_rule)):
-        n = counts.get(rule, 0)
-        s = sup_by_rule.get(rule, 0)
-        state = f"FINDINGS={n}" if n else "ok"
-        extra = f"  suppressed={s}" if s else ""
-        lines.append(f"  {rule:<22} {state}{extra}")
-    if report.suppressions:
-        lines.append(f"  suppressions ({len(report.suppressions)}):")
-        for s in report.suppressions:
-            lines.append(
-                f"    {s.path}:{s.line} [{','.join(s.rules)}] -- {s.reason}"
-            )
-    lines.append(
-        "  tree clean"
-        if report.ok
-        else f"  TREE DIRTY: {len(report.unsuppressed())} unsuppressed "
-        "finding(s) — run python -m dryad_tpu.tools.lint"
-    )
-    return "\n".join(lines)

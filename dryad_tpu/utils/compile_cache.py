@@ -1,8 +1,8 @@
 """Where JAX's persistent compile cache lives — decided in ONE place.
 
 The library itself never configures a cache; the processes that START
-it do (``chip_smoke.py``, ``bench.py`` and its child scripts, both
-conftests), all through :func:`enable_compile_cache`.
+it do (``chip_smoke.py``, ``benchmarks/run.py``, both conftests), all
+through :func:`enable_compile_cache`.
 
 ``JAX_COMPILATION_CACHE_DIR`` wins: jax reads it into
 ``jax_compilation_cache_dir`` on import, and a ``jax.config.update``

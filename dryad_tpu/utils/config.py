@@ -2,9 +2,11 @@
 
 Mirrors the reference's two-level config: per-context knobs on
 ``DryadLinqContext`` (reference ``LinqToDryad/DryadLinqContext.cs:577-1107``)
-and process-wide compile-time defaults in ``StaticConfig``
-(reference ``LinqToDryad/DryadLinqGlobals.cs:36-74``), with environment
-variable overrides (reference env plumbing ``LocalJobSubmission.cs:169``).
+and process-wide defaults in ``StaticConfig`` (reference
+``LinqToDryad/DryadLinqGlobals.cs:36-74``).  An option is set ONE way:
+by passing a ``DryadConfig``.  Only deployment settings — where a fleet
+spills and dumps, how loud it logs, what a tenant may admit — also take
+a default from the environment, read once at import.
 """
 
 from __future__ import annotations
@@ -19,32 +21,9 @@ def _env_int(name: str, default: int) -> int:
     return int(v) if v not in (None, "") else default
 
 
-def _env_float(name: str, default: float) -> float:
-    v = os.environ.get(name)
-    return float(v) if v not in (None, "") else default
-
-
-def _env_bool(name: str, default: bool) -> bool:
-    v = os.environ.get(name)
-    if v in (None, ""):
-        return default
-    return v.lower() in ("1", "true", "yes", "on")
-
-
 class StaticConfig:
-    """Process-wide defaults (reference ``DryadLinqGlobals.cs:36-74``).
+    """Process-wide defaults (reference ``DryadLinqGlobals.cs:36-74``)."""
 
-    Values are read once at import; env vars named ``DRYAD_TPU_*`` override.
-    """
-
-    # Reference: StaticConfig.DefaultPartitionCount = 8.
-    default_partition_count: int = _env_int("DRYAD_TPU_DEFAULT_PARTITIONS", 8)
-    # Reference: StaticConfig.MaxPartitionCount = 20000.
-    max_partition_count: int = _env_int("DRYAD_TPU_MAX_PARTITIONS", 20000)
-    # Analog of UseMemoryFIFO: keep data in HBM between fused stages.
-    use_hbm_channels: bool = _env_bool("DRYAD_TPU_USE_HBM_CHANNELS", True)
-    # Per-(src,dst) shuffle bucket slack over the uniform expectation.
-    shuffle_slack: float = _env_float("DRYAD_TPU_SHUFFLE_SLACK", 2.0)
     # Logging level name for the framework logger.
     logging_level: str = os.environ.get("DRYAD_TPU_LOGGING_LEVEL", "INFO")
 
@@ -54,8 +33,6 @@ class DryadConfig:
     """Per-context configuration (reference ``DryadLinqContext`` properties).
 
     Attributes map to reference context knobs:
-    - ``partition_count``: default output partitioning (``DefaultPartitionCount``).
-    - ``enable_speculative_duplication``: ``DryadLinqContext.cs:959``.
     - ``max_stage_failures``: GM failure budget (``DrGraph.h:42``
       ``m_maxActiveFailureCount``).
     - ``shuffle_slack`` / ``max_shuffle_retries``: padded-bucket shuffle
@@ -67,10 +44,8 @@ class DryadConfig:
       ``DryadLinqSampler.cs:38-42``).
     """
 
-    partition_count: int = StaticConfig.default_partition_count
-    enable_speculative_duplication: bool = True
     max_stage_failures: int = 3
-    shuffle_slack: float = StaticConfig.shuffle_slack
+    shuffle_slack: float = 2.0
     max_shuffle_retries: int = 3
     intermediate_compression: Optional[str] = None  # None | "zlib"
     sample_rate: float = 0.001
@@ -85,8 +60,6 @@ class DryadConfig:
     # Checkpoint retention lease in seconds (channel-file
     # retain/lease-grace analog, DrProcess.h:80-89); None keeps forever.
     checkpoint_retain_seconds: Optional[float] = None
-    # Thread count for host-side IO (DRYAD_THREADS_PER_WORKER analog).
-    io_threads: int = _env_int("DRYAD_TPU_IO_THREADS", 4)
     # Outlier threshold in sigmas for speculative duplication
     # (reference DrStageStatistics.cpp:24-25: 3 sigma).
     outlier_sigmas: float = 3.0
@@ -94,9 +67,7 @@ class DryadConfig:
     # completed samples the trimmed-sigma fit degenerates (variance ~0
     # flags EVERY later attempt an outlier); the threshold is clamped
     # to floor_ratio x the trimmed mean.
-    straggler_floor_ratio: float = _env_float(
-        "DRYAD_TPU_STRAGGLER_FLOOR", 1.5
-    )
+    straggler_floor_ratio: float = 1.5
     # Coded stage redundancy (dryad_tpu.redundancy): a partitioned
     # aggregation whose combiner is LINEAR (sum/count/mean partials, or
     # Decomposable(linear=True)) runs as k systematic + up to r parity
@@ -104,31 +75,26 @@ class DryadConfig:
     # stage output (exactly for integer accumulators), so stragglers
     # need no identification and killed vertices no re-execution.
     # Non-linear combiners keep the duplicate-on-straggle path.
-    coded_redundancy: bool = _env_bool("DRYAD_TPU_CODED_REDUNDANCY", True)
-    coded_parity_tasks: int = _env_int("DRYAD_TPU_CODED_PARITY", 2)
-    # Float decode guard: refuse coded subsets whose combination-weight
-    # L1 norm would amplify rounding noise beyond this factor.
-    coded_max_amplification: float = _env_float(
-        "DRYAD_TPU_CODED_MAX_AMP", 1e6
-    )
+    coded_redundancy: bool = True
+    coded_parity_tasks: int = 2
     # Retry backoff (exec.failure.RetryPolicy): transient stage/vertex
     # failures wait base * 2^(failures-1) seconds (capped at max) plus
     # seeded jitter before re-executing — a crashing dependency gets
     # breathing room instead of an immediate retry storm.
-    retry_backoff_base: float = _env_float("DRYAD_TPU_RETRY_BACKOFF", 0.05)
+    retry_backoff_base: float = 0.05
     retry_backoff_max: float = 2.0
     retry_jitter: float = 0.5  # backoff *= 1 + jitter * U(0,1), seeded
-    retry_seed: int = _env_int("DRYAD_TPU_RETRY_SEED", 0)
+    retry_seed: int = 0
     # Broadcast-join threshold: with strategy='auto', a right side whose
     # TOTAL row capacity (per-partition capacity x P) is at or below this
     # is replicated via all_gather instead of co-hash-partitioned (the
     # dynamic broadcast decision of DynamicManager.cs:51 /
     # DrDynamicBroadcast.h:23, made trace-time from static capacities).
-    broadcast_limit: int = _env_int("DRYAD_TPU_BROADCAST_LIMIT", 1 << 16)
+    broadcast_limit: int = 1 << 16
     # order_by+take(n) fuses into a shuffle-free distributed top-k when
     # n is at or below this (each partition gathers P*n head rows);
     # larger takes keep the full range-exchange sort.
-    topk_limit: int = _env_int("DRYAD_TPU_TOPK_LIMIT", 1024)
+    topk_limit: int = 1024
     # Auto-dense STRING group_by: a single-STRING-key group_by with
     # sum/count/mean aggs lowers to the MXU bucket path keyed on dense
     # dictionary codes (ops/stringcode.py) when the context dictionary
@@ -139,7 +105,7 @@ class DryadConfig:
     # range is [0, K), K <= auto_dense_limit, rides the same MXU bucket
     # path (with a range-miss guard for post-ingest fabrication).
     auto_dense_ints: bool = True
-    auto_dense_limit: int = _env_int("DRYAD_TPU_AUTO_DENSE_LIMIT", 1 << 17)
+    auto_dense_limit: int = 1 << 17
     # Compile-once dictionary coding (static-vs-operand param split):
     # the string CodeTable/DecodeTable arrays ride the compiled program
     # as call-time DEVICE OPERANDS on a power-of-two shape palette —
@@ -149,23 +115,19 @@ class DryadConfig:
     # the device.  Off = the legacy baked-constant path (each table
     # content is its own compile-cache key) kept as the differential
     # baseline.
-    stringcode_runtime_tables: bool = _env_bool(
-        "DRYAD_TPU_STRINGCODE_RUNTIME_TABLES", True
-    )
+    stringcode_runtime_tables: bool = True
     # Device-resident input cache budget in bytes (0 disables): ingested
     # host/store tables stay sharded in HBM across submits, LRU-evicted
     # by size — the on-device analog of the ProcessService LRU block
     # cache (Cache.cs:32) applied to ingest instead of channel files.
     # Repeated queries over one table skip the host->device transfer.
-    device_cache_bytes: int = _env_int(
-        "DRYAD_TPU_DEVICE_CACHE", 2 * 1024 * 1024 * 1024
-    )
+    device_cache_bytes: int = 2 * 1024 * 1024 * 1024
     # Target rows per independent vertex task: when a partitioned
     # submission doesn't pin nparts, the fan-out is computed from the
     # OBSERVED input size (the data-size-driven consumer-count
     # recomputation of DrDynamicRangeDistributor.cpp:54-110:
     # copies = sampledSize / dataPerVertex).
-    rows_per_vertex: int = _env_int("DRYAD_TPU_ROWS_PER_VERTEX", 1 << 18)
+    rows_per_vertex: int = 1 << 18
     # Whole-DAG SPMD fusion (plan.fuse): maximal runs of consecutive
     # device-eligible stages — including their hash/range exchanges —
     # compile and dispatch as ONE shard_map region, dropping dispatches
@@ -174,7 +136,7 @@ class DryadConfig:
     # retries the WHOLE region at the next palette capacity (same
     # bounded-palette contract as single-stage overflow).  Off = the
     # driver-mediated per-stage path, kept as the differential baseline.
-    plan_fuse: bool = _env_bool("DRYAD_TPU_PLAN_FUSE", True)
+    plan_fuse: bool = True
     # How many overflow-capable stages may be DISPATCHED speculatively
     # before the driver syncs their overflow flags in one batched
     # readback (the GM pump's concurrent vertex management,
@@ -182,7 +144,7 @@ class DryadConfig:
     # control round-trip instead of five;
     # an overflow re-runs the affected suffix at a larger boost.
     # 1 = legacy per-stage sync.
-    overflow_sync_depth: int = _env_int("DRYAD_TPU_OVERFLOW_SYNC_DEPTH", 4)
+    overflow_sync_depth: int = 4
     # Memory-bounded staged exchange (plan.xchgplan): hash/range/join
     # repartitions decompose into ppermute rounds shipping at most this
     # many destination buckets each, so peak extra HBM per device is
@@ -195,12 +157,10 @@ class DryadConfig:
     # fits exchange_hbm_budget_mb, else the widest window that does
     # (plan.xchgplan.resolve_window; the runtime rewriter can pin the
     # auto choice via RewriteController.retune_exchange).
-    exchange_window: int = _env_int("DRYAD_TPU_EXCHANGE_WINDOW", 0)
+    exchange_window: int = 0
     # HBM the auto exchange-window policy may spend on one exchange's
     # staging buffers (only read when exchange_window == -1).
-    exchange_hbm_budget_mb: int = _env_int(
-        "DRYAD_TPU_EXCHANGE_HBM_BUDGET_MB", 256
-    )
+    exchange_hbm_budget_mb: int = 256
     # Stage-level fan-out adaptation (DrDynamicRangeDistributor.cpp:
     # 54-110: consumer copies = observed size / data-per-vertex): when a
     # stage's input row count is STATICALLY bounded at or below
@@ -209,19 +169,15 @@ class DryadConfig:
     # ceil(rows / tail_rows_per_partition) partitions instead of all P —
     # the remaining partitions run empty (masked) and per-partition
     # padding shrinks.  0 disables.
-    tail_fanout_rows: int = _env_int("DRYAD_TPU_TAIL_FANOUT_ROWS", 4096)
-    tail_rows_per_partition: int = _env_int(
-        "DRYAD_TPU_TAIL_ROWS_PER_PARTITION", 512
-    )
+    tail_fanout_rows: int = 4096
+    tail_rows_per_partition: int = 512
     # Out-of-core streaming (exec.outofcore; reference streaming channel
     # stack channelinterface.h:212): max rows a phase-2 bucket may hold
     # before it re-splits from observed volume, the partial-accumulator
     # compaction threshold, and the phase-1 spill fan-out.
-    stream_bucket_rows: int = _env_int("DRYAD_TPU_STREAM_BUCKET_ROWS", 1 << 21)
-    stream_combine_rows: int = _env_int(
-        "DRYAD_TPU_STREAM_COMBINE_ROWS", 1 << 20
-    )
-    stream_buckets: int = _env_int("DRYAD_TPU_STREAM_BUCKETS", 32)
+    stream_bucket_rows: int = 1 << 21
+    stream_combine_rows: int = 1 << 20
+    stream_buckets: int = 32
     # Spill directory for streaming buckets (None: a fresh tempdir).
     stream_spill_dir: Optional[str] = os.environ.get(
         "DRYAD_TPU_STREAM_SPILL_DIR"
@@ -231,12 +187,7 @@ class DryadConfig:
     # RChannelReader read-ahead budget (channelinterface.h:212).
     # 1 = the serial legacy driver (no prefetch thread, no background
     # spill writer, per-chunk host readback of partials).
-    stream_pipeline_depth: int = _env_int(
-        "DRYAD_TPU_STREAM_PIPELINE_DEPTH", 4
-    )
-    # Bounded buffer of the background spill writer, in queued pieces
-    # (exec.spill.SpillWriter): backpressure for the scatter phase.
-    stream_writer_queue: int = _env_int("DRYAD_TPU_STREAM_WRITER_QUEUE", 8)
+    stream_pipeline_depth: int = 4
     # Topology- and distribution-aware combine trees (exec.combinetree):
     # streaming group_by partials accumulate into similarity-placed tree
     # groups whose level-0 merges ELIDE the hash exchange (partials are
@@ -245,50 +196,25 @@ class DryadConfig:
     # final fold pays a full exchange (on a hybrid mesh: one ICI hop +
     # exactly one DCN hop via the tree exchange).  Off = the flat
     # N-ary-merge combiner, kept as the differential baseline.
-    combine_tree: bool = _env_bool("DRYAD_TPU_COMBINE_TREE", True)
+    combine_tree: bool = True
     # Max batches one tree-group flush folds in a single program
     # (stable fan-in -> stable shapes -> compile reuse).
-    combine_tree_fan: int = _env_int("DRYAD_TPU_COMBINE_TREE_FAN", 16)
-    # Coarse key-range resolution of the placement/degrade histograms
-    # (obs.metrics.KeyRangeHistogram): key hashes fold into this many
-    # ranges; placement reads per-range counts, degrade reads per-range
-    # distinct-occupancy estimates.  Power of two.
-    combine_tree_ranges: int = _env_int("DRYAD_TPU_COMBINE_TREE_RANGES", 64)
+    combine_tree_fan: int = 16
     # Tree groups (level-0 accumulators).  0 = auto: the DCN slice
     # count on a hybrid mesh, else 4.
-    combine_tree_groups: int = _env_int("DRYAD_TPU_COMBINE_TREE_GROUPS", 0)
-    # Per-key-range host degrade threshold: a range whose estimated
-    # distinct-key fraction (est. distinct / rows seen) stays at or
-    # above this stops reducing on device and streams to host
-    # accumulation; hot (reducing) ranges stay in the tree.
-    combine_tree_degrade_ratio: float = _env_float(
-        "DRYAD_TPU_COMBINE_TREE_DEGRADE_RATIO", 0.75
-    )
+    combine_tree_groups: int = 0
     # Host-degrade re-probe (flat combiner): after this many CONSECUTIVE
     # host combines that DO reduce below the device capacity check, the
     # device path is retried (the degrade decision is no longer sticky).
     # 0 disables re-probing.
-    stream_host_reprobe: int = _env_int("DRYAD_TPU_STREAM_HOST_REPROBE", 2)
-    # Ring-buffer cap for the context EventLog's in-memory mirror
-    # (exec.events): long out-of-core jobs emit per-chunk/span events
-    # without bound; the file sink (event_log_dir) keeps the full
-    # stream.  0 = unbounded (legacy behavior).
-    obs_events_mem_cap: int = _env_int("DRYAD_TPU_OBS_EVENTS_MEM_CAP", 1 << 16)
+    stream_host_reprobe: int = 2
     # Flight recorder (obs.flightrec): always-on bounded ring of recent
     # events + periodic health microsnapshots in every process, dumped
     # atomically to blackbox-<pid>.json on JobFailedError, unhandled
     # exceptions, and worker death (incl. the chaos os._exit path) —
     # crash forensics that survive the process.  Off = no ring, no
     # dump hooks.
-    obs_flight_recorder: bool = _env_bool("DRYAD_TPU_FLIGHT_RECORDER", True)
-    # Flight-recorder ring capacity in events and the minimum seconds
-    # between health microsnapshots (RSS, in-flight dispatches,
-    # pipeline occupancy, operand-pool residency; sampled
-    # opportunistically on record — no background thread).
-    flightrec_events: int = _env_int("DRYAD_TPU_FLIGHTREC_EVENTS", 2048)
-    flightrec_snapshot_s: float = _env_float(
-        "DRYAD_TPU_FLIGHTREC_SNAPSHOT_S", 1.0
-    )
+    obs_flight_recorder: bool = True
     # Blackbox dump directory; None = the event_log_dir when set, else
     # the process working directory.
     flightrec_dir: Optional[str] = os.environ.get(
@@ -300,24 +226,11 @@ class DryadConfig:
     # combine-tree thrash, overflow loops) and emit schema-registered
     # ``diagnosis`` events; the straggler diagnosis seeds coded-spare
     # pre-launch.  Off = record-only observability (PR 3 behavior).
-    obs_diagnosis: bool = _env_bool("DRYAD_TPU_OBS_DIAGNOSIS", True)
-    # Partition-skew trigger: max/mean per-partition (or per-range) row
-    # ratio at or above this diagnoses ``partition_skew``.
-    diagnose_skew_ratio: float = _env_float(
-        "DRYAD_TPU_DIAGNOSE_SKEW_RATIO", 4.0
-    )
-    # Recompile-storm trigger: this many xla_compile events for ONE
-    # lowering tier within the sliding window diagnoses a storm (the
-    # palette exists precisely so tiers compile once).
-    diagnose_recompile_burst: int = _env_int(
-        "DRYAD_TPU_DIAGNOSE_RECOMPILE_BURST", 4
-    )
+    obs_diagnosis: bool = True
     # Per-(rule, subject) re-diagnosis cooldown in seconds: a persistent
     # pathology re-announces at most this often instead of flooding the
     # stream it is diagnosing.
-    diagnose_cooldown_s: float = _env_float(
-        "DRYAD_TPU_DIAGNOSE_COOLDOWN_S", 5.0
-    )
+    diagnose_cooldown_s: float = 5.0
     # Async device-paced dispatch (exec.pipeline.DispatchWindow): how
     # many out-of-core chunk dispatches may be in flight before the
     # streaming driver blocks on its oldest readback.  The driver
@@ -328,7 +241,7 @@ class DryadConfig:
     # Overflow retries are detected at drain time and the retried
     # chunk re-enters the window.  1 = the serial dispatch-then-drain
     # legacy driver, kept as the differential baseline.
-    dispatch_depth: int = _env_int("DRYAD_TPU_DISPATCH_DEPTH", 2)
+    dispatch_depth: int = 2
     # Cross-chunk plan fusion: the streaming driver lowers up to this
     # many chunk partial-plans as ONE multi-root program per dispatch
     # (api.context.DryadContext.run_many_to_host_async) — the chunk
@@ -337,21 +250,13 @@ class DryadConfig:
     # collapse into one.  Each chunk remains its own computation inside
     # the region (per-chunk reduction order unchanged -> byte
     # identical).  1 = one chunk per dispatch (legacy).
-    chunk_fuse: int = _env_int("DRYAD_TPU_CHUNK_FUSE", 1)
-    # Device-side do_while routing: attempt the lax.while_loop lowering
-    # for EVERY fixed-point stage (not only device=True plans), keeping
-    # iteration on the chip instead of paying one dispatch round trip
-    # per driver-loop iteration; lowering refusals fall back to the
-    # driver loop exactly as the explicit device path does.
-    do_while_device_auto: bool = _env_bool(
-        "DRYAD_TPU_DO_WHILE_DEVICE_AUTO", True
-    )
+    chunk_fuse: int = 1
     # Batched worker command streams (cluster.localjob/worker): up to
     # this many gang run commands ship per worker as ONE ``runbatch``
     # mailbox command with one aggregated status round trip (per-
     # command fault classification preserved in the aggregate).
     # 0 disables batching (one mailbox round trip per command).
-    command_batch: int = _env_int("DRYAD_TPU_COMMAND_BATCH", 8)
+    command_batch: int = 8
     # Worker-side combine, the gang tree's level -1 (cluster.localjob
     # submit_partitioned + cluster.worker ``combineparts``): after the
     # vertex wave, each gang worker pre-merges the un-finalized partial
@@ -360,9 +265,7 @@ class DryadConfig:
     # driver ingress drops by the per-worker vertex fan-in and the
     # driver's level-0/1 tree merges per-WORKER partials.  Off = flat
     # per-vertex assembly, kept as the differential oracle.
-    gang_combine_tree: bool = _env_bool(
-        "DRYAD_TPU_GANG_COMBINE_TREE", False
-    )
+    gang_combine_tree: bool = False
     # Overlapped gang command streams (cluster.gangwindow): how many
     # ``runbatch`` envelopes may be in flight per worker before
     # ``submit_many`` blocks on its oldest aggregated status.  The
@@ -370,16 +273,14 @@ class DryadConfig:
     # submit order, so batch commit order is identical to the serial
     # loop.  1 = one blocking round trip per batch (the differential
     # baseline).
-    gang_batch_depth: int = _env_int("DRYAD_TPU_GANG_BATCH_DEPTH", 1)
+    gang_batch_depth: int = 1
     # Per-worker gang partition cache budget in host bytes
     # (cluster.partcache.PartitionCache): a worker keeps the result
     # partitions it wrote, content-fingerprint-keyed, so a later
     # sub-command referencing them (level -1 ``combineparts``) reads
     # from memory instead of the job root; entries LRU-evict by size
     # with spill-to-file (spilled entries stay servable).  0 disables.
-    gang_partition_cache_bytes: int = _env_int(
-        "DRYAD_TPU_GANG_PARTITION_CACHE", 64 * 1024 * 1024
-    )
+    gang_partition_cache_bytes: int = 64 * 1024 * 1024
     # Serving tier (dryad_tpu.serve.QueryService): default per-tenant
     # admission quotas — max queries a tenant may have admitted-and-
     # unresolved at once, and the summed host-input bytes those admitted
@@ -395,34 +296,20 @@ class DryadConfig:
     # fingerprints match a resident entry resolve with ZERO device
     # dispatches; entries LRU-evict by size and invalidate on the
     # owning session's ingest-epoch bump.
-    serve_result_cache_bytes: int = _env_int(
-        "DRYAD_TPU_SERVE_CACHE_BYTES", 256 * 1024 * 1024
-    )
-    # Weighted deficit-round-robin cost quantum: one scheduling cost
-    # unit per this many host-input bytes (a query always costs at
-    # least one unit; each visit refills weight units), so a heavy
-    # tenant's big-input queries consume deficit proportionally and
-    # cannot starve a light tenant.
-    serve_drr_quantum_bytes: int = _env_int(
-        "DRYAD_TPU_SERVE_DRR_QUANTUM", 1 << 22
-    )
+    serve_result_cache_bytes: int = 256 * 1024 * 1024
     # Result-cache admission policy: "cost" admits an entry only when
     # its observed recompute time amortizes its bytes (at least
     # serve_cache_min_sec_per_gb seconds of saved work per cached GB),
     # so cheap-but-large results cannot evict expensive ones; "all" is
     # the legacy unconditional insert.
-    serve_cache_admission: str = os.environ.get(
-        "DRYAD_TPU_SERVE_CACHE_ADMISSION", "cost"
-    )
-    serve_cache_min_sec_per_gb: float = _env_float(
-        "DRYAD_TPU_SERVE_CACHE_MIN_SEC_PER_GB", 0.5
-    )
+    serve_cache_admission: str = "cost"
+    serve_cache_min_sec_per_gb: float = 0.5
     # Runtime plan rewriting (dryad_tpu.rewrite): the controller taps
     # the event stream, folds diagnosis events into RewriteActions,
     # and the drivers apply them at chunk/window boundaries.  Requires
     # obs_diagnosis; every rewrite is byte-identity-preserving (the
     # fuzz-differential suite runs this knob on vs off).
-    plan_rewrite: bool = _env_bool("DRYAD_TPU_PLAN_REWRITE", True)
+    plan_rewrite: bool = True
     # Continuous telemetry plane (dryad_tpu.obs.telemetry): a
     # ResourceMonitor taps the event stream and samples device HBM /
     # host RSS plus every shared flightrec probe on an interval,
@@ -430,36 +317,19 @@ class DryadConfig:
     # HeadroomProvider that the adaptive exchange-window and
     # dispatch-depth policies consult.  Off = no sampler, adaptive
     # knobs fall back to configured budgets/defaults.
-    obs_telemetry: bool = _env_bool("DRYAD_TPU_OBS_TELEMETRY", True)
-    # Min seconds between resource samples (tap-paced; a background
-    # thread in resident processes uses the same interval).
-    telemetry_sample_s: float = _env_float(
-        "DRYAD_TPU_TELEMETRY_SAMPLE_S", 1.0
-    )
-    # Rolling-window width for the telemetry metric store — counter
-    # totals and SLO latency percentiles read over this horizon.
-    telemetry_window_s: float = _env_float(
-        "DRYAD_TPU_TELEMETRY_WINDOW_S", 60.0
-    )
+    obs_telemetry: bool = True
     # Query-scoped trace propagation (obs.tracectx): run_* entry
     # points mint a TraceContext so every span / exchange_round /
     # dispatch_gap / gang_window / diagnosis event is attributable to
     # one query (obs.critpath folds them into a critical-path
     # breakdown).  Off = events still flow, unstamped — no per-query
-    # attribution; the bench --obs-overhead A/B flips this.
-    query_trace: bool = _env_bool("DRYAD_TPU_QUERY_TRACE", True)
+    # attribution.
+    query_trace: bool = True
 
     def __post_init__(self) -> None:
         self.validate()
 
     def validate(self) -> None:
-        if self.partition_count < 1:
-            raise ValueError("partition_count must be >= 1")
-        if self.partition_count > StaticConfig.max_partition_count:
-            raise ValueError(
-                f"partition_count {self.partition_count} exceeds "
-                f"max {StaticConfig.max_partition_count}"
-            )
         if self.shuffle_slack < 1.0:
             raise ValueError("shuffle_slack must be >= 1.0")
         if self.intermediate_compression not in (None, "zlib"):
@@ -476,8 +346,6 @@ class DryadConfig:
             raise ValueError("straggler_floor_ratio must be >= 1.0")
         if self.coded_parity_tasks < 1:
             raise ValueError("coded_parity_tasks must be >= 1")
-        if self.coded_max_amplification <= 0:
-            raise ValueError("coded_max_amplification must be > 0")
         if self.retry_backoff_base < 0:
             raise ValueError("retry_backoff_base must be >= 0")
         if self.retry_backoff_max < self.retry_backoff_base:
@@ -486,8 +354,6 @@ class DryadConfig:
             )
         if self.retry_jitter < 0:
             raise ValueError("retry_jitter must be >= 0")
-        if self.io_threads < 1:
-            raise ValueError("io_threads must be >= 1")
         if self.rows_per_vertex < 1:
             raise ValueError("rows_per_vertex must be >= 1")
         if self.device_cache_bytes < 0:
@@ -512,34 +378,12 @@ class DryadConfig:
             raise ValueError("stream_buckets must be >= 2")
         if self.stream_pipeline_depth < 1:
             raise ValueError("stream_pipeline_depth must be >= 1")
-        if self.stream_writer_queue < 1:
-            raise ValueError("stream_writer_queue must be >= 1")
-        if self.obs_events_mem_cap < 0:
-            raise ValueError("obs_events_mem_cap must be >= 0")
-        if self.flightrec_events < 16:
-            raise ValueError("flightrec_events must be >= 16")
-        if self.flightrec_snapshot_s <= 0:
-            raise ValueError("flightrec_snapshot_s must be > 0")
-        if self.diagnose_skew_ratio < 1.0:
-            raise ValueError("diagnose_skew_ratio must be >= 1.0")
-        if self.diagnose_recompile_burst < 2:
-            raise ValueError("diagnose_recompile_burst must be >= 2")
         if self.diagnose_cooldown_s < 0:
             raise ValueError("diagnose_cooldown_s must be >= 0")
         if self.combine_tree_fan < 2:
             raise ValueError("combine_tree_fan must be >= 2")
-        if self.combine_tree_ranges < 2 or (
-            self.combine_tree_ranges & (self.combine_tree_ranges - 1)
-        ):
-            raise ValueError(
-                "combine_tree_ranges must be a power of two >= 2"
-            )
         if self.combine_tree_groups < 0:
             raise ValueError("combine_tree_groups must be >= 0")
-        if not 0.0 < self.combine_tree_degrade_ratio <= 1.0:
-            raise ValueError(
-                "combine_tree_degrade_ratio must be in (0, 1]"
-            )
         if self.stream_host_reprobe < 0:
             raise ValueError("stream_host_reprobe must be >= 0")
         if self.dispatch_depth != -1 and self.dispatch_depth < 1:
@@ -561,18 +405,12 @@ class DryadConfig:
             raise ValueError("serve_max_bytes must be >= 0")
         if self.serve_result_cache_bytes < 0:
             raise ValueError("serve_result_cache_bytes must be >= 0")
-        if self.serve_drr_quantum_bytes < 1:
-            raise ValueError("serve_drr_quantum_bytes must be >= 1")
         if self.serve_cache_admission not in ("cost", "all"):
             raise ValueError(
                 "serve_cache_admission must be 'cost' or 'all'"
             )
         if self.serve_cache_min_sec_per_gb < 0:
             raise ValueError("serve_cache_min_sec_per_gb must be >= 0")
-        if self.telemetry_sample_s <= 0:
-            raise ValueError("telemetry_sample_s must be > 0")
-        if self.telemetry_window_s <= 0:
-            raise ValueError("telemetry_window_s must be > 0")
 
 
 # Every ``DryadConfig`` field, one line each — THE documented key
@@ -583,9 +421,6 @@ class DryadConfig:
 # package, so a renamed or misspelled knob cannot silently read a
 # default.
 CONFIG_KEYS = {
-    "partition_count": "default output partitioning (DefaultPartitionCount)",
-    "enable_speculative_duplication":
-        "duplicate straggling vertex tasks (DryadLinqContext.cs:959)",
     "max_stage_failures": "GM failure budget per stage before job failure",
     "shuffle_slack": "padded shuffle-bucket slack over uniform expectation",
     "max_shuffle_retries": "bounded shape palette for overflow retries",
@@ -595,12 +430,10 @@ CONFIG_KEYS = {
     "profile_dir": "XLA/JAX profiler output directory; None disables",
     "checkpoint_dir": "stage-output checkpoint directory; None disables",
     "checkpoint_retain_seconds": "checkpoint retention lease; None keeps",
-    "io_threads": "host-side IO thread count (DRYAD_THREADS_PER_WORKER)",
     "outlier_sigmas": "speculative-duplication outlier threshold (sigmas)",
     "straggler_floor_ratio": "straggler-threshold floor over trimmed mean",
     "coded_redundancy": "k-of-n coded spares for linear partial aggregates",
     "coded_parity_tasks": "max parity spares r per coded stage",
-    "coded_max_amplification": "float-decode rounding amplification guard",
     "retry_backoff_base": "transient-retry backoff base seconds",
     "retry_backoff_max": "transient-retry backoff cap seconds",
     "retry_jitter": "seeded retry-backoff jitter fraction",
@@ -626,26 +459,17 @@ CONFIG_KEYS = {
     "stream_buckets": "phase-1 spill fan-out (bucket count)",
     "stream_spill_dir": "spill directory; None = fresh tempdir",
     "stream_pipeline_depth": "chunks in flight across the ooc pipeline",
-    "stream_writer_queue": "background spill-writer queue, in pieces",
     "combine_tree": "topology-aware hierarchical streaming combines",
     "combine_tree_fan": "max batches folded per tree-group flush",
-    "combine_tree_ranges": "key-range histogram resolution (power of two)",
     "combine_tree_groups": "level-0 tree groups; 0 = auto from topology",
-    "combine_tree_degrade_ratio": "per-range host-degrade distinct ratio",
     "stream_host_reprobe": "reducing host combines before device re-probe",
-    "obs_events_mem_cap": "EventLog in-memory ring cap; 0 unbounded",
     "obs_flight_recorder": "crash-forensics ring + blackbox dump hooks",
-    "flightrec_events": "flight-recorder ring capacity in events",
-    "flightrec_snapshot_s": "min seconds between health microsnapshots",
     "flightrec_dir": "blackbox dump dir; None = event_log_dir or cwd",
     "obs_diagnosis": "online pathology detection over the live stream",
-    "diagnose_skew_ratio": "partition-skew max/mean row-ratio trigger",
-    "diagnose_recompile_burst": "per-tier compiles in window = storm",
     "diagnose_cooldown_s": "per-(rule, subject) re-diagnosis cooldown",
     "dispatch_depth": "ooc chunk dispatches in flight; 1 = serial "
                       "driver, -1 = adaptive from measured headroom",
     "chunk_fuse": "chunk partial-plans lowered per dispatch; 1 = legacy",
-    "do_while_device_auto": "try lax.while_loop for every fixed point",
     "command_batch": "gang run commands per runbatch round trip; 0 off",
     "gang_combine_tree": "worker-side level -1 partial pre-merge",
     "gang_batch_depth": "runbatch envelopes in flight per worker; 1 serial",
@@ -653,7 +477,6 @@ CONFIG_KEYS = {
     "serve_max_inflight": "per-tenant admitted-query cap (QueryRejected)",
     "serve_max_bytes": "per-tenant admitted host-input byte budget; 0 off",
     "serve_result_cache_bytes": "plan-fingerprint result cache; 0 off",
-    "serve_drr_quantum_bytes": "input bytes per fair-share cost unit",
     "serve_cache_admission":
         "result-cache admission: 'cost' (amortizing only) or 'all'",
     "serve_cache_min_sec_per_gb":
@@ -661,8 +484,6 @@ CONFIG_KEYS = {
     "plan_rewrite": "runtime plan rewriter (dryad_tpu.rewrite); "
                     "diagnosis-driven, byte-identity-preserving",
     "obs_telemetry": "continuous resource sampler + measured headroom",
-    "telemetry_sample_s": "min seconds between resource samples",
-    "telemetry_window_s": "rolling metric window for SLO readouts",
     "query_trace": "query-scoped trace propagation (obs.tracectx); "
                    "qid-stamps events for critical-path attribution",
 }

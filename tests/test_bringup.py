@@ -26,9 +26,10 @@ def test_dense_block_fold(mesh8, monkeypatch, nparts, key, strategy):
     2500 rows folds in three blocks (the last one ragged): counts stay
     equal to np.bincount, float sums within the split-bf16 bound."""
     from dryad_tpu.exec import kernels
+    from dryad_tpu.ops import pallas_bucket
 
     monkeypatch.setattr(kernels, "_DENSE_BLOCK_ROWS", 1 << 10)
-    monkeypatch.setenv("DRYAD_TPU_BUCKET_STRATEGY", strategy)
+    monkeypatch.setattr(pallas_bucket, "_default_strategy", lambda: strategy)
     rng = np.random.default_rng(nparts)
     n, K = 2500 * nparts, 300
     code = rng.integers(0, K, n).astype(np.int32)

@@ -341,7 +341,7 @@ def test_config_validation_rejects_bad_knobs():
         dict(max_shuffle_retries=-1),
         dict(max_stage_failures=0),
         dict(outlier_sigmas=0.0),
-        dict(io_threads=0),
+        dict(device_cache_bytes=-1),
         dict(rows_per_vertex=0),
     ):
         with pytest.raises(ValueError):

@@ -4,8 +4,7 @@ and the suppression grammar.
 The gate test is THE static-analysis entry in tier-1: every registered
 checker runs over the real package + test tree and must come back with
 zero unsuppressed findings — the same invariant ``python -m
-dryad_tpu.tools.lint`` enforces with its exit status and ``bench.py
---lint-gate`` enforces before recording numbers.
+dryad_tpu.tools.lint`` enforces with its exit status.
 """
 
 import json

@@ -860,6 +860,12 @@ def test_config_key_clean_fixture():
             '"rows per streamed chunk"',
             '""',
         ),
+        # a documented field that nothing outside config.py reads
+        (
+            USER,
+            "    ratio = cfg.straggler_floor_ratio\n",
+            "    ratio = 1.5\n",
+        ),
     ],
 )
 def test_config_key_fires(path, old, new):

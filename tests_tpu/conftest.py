@@ -6,8 +6,8 @@ the Mosaic-compiled Pallas bucket kernel, XLA's TPU lowering of the
 sort path, and end-to-end workloads on the chip.
 
 It is its own command, run in its own process after any other holder
-of the chip (``chip_smoke.py``, ``bench.py``) has exited — one process
-owns a chip at a time.  On a machine without a TPU the suite FAILS at
+of the chip (``chip_smoke.py``, ``benchmarks/run.py``) has exited — one
+process owns a chip at a time.  On a machine without a TPU the suite FAILS at
 collection, naming the platform it found; it never skips.
 """
 
