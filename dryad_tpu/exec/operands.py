@@ -97,10 +97,9 @@ class DeviceOperandPool:
         host = tuple(
             np.ascontiguousarray(a) for a in obj.operand_arrays()
         )
-        # Residency keys on the BUFFER layout (type + shapes/dtypes),
-        # not the full compile signature: a probe-bound tier change
-        # recompiles the program but the resident buffers still match
-        # row for row, so the widen delta still scatters.
+        # Residency keys on the BUFFER layout (type + shapes/dtypes):
+        # whatever else a compile signature may hold, buffers of one
+        # layout match row for row, so the widen delta still scatters.
         tier = (type(obj).__name__,) + tuple(
             (a.shape, str(a.dtype)) for a in host
         )

@@ -10,8 +10,10 @@ with no shuffle — the reference pays a full hash repartition for the
 same query (``DryadLinqQueryNode.cs:3581``).
 
 The mapping table is host-built open addressing over the 64-bit hash
-(linear probing, power-of-two slots, load <= 0.5); lookup is a
-vectorized gather loop of ``probe_bound`` rounds.  Tables are wrapped in VALUE-equal
+(linear probing, power-of-two slots, load <= 0.5); the device does not
+probe it: lookup merges the rows with the slots by one carried sort and
+hands each row the code of the slot that holds its two words
+(``CodeTable.lookup``).  Tables are wrapped in VALUE-equal
 objects so the executor's structural compile cache can key on table
 *content* (the legacy baked-constant path), or — with
 ``stringcode_runtime_tables`` — on the table's **shape palette tier**
@@ -56,11 +58,12 @@ class CodeTable:
     out-of-range drop in BOTH palette modes).
 
     Shape palette: ``num_slots`` is ``2 * palette_domain(K)`` (load
-    <= 0.5) and the probe loop runs ``probe_bound`` (the
-    observed max probe rounded up to a power of two) iterations, so the
-    traced lookup depends only on the ``(num_slots, probe_bound)`` tier
-    — two tables of the same tier produce byte-identical traces and the
-    arrays can travel as runtime operands (``operand_arrays``)."""
+    <= 0.5) and the traced lookup depends on nothing else of the table
+    — two tables of one ``num_slots`` tier produce byte-identical
+    traces and the arrays can travel as runtime operands
+    (``operand_arrays``).  ``max_probe``, the longest chain the host
+    build met, is a diagnostic only: the device lookup is a merge and
+    has no probe budget."""
 
     operand_arity = 3  # (slots_h0, slots_h1, slots_code)
 
@@ -89,11 +92,6 @@ class CodeTable:
         self.num_codes = K
         self.num_codes_padded = S // 2  # pow2 >= K: the palette domain
         self.max_probe = max_probe
-        # pow2-quantized probe budget: tier-static, so an append that
-        # lengthens one probe chain within the budget does not change
-        # the traced loop (probing past a key's true chain is harmless:
-        # hits require an exact stored (h0, h1) match)
-        self.probe_bound = palette_domain(max_probe)
         self.slots_h0 = slots_h0
         self.slots_h1 = slots_h1
         self.slots_code = slots_code
@@ -134,7 +132,7 @@ class CodeTable:
     def operand_signature(self) -> Tuple:
         """Shape-palette tier: everything the traced lookup bakes in.
         Tables sharing a signature are interchangeable at call time."""
-        return ("CodeTable", self.num_slots, self.probe_bound)
+        return ("CodeTable", self.num_slots)
 
     def operand_arrays(self) -> Tuple[np.ndarray, ...]:
         return (self.slots_h0, self.slots_h1, self.slots_code)
@@ -149,7 +147,43 @@ class CodeTable:
         ``operands``: the (slots_h0, slots_h1, slots_code) device
         arrays when the tables travel as runtime operands; None bakes
         them into the trace as constants (legacy path).  Either way the
-        trace depends only on ``operand_signature()`` values."""
+        trace depends only on ``operand_signature()`` values.
+
+        A merge, not a probe: the ``S`` slots (as they lie, empty ones
+        included) and the ``n`` rows are sorted together by one
+        ``lax.sort`` on both hash words and then on one word that says
+        who an element is (a row's index; ``-(code + 2)`` for a slot, so
+        -1 is an empty one): slots come first inside a run of equal
+        words, and with every operand a key the sort needs no stability.
+        A real entry then lies before every row of its run, so its
+        code travels down the run by a running maximum: each real entry
+        and each run's head writes
+        ``(t << b) | (code + 1)``, ``t`` twice the count of real entries
+        so far (plus one for a head that is not a real entry, which so
+        takes over from the entry before it with code + 1 = 0, a miss);
+        ``t`` never falls, so the maximum so far is the last word
+        written.  ``t`` has ``log2 S + 1`` bits and ``code + 1`` has
+        ``log2 S``: up to ``S = 2^15`` one int32 holds both, past that
+        the code travels in as many slices as it takes (two to
+        ``S = 2^20``), each its own running maximum.  A second sort, on
+        the carried word, brings the codes back to row order.  No gather,
+        no loop, no probe budget: the hit is the exact match of both
+        words, whatever the chain in the host-built table.
+
+        Chip-measured on one v5e, seconds a call (PR 29; "the loop" is
+        the ``fori_loop`` this replaces, ``probe_bound`` rounds of three
+        row-sized gathers): 2^23 rows into 2^15 slots, the loop (64
+        rounds) 12.616, this 0.0430 (0.0658 as a stable sort on the two
+        words alone, which carries an index more; 0.0475 into 2^17
+        slots, the code in two slices); 2^12 rows into 2^18 slots, the
+        largest table the default ``auto_dense_limit`` allows, the loop
+        (32 rounds) 0.00425, this 0.00137; 2^12 into 2^21, the loop (64)
+        0.00809, this 0.01147: the one shape measured with the loop
+        ahead, by 3.4 ms, at a table no default plan builds, so there is
+        one form.  The code carried by a copy-forward
+        ``lax.associative_scan`` in place of the running maximum: 2^20
+        rows into 2^15, 0.00780 against 0.00475, and at 2^23 its compile
+        for a v5e did not end in 16 minutes."""
         import jax
         import jax.numpy as jnp
 
@@ -160,24 +194,39 @@ class CodeTable:
             th0 = jnp.asarray(self.slots_h0)
             th1 = jnp.asarray(self.slots_h1)
             tco = jnp.asarray(self.slots_code)
-        idx = (h0 ^ (h1 * jnp.uint32(0x9E3779B9))).astype(jnp.uint32) & jnp.uint32(S - 1)
-        idx = idx.astype(jnp.int32)
-
-        # A real loop, not an unrolled one: each probe gathers three
-        # row-sized arrays, and XLA:TPU keeps every unrolled probe's
-        # gathers live at once (2^26 rows x 16 probes asked for 24 GB of
-        # HBM temp on a v5e).  One probe's temporaries at a time.
-        def probe(p, code):
-            j = (idx + p) & (S - 1)
-            slot_code = tco[j]
-            hit = (th0[j] == h0) & (th1[j] == h1) & (slot_code >= 0)
-            return jnp.where(hit & (code < 0), slot_code, code)
-
+        n = h0.shape[0]
         with jax.named_scope("dryad.string_code.probe"):
-            code = jax.lax.fori_loop(
-                0, self.probe_bound, probe,
-                jnp.full(h0.shape, -1, jnp.int32),
+            k0, k1, who = jax.lax.sort(
+                (
+                    jnp.concatenate([th0, h0]),
+                    jnp.concatenate([th1, h1]),
+                    jnp.concatenate(
+                        [-(tco + 2), jnp.arange(n, dtype=jnp.int32)]
+                    ),
+                ),
+                num_keys=3, is_stable=False,
             )
+            real = who <= -2
+            head = jnp.concatenate([
+                jnp.ones((1,), jnp.bool_),
+                (k0[1:] != k0[:-1]) | (k1[1:] != k1[:-1]),
+            ])
+            t = 2 * jnp.cumsum(real.astype(jnp.int32)) + (~real)
+            code1 = jnp.where(real, -1 - who, 0)  # code + 1; 0 = none
+            code_bits = (S // 2).bit_length()
+            step = 31 - (S + 1).bit_length()  # what int32 leaves beside t
+            low = (1 << step) - 1
+            found = jnp.zeros_like(code1)
+            for lo in range(0, code_bits, step):
+                last = jax.lax.cummax(jnp.where(
+                    head | real, (t << step) | ((code1 >> lo) & low), 0
+                ))
+                found = found | ((last & low) << lo)
+            # rows ascend on ``who``; the slots, all negative, come first
+            _, found = jax.lax.sort(
+                (who, found), num_keys=1, is_stable=False
+            )
+        code = found[S:] - 1
         return jnp.where(code < 0, jnp.int32(self.num_codes_padded), code)
 
 

@@ -155,6 +155,14 @@ def test_decode_padded_buffer_precomputed_and_sliced(mesh8):
     assert np.array_equal(got_op, exp)
 
 
+def _chain_pairs(K, S, start=5):
+    """K pairs whose slot hashes all start at one slot of an S-slot
+    table: the host build chains them K long."""
+    pairs = np.zeros((K, 2), np.uint32)
+    pairs[:, 0] = start + S * np.arange(K, dtype=np.uint32)
+    return pairs
+
+
 def test_palette_tiers_are_pow2_and_shared():
     from dryad_tpu.ops.stringcode import CodeTable, palette_domain
 
@@ -162,14 +170,146 @@ def test_palette_tiers_are_pow2_and_shared():
         4, 4, 4, 8, 64, 128,
     ]
     rng = np.random.default_rng(0)
-    # two different contents in one domain tier share the signature
-    # (interchangeable at call time) unless their probe bound differs
+    # different contents in one domain tier share the signature
+    # (interchangeable at call time) whatever their longest chain: the
+    # device lookup is a merge, so no probe budget shapes its trace
     a = CodeTable(rng.integers(0, 2**32, (40, 2)).astype(np.uint32))
     b = CodeTable(rng.integers(0, 2**32, (60, 2)).astype(np.uint32))
-    assert a.num_slots == b.num_slots == 2 * palette_domain(60)
-    if a.probe_bound == b.probe_bound:
-        assert a.operand_signature() == b.operand_signature()
-    assert a.operand_sha() != b.operand_sha()
+    c = CodeTable(_chain_pairs(60, 128))
+    assert a.num_slots == b.num_slots == c.num_slots == 2 * palette_domain(60)
+    assert c.max_probe == 60 > 4 * max(a.max_probe, b.max_probe)
+    assert a.operand_signature() == b.operand_signature()
+    assert a.operand_signature() == c.operand_signature()
+    assert a.operand_signature() == ("CodeTable", 128)
+    assert len({a.operand_sha(), b.operand_sha(), c.operand_sha()}) == 3
+
+
+def _lookup_case(name):
+    """(table pairs, row words) of one edge of the merge lookup."""
+    rng = np.random.default_rng(29)
+
+    def fresh(k):
+        return rng.integers(1, 2**32, (k, 2)).astype(np.uint32)
+
+    def mixed(pairs, n):  # hits with repeats, and misses
+        rows = pairs[rng.integers(0, len(pairs), n)].copy()
+        gone = rng.random(n) < 0.3
+        rows[gone] = fresh(int(gone.sum()))
+        return rows
+
+    zeros = np.zeros((6, 2), np.uint32)
+    if name == "hits_misses_repeats":
+        pairs = fresh(300)
+        return pairs, mixed(pairs, 2000)
+    if name == "zero_words_without_entry":  # empty slots are (0, 0) too
+        pairs = fresh(50)
+        return pairs, np.concatenate([zeros, mixed(pairs, 100)])
+    if name == "zero_words_with_entry":
+        pairs = fresh(50)
+        pairs[17] = 0
+        return pairs, np.concatenate([zeros, mixed(pairs, 100)])
+    if name == "chain_past_any_probe_bound":
+        pairs = _chain_pairs(60, 128)
+        return pairs, mixed(pairs, 500)
+    if name == "few_rows_large_table":
+        pairs = fresh(5000)
+        return pairs, mixed(pairs, 7)
+    if name == "many_rows_small_table":
+        pairs = fresh(3)
+        return pairs, mixed(pairs, 20000)
+    if name == "code_in_two_slices":  # S = 2^16: past one int32 word
+        pairs = fresh(20000)
+        return pairs, mixed(pairs, 3000)
+    if name == "no_rows":
+        return fresh(10), np.zeros((0, 2), np.uint32)
+    if name == "empty_table":
+        return np.zeros((0, 2), np.uint32), np.concatenate(
+            [zeros, fresh(20)]
+        )
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("path", ["baked", "operands"])
+@pytest.mark.parametrize("case", [
+    "hits_misses_repeats", "zero_words_without_entry",
+    "zero_words_with_entry", "chain_past_any_probe_bound",
+    "few_rows_large_table", "many_rows_small_table",
+    "code_in_two_slices", "no_rows", "empty_table",
+])
+def test_merge_lookup_matches_numpy_dictionary(case, path):
+    """Every row gets the code a Python dictionary over the table's
+    (h0, h1) pairs gives it, a miss the padded domain — through the
+    baked constants and through runtime operands."""
+    import jax
+    import jax.numpy as jnp
+
+    from dryad_tpu.ops.stringcode import CodeTable
+
+    pairs, rows = _lookup_case(case)
+    table = CodeTable(pairs)
+    ref = {(int(a), int(b)): c for c, (a, b) in enumerate(pairs)}
+    want = [
+        ref.get((int(a), int(b)), table.num_codes_padded) for a, b in rows
+    ]
+    if path == "operands":
+        ops = tuple(jnp.asarray(a) for a in table.operand_arrays())
+        got = jax.jit(
+            lambda a, b, o: table.lookup(a, b, operands=o)
+        )(jnp.asarray(rows[:, 0]), jnp.asarray(rows[:, 1]), ops)
+    else:
+        got = jax.jit(table.lookup)(
+            jnp.asarray(rows[:, 0]), jnp.asarray(rows[:, 1])
+        )
+    assert got.dtype == jnp.int32 and got.shape == (len(rows),)
+    assert np.asarray(got).tolist() == want
+
+
+def test_one_trace_serves_a_table_of_any_chain():
+    """A lookup traced from a short-chained table, fed the arrays of a
+    table of the same tier whose chain is 60 long (past the old loop's
+    budget of 4 rounds for the first), still hits every row."""
+    import jax
+    import jax.numpy as jnp
+
+    from dryad_tpu.ops.stringcode import CodeTable
+
+    rng = np.random.default_rng(3)
+    short = CodeTable(rng.integers(0, 2**32, (60, 2)).astype(np.uint32))
+    pairs = _chain_pairs(60, 128)
+    long_ = CodeTable(pairs)
+    assert short.max_probe <= 4 < long_.max_probe
+    traced = jax.jit(lambda a, b, o: short.lookup(a, b, operands=o))
+    got = traced(
+        jnp.asarray(pairs[:, 0]), jnp.asarray(pairs[:, 1]),
+        tuple(jnp.asarray(a) for a in long_.operand_arrays()),
+    )
+    assert np.asarray(got).tolist() == list(range(60))
+
+
+def test_lowered_lookup_has_no_loop_and_no_gather():
+    """The lookup is two sorts and scans: its lowered text holds no
+    ``while`` (the probe loop) and no gather of any size (the loop's
+    three row-sized ones), through both operand modes."""
+    import jax
+    import jax.numpy as jnp
+
+    from dryad_tpu.ops.stringcode import CodeTable
+
+    table = CodeTable(_chain_pairs(60, 128))
+    rows = jax.ShapeDtypeStruct((4096,), jnp.uint32)
+    ops = tuple(
+        jax.ShapeDtypeStruct(a.shape, a.dtype)
+        for a in table.operand_arrays()
+    )
+    for text in (
+        jax.jit(table.lookup).lower(rows, rows).as_text(),
+        jax.jit(
+            lambda a, b, o: table.lookup(a, b, operands=o)
+        ).lower(rows, rows, ops).as_text(),
+    ):
+        assert "stablehlo.while" not in text
+        assert "gather" not in text
+        assert text.count("stablehlo.sort") == 2
 
 
 def test_operand_pool_scatters_only_the_widened_delta(mesh8):
@@ -188,8 +328,7 @@ def test_operand_pool_scatters_only_the_widened_delta(mesh8):
     for i in range(100, 120):  # 100 -> 120 stays inside domain 128
         d.add(f"s{i}")
     code2, dec2 = build_tables(d)
-    # same buffer layout (the pool's residency key); the full compile
-    # signature may still differ by the pow2 probe bound
+    # same buffer layout (the pool's residency key)
     assert [a.shape for a in code1.operand_arrays()] == [
         a.shape for a in code2.operand_arrays()
     ]
@@ -205,6 +344,16 @@ def test_operand_pool_scatters_only_the_widened_delta(mesh8):
     assert 0 < delta_bytes < full_bytes / 2
     for got, want in zip(dev2, code2.operand_arrays()):
         assert np.array_equal(np.asarray(got), want)
+    # the scattered buffers answer for the widened table through the
+    # trace of the narrower one (one tier, one trace)
+    import jax.numpy as jnp
+
+    assert code1.operand_signature() == code2.operand_signature()
+    codes = code1.lookup(
+        jnp.asarray(dec2.words[:, 0]), jnp.asarray(dec2.words[:, 1]),
+        operands=dev2,
+    )
+    assert np.asarray(codes).tolist() == list(range(120))
     # same content again: resident, no traffic
     dev3 = pool.get(code2)
     assert dev3 is dev2 and pool.hits == 1
