@@ -907,9 +907,17 @@ class DryadContext:
         else:
             valid, host_cols, _ = batch.fetch_host(tracer=self.tracer)
         self._account_d2h(valid, host_cols)
+        # valid rows a partition of the answer, off the mask the host
+        # already holds: for a range partition, how evenly the elected
+        # splitters cut the table
+        shard_rows = [
+            int(np.count_nonzero(part))
+            for part in np.array_split(valid, num_partitions(self.mesh))
+        ]
         with self.tracer.span(
-            "decode", cat="decode", rows=int(np.count_nonzero(valid)),
-            capacity=len(valid),
+            "decode", cat="decode", rows=sum(shard_rows),
+            capacity=len(valid), shards=len(shard_rows),
+            shard_rows_max=max(shard_rows), shard_rows_min=min(shard_rows),
         ):
             table = batch.to_numpy(
                 query.schema, self.dictionary, _host=(valid, host_cols)

@@ -152,6 +152,12 @@ def _lowering_key_hash(key) -> str:
     return format(zlib.crc32(repr(key).encode()) & 0xFFFFFFFF, "08x")
 
 
+def _ici_bytes(xchg_rounds) -> int:
+    """Bytes one chip puts on the ICI in a dispatch: the sum over the
+    program's ``exchange_round`` accounting."""
+    return sum(int(rnd["ici_bytes"]) for rnd in xchg_rounds)
+
+
 class _CompileTimed:
     """First-call timing shim over a freshly compiled stage program.
 
@@ -1167,11 +1173,15 @@ class GraphExecutor:
                 # The obs span (cat=execute): dispatch + any
                 # rides-along readback, attributed to this attempt; in
                 # the XLA profiler timeline it is the annotation
-                # ``dryad:dispatch:<stage>`` (obs/span.py).
+                # ``dryad:dispatch:<stage>`` (obs/span.py), which also
+                # says how many bytes a chip puts on the ICI in the
+                # stage's exchanges (a trace-time constant: 0 on the
+                # one dispatch that traces, whose event gets it below).
                 with self.tracer.span(
                     stage.name, cat="execute", stage=stage.id,
                     version=version, boost=boost,
-                ):
+                    xchg_ici_bytes=_ici_bytes(fn.xchg_rounds),
+                ) as dispatch_span:
                     # OPERAND params ride the replicated slot: current
                     # table content from the pool (uploaded/scattered
                     # once per content, reused across dispatches)
@@ -1187,6 +1197,8 @@ class GraphExecutor:
                             name=stage.name,
                             qid=tracectx.current_qid(), **rnd,
                         )
+                    dispatch_span.add(
+                        xchg_ici_bytes=_ici_bytes(fn.xchg_rounds))
                     counts_dev = None
                     if want_count:
                         import jax.numpy as jnp
