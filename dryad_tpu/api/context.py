@@ -12,6 +12,7 @@ the GraphExecutor (replacing the GraphManager process tree).
 
 from __future__ import annotations
 
+import gc
 import itertools
 import math
 import os
@@ -78,20 +79,21 @@ def _infer_schema(arrays: Dict[str, np.ndarray]) -> Schema:
     return Schema(fields)
 
 
-def _fetch_with_miss(batch, deferred, tracer):
+def _fetch_with_miss(batch, deferred, tracer, metrics):
     """Fetch a result batch host-side with the job's deferred dict-miss
     counters riding the same ``device_get``, resolve the deferred tail
-    (raises on a nonzero counter), and return ``(valid, host_cols)``."""
+    (raises on a nonzero counter), and return ``(valid, host_cols,
+    rows)`` as :meth:`ColumnBatch.fetch_host` gives them."""
     miss = deferred.miss_arrays()
     try:
-        valid, host_cols, miss_vals = batch.fetch_host(
-            extra=miss, tracer=tracer
+        valid, host_cols, miss_vals, rows = batch.fetch_host(
+            extra=miss, tracer=tracer, metrics=metrics
         )
     except Exception as e:  # transfer failure: close out the job
         deferred.abort(f"output transfer failed: {e!r}")
         raise
     deferred.finish(miss_vals)
-    return valid, host_cols
+    return valid, host_cols, rows
 
 
 class DryadContext:
@@ -123,6 +125,9 @@ class DryadContext:
         # context); in-place mutation of arrays passed to from_arrays is
         # NOT tracked — inputs snapshot at first execution.
         self._device_cache: "OrderedDict[int, tuple]" = OrderedDict()
+        # an ingest has handed host arrays to device_put and no fetch
+        # has let go of them since (see _release_ingested)
+        self._ingest_unreleased = False
         self.diagnosis: Optional[DiagnosisEngine] = None
         self.rewriter = None
         # Continuous telemetry plane (obs.telemetry): the tap-paced
@@ -660,6 +665,7 @@ class DryadContext:
             del self._device_cache[node.id]
         with self.tracer.span("bind", cat="ingest", node=node.id):
             batch = self._ingest_binding(kind, rest, node)
+        self._ingest_unreleased = True
         if budget:
             nbytes = sum(
                 a.size * a.dtype.itemsize for a in batch.data.values()
@@ -900,43 +906,66 @@ class DryadContext:
 
     def _fetch_table(self, query: Query, batch, deferred=None):
         """A result batch as the user's logical host table: the fetch
-        (``deferred``'s miss counters riding it), the byte accounting,
-        and the decode of the valid rows."""
+        (``deferred``'s miss counters riding it; the byte accounting is
+        the fetch's own) and the decode of the valid rows, which are a
+        slice a shard where the fetch measured the batch and found no
+        hole, and the mask's otherwise."""
+        metrics = self.executor.metrics if self.executor is not None else None
         if deferred is not None:
-            valid, host_cols = _fetch_with_miss(batch, deferred, self.tracer)
+            valid, host_cols, rows = _fetch_with_miss(
+                batch, deferred, self.tracer, metrics
+            )
         else:
-            valid, host_cols, _ = batch.fetch_host(tracer=self.tracer)
-        self._account_d2h(valid, host_cols)
-        # valid rows a partition of the answer, off the mask the host
-        # already holds: for a range partition, how evenly the elected
-        # splitters cut the table
-        shard_rows = [
-            int(np.count_nonzero(part))
-            for part in np.array_split(valid, num_partitions(self.mesh))
-        ]
+            valid, host_cols, _, rows = batch.fetch_host(
+                tracer=self.tracer, metrics=metrics
+            )
+        # valid rows a partition of the answer (for a range partition,
+        # how evenly the elected splitters cut the table): as counted
+        # on the device, or off the mask the host holds
+        if rows is not None:
+            shard_rows = list(rows.counts)
+        else:
+            shard_rows = [
+                int(np.count_nonzero(part))
+                for part in np.array_split(valid, num_partitions(self.mesh))
+            ]
         with self.tracer.span(
             "decode", cat="decode", rows=sum(shard_rows),
-            capacity=len(valid), shards=len(shard_rows),
+            capacity=batch.capacity, fetched=len(valid),
+            shards=len(shard_rows),
             shard_rows_max=max(shard_rows), shard_rows_min=min(shard_rows),
         ):
+            packed = rows is not None and rows.packed
             table = batch.to_numpy(
-                query.schema, self.dictionary, _host=(valid, host_cols)
+                query.schema, self.dictionary,
+                _host=(rows.slices() if packed else valid, host_cols),
             )
             if self._codecs:
                 from dryad_tpu.columnar.codecs import collapse_table
 
                 table = collapse_table(table, self._codecs)
+        self._release_ingested()
         return table
 
-    def _account_d2h(self, valid, host_cols) -> None:
-        """Device->host transfer byte accounting (obs.metrics): every
-        result fetch funnels through here or the streaming executor."""
-        if self.executor is not None:
-            self.executor.metrics.add(
-                "d2h_bytes",
-                sum(np.asarray(v).nbytes for v in host_cols.values())
-                + np.asarray(valid).nbytes,
-            )
+    def _release_ingested(self) -> None:
+        """Let go of the host arrays an ingest copied to the device, in
+        the job that made them.  jax keeps the source array of every
+        ``device_put`` alive until its copy is done and cannot drop it
+        from the runtime's thread: the array joins a list that the next
+        dispatch, or the next Python collection (jax hooks
+        ``gc.callbacks``, jax issue 14882), empties on the calling
+        thread.  Unmapping a table's worth of arrays there takes
+        milliseconds (134 MB: 3 ms in a process that read its program
+        from the compile cache, 10 - 13 ms in one that compiled it;
+        PERF.md section 6, PR 31), and they landed in whichever job
+        dispatched next, the requery.  The answer is on the host here,
+        so every copy of the job is done: one generation-0 collection
+        empties the list now, under a ``release`` span."""
+        if not self._ingest_unreleased:
+            return
+        self._ingest_unreleased = False
+        with self.tracer.span("release", cat="ingest"):
+            gc.collect(0)
 
     def run_to_host_async(self, query: Query):
         """Dispatch the device job NOW; return a zero-arg ``fetch``
@@ -1048,10 +1077,12 @@ class DryadContext:
             return JobHandle(table, path)
         batch, deferred = self._execute_device(query, defer_miss=True)
         P = num_partitions(self.mesh)
-        cap = batch.capacity // P
         parts = []
         # overlapped d2h copies; miss counters ride the same transfer
-        valid, host_cols = _fetch_with_miss(batch, deferred, self.tracer)
+        valid, host_cols, _ = _fetch_with_miss(
+            batch, deferred, self.tracer, self.executor.metrics
+        )
+        cap = len(valid) // P  # slots a partition, as fetched
         for i in range(P):
             sl = slice(i * cap, (i + 1) * cap)
             m = valid[sl]

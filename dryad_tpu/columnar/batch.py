@@ -14,11 +14,15 @@ columns: logical INT64/STRING columns are two uint32 word columns (see
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+import functools
+import math
+import time
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from dryad_tpu.columnar.schema import (
     ColumnType,
@@ -227,9 +231,10 @@ class ColumnBatch:
         return ColumnBatch(data, jnp.asarray(valid))
 
     def fetch_host(
-        self, extra: Sequence[jax.Array] = (), tracer: Tracer = UNTRACED
+        self, extra: Sequence[jax.Array] = (), tracer: Tracer = UNTRACED,
+        metrics=None,
     ):
-        """(valid, columns, extras) on the host, via ONE
+        """(valid, columns, extras, rows) on the host, via ONE
         ``jax.device_get`` so PJRT overlaps all the device->host copies
         (copy_to_host_async then a single block).  A per-column
         ``np.asarray`` loop pays one synchronous transfer round-trip
@@ -238,23 +243,52 @@ class ColumnBatch:
         dict-miss counters) ride the same transfer; ``extras`` is empty
         when none were passed.
 
+        **What is copied.**  Padding does not travel.  For a batch
+        whose whole copy is ``TRIM_MIN_BYTES`` or more (and that lies
+        on this process's devices in one layout), a small program over
+        ``valid`` alone says, a shard, how many rows are valid and how
+        far they reach (``rows``, a :class:`ShardRows`; one readback of
+        2 x P integers).  Where the furthest reach fits a smaller tier
+        of :func:`trim_tiers` than the capacity, a second small program
+        cuts ``valid`` and every column to ``[:tier]`` a shard on the
+        device, and the one ``device_get`` copies P x tier slots
+        instead of P x capacity: ``valid`` and ``columns`` then hold
+        shard s in ``[s * tier, (s + 1) * tier)``.  Nothing is assumed
+        about the layout, it is measured a fetch: a batch with holes is
+        cut after its last valid row and masked on the host as ever, a
+        full one is copied whole.  Under the gate, or across processes,
+        ``rows`` is None and the arrays come back at capacity.
+
         The wait ``device_get`` would make itself is made first, so
-        "the program had not finished" (``fetch_wait``) and "the copy
-        took long" (``fetch_copy``) are two spans of ``tracer``;
-        ``fetch_copy``'s ``bytes`` is the batch's (``valid`` + columns,
-        what ``d2h_bytes`` counts), without the few bytes of ``extra``."""
+        "the program had not finished" (``fetch_wait``), "asking and
+        cutting" (``fetch_trim``, only over the gate) and "the copy
+        took long" (``fetch_copy``) are spans of ``tracer``;
+        ``fetch_copy``'s ``bytes`` is what was copied of the batch
+        (``valid`` + columns, without the few bytes of ``extra``), and
+        so is what ``metrics`` (the executor's registry) gains under
+        ``d2h_bytes``; ``d2h_bytes_trimmed`` gains the bytes left on
+        the device, ``xla_compiles`` the first use of either small
+        program at a shape."""
         assert "#valid" not in self.data, "'#valid' is a reserved name"
-        wanted = ({"#valid": self.valid, **self.data}, list(extra))
+        arrays = {"#valid": self.valid, **self.data}
+        extra = list(extra)
         with tracer.span("fetch_wait", cat="readback"):
-            jax.block_until_ready(wanted)
-        nbytes = sum(a.size * a.dtype.itemsize for a in wanted[0].values())
+            jax.block_until_ready((arrays, extra))
+        whole = _nbytes(arrays)
+        rows = None
+        if whole >= TRIM_MIN_BYTES and _one_local_layout(arrays):
+            arrays, rows = _trim_to_extent(arrays, tracer, metrics)
+        nbytes = _nbytes(arrays)
         with tracer.span(
             "fetch_copy", cat="readback", bytes=nbytes,
             capacity=self.capacity, columns=len(self.data),
         ):
-            host, extras = jax.device_get(wanted)
+            host, extras = jax.device_get((arrays, extra))
+        if metrics is not None:
+            metrics.add("d2h_bytes", nbytes)
+            metrics.add("d2h_bytes_trimmed", whole - nbytes)
         valid = host.pop("#valid")
-        return valid, host, extras
+        return valid, host, extras, rows
 
     def to_numpy(
         self,
@@ -263,10 +297,187 @@ class ColumnBatch:
         _host: Optional[Tuple[np.ndarray, Dict[str, np.ndarray]]] = None,
     ) -> Dict[str, np.ndarray]:
         """Decode valid rows back to host logical columns.  ``_host``:
-        already-fetched ``(valid, columns)`` from :meth:`fetch_host`
-        (callers that batched the transfer with extra arrays)."""
+        already-fetched ``(rows, columns)`` from :meth:`fetch_host`
+        (callers that batched the transfer with extra arrays); ``rows``
+        is whatever :func:`decode_physical_table` takes."""
         valid, host = _host if _host is not None else self.fetch_host()[:2]
         return decode_physical_table(schema, valid, host, dictionary)
+
+
+# fetch_host asks how far a batch's valid rows reach only when the
+# batch's whole copy (capacity x bytes a row, every shard) is at least
+# this: where asking is cheaper than the padding could be.  Asking is
+# one small program, a synchronous readback of 2 x P integers and the
+# dispatch of the cut.  Measured on the four-chip v5e host over batches
+# of 9 B a slot (PERF.md section 6, PR 31): a whole fetch takes 1.24 ms
+# + 0.114 ms a MB up to 38 MB (small copies run at 8.8 GB/s; the 0.8
+# GB/s of section 5 is the rate of answers of hundreds of MB), a fetch
+# that asks and finds 1% valid 3.0 - 3.1 ms flat, one that asks and
+# finds the batch full 1.2 - 1.4 ms over the whole fetch.  So an answer
+# that is all padding pays the asking back from 16 MB on (9.4 MB: 2.1
+# ms whole, 3.1 asked; 37.7 MB: 5.4 whole, 3.1 asked), and under that
+# asking can only lose.  (At the 0.8 GB/s of large answers the same
+# 1.2 ms would buy 1 MB, ISSUE 31's guess; the measured rate of small
+# copies is what a small answer pays.)
+TRIM_MIN_BYTES = 16 << 20
+
+
+class ShardRows(NamedTuple):
+    """What :meth:`ColumnBatch.fetch_host` measured of a batch on the
+    device: of each shard's ``tier`` fetched slots, ``counts`` are valid
+    and all of them lie in the first ``extents``."""
+
+    tier: int
+    counts: Tuple[int, ...]
+    extents: Tuple[int, ...]
+
+    @property
+    def packed(self) -> bool:
+        """No hole before a shard's last valid row: its rows are a
+        slice."""
+        return self.counts == self.extents
+
+    def slices(self) -> List[slice]:
+        """The valid rows of the fetched arrays, a shard (``packed``)."""
+        return [
+            slice(s * self.tier, s * self.tier + n)
+            for s, n in enumerate(self.counts)
+        ]
+
+
+@functools.lru_cache(maxsize=64)
+def trim_tiers(capacity: int) -> Tuple[int, ...]:
+    """The sizes a shard of ``capacity`` slots may be cut to for the
+    copy back, ascending: ``capacity / 2^(k/4)`` rounded up to 8 rows,
+    down to 8, and ``capacity`` itself last.  A bounded ladder (about
+    ``4 log2(capacity)`` rungs, so as many trim programs at worst) that
+    copies at most a fifth over what the valid rows need; the powers of
+    two below ``capacity`` are rungs exactly."""
+    tiers = set()
+    k = 0
+    while True:
+        rows = math.ceil(
+            capacity * 2.0 ** (-(k % 4) / 4.0) / (1 << (k // 4))
+        )
+        tier = min(capacity, -(-rows // 8) * 8)
+        tiers.add(tier)
+        if tier <= 8:
+            return tuple(sorted(tiers))
+        k += 1
+
+
+def _nbytes(arrays: Dict[str, jax.Array]) -> int:
+    return sum(a.size * a.dtype.itemsize for a in arrays.values())
+
+
+def _one_local_layout(arrays: Dict[str, jax.Array]) -> bool:
+    """Every array is a device array this process can read whole, laid
+    over the devices as ``valid`` is (one device, or a mesh's named
+    sharding): the shards can then be cut alike.  The multi-controller
+    gang's batches are not, and keep the whole fetch."""
+    valid = arrays["#valid"]
+    if not isinstance(valid, jax.Array) or valid.shape[0] == 0:
+        return False
+    sharding = valid.sharding
+    if not (
+        isinstance(sharding, NamedSharding) or len(sharding.device_set) == 1
+    ):
+        return False
+    return all(
+        isinstance(a, jax.Array)
+        and a.is_fully_addressable
+        and a.sharding.is_equivalent_to(sharding, a.ndim)
+        for a in arrays.values()
+    )
+
+
+# the two egress programs, compiled: (name, sharding, shapes and
+# dtypes[, tier]) -> executable.  Bounded by the tier ladder a shape.
+_EGRESS_PROGRAMS: Dict[tuple, Callable] = {}
+
+
+def _egress_program(key, shard_fn, args, tracer, metrics) -> Callable:
+    """``shard_fn`` (a shard's arrays -> a shard's arrays) as a program
+    of its own over ``args``' sharding, compiled once a ``key``.  A
+    compile is a ``compile`` span and counts into ``xla_compiles`` /
+    ``xla_compile_s`` like a stage's."""
+    prog = _EGRESS_PROGRAMS.get(key)
+    if prog is not None:
+        return prog
+    name, sharding = key[0], key[1]
+    if isinstance(sharding, NamedSharding):
+        fn = jax.shard_map(
+            shard_fn, mesh=sharding.mesh, in_specs=(sharding.spec,),
+            out_specs=sharding.spec, check_vma=False,
+        )
+    else:  # one device: the shard is the array
+
+        def fn(shard):
+            return shard_fn(shard)
+
+    fn.__name__ = fn.__qualname__ = name
+    t0 = time.monotonic()
+    with tracer.span(name, cat="compile"):
+        prog = jax.jit(fn).lower(*args).compile()
+    if metrics is not None:
+        metrics.add("xla_compiles", 1.0, stage=name)
+        metrics.add("xla_compile_s", time.monotonic() - t0, stage=name)
+    _EGRESS_PROGRAMS[key] = prog
+    return prog
+
+
+def _shard_extent(valid: jax.Array) -> jax.Array:
+    """One shard's (valid rows, index of the last valid row + 1)."""
+    with jax.named_scope("dryad.egress.extent"):
+        last = jnp.where(
+            valid, jnp.arange(1, valid.shape[0] + 1, dtype=jnp.int32), 0
+        )
+        return jnp.stack(
+            [jnp.sum(valid, dtype=jnp.int32), jnp.max(last)]
+        )[None]
+
+
+def _shard_prefix(tier: int, shard: Dict[str, jax.Array]):
+    """One shard's first ``tier`` slots of every array."""
+    with jax.named_scope("dryad.egress.trim"):
+        return {n: a[:tier] for n, a in shard.items()}
+
+
+def _trim_to_extent(arrays, tracer: Tracer, metrics):
+    """``arrays`` (``#valid`` and the columns, one layout) cut on the
+    device to the tier their valid rows reach, and the
+    :class:`ShardRows` read back on the way.  At the top tier the
+    arrays come back as they are."""
+    valid = arrays["#valid"]
+    sharding = valid.sharding
+    capacity = sharding.shard_shape(valid.shape)[0]  # slots a shard
+    with tracer.span(
+        "fetch_trim", cat="readback", capacity=valid.shape[0],
+        shards=valid.shape[0] // capacity,
+    ) as sp:
+        extent_of = _egress_program(
+            ("dryad_egress_extent", sharding, valid.shape),
+            _shard_extent, (valid,), tracer, metrics,
+        )
+        measured = np.asarray(jax.device_get(extent_of(valid)))
+        counts = tuple(measured[:, 0].tolist())
+        extents = tuple(measured[:, 1].tolist())
+        reach = max(extents)
+        tier = next(t for t in trim_tiers(capacity) if t >= reach)
+        sp.add(
+            tier=tier, extent_max=reach, count=sum(counts),
+            trimmed=int(tier < capacity),
+        )
+        if tier < capacity:
+            shapes = tuple(
+                (n, a.shape, a.dtype.name) for n, a in sorted(arrays.items())
+            )
+            arrays = _egress_program(
+                ("dryad_egress_trim", sharding, shapes, tier),
+                functools.partial(_shard_prefix, tier), (arrays,),
+                tracer, metrics,
+            )(arrays)
+    return arrays, ShardRows(tier, counts, extents)
 
 
 def decode_physical_table(
@@ -275,14 +486,25 @@ def decode_physical_table(
     host: Dict[str, np.ndarray],
     dictionary: Optional[StringDictionary] = None,
 ) -> Dict[str, np.ndarray]:
-    """Physical host columns -> logical table (``valid`` is a bool mask
-    or a full slice).  The inverse of :func:`encode_physical`."""
+    """Physical host columns -> logical table.  ``valid`` says which
+    slots are rows: a bool mask, a full slice, or a list of slices, one
+    a shard (:meth:`ShardRows.slices`: the fetched prefix of each shard
+    where it has no hole, so no mask is walked), whose rows are joined
+    in shard order.  The inverse of :func:`encode_physical`."""
+    if isinstance(valid, list):
+
+        def rows(name):
+            return np.concatenate([host[name][s] for s in valid])
+
+    else:
+
+        def rows(name):
+            return np.asarray(host[name])[valid]
+
     out: Dict[str, np.ndarray] = {}
     for f in schema.fields:
         if f.ctype == ColumnType.STRING:
-            lo = host[f"{f.name}#h0"][valid]
-            hi = host[f"{f.name}#h1"][valid]
-            hashes = join64(lo, hi)
+            hashes = join64(rows(f"{f.name}#h0"), rows(f"{f.name}#h1"))
             if dictionary is None:
                 out[f.name] = hashes  # fall back to raw hashes
             else:
@@ -290,15 +512,15 @@ def decode_physical_table(
                     dictionary.lookup_all(hashes), dtype=object
                 )
         elif f.ctype == ColumnType.INT64:
-            lo = host[f"{f.name}#h0"][valid]
-            hi = host[f"{f.name}#h1"][valid]
-            out[f.name] = join64(lo, hi, signed=True)
+            out[f.name] = join64(
+                rows(f"{f.name}#h0"), rows(f"{f.name}#h1"), signed=True
+            )
         elif f.ctype == ColumnType.FLOAT64:
             from dryad_tpu.columnar.schema import ordered_i64_to_f64
 
-            lo = host[f"{f.name}#h0"][valid]
-            hi = host[f"{f.name}#h1"][valid]
-            out[f.name] = ordered_i64_to_f64(join64(lo, hi, signed=True))
+            out[f.name] = ordered_i64_to_f64(join64(
+                rows(f"{f.name}#h0"), rows(f"{f.name}#h1"), signed=True
+            ))
         else:
-            out[f.name] = np.asarray(host[f.name])[valid]
+            out[f.name] = rows(f.name)
     return out

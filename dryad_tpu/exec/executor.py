@@ -1479,7 +1479,6 @@ class GraphExecutor:
         (b,) = self._resolve_inputs(stage, bindings, results)
         self.events.emit("apply_host_start", stage=stage.id)
         P = self.P
-        cap = b.capacity // P
         if jax.process_count() > 1:
             # a plain host fetch of a cross-process array raises in a
             # multi-controller gang; gather the batch first (apply_host
@@ -1493,7 +1492,11 @@ class GraphExecutor:
                 for n, v in b.data.items()
             }
         else:
-            valid, host_cols, _ = b.fetch_host()  # overlapped d2h copies
+            # overlapped d2h copies, of the slots the valid rows reach
+            valid, host_cols, _, _ = b.fetch_host(
+                tracer=self.tracer, metrics=self.metrics
+            )
+        cap = len(valid) // P  # slots a partition, as fetched
         schema = p["schema"]
         phys = schema.device_names()
         expected = {n: _phys_np_dtype(n, schema) for n in phys}

@@ -990,12 +990,8 @@ class StreamExecutor:
     def _batch_to_host(self, batch, schema) -> Dict[str, np.ndarray]:
         """Materialize a device batch as a host logical table (the
         degrade path when device-side combining stops paying)."""
-        self.metrics.add(
-            "d2h_bytes",
-            sum(int(v.nbytes) for v in batch.data.values())
-            + int(batch.valid.nbytes),
-        )
-        return batch.to_numpy(schema, self.ctx.dictionary)
+        fetched = batch.fetch_host(metrics=self.metrics)  # counts d2h_bytes
+        return batch.to_numpy(schema, self.ctx.dictionary, _host=fetched[:2])
 
     def _group_partial_device(self, node, stream, keys, agg_list):
         """Pipelined driver: per-chunk partials stay DEVICE-RESIDENT

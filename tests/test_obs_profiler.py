@@ -152,7 +152,7 @@ def test_a_jobs_spans_nest_under_its_collect(recorded):
         "dryad:ingest:h2d", "dryad:compile:input+order_by",
         "dryad:dispatch:input+order_by", "dryad:readback:drain",
         "dryad:readback:fetch_wait", "dryad:readback:fetch_copy",
-        "dryad:decode:decode",
+        "dryad:decode:decode", "dryad:ingest:release",
     } == got
     for name, start, end, stats in inside:
         assert root[1] <= start <= end <= root[2], name
@@ -162,11 +162,13 @@ def test_a_jobs_spans_nest_under_its_collect(recorded):
     assert by_id[h2d[3]["parent_id"]][0] == "dryad:ingest:bind"
     compiled, = [a for a in inside if a[0].startswith("dryad:compile:")]
     assert by_id[compiled[3]["parent_id"]][0].startswith("dryad:dispatch:")
+    # the job that copied host arrays in lets go of them at its end
+    assert max(inside, key=lambda a: a[2])[0] == "dryad:ingest:release"
     # the requery finds the table resident and the program compiled
     _, again = _job(recorded, 1)
     assert {a[0] for a in again} == got - {
         "dryad:ingest:bind", "dryad:ingest:encode", "dryad:ingest:h2d",
-        "dryad:compile:input+order_by"}
+        "dryad:compile:input+order_by", "dryad:ingest:release"}
 
 
 def test_bind_time_spans_have_no_parent_and_no_query(recorded):

@@ -299,7 +299,7 @@ def test_deferred_abort_emits_job_failed(ctx, monkeypatch):
         lambda cols: {"x": cols["x"] + 1}
     )
 
-    def boom(self, extra=(), tracer=None):
+    def boom(self, extra=(), tracer=None, metrics=None):
         raise RuntimeError("transfer died")
 
     monkeypatch.setattr(ColumnBatch, "fetch_host", boom)

@@ -350,3 +350,43 @@ def test_pallas_bucket_per_term_on_chip(jaxmod):
     ref_f = np.bincount(k, weights=fv, minlength=K)
     err = np.abs(np.asarray(sums[1]) - ref_f)
     assert np.all(err <= _split_bf16_bound(k, fv, K))
+
+
+def test_fetch_trim_across_the_chips(jaxmod):
+    """The copy back of a group-by's answer over every chip of the
+    machine (the four-chip host: a ``shard_map`` slice of each shard's
+    padded columns): the trimmed answer is NumPy's, and what was copied
+    is a fraction of the capacity's bytes."""
+    from dryad_tpu import DryadContext
+
+    P = len(jaxmod.devices())
+    rng = np.random.default_rng(31)
+    n, groups = 1 << 20, 1 << 16
+    # one negative key keeps the dense rewrite off: the hash exchange
+    # and the segmented fold, whose answer is a prefix of each shard
+    k = (rng.integers(0, groups, n) - 1).astype(np.int32)
+    v = rng.standard_normal(n).astype(np.float32)
+    ctx = DryadContext(num_partitions_=P)
+    out = ctx.from_arrays({"k": k, "v": v}).group_by(
+        "k", {"c": ("count", None), "s": ("sum", "v")}).collect()
+
+    counts = np.bincount(k + 1, minlength=groups)
+    sums = np.bincount(k + 1, weights=v, minlength=groups)
+    present = np.flatnonzero(counts)
+    order = np.argsort(out["k"])
+    np.testing.assert_array_equal(out["k"][order], present - 1)
+    np.testing.assert_array_equal(out["c"][order], counts[present])
+    abs_sums = np.bincount(k + 1, weights=np.abs(v), minlength=groups)
+    tol = (counts[present] + 8) * 2.0**-23 * abs_sums[present] + 1e-6
+    assert np.all(np.abs(out["s"][order] - sums[present]) <= tol)
+
+    spans = {e["name"]: e for e in ctx.events.events() if e["kind"] == "span"}
+    trim, copy, decode = (spans[n] for n in ("fetch_trim", "fetch_copy", "decode"))
+    assert trim["trimmed"] == 1 and trim["shards"] == P == decode["shards"]
+    assert trim["count"] == len(present) == decode["rows"]
+    # mask + key + count + sum: 13 B a slot, of the slots fetched
+    assert copy["bytes"] == 13 * decode["fetched"] < 13 * decode["capacity"] // 4
+    metrics = ctx.executor.metrics
+    assert metrics.total("d2h_bytes") == copy["bytes"]
+    assert (metrics.total("d2h_bytes_trimmed")
+            == 13 * (decode["capacity"] - decode["fetched"]))
