@@ -23,7 +23,7 @@ Dryad + DryadLINQ (reference: wycharry/Dryad), re-designed TPU-first:
 """
 
 from dryad_tpu.utils.config import DryadConfig, StaticConfig
-from dryad_tpu.columnar.schema import Schema, ColumnType, StringDictionary
+from dryad_tpu.columnar.schema import BYTES, Schema, ColumnType, StringDictionary
 from dryad_tpu.columnar.batch import ColumnBatch
 
 from dryad_tpu.api.decomposable import Decomposable
@@ -36,6 +36,7 @@ __all__ = [
     "DryadConfig",
     "StaticConfig",
     "Schema",
+    "BYTES",
     "ColumnType",
     "StringDictionary",
     "ColumnBatch",

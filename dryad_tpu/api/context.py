@@ -25,7 +25,12 @@ import numpy as np
 from dryad_tpu.api.query import JobHandle, Query
 from dryad_tpu.columnar import io as CIO
 from dryad_tpu.columnar.batch import ColumnBatch
-from dryad_tpu.columnar.schema import ColumnType, Schema, StringDictionary
+from dryad_tpu.columnar.schema import (
+    BYTES,
+    ColumnType,
+    Schema,
+    StringDictionary,
+)
 from dryad_tpu.exec.events import EventLog
 from dryad_tpu.exec.executor import GraphExecutor
 from dryad_tpu.obs import flightrec, tracectx
@@ -70,7 +75,9 @@ def _infer_schema(arrays: Dict[str, np.ndarray]) -> Schema:
     fields = []
     for name, a in arrays.items():
         a = np.asarray(a)
-        if a.dtype == object or a.dtype.kind in ("U", "S"):
+        if a.dtype == np.uint8 and a.ndim == 2 and a.shape[1] > 0:
+            fields.append((name, BYTES(a.shape[1])))
+        elif a.dtype == object or a.dtype.kind in ("U", "S"):
             fields.append((name, ColumnType.STRING))
         elif a.dtype in _NP_TYPE_MAP:
             fields.append((name, _NP_TYPE_MAP[a.dtype]))
@@ -939,6 +946,7 @@ class DryadContext:
             table = batch.to_numpy(
                 query.schema, self.dictionary,
                 _host=(rows.slices() if packed else valid, host_cols),
+                tracer=self.tracer,
             )
             if self._codecs:
                 from dryad_tpu.columnar.codecs import collapse_table

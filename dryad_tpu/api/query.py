@@ -671,13 +671,13 @@ class Query:
         f = self.schema.field(tag_col)
         if f.ctype.is_split:
             phys = self._physical_row({tag_col: value})
-            h0 = phys[f"{tag_col}#h0"]
-            h1 = phys[f"{tag_col}#h1"]
+            words = [(n, phys[n]) for n in f.identity_names]
 
             def fn(cols):
-                return (cols[f"{tag_col}#h0"] == h0) & (
-                    cols[f"{tag_col}#h1"] == h1
-                )
+                same = cols[words[0][0]] == words[0][1]
+                for n, w in words[1:]:
+                    same = same & (cols[n] == w)
+                return same
         else:
             def fn(cols):
                 return cols[tag_col] == value
@@ -984,6 +984,14 @@ class Query:
         arrays = {}
         for f in self.schema.fields:
             v = values.get(f.name)
+            if f.ctype.is_bytes:
+                width = f.ctype.width
+                if v is None:
+                    v = bytes(width)
+                if isinstance(v, (bytes, bytearray)):
+                    v = np.frombuffer(bytes(v), np.uint8)
+                arrays[f.name] = np.asarray(v, np.uint8).reshape(1, width)
+                continue
             if v is None:
                 v = "" if f.ctype == ColumnType.STRING else 0
             arrays[f.name] = np.asarray([v])

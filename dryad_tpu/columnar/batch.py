@@ -28,8 +28,10 @@ from dryad_tpu.columnar.schema import (
     ColumnType,
     Schema,
     StringDictionary,
+    bytes_to_words,
     join64,
     split64,
+    words_to_bytes,
 )
 from dryad_tpu.obs.span import UNTRACED, Tracer
 
@@ -39,9 +41,13 @@ def encode_physical(
 ) -> Dict[str, np.ndarray]:
     """One logical host column -> its physical device/store columns
     (STRING: Hash64 words + memcomparable prefix ranks; INT64/FLOAT64:
-    order-preserving split words).  Shared by device ingest and the
+    order-preserving split words; BYTES: big-endian words).  Shared by
+    device ingest and the
     streaming store writer, so ``.dpf`` parts written out-of-core read
     back through the same ``store`` binding path."""
+    if field.ctype.is_bytes:
+        words = bytes_to_words(a, field.ctype.width)
+        return dict(zip(field.device_names, words))
     if field.ctype == ColumnType.STRING:
         if dictionary is None:
             raise ValueError(f"STRING column {field.name} needs a dictionary")
@@ -72,11 +78,14 @@ def encode_table(
     schema: Schema,
     arrays: Dict[str, np.ndarray],
     dictionary: Optional[StringDictionary],
+    tracer: Tracer = UNTRACED,
 ) -> Tuple[Dict[str, np.ndarray], int]:
     """Logical host table -> ``(physical host columns, row count)``.
     Host-only (NumPy in, NumPy out): the sharded ingest edge
     (``parallel.distribute.from_host_table``) places these columns
-    itself, so nothing here may touch a device."""
+    itself, so nothing here may touch a device.  Turning a BYTES
+    column into its words is a ``pack`` span of ``tracer`` (``bytes``
+    of the column as handed in, ``rows``)."""
     n = None
     for name in schema.names:
         a = np.asarray(arrays[name])
@@ -86,9 +95,14 @@ def encode_table(
             raise ValueError("ragged input columns")
     phys: Dict[str, np.ndarray] = {}
     for f in schema.fields:
-        phys.update(
-            encode_physical(f, np.asarray(arrays[f.name]), dictionary)
-        )
+        a = np.asarray(arrays[f.name])
+        if f.ctype.is_bytes:
+            with tracer.span(
+                "pack", cat="ingest", bytes=a.size, rows=len(a)
+            ):
+                phys.update(encode_physical(f, a, dictionary))
+        else:
+            phys.update(encode_physical(f, a, dictionary))
     return phys, n or 0
 
 
@@ -295,13 +309,14 @@ class ColumnBatch:
         schema: Schema,
         dictionary: Optional[StringDictionary] = None,
         _host: Optional[Tuple[np.ndarray, Dict[str, np.ndarray]]] = None,
+        tracer: Tracer = UNTRACED,
     ) -> Dict[str, np.ndarray]:
         """Decode valid rows back to host logical columns.  ``_host``:
         already-fetched ``(rows, columns)`` from :meth:`fetch_host`
         (callers that batched the transfer with extra arrays); ``rows``
         is whatever :func:`decode_physical_table` takes."""
         valid, host = _host if _host is not None else self.fetch_host()[:2]
-        return decode_physical_table(schema, valid, host, dictionary)
+        return decode_physical_table(schema, valid, host, dictionary, tracer)
 
 
 # fetch_host asks how far a batch's valid rows reach only when the
@@ -485,12 +500,15 @@ def decode_physical_table(
     valid,
     host: Dict[str, np.ndarray],
     dictionary: Optional[StringDictionary] = None,
+    tracer: Tracer = UNTRACED,
 ) -> Dict[str, np.ndarray]:
     """Physical host columns -> logical table.  ``valid`` says which
     slots are rows: a bool mask, a full slice, or a list of slices, one
     a shard (:meth:`ShardRows.slices`: the fetched prefix of each shard
     where it has no hole, so no mask is walked), whose rows are joined
-    in shard order.  The inverse of :func:`encode_physical`."""
+    in shard order.  The inverse of :func:`encode_physical`; turning a
+    BYTES column's words back into bytes is an ``unpack`` span of
+    ``tracer`` (``bytes`` of the column as handed out, ``rows``)."""
     if isinstance(valid, list):
 
         def rows(name):
@@ -503,7 +521,9 @@ def decode_physical_table(
 
     out: Dict[str, np.ndarray] = {}
     for f in schema.fields:
-        if f.ctype == ColumnType.STRING:
+        if f.ctype.is_bytes:
+            out[f.name] = _unpack_bytes(f, valid, host, rows, tracer)
+        elif f.ctype == ColumnType.STRING:
             hashes = join64(rows(f"{f.name}#h0"), rows(f"{f.name}#h1"))
             if dictionary is None:
                 out[f.name] = hashes  # fall back to raw hashes
@@ -523,4 +543,25 @@ def decode_physical_table(
             ))
         else:
             out[f.name] = rows(f.name)
+    return out
+
+
+def _unpack_bytes(field, valid, host, rows, tracer: Tracer) -> np.ndarray:
+    """One BYTES column of :func:`decode_physical_table`, ``[rows,
+    width]`` uint8.  Where the valid rows are slices of the fetched
+    words, each shard's are unpacked straight into its rows of the
+    answer: no copy of the words is made first."""
+    width, names = field.ctype.width, field.device_names
+    if isinstance(valid, list):
+        parts = [[host[c][s] for c in names] for s in valid]  # views
+    else:
+        parts = [[rows(c) for c in names]]
+    total = sum(len(words[0]) for words in parts)
+    with tracer.span("unpack", cat="decode", bytes=total * width, rows=total):
+        out = np.empty((total, width), np.uint8)
+        at = 0
+        for words in parts:
+            n = len(words[0])
+            words_to_bytes(words, width, out[at : at + n])
+            at += n
     return out

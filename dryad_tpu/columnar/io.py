@@ -21,7 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from dryad_tpu.columnar.schema import ColumnType, Schema, StringDictionary
+from dryad_tpu.columnar.schema import Schema, StringDictionary, parse_ctype
 
 MANIFEST = "manifest.json"
 DICTFILE = "dictionary.json"
@@ -123,7 +123,7 @@ def load_store_meta(path: str):
     store metadata format."""
     with open(os.path.join(path, MANIFEST)) as fh:
         manifest = json.load(fh)
-    schema = Schema([(n, ColumnType(t)) for n, t in manifest["schema"]])
+    schema = Schema([(n, parse_ctype(t)) for n, t in manifest["schema"]])
     dict_map: Dict[int, str] = {}
     dpath = os.path.join(path, DICTFILE)
     if os.path.exists(dpath):

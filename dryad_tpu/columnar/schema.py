@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -43,6 +44,10 @@ class ColumnType(enum.Enum):
         return self in (ColumnType.INT64, ColumnType.FLOAT64, ColumnType.STRING)
 
     @property
+    def is_bytes(self) -> bool:
+        return False
+
+    @property
     def numpy_dtype(self) -> np.dtype:
         return {
             ColumnType.INT32: np.dtype(np.int32),
@@ -55,7 +60,58 @@ class ColumnType(enum.Enum):
         }[self]
 
 
-def device_column_names(name: str, ctype: ColumnType) -> List[str]:
+@dataclasses.dataclass(frozen=True)
+class BytesType:
+    """Fixed-width opaque bytes, ``BYTES(width)``: the column type that
+    carries its width.  Takes a :class:`ColumnType` member's place in a
+    :class:`Field` and answers what the members answer.
+
+    Host form: a 2-D ``uint8`` array ``[rows, width]``, in and out.
+    Device form: ``ceil(width / 4)`` uint32 columns ``#b0``, ``#b1``,
+    ..., each four bytes as one big-endian word, the last zero-padded
+    on the right, so the words' lexicographic order is the bytes'
+    ``memcmp`` order and, every value having the same width, the
+    padding never decides.  No dictionary, no hash: the words are the
+    value."""
+
+    width: int
+
+    def __post_init__(self) -> None:
+        if not isinstance(self.width, int) or self.width < 1:
+            raise ValueError(f"BYTES width must be a positive int, got {self.width!r}")
+
+    is_split = True
+    is_bytes = True
+
+    @property
+    def value(self) -> str:
+        return f"bytes[{self.width}]"
+
+    @property
+    def words(self) -> int:
+        """uint32 device columns a value takes."""
+        return -(-self.width // 4)
+
+    @property
+    def numpy_dtype(self) -> np.dtype:
+        return np.dtype(np.uint8)
+
+    def __repr__(self) -> str:
+        return f"BYTES({self.width})"
+
+
+BYTES = BytesType
+
+
+def parse_ctype(value: str):
+    """The column type a manifest's string names: the inverse of
+    ``ctype.value`` (``"int32"`` ..., ``"bytes[10]"``)."""
+    if value.startswith("bytes[") and value.endswith("]"):
+        return BytesType(int(value[6:-1]))
+    return ColumnType(value)
+
+
+def device_column_names(name: str, ctype) -> List[str]:
     """Physical device-column names backing one logical column.
 
     INT64  -> ``#h0`` (low word), ``#h1`` (high word).
@@ -63,12 +119,93 @@ def device_column_names(name: str, ctype: ColumnType) -> List[str]:
     an order-preserving uint32 rank of the first 4 UTF-8 bytes
     (big-endian), so range partitioning / OrderBy on strings is exact on
     4-byte prefixes with hash-order tie-breaking beyond that.
+    BYTES(w) -> ``#b0`` ... ``#b<ceil(w / 4) - 1>``, big-endian words in
+    byte order (:class:`BytesType`).
     """
+    if ctype.is_bytes:
+        return [f"{name}#b{i}" for i in range(ctype.words)]
     if ctype == ColumnType.STRING:
         return [f"{name}#h0", f"{name}#h1", f"{name}#r0", f"{name}#r1"]
     if ctype in (ColumnType.INT64, ColumnType.FLOAT64):
         return [f"{name}#h0", f"{name}#h1"]
     return [name]
+
+
+# Rows a block of the two transposing passes below: a block of a
+# 128-byte column and its words (2 x 2 MiB) stays in the host's cache,
+# where one whole-table transposing copy walks a page a row a word.
+# A table of many blocks is cut into as many runs of rows as there are
+# threads, because most of a pass over hundreds of MiB is the first
+# touch of newly mapped pages, which threads take side by side
+# (NumPy's copies release the GIL).  On the chip's host, 2^23 records
+# of 100 bytes: ``pack`` 1.267 -> 0.365 s, ``unpack`` 1.326 -> 0.507 s
+# with four threads, and the requery's run-to-run spread 1.23% -> 0.35%
+# (PERF.md section 6, PR 32).
+_WORD_BLOCK_ROWS = 1 << 14
+_WORD_THREADS = 4
+
+
+def _over_row_blocks(rows: int, width: int, work) -> None:
+    """``work(block, lo, n)`` for every block of rows ``[lo, lo + n)``;
+    ``block`` is a zeroed ``[block rows, 4 * words]`` uint8 scratch, one
+    a thread."""
+    words = -(-width // 4)
+
+    def run(lo: int, hi: int) -> None:
+        block = np.zeros((min(hi - lo, _WORD_BLOCK_ROWS), 4 * words), np.uint8)
+        for at in range(lo, hi, _WORD_BLOCK_ROWS):
+            work(block, at, min(_WORD_BLOCK_ROWS, hi - at))
+
+    blocks = -(-rows // _WORD_BLOCK_ROWS)
+    threads = min(_WORD_THREADS, blocks // 4)
+    if threads < 2:
+        run(0, rows)
+        return
+    per = -(-blocks // threads) * _WORD_BLOCK_ROWS
+    with ThreadPoolExecutor(threads) as pool:
+        list(pool.map(
+            lambda lo: run(lo, min(rows, lo + per)), range(0, rows, per)
+        ))
+
+
+def bytes_to_words(values: np.ndarray, width: int) -> List[np.ndarray]:
+    """``[rows, width]`` uint8 -> ``ceil(width / 4)`` uint32 arrays of
+    big-endian words (the rows of one ``[words, rows]`` array), the
+    last zero-padded.  Array passes a block of rows, never a row."""
+    a = np.asarray(values)
+    if a.dtype != np.uint8 or a.ndim != 2 or a.shape[1] != width:
+        raise ValueError(
+            f"BYTES({width}) takes a [rows, {width}] uint8 array, got "
+            f"{a.dtype} {a.shape}"
+        )
+    rows = a.shape[0]
+    out = np.empty((-(-width // 4), rows), np.uint32)
+
+    def pack(block, lo, n):  # the block's padding bytes stay zero
+        block[:n, :width] = a[lo : lo + n]
+        out[:, lo : lo + n] = block.view(">u4")[:n].T
+
+    _over_row_blocks(rows, width, pack)
+    return list(out)
+
+
+def words_to_bytes(
+    words: Sequence[np.ndarray], width: int, out: Optional[np.ndarray] = None
+) -> np.ndarray:
+    """The inverse of :func:`bytes_to_words`: ``[rows, width]`` uint8,
+    written into ``out`` where given."""
+    rows = len(words[0])
+    if out is None:
+        out = np.empty((rows, width), np.uint8)
+
+    def unpack(block, lo, n):
+        as_words = block.view(">u4")
+        for i, w in enumerate(words):
+            as_words[:n, i] = w[lo : lo + n]
+        out[lo : lo + n] = block[:n, :width]
+
+    _over_row_blocks(rows, width, unpack)
+    return out
 
 
 def string_prefix_rank(strings: "np.ndarray", offset: int = 0) -> "np.ndarray":
@@ -201,11 +338,18 @@ class StringDictionary:
 @dataclasses.dataclass(frozen=True)
 class Field:
     name: str
-    ctype: ColumnType
+    ctype: ColumnType  # or a BytesType, which carries the width
 
     @property
     def device_names(self) -> List[str]:
         return device_column_names(self.name, self.ctype)
+
+    @property
+    def identity_names(self) -> List[str]:
+        """The device columns whose tuple-equality is value equality:
+        all of them, but for STRING, whose ``#r`` rank words only order."""
+        names = self.device_names
+        return names[:2] if self.ctype == ColumnType.STRING else names
 
 
 class Schema:

@@ -38,7 +38,12 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from dryad_tpu.columnar import io as CIO
-from dryad_tpu.columnar.schema import ColumnType, Schema, StringDictionary
+from dryad_tpu.columnar.schema import (
+    ColumnType,
+    Schema,
+    StringDictionary,
+    parse_ctype,
+)
 
 ReadResult = Tuple[Schema, List[Dict[str, np.ndarray]], StringDictionary]
 
@@ -165,7 +170,7 @@ def _read_store_via(fetch: Callable[[str], bytes], threads: int) -> ReadResult:
     from concurrent.futures import ThreadPoolExecutor
 
     manifest = json.loads(fetch(CIO.MANIFEST).decode("utf-8"))
-    schema = Schema([(n, ColumnType(t)) for n, t in manifest["schema"]])
+    schema = Schema([(n, parse_ctype(t)) for n, t in manifest["schema"]])
     dictionary = StringDictionary()
     try:
         dmap = json.loads(fetch(CIO.DICTFILE).decode("utf-8"))

@@ -172,11 +172,11 @@ class _CompileTimed:
 
     __slots__ = (
         "fn", "_exec", "_name", "_key", "_build_s", "_pending",
-        "xchg_rounds", "join_plans",
+        "xchg_rounds", "join_plans", "sorted_words",
     )
 
     def __init__(self, fn, executor, name, key_hash, build_s,
-                 xchg_rounds=None, join_plans=None):
+                 xchg_rounds=None, join_plans=None, sorted_words=None):
         self.fn = fn
         self._exec = executor
         self._name = name
@@ -191,6 +191,14 @@ class _CompileTimed:
         # (kernels._apply_join_strategy): emitted as join_plan events
         # by the first call, which is the one that traces.
         self.join_plans = join_plans if join_plans is not None else []
+        # [4-byte words of the widest row a sort of the stage carries]
+        # (kernels.build_stage_fn), filled at trace time like the two
+        # above: the ``row_words`` stat of every ``dispatch`` span.
+        self.sorted_words = sorted_words if sorted_words is not None else []
+
+    @property
+    def row_words(self) -> int:
+        return max(self.sorted_words, default=0)
 
     def __call__(self, *args):
         if not self._pending:
@@ -429,11 +437,13 @@ class GraphExecutor:
             sizes = tuple(self.mesh.shape[a] for a in axes)
             cell: List[Dict[str, int]] = []
             joins: List[Dict[str, Any]] = []
+            sorts: List[int] = []
             if isinstance(run_stage, FusedStage):
                 fn = build_fused_fn(
                     run_stage, self.P, self.config.shuffle_slack, boost,
                     axes, sizes, operand_objs=objs,
                     window=window, xchg_cell=cell, join_cell=joins,
+                    sort_cell=sorts,
                 )
                 compiled = compile_fused(self.mesh, fn)
             else:
@@ -441,12 +451,13 @@ class GraphExecutor:
                     run_stage, self.P, self.config.shuffle_slack, boost,
                     axes, sizes, operand_objs=objs,
                     window=window, xchg_cell=cell, join_cell=joins,
+                    sort_cell=sorts,
                 )
                 compiled = compile_stage(self.mesh, fn)
             hit = _CompileTimed(
                 compiled, self, run_stage.name,
                 _lowering_key_hash(key), time.monotonic() - t0,
-                xchg_rounds=cell, join_plans=joins,
+                xchg_rounds=cell, join_plans=joins, sorted_words=sorts,
             )
             self._compiled[key] = hit
         return hit
@@ -1175,12 +1186,15 @@ class GraphExecutor:
                 # the XLA profiler timeline it is the annotation
                 # ``dryad:dispatch:<stage>`` (obs/span.py), which also
                 # says how many bytes a chip puts on the ICI in the
-                # stage's exchanges (a trace-time constant: 0 on the
-                # one dispatch that traces, whose event gets it below).
+                # stage's exchanges and how many 4-byte words the
+                # widest row that a sort of the stage carries has
+                # (trace-time constants: 0 on the one dispatch that
+                # traces, whose event gets them below).
                 with self.tracer.span(
                     stage.name, cat="execute", stage=stage.id,
                     version=version, boost=boost,
                     xchg_ici_bytes=_ici_bytes(fn.xchg_rounds),
+                    row_words=fn.row_words,
                 ) as dispatch_span:
                     # OPERAND params ride the replicated slot: current
                     # table content from the pool (uploaded/scattered
@@ -1198,7 +1212,8 @@ class GraphExecutor:
                             qid=tracectx.current_qid(), **rnd,
                         )
                     dispatch_span.add(
-                        xchg_ici_bytes=_ici_bytes(fn.xchg_rounds))
+                        xchg_ici_bytes=_ici_bytes(fn.xchg_rounds),
+                        row_words=fn.row_words)
                     counts_dev = None
                     if want_count:
                         import jax.numpy as jnp

@@ -1105,7 +1105,8 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
                    operand_objs: "Tuple[Any, ...]" = (),
                    window: int = 0,
                    xchg_cell: "List[Dict[str, int]]" = None,
-                   join_cell: "List[Dict[str, Any]]" = None):
+                   join_cell: "List[Dict[str, Any]]" = None,
+                   sort_cell: "List[int]" = None):
     """Compose a whole fused REGION (``plan.fuse.FusedStage``) into one
     per-partition function: the member stage fns chain device-resident
     — member i's output batches feed member j's slots directly in HBM,
@@ -1136,12 +1137,13 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
     # the region fn flattens them in member order into the caller's.
     member_cells = [[] for _ in members]
     member_joins = [[] for _ in members]
+    member_sorts = [[] for _ in members]
     member_fns = [
         build_stage_fn(
             m, P, slack, boost, axes, axis_sizes,
             operand_objs=member_objs[i],
             window=window, xchg_cell=member_cells[i],
-            join_cell=member_joins[i],
+            join_cell=member_joins[i], sort_cell=member_sorts[i],
         )
         for i, m in enumerate(members)
     ]
@@ -1183,6 +1185,8 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
             xchg_cell[:] = [r for c in member_cells for r in c]
         if join_cell is not None:
             join_cell[:] = [r for c in member_joins for r in c]
+        if sort_cell is not None:
+            sort_cell[:] = [max((w for c in member_sorts for w in c), default=0)]
         return region_outs, (overflow, miss)
 
     return fn
@@ -1194,7 +1198,8 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
                    operand_objs: "Tuple[Any, ...]" = (),
                    window: int = 0,
                    xchg_cell: "List[Dict[str, int]]" = None,
-                   join_cell: "List[Dict[str, Any]]" = None):
+                   join_cell: "List[Dict[str, Any]]" = None,
+                   sort_cell: "List[int]" = None):
     """Compose the stage's ops into one per-partition function.
 
     ``operand_objs``: the stage's OPERAND-registered param objects (in
@@ -1217,10 +1222,13 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
                 f"stage {stage.name!r}: {len(rep)} replicated operand "
                 f"arrays for {pos} registered operand slots"
             )
-        for op in stage.ops:
-            if op.kind == "do_while":
-                raise RuntimeError("do_while stages are driver-evaluated")
-            apply_op(ctx, op.kind, op.params)
+        with SORT.widest_row() as row_words:
+            for op in stage.ops:
+                if op.kind == "do_while":
+                    raise RuntimeError(
+                        "do_while stages are driver-evaluated"
+                    )
+                apply_op(ctx, op.kind, op.params)
         outs = tuple(ctx.slots[s] for s in stage.out_slots)
         # Overflow flags from resize/join are per-device; reduce across the
         # mesh so the replicated output is truly uniform (a silently
@@ -1233,6 +1241,9 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
             xchg_cell[:] = list(ctx.xchg_log)
         if join_cell is not None:
             join_cell[:] = list(ctx.join_log)
+        if sort_cell is not None:
+            # 4-byte words of the widest row a sort of the stage carried
+            sort_cell[:] = row_words
         return outs, (overflow, miss)
 
     return fn

@@ -2257,6 +2257,8 @@ def _empty_table(schema: Schema) -> Dict[str, np.ndarray]:
     for f in schema.fields:
         if f.ctype is ColumnType.STRING:
             out[f.name] = np.array([], object)
+        elif f.ctype.is_bytes:
+            out[f.name] = np.zeros((0, f.ctype.width), np.uint8)
         else:
             out[f.name] = np.array([], f.ctype.numpy_dtype)
     return out

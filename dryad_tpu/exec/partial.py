@@ -272,22 +272,21 @@ def coded_combine(tables, coeffs, key_cols, state_cols):
     return out
 
 
-_PHYS_SUFFIXES = ("#h0", "#h1", "#r0", "#r1")
-
-
 def copy_physical(cols, src: str, dst: str, out) -> None:
     """Copy a logical column between physical column dicts, whatever
-    its physical width (plain, split-word, or string 4-column)."""
+    its physical width (plain, split-word, string 4-column, or the
+    words of a BYTES column)."""
     if src in cols:
         out[dst] = cols[src]
         return
-    found = False
-    for suf in _PHYS_SUFFIXES:
-        if f"{src}{suf}" in cols:
-            out[f"{dst}{suf}"] = cols[f"{src}{suf}"]
-            found = True
-    if not found:
+    words = [
+        c for c in cols
+        if c.startswith(f"{src}#") and "#" not in c[len(src) + 1:]
+    ]
+    if not words:
         raise KeyError(src)
+    for c in words:
+        out[f"{dst}{c[len(src):]}"] = cols[c]
 
 
 def finalize_fn(plan):

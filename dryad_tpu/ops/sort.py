@@ -13,7 +13,10 @@ partition (searchsorted semantics), so secondary sort keys stay local.
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+import contextlib
+import math
+import threading
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,11 +57,71 @@ def _carry_profitable() -> bool:
     return _on_tpu()
 
 
+# The scope of a payload that moves apart from its sort (the row-index
+# form of :func:`sort_carry` where the row's width chose it).
+PAYLOAD_SCOPE = "dryad.sort.payload"
+
+# How a row's columns go through a carried sort: as extra ``lax.sort``
+# operands ("ride"), or gathered afterwards by one carried row index
+# ("index").
+RIDE, INDEX = "ride", "index"
+
+# The widest row, in 4-byte words, that rides its sorts on the TPU and
+# that is gathered a column at a time elsewhere.  The compile time of
+# a variadic ``lax.sort`` on the TPU grows far faster than its
+# operands: the ``order_by`` of a 26-word row (the sort benchmark's
+# 100-byte record), three carried sorts of 28 - 29 operands, had not
+# compiled after 1,200 s on the chip's host, where the 4-operand sorts
+# of ``sort-1c`` take 86 s together (``PERF.md`` section 6, PR 32).  A
+# wider row goes by the row index, all its words in one stacked
+# gather.  No row of the cells that ride is wider than 5 words; where
+# between 5 and 26 the two forms cross has not been measured.
+WIDE_ROW_WORDS = 8
+
+
+def carry_form(row_words: int) -> str:
+    """The form :func:`sort_carry` takes for a row of ``row_words``
+    4-byte words, from what a trace can see: the platform and the
+    row's width."""
+    rides = _carry_profitable() and row_words <= WIDE_ROW_WORDS
+    return RIDE if rides else INDEX
+
+
+def _words(arrays: Sequence[jax.Array]) -> int:
+    """4-byte words a row of these columns takes (shapes only)."""
+    return sum(
+        -(-a.dtype.itemsize * math.prod(a.shape[1:]) // 4) for a in arrays
+    )
+
+
+class _WidestRow(threading.local):
+    words = 0
+
+
+_widest_row = _WidestRow()
+
+
+@contextlib.contextmanager
+def widest_row():
+    """Trace-time record of the widest row (4-byte words of the carried
+    columns) any :func:`sort_carry` under it moved: the ``row_words``
+    stat of a stage's ``dispatch`` span.  Yields a one-element list
+    that holds the reading once the block has ended."""
+    before, _widest_row.words = _widest_row.words, 0
+    seen = [0]
+    try:
+        yield seen
+    finally:
+        seen[0] = _widest_row.words
+        _widest_row.words = max(before, seen[0])
+
+
 @jax.named_scope("dryad.sort.carry")
 def sort_carry(
     operands: Sequence[jax.Array],
     valid: jax.Array,
     carry: Sequence[jax.Array] = (),
+    form: Optional[str] = None,
 ) -> Tuple[jax.Array, List[jax.Array], List[jax.Array]]:
     """Stable sort (valid rows first, lexicographic by uint32 operands)
     carrying payload arrays along.
@@ -71,22 +134,53 @@ def sort_carry(
     payload is gathered by the sorted row index (cheaper off-TPU).
     ``lax.sort`` operands share one shape, so a payload with trailing
     dimensions never rides: it is gathered by one carried row index,
-    which is sorted only when such a payload is present.
+    which is sorted only when such a payload is present.  ``form``
+    (``RIDE`` / ``INDEX``) is what :func:`carry_form` says of the
+    row's width unless a caller that compares the two forces one.
     """
     inv = jnp.logical_not(valid).astype(jnp.uint32)
     ops = (inv,) + tuple(o.astype(jnp.uint32) for o in operands)
-    profitable = bool(carry) and _carry_profitable()
-    rides = [profitable and c.ndim == 1 for c in carry]
+    row_words = _words(carry)
+    _widest_row.words = max(_widest_row.words, row_words)
+    chosen = form or carry_form(row_words)
+    rides = [chosen == RIDE and c.ndim == 1 for c in carry]
     riders = tuple(c for c, r in zip(carry, rides) if r)
     if not all(rides):  # the row index, last, for what cannot ride
         riders += (jnp.arange(valid.shape[0], dtype=jnp.int32),)
     res = jax.lax.sort(ops + riders, num_keys=len(ops), is_stable=True)
     rode, order = iter(res[len(ops):]), res[-1]
-    return (
-        res[0] == 0,
-        list(res[1:len(ops)]),
-        [next(rode) if r else c[order] for c, r in zip(carry, rides)],
-    )
+    sorted_valid = res[0] == 0
+    with jax.named_scope(PAYLOAD_SCOPE):  # what moves apart from the sort
+        apart = iter(_take_rows(
+            [c for c, r in zip(carry, rides) if not r], order,
+            stacked=row_words > WIDE_ROW_WORDS,
+        ))
+    moved = [next(rode) if r else next(apart) for r in rides]
+    return sorted_valid, list(res[1:len(ops)]), moved
+
+
+def _take_rows(
+    columns: Sequence[jax.Array], order: jax.Array, stacked: bool
+) -> List[jax.Array]:
+    """``[c[order] for c in columns]``.  ``stacked``: the 4-byte 1-D
+    columns go as one ``[columns, rows]`` array through ONE gather
+    along its rows, which on the TPU costs a tenth of a gather a
+    column (26 words over 2^24 slots: 0.663 s against 6.27 s;
+    ``PERF.md`` section 6, PR 32)."""
+    stack = [
+        stacked and c.ndim == 1 and c.dtype.itemsize == 4 for c in columns
+    ]
+    words = [
+        jax.lax.bitcast_convert_type(c, jnp.uint32)
+        for c, s in zip(columns, stack) if s
+    ]
+    if len(words) < 2:
+        return [c[order] for c in columns]
+    taken = iter(jnp.stack(words)[:, order])
+    return [
+        jax.lax.bitcast_convert_type(next(taken), c.dtype) if s else c[order]
+        for c, s in zip(columns, stack)
+    ]
 
 
 def sort_batch_by_operands(

@@ -75,7 +75,7 @@ def from_host_table(
     # column, no full-size array on the default device.
     rows = len(next(iter(arrays.values()))) if arrays else 0
     with tracer.span("encode", cat="ingest", rows=rows):
-        phys, _n = encode_table(schema, arrays, dictionary)
+        phys, _n = encode_table(schema, arrays, dictionary, tracer)
     return from_physical_table(
         phys, mesh, partition_capacity, tracer=tracer, metrics=metrics
     )

@@ -4,7 +4,8 @@
 the caller doesn't declare the output schema we trace it with
 ``jax.eval_shape`` on dummy columns and reconstruct logical fields from
 the physical names: ``x#h0``/``x#h1``/``x#r0``/``x#r1`` quads are STRING,
-``x#h0``/``x#h1`` pairs are INT64, everything else maps by dtype.
+``x#h0``/``x#h1`` pairs are INT64, ``x#b0`` ... ``x#b<k-1>`` runs are
+BYTES, everything else maps by dtype.
 """
 
 from __future__ import annotations
@@ -14,7 +15,7 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-from dryad_tpu.columnar.schema import ColumnType, Schema
+from dryad_tpu.columnar.schema import BYTES, ColumnType, Schema
 
 _DEVICE_DTYPES = {
     ColumnType.INT32: jnp.int32,
@@ -63,6 +64,22 @@ def schema_from_physical(
             if base in seen:
                 continue
             seen.add(base)
+            mine = {n for n in names if n.startswith(f"{base}#")}
+            if any(n.startswith(f"{base}#b") for n in mine):
+                # BYTES: the words say how many, not how wide the last
+                # one is; a surviving column keeps its input width
+                if mine != {f"{base}#b{i}" for i in range(len(mine))}:
+                    raise ValueError(
+                        f"incomplete split column set for {base!r}: "
+                        f"{sorted(mine)}"
+                    )
+                ctype = BYTES(4 * len(mine))
+                if like is not None and base in like:
+                    kept = like.field(base).ctype
+                    if kept.is_bytes and kept.words == len(mine):
+                        ctype = kept
+                fields.append((base, ctype))
+                continue
             has = {f"{base}#{s}" for s in ("h0", "h1", "r0", "r1")} & names
             if has == {f"{base}#h0", f"{base}#h1", f"{base}#r0", f"{base}#r1"}:
                 fields.append((base, ColumnType.STRING))

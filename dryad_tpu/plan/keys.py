@@ -24,11 +24,7 @@ def equality_cols(schema: Schema, names: Sequence[str]) -> List[str]:
     """Physical columns whose tuple-equality == logical key equality."""
     out: List[str] = []
     for n in names:
-        f = schema.field(n)
-        if f.ctype.is_split:
-            out += [f"{n}#h0", f"{n}#h1"]
-        else:
-            out.append(n)
+        out.extend(schema.field(n).identity_names)
     return out
 
 
@@ -47,7 +43,10 @@ class OrderingOperands:
 
     INT64: (sign-flipped high word, low word).  STRING: (8-byte prefix
     rank words, hash words) — exact for 8-byte prefixes, hash-order
-    beyond (documented engine semantic for string ordering).
+    beyond (documented engine semantic for string ordering).  BYTES:
+    its big-endian words in byte order, one operand a word — exact
+    ``memcmp`` order over every byte (the last word's zero padding is
+    the same in every row, so it never decides).
 
     VALUE-equal (not identity-equal): re-lowering the same logical plan
     builds a new instance, and the compiled-stage cache keys ops by
@@ -69,7 +68,10 @@ class OrderingOperands:
     def __call__(self, batch: ColumnBatch) -> List[jax.Array]:
         ops: List[jax.Array] = []
         for f, desc in self.fields:
-            if f.ctype == ColumnType.STRING:
+            if f.ctype.is_bytes:
+                words = [batch.data[n] for n in f.device_names]
+                ops.extend(~w if desc else w for w in words)
+            elif f.ctype == ColumnType.STRING:
                 r0 = batch.data[f"{f.name}#r0"]
                 r1 = batch.data[f"{f.name}#r1"]
                 h0 = batch.data[f"{f.name}#h0"]

@@ -390,3 +390,40 @@ def test_fetch_trim_across_the_chips(jaxmod):
     assert metrics.total("d2h_bytes") == copy["bytes"]
     assert (metrics.total("d2h_bytes_trimmed")
             == 13 * (decode["capacity"] - decode["fetched"]))
+
+
+def test_the_third_key_word_decides_at_the_cells_rows(jaxmod):
+    """``sort-100b-1c``'s query at the cell's rows on a table whose
+    keys all share bytes 0 - 7, so that bytes 8 and 9, the half-empty
+    third word, decide every comparison (uniform keys never tie on
+    eight bytes at 2^23 rows: the timed data cannot show it), with
+    duplicates; the 90-byte payload follows, through the chip's own
+    form of the carried sort."""
+    import json
+    import os
+
+    from dryad_tpu import DryadContext
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "traffic", "sort_100b.json")) as fh:
+        rows = json.load(fh)["rows"]
+    rng = np.random.default_rng(32)
+    key = np.empty((rows, 10), np.uint8)
+    key[:, :8] = rng.integers(0, 256, 8, dtype=np.uint8)
+    tail = rng.integers(0, 1 << 16, rows, dtype=np.uint32)
+    key[:, 8], key[:, 9] = tail >> 8, tail & 0xFF
+    # a payload that is a function of the tail and of the byte's place
+    payload = ((tail[:, None] * np.uint32(2654435761)
+                + np.arange(90, dtype=np.uint32) * np.uint32(40503))
+               >> np.uint32(11)).astype(np.uint8)
+    ctx = DryadContext(num_partitions_=1)
+    out = ctx.from_arrays({"key": key, "payload": payload}).order_by(["key"]).collect()
+    order = np.argsort(tail, kind="stable")
+    assert out["key"].shape == (rows, 10) and out["payload"].shape == (rows, 90)
+    assert out["key"].dtype == out["payload"].dtype == np.uint8
+    np.testing.assert_array_equal(out["key"], key[order])
+    # duplicates carry equal payloads, so stability cannot hide a swap
+    np.testing.assert_array_equal(out["payload"], payload[order])
+    dispatched = [e for e in ctx.events.events()
+                  if e["kind"] == "span" and e.get("cat") == "execute"]
+    assert {e["row_words"] for e in dispatched} == {26}
