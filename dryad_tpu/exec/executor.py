@@ -172,11 +172,12 @@ class _CompileTimed:
 
     __slots__ = (
         "fn", "_exec", "_name", "_key", "_build_s", "_pending",
-        "xchg_rounds", "join_plans", "sorted_words",
+        "xchg_rounds", "join_plans", "sorted_words", "elided",
     )
 
     def __init__(self, fn, executor, name, key_hash, build_s,
-                 xchg_rounds=None, join_plans=None, sorted_words=None):
+                 xchg_rounds=None, join_plans=None, sorted_words=None,
+                 elided=None):
         self.fn = fn
         self._exec = executor
         self._name = name
@@ -195,10 +196,18 @@ class _CompileTimed:
         # (kernels.build_stage_fn), filled at trace time like the two
         # above: the ``row_words`` stat of every ``dispatch`` span.
         self.sorted_words = sorted_words if sorted_words is not None else []
+        # [exchanges the trace skipped because the mesh has one
+        # partition] (kernels._elided), filled at trace time too: the
+        # ``xchg_elided`` stat of every ``dispatch`` span.
+        self.elided = elided if elided is not None else []
 
     @property
     def row_words(self) -> int:
         return max(self.sorted_words, default=0)
+
+    @property
+    def xchg_elided(self) -> int:
+        return sum(self.elided)
 
     def __call__(self, *args):
         if not self._pending:
@@ -438,12 +447,13 @@ class GraphExecutor:
             cell: List[Dict[str, int]] = []
             joins: List[Dict[str, Any]] = []
             sorts: List[int] = []
+            elided: List[int] = []
             if isinstance(run_stage, FusedStage):
                 fn = build_fused_fn(
                     run_stage, self.P, self.config.shuffle_slack, boost,
                     axes, sizes, operand_objs=objs,
                     window=window, xchg_cell=cell, join_cell=joins,
-                    sort_cell=sorts,
+                    sort_cell=sorts, elided_cell=elided,
                 )
                 compiled = compile_fused(self.mesh, fn)
             else:
@@ -451,13 +461,14 @@ class GraphExecutor:
                     run_stage, self.P, self.config.shuffle_slack, boost,
                     axes, sizes, operand_objs=objs,
                     window=window, xchg_cell=cell, join_cell=joins,
-                    sort_cell=sorts,
+                    sort_cell=sorts, elided_cell=elided,
                 )
                 compiled = compile_stage(self.mesh, fn)
             hit = _CompileTimed(
                 compiled, self, run_stage.name,
                 _lowering_key_hash(key), time.monotonic() - t0,
                 xchg_rounds=cell, join_plans=joins, sorted_words=sorts,
+                elided=elided,
             )
             self._compiled[key] = hit
         return hit
@@ -1186,14 +1197,16 @@ class GraphExecutor:
                 # the XLA profiler timeline it is the annotation
                 # ``dryad:dispatch:<stage>`` (obs/span.py), which also
                 # says how many bytes a chip puts on the ICI in the
-                # stage's exchanges and how many 4-byte words the
-                # widest row that a sort of the stage carries has
-                # (trace-time constants: 0 on the one dispatch that
-                # traces, whose event gets them below).
+                # stage's exchanges, how many exchanges the trace
+                # skipped because the mesh has one partition, and how
+                # many 4-byte words the widest row that a sort of the
+                # stage carries has (trace-time constants: 0 on the one
+                # dispatch that traces, whose event gets them below).
                 with self.tracer.span(
                     stage.name, cat="execute", stage=stage.id,
                     version=version, boost=boost,
                     xchg_ici_bytes=_ici_bytes(fn.xchg_rounds),
+                    xchg_elided=fn.xchg_elided,
                     row_words=fn.row_words,
                 ) as dispatch_span:
                     # OPERAND params ride the replicated slot: current
@@ -1213,6 +1226,7 @@ class GraphExecutor:
                         )
                     dispatch_span.add(
                         xchg_ici_bytes=_ici_bytes(fn.xchg_rounds),
+                        xchg_elided=fn.xchg_elided,
                         row_words=fn.row_words)
                     counts_dev = None
                     if want_count:

@@ -86,6 +86,10 @@ class StageContext:
         # _exchange at trace time and surfaced by the executor as
         # exchange_round events (no device readback involved).
         self.xchg_log: List[Dict[str, int]] = []
+        # Exchanges this trace skipped because the mesh has one
+        # partition (``_elided``); the ``xchg_elided`` stat of
+        # the stage's ``dispatch`` spans.
+        self.xchg_elided = 0
         # One record a join kernel of what ``_apply_join_strategy``
         # decided at trace time; the executor emits each as a
         # ``join_plan`` event, once a compile.
@@ -193,6 +197,26 @@ def _fanout(ctx: StageContext, nparts) -> int:
     return min(int(nparts), ctx.P)
 
 
+def _elided(ctx: StageContext, exchange: bool) -> bool:
+    """The ONE rule for a mesh of one partition (every axis of size 1):
+    a repartition is the identity there, so ``exchange_hash``,
+    ``exchange_range`` and the ``resize`` paired with each trace NOTHING
+    — no splitter sample, no bucket layout, no send buffer, no
+    collective, no compaction, no growth of the capacity by the slack.
+    The slot's batch goes on as it is (invalid rows where they were,
+    which every kernel downstream masks as it does after a ``where``),
+    ``ctx.overflow`` untouched, and no ``exchange_round`` is accounted.
+    Decided here, from the partition count the program is traced for,
+    and nowhere else: ``lower()`` emits the same stage ops at every
+    width (four of its callers do not know P).  ``exchange`` says
+    whether the caller is an exchange, counted into
+    ``ctx.xchg_elided``, or the ``resize`` that follows one."""
+    if ctx.P != 1:
+        return False
+    ctx.xchg_elided += int(exchange)
+    return True
+
+
 def _exchange(
     ctx: StageContext, b: ColumnBatch, dest, P: int, B: int, axes
 ) -> Tuple[ColumnBatch, jax.Array]:
@@ -223,6 +247,8 @@ def _exchange(
 def _do_exchange_hash(
     ctx: StageContext, slot: int, keys, tree=None, nparts=None
 ) -> None:
+    if _elided(ctx, exchange=True):
+        return
     b = ctx.slots[slot]
     if tree is not None and len(ctx.axes) == 2:
         _tree_exchange_hash(ctx, slot, keys, tree)
@@ -285,6 +311,12 @@ def _tree_exchange_hash(ctx: StageContext, slot: int, keys, tree) -> None:
 def _do_resize(
     ctx: StageContext, slot: int, factor: float, nparts=None
 ) -> None:
+    """The capacity after an exchange (every ``resize`` stage op follows
+    one, ``plan/lower.py``): compact, then entry capacity x growth x
+    boost x slack.  On one partition the exchange before it moved
+    nothing, so there is nothing to compact or to make room for."""
+    if _elided(ctx, exchange=False):
+        return
     b = ctx.slots[slot]
     # A fan-reduced exchange concentrates ~P/P_eff partitions' rows
     # onto each live partition; scale the post-shuffle capacity so the
@@ -317,6 +349,12 @@ def _k_exchange_hash(ctx: StageContext, p) -> None:
 
 
 def _k_exchange_range(ctx: StageContext, p) -> None:
+    """Repartition by range: elect splitters from a sample of every
+    shard, send each row to the partition whose range holds it.  On a
+    mesh of one partition it emits nothing (``_elided``), and an
+    ``order_by`` is its ``local_sort`` alone."""
+    if _elided(ctx, exchange=True):
+        return
     b = ctx.slots[p["slot"]]
     operands = p["operands_fn"](b)
     # Splitter sample count = sample_rate fraction of the partition
@@ -1106,7 +1144,8 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
                    window: int = 0,
                    xchg_cell: "List[Dict[str, int]]" = None,
                    join_cell: "List[Dict[str, Any]]" = None,
-                   sort_cell: "List[int]" = None):
+                   sort_cell: "List[int]" = None,
+                   elided_cell: "List[int]" = None):
     """Compose a whole fused REGION (``plan.fuse.FusedStage``) into one
     per-partition function: the member stage fns chain device-resident
     — member i's output batches feed member j's slots directly in HBM,
@@ -1138,12 +1177,14 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
     member_cells = [[] for _ in members]
     member_joins = [[] for _ in members]
     member_sorts = [[] for _ in members]
+    member_elided = [[] for _ in members]
     member_fns = [
         build_stage_fn(
             m, P, slack, boost, axes, axis_sizes,
             operand_objs=member_objs[i],
             window=window, xchg_cell=member_cells[i],
             join_cell=member_joins[i], sort_cell=member_sorts[i],
+            elided_cell=member_elided[i],
         )
         for i, m in enumerate(members)
     ]
@@ -1187,6 +1228,8 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
             join_cell[:] = [r for c in member_joins for r in c]
         if sort_cell is not None:
             sort_cell[:] = [max((w for c in member_sorts for w in c), default=0)]
+        if elided_cell is not None:
+            elided_cell[:] = [sum(n for c in member_elided for n in c)]
         return region_outs, (overflow, miss)
 
     return fn
@@ -1199,7 +1242,8 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
                    window: int = 0,
                    xchg_cell: "List[Dict[str, int]]" = None,
                    join_cell: "List[Dict[str, Any]]" = None,
-                   sort_cell: "List[int]" = None):
+                   sort_cell: "List[int]" = None,
+                   elided_cell: "List[int]" = None):
     """Compose the stage's ops into one per-partition function.
 
     ``operand_objs``: the stage's OPERAND-registered param objects (in
@@ -1244,6 +1288,9 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
         if sort_cell is not None:
             # 4-byte words of the widest row a sort of the stage carried
             sort_cell[:] = row_words
+        if elided_cell is not None:
+            # exchanges this trace skipped on a mesh of one partition
+            elided_cell[:] = [ctx.xchg_elided]
         return outs, (overflow, miss)
 
     return fn

@@ -764,7 +764,10 @@ class _Builder:
             # order_by only needs global ORDER, so its exchange spreads
             # equal keys across partitions (skew-proof, kernels.py
             # _k_exchange_range); range_partition promises equal-key
-            # COLOCATION and keeps strict splitters.
+            # COLOCATION and keeps strict splitters.  The ops are the
+            # same at every width (P may be unknown here): on a mesh of
+            # one partition the exchange and its resize trace nothing
+            # (kernels._elided) and an order_by is its local_sort alone.
             nparts = self._tail_nparts(node.inputs[0])
             if nparts:
                 self.reduced.add(node.id)

@@ -18,7 +18,9 @@ from dryad_tpu.plan.lower import StageGraph
 from dryad_tpu.plan.nodes import Node, walk
 
 # Stage-op kinds that imply a cross-partition exchange inside the
-# compiled program (all_to_all / collective boundary).
+# compiled program (all_to_all / collective boundary).  The plan has
+# them at every width; a context of one partition traces none of them
+# (``exec/kernels.py::_elided``), which the legend then says.
 _EXCHANGE_OPS = {"exchange_hash", "exchange_range"}
 
 
@@ -50,8 +52,9 @@ def explain_logical(roots: Sequence[Node]) -> str:
     return "\n".join(lines)
 
 
-def explain_stages(graph: StageGraph) -> str:
-    """Render the fused stage graph (the SuperNode view)."""
+def explain_stages(graph: StageGraph, partitions: int = 0) -> str:
+    """Render the fused stage graph (the SuperNode view).  ``partitions``
+    is the context's width where the caller knows it (0 = unknown)."""
     lines = ["== stage graph =="]
     for s in graph.stages:
         refs = []
@@ -71,8 +74,11 @@ def explain_stages(graph: StageGraph) -> str:
     n_ex = sum(
         1 for s in graph.stages for op in s.ops if op.kind in _EXCHANGE_OPS
     )
-    lines.append(f"-- {len(graph.stages)} stages, {n_ex} exchanges "
-                 f"(* = cross-partition collective)")
+    legend = "* = cross-partition collective"
+    if partitions == 1 and n_ex:
+        legend += ("; on this context's one partition an exchange and its "
+                   "resize trace nothing")
+    lines.append(f"-- {len(graph.stages)} stages, {n_ex} exchanges ({legend})")
     return "\n".join(lines)
 
 
@@ -164,12 +170,15 @@ def explain_dot(query) -> str:
 def explain(query) -> str:
     """Full explain text for an API ``Query`` (logical + fused stages
     + the whole-DAG fusion regions the executor will dispatch)."""
+    from dryad_tpu.parallel.mesh import num_partitions
     from dryad_tpu.plan.lower import lower
 
     graph = lower([query.node], query.ctx.config, query.ctx.dictionary)
+    mesh = query.ctx.mesh  # None under local_debug
     return (
         explain_logical([query.node])
-        + "\n\n" + explain_stages(graph)
+        + "\n\n" + explain_stages(
+            graph, num_partitions(mesh) if mesh is not None else 0)
         + "\n\n" + explain_fusion(graph, query.ctx.config)
     )
 

@@ -12,6 +12,7 @@ import importlib.util
 import os
 import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -131,10 +132,17 @@ def test_the_cells_query_is_exact(job, shape, P):
             assert balance == 1.0
 
     # the dispatch span says what a chip put on the ICI, as the
-    # exchange_round events do; a retry is one more dispatch, at boost > 1
+    # exchange_round events do; a retry is one more dispatch, at boost > 1.
+    # On one partition the exchange is not traced: no round, no byte, no
+    # slack on the answer's capacity, and the span says one was skipped.
     dispatched = spans(events, cat="execute")
     rounds = [e for e in events if e["kind"] == "exchange_round"]
-    assert [e["xchg_ici_bytes"] for e in dispatched] == [r["ici_bytes"] for r in rounds]
+    if P == 1:
+        assert not rounds
+        assert [e["capacity"] for e in decoded] == [ROWS, ROWS]
+    else:
+        assert [e["xchg_ici_bytes"] for e in dispatched] == [r["ici_bytes"] for r in rounds]
+    assert [e["xchg_elided"] for e in dispatched] == [int(P == 1)] * len(dispatched)
     boosts = [e["boost"] for e in dispatched]
     assert boosts == BOOSTS.get((shape, P), [1, 1])
     overflows = [e for e in events if e["kind"] == "stage_overflow"]
@@ -166,3 +174,22 @@ def test_the_stage_program_is_the_parents(job, monkeypatch):
     assert any(under + "dryad.exchange.layout/dryad.sort.carry/" in p for p in paths)
     assert any("/dryad.resize/" in p + "/" for p in paths)
     assert any("/dryad.local_sort/dryad.sort.carry/" in p for p in paths)
+
+
+def test_the_one_chip_program_is_the_local_sort_alone(job, monkeypatch):
+    """``sort-1c``'s program, the same query at P = 1: the exchange and
+    its ``resize`` trace nothing there, so every operation is the
+    ``local_sort``'s and the answer has the slots the table had."""
+    program, = lowered_programs(
+        job, monkeypatch, the_table(job, "uniform"), {"rows": ROWS}, 1)
+    assert "module @jit_dryad_stage_2 " in program.as_text()
+    compiled = program.compile().as_text()
+    paths = re.findall(r'op_name="([^"]*)"', compiled)
+    assert any("/dryad.local_sort/dryad.sort.carry/" in p for p in paths)
+    assert not [p for p in paths if "dryad.exchange" in p or "dryad.resize" in p
+                or "dryad.sort.splitters" in p]
+    assert "scatter" not in compiled and "all-to-all" not in compiled
+    # no slack: the answer has the slots the table had
+    slots = {leaf.shape[0] for info in (program.args_info, program.out_info)
+             for leaf in jax.tree_util.tree_leaves(info) if leaf.shape}
+    assert slots == {ROWS}

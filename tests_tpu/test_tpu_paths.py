@@ -238,8 +238,17 @@ def test_exchange_carry_on_chip(jaxmod):
     """``order_by`` and a sort-path ``group_by`` through DryadContext
     on the chip, where the exchange layout and ``resize`` carry the
     columns through ``lax.sort`` (the default here, and only here),
-    against the LocalDebug interpreter (``exec/localdebug.py``)."""
+    against the LocalDebug interpreter (``exec/localdebug.py``).  On
+    one chip the plan's exchanges trace nothing (the ``dispatch`` span
+    counts them, ``xchg_elided``) and the two queries are their
+    ``local_sort`` and fold alone, so there the layout and ``resize``
+    are driven directly, against NumPy."""
+    import jax
+    import jax.numpy as jnp
+
     from dryad_tpu import DryadContext
+    from dryad_tpu.columnar.batch import ColumnBatch
+    from dryad_tpu.ops import shuffle as SH
     from dryad_tpu.ops.sort import _carry_profitable
     from dryad_tpu.plan.lower import lower
 
@@ -279,6 +288,31 @@ def test_exchange_carry_on_chip(jaxmod):
     np.testing.assert_allclose(
         grouped["s"][at], want_grouped["s"][want_at], rtol=1e-4, atol=1e-4
     )
+    one_chip = len(jaxmod.devices()) == 1
+    dispatched = [e for e in ctx.events.events()
+                  if e["kind"] == "span" and e.get("cat") == "execute"]
+    assert [e["xchg_elided"] for e in dispatched] == [int(one_chip)] * 2
+    rounds = [e for e in ctx.events.events() if e["kind"] == "exchange_round"]
+    assert len(rounds) == (0 if one_chip else 2)
+
+    # the bucket layout over three destinations and the compaction, jitted
+    # on one chip: rows side by side by destination, in their own order
+    valid = rng.random(n) < 0.8
+    dest = rng.integers(0, 3, n).astype(np.int32)
+    batch = ColumnBatch(
+        {c: jnp.asarray(a) for c, a in tbl.items()}, jnp.asarray(valid))
+    sb, dsorted, within, _, overflow = jax.jit(
+        lambda b, d: SH._bucket_layout(b, d, 3, n))(batch, jnp.asarray(dest))
+    order = np.argsort(np.where(valid, dest, 3), kind="stable")
+    np.testing.assert_array_equal(np.asarray(dsorted), np.where(valid, dest, 3)[order])
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(sb.data[name])[: valid.sum()], tbl[name][order][: valid.sum()])
+    assert not bool(overflow) and int(np.asarray(within)[0]) == 0
+    packed, dropped = jax.jit(lambda b: SH.resize(b, n // 2))(batch)
+    assert bool(dropped) and packed.capacity == n // 2
+    np.testing.assert_array_equal(
+        np.asarray(packed.data["v"]), tbl["v"][valid][: n // 2])
 
 
 def _split_bf16_bound(k, v, K):
@@ -361,7 +395,10 @@ def test_fetch_trim_across_the_chips(jaxmod):
 
     P = len(jaxmod.devices())
     rng = np.random.default_rng(31)
-    n, groups = 1 << 20, 1 << 16
+    # 2^21 rows: on one chip the answer keeps its input's capacity (no
+    # exchange, no slack), and 2^21 slots of 13 B are over the 16 MiB
+    # under which a fetch does not ask
+    n, groups = 1 << 21, 1 << 16
     # one negative key keeps the dense rewrite off: the hash exchange
     # and the segmented fold, whose answer is a prefix of each shard
     k = (rng.integers(0, groups, n) - 1).astype(np.int32)
