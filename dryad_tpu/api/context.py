@@ -24,7 +24,7 @@ import numpy as np
 
 from dryad_tpu.api.query import JobHandle, Query
 from dryad_tpu.columnar import io as CIO
-from dryad_tpu.columnar.batch import ColumnBatch
+from dryad_tpu.columnar.batch import ColumnBatch, _nbytes
 from dryad_tpu.columnar.schema import (
     BYTES,
     ColumnType,
@@ -235,12 +235,15 @@ class DryadContext:
             )
             self.executor.rewriter = self.rewriter
             self.executor.headroom = self.headroom
-        # ONE tracer (one thread-local span stack) for the context and
-        # its executor, so a stage's span nests under the job's
+        # ONE tracer (one thread-local span stack) for the context, its
+        # executor and its telemetry sampler, so a stage's span and a
+        # sample's nest under the job's
         self.tracer = (
             self.executor.tracer if self.executor is not None
             else Tracer(self.events)
         )
+        if self.telemetry is not None:
+            self.telemetry.tracer = self.tracer
 
     def rebuild_mesh(self, exclude_device_ids) -> None:
         """Elastic recovery: shrink the mesh past failed devices and
@@ -442,7 +445,9 @@ class DryadContext:
             data = data.encode("utf-8") if isinstance(data, str) else bytes(data)
             size = len(data)
         # bind time, before any collect(): no parent span and no qid
-        with self.tracer.span("tokenize", cat="ingest", bytes=size) as span:
+        with self.tracer.span(
+            "tokenize", cat="ingest", account=True, bytes=size
+        ) as span:
             if many:
                 # Multi-file ingest: the native prefetch channel reads
                 # file i+1 while file i tokenizes (reference async
@@ -464,9 +469,15 @@ class DryadContext:
                     with open(data, "rb") as fh:
                         data = fh.read()
                 h0, h1, r0, r1 = self._tokenize_buf(data)
-            span.add(rows=len(h0))
-        with self.tracer.span("vocab", cat="ingest", rows=len(h0)):
+            span.add(
+                rows=len(h0),
+                bytes_out=h0.nbytes + h1.nbytes + r0.nbytes + r1.nbytes,
+            )
+        with self.tracer.span(
+            "vocab", cat="ingest", account=True, rows=len(h0)
+        ) as span:
             vocab = _word_vocab(h0, h1)
+            span.add(bytes_out=vocab.nbytes)
         schema = Schema([(column, ColumnType.STRING)])
         node = Node(
             "input", [], schema, PartitionInfo.roundrobin(),
@@ -718,8 +729,9 @@ class DryadContext:
             # Host-side (P * cap) layout + one device_put per column
             # (same no-jitted-ingest policy as from_physical_table).
             with self.tracer.span(
-                "encode", cat="ingest", rows=sum(rows_per), capacity=P * cap
-            ):
+                "encode", cat="ingest", account=True,
+                rows=sum(rows_per), capacity=P * cap,
+            ) as span:
                 data = {
                     c: np.zeros(P * cap, _phys_dtype(c, schema))
                     for c in phys
@@ -733,6 +745,7 @@ class DryadContext:
                             data[c][at : at + n] = cols[c]
                         valid[at : at + n] = True
                         at += n
+                span.add(bytes_out=_nbytes(data) + valid.nbytes)
             return D.shard_host_padded(
                 data, valid, self.mesh,
                 tracer=self.tracer, metrics=self.executor.metrics,
@@ -909,15 +922,24 @@ class DryadContext:
         # deferred check still raises before any result reaches the
         # caller.
         batch, deferred = self._execute_device(query, defer_miss=True)
-        return self._fetch_table(query, batch, deferred)
+        table = self._fetch_table(query, batch, deferred, done=False)
+        # the answer's device arrays, and what jax cached on them
+        with self.tracer.span("drop", cat="readback"):
+            del batch, deferred
+        self._release_ingested(done=True)
+        return table
 
-    def _fetch_table(self, query: Query, batch, deferred=None):
+    def _fetch_table(self, query: Query, batch, deferred=None, done=True):
         """A result batch as the user's logical host table: the fetch
         (``deferred``'s miss counters riding it; the byte accounting is
         the fetch's own) and the decode of the valid rows, which are a
         slice a shard where the fetch measured the batch and found no
-        hole, and the mask's otherwise."""
+        hole, and the mask's otherwise.  ``done``: the job ends with
+        this call (``_run_to_host`` says no: it drops the batch first
+        and then lets go of what the job ingested, see
+        :meth:`_release_ingested`)."""
         metrics = self.executor.metrics if self.executor is not None else None
+        self._release_ingested()
         if deferred is not None:
             valid, host_cols, rows = _fetch_with_miss(
                 batch, deferred, self.tracer, metrics
@@ -937,11 +959,11 @@ class DryadContext:
                 for part in np.array_split(valid, num_partitions(self.mesh))
             ]
         with self.tracer.span(
-            "decode", cat="decode", rows=sum(shard_rows),
+            "decode", cat="decode", account=True, rows=sum(shard_rows),
             capacity=batch.capacity, fetched=len(valid),
             shards=len(shard_rows),
             shard_rows_max=max(shard_rows), shard_rows_min=min(shard_rows),
-        ):
+        ) as span:
             packed = rows is not None and rows.packed
             table = batch.to_numpy(
                 query.schema, self.dictionary,
@@ -952,26 +974,45 @@ class DryadContext:
                 from dryad_tpu.columnar.codecs import collapse_table
 
                 table = collapse_table(table, self._codecs)
-        self._release_ingested()
+            span.add(bytes_out=_nbytes(table))
+        # The fetched host copies go here (and in ``_run_to_host`` the
+        # device arrays that may own them): a table's worth of memory
+        # to unmap (15 ms for 302 MB, 45 ms for 881 MB, 56 ms for the
+        # four shards' 718 MB; PERF.md section 6, PR 34), which fell at
+        # the function's return, under no span.  Where the runtime still
+        # holds a copy (it lets go at the thread's next call into jax
+        # or Python collection) the unmap comes later: in ``release`` in
+        # a job that ingested, in the next job otherwise, as ever.
+        with self.tracer.span("drop", cat="readback"):
+            del valid, host_cols
+        if done:
+            self._release_ingested(done=True)
         return table
 
-    def _release_ingested(self) -> None:
+    def _release_ingested(self, done: bool = False) -> None:
         """Let go of the host arrays an ingest copied to the device, in
         the job that made them.  jax keeps the source array of every
         ``device_put`` alive until its copy is done and cannot drop it
         from the runtime's thread: the array joins a list that the next
-        dispatch, or the next Python collection (jax hooks
-        ``gc.callbacks``, jax issue 14882), empties on the calling
-        thread.  Unmapping a table's worth of arrays there takes
-        milliseconds (134 MB: 3 ms in a process that read its program
-        from the compile cache, 10 - 13 ms in one that compiled it;
-        PERF.md section 6, PR 31), and they landed in whichever job
-        dispatched next, the requery.  The answer is on the host here,
-        so every copy of the job is done: one generation-0 collection
-        empties the list now, under a ``release`` span."""
+        call into jax on the calling thread, or the next Python
+        collection (jax hooks ``gc.callbacks``, jax issue 14882),
+        empties (``PythonRefManager::CollectGarbage``).  Unmapping a
+        table's worth of arrays there takes milliseconds (302 MB: 25
+        ms; PERF.md section 6, PR 31 and 34), and they landed in
+        whichever call came next: the fetch's ``block_until_ready``
+        (``fetch_wait``), or the requery's dispatch.  One generation-0
+        collection empties the list under a ``release`` span instead,
+        twice a job that ingested: before the fetch, when a stage that
+        was waited for has used every copy (a job that dispatched and
+        did not wait finds nothing to drop yet), and when the job is
+        ``done``, the answer on the host, for what is left.  The second
+        also lets go of what the runtime still held of the answer's
+        host copies (``drop`` in :meth:`_run_to_host`), so they are
+        unmapped inside the span and not at the next job's first call
+        into jax."""
         if not self._ingest_unreleased:
             return
-        self._ingest_unreleased = False
+        self._ingest_unreleased = not done
         with self.tracer.span("release", cat="ingest"):
             gc.collect(0)
 

@@ -98,9 +98,11 @@ def encode_table(
         a = np.asarray(arrays[f.name])
         if f.ctype.is_bytes:
             with tracer.span(
-                "pack", cat="ingest", bytes=a.size, rows=len(a)
-            ):
-                phys.update(encode_physical(f, a, dictionary))
+                "pack", cat="ingest", account=True, bytes=a.size, rows=len(a)
+            ) as sp:
+                words = encode_physical(f, a, dictionary)
+                sp.add(bytes_out=_nbytes(words))
+            phys.update(words)
         else:
             phys.update(encode_physical(f, a, dictionary))
     return phys, n or 0
@@ -294,10 +296,13 @@ class ColumnBatch:
             arrays, rows = _trim_to_extent(arrays, tracer, metrics)
         nbytes = _nbytes(arrays)
         with tracer.span(
-            "fetch_copy", cat="readback", bytes=nbytes,
+            "fetch_copy", cat="readback", account=True, bytes=nbytes,
             capacity=self.capacity, columns=len(self.data),
         ):
             host, extras = jax.device_get((arrays, extra))
+            # a cut copy goes here, and the host copy of each of its
+            # shards with it (``host`` is jax's assembly of them)
+            del arrays
         if metrics is not None:
             metrics.add("d2h_bytes", nbytes)
             metrics.add("d2h_bytes_trimmed", whole - nbytes)
@@ -381,7 +386,8 @@ def trim_tiers(capacity: int) -> Tuple[int, ...]:
         k += 1
 
 
-def _nbytes(arrays: Dict[str, jax.Array]) -> int:
+def _nbytes(arrays) -> int:
+    """Bytes of a dict of arrays, on the device or on the host."""
     return sum(a.size * a.dtype.itemsize for a in arrays.values())
 
 
@@ -557,7 +563,10 @@ def _unpack_bytes(field, valid, host, rows, tracer: Tracer) -> np.ndarray:
     else:
         parts = [[rows(c) for c in names]]
     total = sum(len(words[0]) for words in parts)
-    with tracer.span("unpack", cat="decode", bytes=total * width, rows=total):
+    with tracer.span(
+        "unpack", cat="decode", account=True, bytes=total * width,
+        rows=total, bytes_out=total * width,
+    ):
         out = np.empty((total, width), np.uint8)
         at = 0
         for words in parts:

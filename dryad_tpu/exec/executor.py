@@ -1200,8 +1200,9 @@ class GraphExecutor:
                 # stage's exchanges, how many exchanges the trace
                 # skipped because the mesh has one partition, and how
                 # many 4-byte words the widest row that a sort of the
-                # stage carries has (trace-time constants: 0 on the one
-                # dispatch that traces, whose event gets them below).
+                # stage carries has (trace-time constants: 0 at open on
+                # the one dispatch that traces, whose event and
+                # annotation get them at its close, from the add below).
                 with self.tracer.span(
                     stage.name, cat="execute", stage=stage.id,
                     version=version, boost=boost,

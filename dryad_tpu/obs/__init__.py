@@ -6,7 +6,8 @@ Observability").  This package is that subsystem for the TPU-native
 framework, layered over the existing ``exec.events.EventLog`` stream:
 
 - :mod:`dryad_tpu.obs.span` — thread-safe hierarchical spans
-  (monotonic clocks, context manager + decorator, parent ids) that
+  (monotonic clocks, context managers, parent ids; a host pass opened
+  with ``account=True`` adds the process's CPU seconds) that
   serialize as ``span`` events;
 - :mod:`dryad_tpu.obs.metrics` — a counter/histogram registry (rows
   and bytes per stage and partition, compile count/time, transfer

@@ -20,7 +20,10 @@ span passes ``parent=`` explicitly (capture it with
 
 Span ids are unique process-wide (one shared counter), so any module
 may construct its own ``Tracer(events)`` over the same log and the
-hierarchy stays consistent.
+hierarchy stays consistent.  The stack of open spans is a tracer's own:
+a module whose spans must nest under another's is handed that tracer
+(the telemetry sampler the context's, so ``resource_sample`` is a child
+of the span whose event found the sample due).
 
 Every span is also a ``jax.profiler.TraceAnnotation`` over the same
 interval, named ``dryad:<phase>:<name>`` (``<phase>`` from
@@ -28,15 +31,29 @@ interval, named ``dryad:<phase>:<name>`` (``<phase>`` from
 ``parent_id``, ``qid`` and the numeric fields it was opened with as
 stats: in a profiler session (``config.profile_dir``, the benchmark's
 traced run) the program's spans lie on the device trace's clock.  With
-no session the annotation is a flag check.  Only fields known at open
-ride the annotation; what ``add()`` attaches later reaches the event
-alone.
+no session the annotation is a flag check.  What ``add()`` attaches
+before the close, and what the close itself learns, rides the same
+annotation (``set_metadata``, in a session only): a numeric field that
+is new, or whose value is no longer the one sent at open, is sent once
+more, and a reader that takes a stat's last value reads the event's.
+
+**A span that accounts for itself** (``account=True``, the host passes
+that write a table's worth of host memory: the encodes, ``pack``,
+``tokenize``, ``vocab``, ``fetch_copy``, ``decode``, ``unpack``) takes
+``resource.getrusage(RUSAGE_SELF)`` at open and at close and adds
+``user_s`` / ``sys_s``: CPU seconds of the whole process in the
+interval, the pass's own thread, its worker threads and the runtime's
+copy threads alike.  Against ``dur`` they say whether one core was busy,
+many were, or the thread waited.  Inclusive of its children, like
+``dur``.  Such a pass also states ``bytes_out`` itself: the bytes of
+the arrays it made (``fetch_copy`` says them under ``bytes``).  Page
+faults are not taken: the chip's host (gVisor) counts none.
 """
 
 from __future__ import annotations
 
-import functools
 import itertools
+import resource
 import threading
 import time
 from typing import Any, Optional
@@ -71,10 +88,10 @@ class Span:
 
     __slots__ = (
         "_tracer", "name", "cat", "fields", "span_id", "parent_id", "_t0",
-        "_annotation",
+        "_annotation", "_sent", "_account", "_usage",
     )
 
-    def __init__(self, tracer, name, cat, parent_id, fields):
+    def __init__(self, tracer, name, cat, parent_id, fields, account=False):
         self._tracer = tracer
         self.name = name
         self.cat = cat
@@ -83,6 +100,9 @@ class Span:
         self.parent_id = parent_id
         self._t0 = 0.0
         self._annotation = None
+        self._sent = None  # the stats the annotation was opened with
+        self._account = account
+        self._usage = None  # getrusage at open, of an accounted span
 
     def add(self, **fields: Any) -> "Span":
         self.fields.update(fields)
@@ -90,7 +110,7 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._tracer._push(self)
-        stats = {
+        stats = self._sent = {
             k: v for k, v in self.fields.items()
             if isinstance(v, (int, float))
         }
@@ -103,9 +123,27 @@ class Span:
         )
         self._annotation.__enter__()
         self._t0 = time.monotonic()
+        if self._account:
+            self._usage = resource.getrusage(resource.RUSAGE_SELF)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        # the account and what goes onto the annotation lie inside the
+        # interval, so the event and the annotation time the same work
+        if self._usage is not None:
+            was, now = self._usage, resource.getrusage(resource.RUSAGE_SELF)
+            self.fields.update(
+                user_s=round(now.ru_utime - was.ru_utime, 6),
+                sys_s=round(now.ru_stime - was.ru_stime, 6),
+            )
+        if TraceAnnotation.is_enabled():
+            sent = self._sent
+            late = {
+                k: v for k, v in self.fields.items()
+                if isinstance(v, (int, float)) and sent.get(k, _UNSET) != v
+            }
+            if late:
+                self._annotation.set_metadata(**late)
         dur = time.monotonic() - self._t0
         self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._pop(self)
@@ -183,30 +221,15 @@ class Tracer:
 
     # -- public ------------------------------------------------------------
     def span(self, name: str, cat: str = "driver", parent=_UNSET,
-             **fields: Any):
+             account: bool = False, **fields: Any):
         """Open a span as a context manager.  ``parent`` defaults to
         this thread's innermost open span; pass an explicit id (or
-        None) when the logical parent lives on another thread."""
+        None) when the logical parent lives on another thread.
+        ``account``: the span accounts for itself (module doc)."""
         if not self.enabled:
             return _NULL
         pid = self.current_id() if parent is _UNSET else parent
-        return Span(self, name, cat, pid, dict(fields))
-
-    def traced(self, name: Optional[str] = None, cat: str = "driver",
-               **fields: Any):
-        """Decorator form: the wrapped call body runs inside a span."""
-
-        def deco(fn):
-            sname = name or fn.__name__
-
-            @functools.wraps(fn)
-            def wrapper(*a, **k):
-                with self.span(sname, cat=cat, **fields):
-                    return fn(*a, **k)
-
-            return wrapper
-
-        return deco
+        return Span(self, name, cat, pid, dict(fields), account)
 
 
 # The default of a ``tracer=`` parameter (``columnar/batch.py``,

@@ -57,6 +57,7 @@ from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from dryad_tpu.obs import flightrec
+from dryad_tpu.obs.span import Tracer
 
 __all__ = [
     "METRIC_KEYS",
@@ -503,6 +504,10 @@ class ResourceMonitor:
             raise ValueError("interval_s must be > 0")
         self.interval_s = float(interval_s)
         self.events = events
+        # the sample's span nests under the open spans of this tracer:
+        # the context sets its own here, so a sample is a child of the
+        # job's span that paid for it
+        self.tracer = Tracer(events)
         self.store = store
         self.headroom = HeadroomProvider()
         self.samples: deque = deque(maxlen=max(1, int(history)))
@@ -519,7 +524,16 @@ class ResourceMonitor:
         """Take one sample now: device HBM (or the host fallback),
         plus every shared flightrec probe.  Retains it in the ring,
         updates the headroom provider and gauges, and emits one
-        ``resource_sample`` event."""
+        ``resource_sample`` event.  The sample runs inside a span
+        ``resource_sample`` (the annotation ``dryad:other:
+        resource_sample``), a child of whatever span of its tracer
+        the sampling thread has open: on an event's tap that is the
+        job's own thread, and the span says what the job paid for the
+        sample."""
+        with self.tracer.span("resource_sample", cat="obs"):
+            return self._sample()
+
+    def _sample(self) -> Dict[str, Any]:
         snap: Dict[str, Any] = {"mono": self._clock()}
         mem = self._device_memory()
         store = self.store
@@ -559,10 +573,10 @@ class ResourceMonitor:
 
     def observe(self, ev: Dict[str, Any]) -> None:
         """EventLog tap: sample when ``interval_s`` has elapsed since
-        the last one.  Never raises; ignores its own samples (no
-        self-sustaining feedback)."""
+        the last one.  Never raises; ignores its own samples and the
+        span around them (no self-sustaining feedback)."""
         try:
-            if ev.get("kind") == "resource_sample":
+            if "resource_sample" in (ev.get("kind"), ev.get("name")):
                 return
             now = self._clock()
             if now - self._last >= self.interval_s:

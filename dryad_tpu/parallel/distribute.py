@@ -15,7 +15,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from dryad_tpu.columnar.batch import ColumnBatch, encode_table
+from dryad_tpu.columnar.batch import ColumnBatch, _nbytes, encode_table
 from dryad_tpu.columnar.schema import Schema, StringDictionary
 from dryad_tpu.obs.span import UNTRACED, Tracer
 from dryad_tpu.parallel.mesh import num_partitions, partition_sharding
@@ -42,8 +42,7 @@ def shard_host_padded(
     once the copies are enqueued, so the span times the enqueue, not
     the transfer."""
     sh = partition_sharding(mesh)
-    nbytes = sum(v.size * v.dtype.itemsize for v in data.values())
-    nbytes += valid.size * valid.dtype.itemsize
+    nbytes = _nbytes(data) + valid.nbytes
     with tracer.span("h2d", cat="ingest", bytes=nbytes):
         out = ColumnBatch(
             {c: jax.device_put(v, sh) for c, v in data.items()},
@@ -74,8 +73,9 @@ def from_host_table(
     # columns through the shared path: one sharded device_put per
     # column, no full-size array on the default device.
     rows = len(next(iter(arrays.values()))) if arrays else 0
-    with tracer.span("encode", cat="ingest", rows=rows):
+    with tracer.span("encode", cat="ingest", account=True, rows=rows) as sp:
         phys, _n = encode_table(schema, arrays, dictionary, tracer)
+        sp.add(bytes_out=_nbytes(phys))
     return from_physical_table(
         phys, mesh, partition_capacity, tracer=tracer, metrics=metrics
     )
@@ -107,7 +107,9 @@ def from_physical_table(
     sizes = [
         min((p + 1) * per, n) - min(p * per, n) for p in range(P)
     ]
-    with tracer.span("encode", cat="ingest", rows=n, capacity=P * cap):
+    with tracer.span(
+        "encode", cat="ingest", account=True, rows=n, capacity=P * cap
+    ) as sp:
         data = {}
         for c in names:
             a = np.asarray(phys[c])
@@ -119,6 +121,7 @@ def from_physical_table(
         valid = np.zeros(P * cap, np.bool_)
         for p, m in enumerate(sizes):
             valid[p * cap : p * cap + m] = True
+        sp.add(bytes_out=_nbytes(data) + valid.nbytes)
     return shard_host_padded(data, valid, mesh, tracer, metrics)
 
 

@@ -165,6 +165,11 @@ def test_the_fetch_is_the_untrimmed_fetch(layout, P, low_gate):
     full = layout in ("last_slot", "full")
     assert trim["trimmed"] == int(not full)
     assert (rows.tier == CAP) == full
+    # the copy accounts for itself, the wait and the trim say seconds
+    # alone (obs/span.py)
+    assert {"user_s", "sys_s"} <= set(copy)
+    for span in (trim, *_spans(events, "fetch_wait")):
+        assert "user_s" not in span
 
 
 @pytest.mark.parametrize("P", [1, 4])
@@ -432,8 +437,10 @@ def _releases(ctx) -> int:
 def test_the_job_that_ingested_lets_go_of_its_host_arrays(how, monkeypatch):
     """The host arrays an ingest handed to ``device_put`` are dropped
     (a generation-0 collection, which jax's own hook turns into the
-    release) at the end of the job that copied them, under one
-    ``release`` span; a requery, which copied nothing, has none."""
+    release) in the job that copied them, under a ``release`` span
+    before the fetch (what a stage that was waited for has used) and
+    another when the job is done (the rest, and the host copies of the
+    answer's arrays); a requery, which copied nothing, has none."""
     collected = []
     monkeypatch.setattr(
         "dryad_tpu.api.context.gc.collect", lambda gen: collected.append(gen)
@@ -445,6 +452,23 @@ def test_the_job_that_ingested_lets_go_of_its_host_arrays(how, monkeypatch):
         return q.collect() if how == "collect" else ctx.run_to_host_async(q)()
 
     np.testing.assert_array_equal(run()["k"], np.arange(64))
-    assert (_releases(ctx), collected) == (1, [0])
+    assert (_releases(ctx), collected) == (2, [0, 0])
+    spans = [e for e in ctx.events.events() if e["kind"] == "span"]
+    names = [e["name"] for e in spans]
+    first, last = [i for i, n in enumerate(names) if n == "release"]
+    assert names[first + 1] == "fetch_wait"
+    if how == "collect":
+        # the job's last acts: the answer's device arrays dropped (and
+        # the host copies of them with those), then what is left let go
+        assert names[last - 3:] == [
+            "decode", "drop", "drop", "release", "collect"]
+        assert spans[last]["parent_id"] == spans[-1]["span_id"]
+    else:  # the closure keeps the device arrays: the host copies alone
+        assert names[last - 2:] == ["decode", "drop", "release"]
+    before = len(ctx.events.events())
     np.testing.assert_array_equal(run()["k"], np.arange(64))
-    assert (_releases(ctx), collected) == (1, [0])
+    assert (_releases(ctx), collected) == (2, [0, 0])
+    again = [e["name"] for e in ctx.events.events()[before:]
+             if e["kind"] == "span"]
+    # a requery drops too: the host copies, and with ``collect`` the arrays
+    assert again.count("drop") == (2 if how == "collect" else 1)

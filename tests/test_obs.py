@@ -104,13 +104,13 @@ class TestSpans:
         assert job["parent_id"] is None
         assert stage["dur"] >= 0 and "mono" in stage
 
-    def test_decorator_and_disabled_tracer(self):
+    def test_a_call_in_a_span_and_disabled_tracer(self):
         log = EventLog(None)
         tr = Tracer(log)
 
-        @tr.traced(cat="execute")
         def work():
-            return 42
+            with tr.span("work", cat="execute"):
+                return 42
 
         assert work() == 42
         assert log.filter("span")[0]["name"] == "work"
@@ -118,6 +118,41 @@ class TestSpans:
         with off.span("nope") as sp:
             sp.add(x=1)
         assert off.current_id() is None
+
+    def test_a_stack_of_open_spans_is_its_tracers_own(self):
+        """Another ``Tracer``'s open span is no parent: a module whose
+        spans must nest under the job's is handed the job's tracer (the
+        telemetry sampler, ``api/context.py``)."""
+        log = EventLog(None)
+        mine, theirs = Tracer(log), Tracer(log)
+        with mine.span("collect", cat="job") as root:
+            assert theirs.current_id() is None
+            with theirs.span("save", cat="checkpoint"):
+                assert mine.current_id() == root.span_id
+            with mine.span("resource_sample", cat="obs"):
+                pass
+        save, sample, collect = log.filter("span")
+        assert save["parent_id"] is None
+        assert sample["parent_id"] == collect["span_id"] == root.span_id
+
+    @pytest.mark.parametrize("account", [False, True])
+    def test_a_span_accounts_for_itself_only_when_asked(self, account):
+        log = EventLog(None)
+        tr = Tracer(log)
+        with tr.span("encode", cat="ingest", account=account, rows=4) as outer:
+            with tr.span("pack", cat="ingest", account=True):
+                made = bytearray(8 << 20)
+            outer.add(bytes_out=len(made))
+        pack, encode = log.filter("span")
+        assert encode["rows"] == 4 and encode["bytes_out"] == 8 << 20
+        assert "account" not in encode  # the keyword is no field
+        stats = {"user_s", "sys_s"}
+        assert stats <= set(pack)
+        if account:  # inclusive of its children, like ``dur``
+            assert encode["user_s"] >= pack["user_s"] - 1e-6
+            assert encode["sys_s"] >= pack["sys_s"] - 1e-6
+        else:
+            assert not stats & set(encode)
 
     def test_error_recorded_on_exception(self):
         log = EventLog(None)

@@ -19,6 +19,14 @@ from dryad_tpu.obs import critpath
 
 ROWS = 1 << 16
 P = 8
+SAMPLE = "dryad:other:resource_sample"
+# the host passes that write a table's worth of host memory, each opened
+# with ``account=True`` (obs/span.py)
+HOST_PASSES = {
+    "dryad:ingest:tokenize", "dryad:ingest:vocab", "dryad:ingest:encode",
+    "dryad:ingest:pack", "dryad:readback:fetch_copy",
+    "dryad:decode:decode", "dryad:decode:unpack",
+}
 
 
 def _annotations(trace_dir):
@@ -37,9 +45,12 @@ def _annotations(trace_dir):
         for line in plane.lines:
             for ev in line.events:
                 if ev.name.startswith("dryad:"):
+                    # a stat sent twice (at open, then at close with
+                    # another value) is there twice: a reader takes the
+                    # last, as ``dict`` does
                     out.append((ev.name, ev.start_ns,
                                 ev.start_ns + ev.duration_ns,
-                                dict(ev.stats)))
+                                dict(ev.stats), list(ev.stats)))
     return sorted(out, key=lambda a: a[1])
 
 
@@ -88,12 +99,23 @@ def recorded(tmp_path_factory):
             .group_by("word", {"count": ("count", None)})
             .collect()
         )
+        # a BYTES column: ``pack`` inside ``encode``, ``unpack`` inside
+        # ``decode``
+        rec["records"] = {
+            "key": rng.integers(0, 256, (4096, 10), dtype=np.uint8),
+            "payload": rng.integers(0, 256, (4096, 6), dtype=np.uint8),
+        }
+        rec["sorted_records"] = (
+            ctx.from_arrays(rec["records"]).order_by([("key", False)]).collect()
+        )
     finally:
         jax.profiler.stop_trace()
     rec["sort"] = sort
     rec["fresh_counters"] = {n: c1[n] - c0[n] for n in c0}
     rec["requery_counters"] = {n: c2[n] - c1[n] for n in c0}
-    rec["annotations"] = _annotations(trace_dir)
+    sent = _annotations(trace_dir)
+    rec["annotations"] = [a[:4] for a in sent]
+    rec["sent"] = {a[3]["span_id"]: a[4] for a in sent}
     rec["spans"] = [e for e in ctx.events.events() if e["kind"] == "span"]
     return rec
 
@@ -146,13 +168,16 @@ def test_every_boundary_of_a_job_is_in_the_host_plane(recorded):
 
 def test_a_jobs_spans_nest_under_its_collect(recorded):
     root, inside = _job(recorded, 0)
+    # the telemetry sample falls in whichever span's event finds it due
+    inside = [a for a in inside if a[0] != SAMPLE]
     got = {a[0] for a in inside}
     assert {
         "dryad:plan:lower", "dryad:ingest:bind", "dryad:ingest:encode",
         "dryad:ingest:h2d", "dryad:compile:input+order_by",
         "dryad:dispatch:input+order_by", "dryad:readback:drain",
         "dryad:readback:fetch_wait", "dryad:readback:fetch_copy",
-        "dryad:decode:decode", "dryad:ingest:release",
+        "dryad:decode:decode", "dryad:readback:drop",
+        "dryad:ingest:release",
     } == got
     for name, start, end, stats in inside:
         assert root[1] <= start <= end <= root[2], name
@@ -166,7 +191,7 @@ def test_a_jobs_spans_nest_under_its_collect(recorded):
     assert max(inside, key=lambda a: a[2])[0] == "dryad:ingest:release"
     # the requery finds the table resident and the program compiled
     _, again = _job(recorded, 1)
-    assert {a[0] for a in again} == got - {
+    assert {a[0] for a in again} - {SAMPLE} == got - {
         "dryad:ingest:bind", "dryad:ingest:encode", "dryad:ingest:h2d",
         "dryad:compile:input+order_by", "dryad:ingest:release"}
 
@@ -229,14 +254,168 @@ def test_without_a_session_the_events_flow_and_the_answer_is_the_same(recorded):
     answer = recorded["sort"].collect()
     for column in ("k", "v"):
         np.testing.assert_array_equal(answer[column], recorded["fresh"][column])
-    new = [e for e in ctx.events.events()[before:] if e["kind"] == "span"]
+    new = [e for e in ctx.events.events()[before:]
+           if e["kind"] == "span" and e["name"] != "resource_sample"]
     assert [e["name"] for e in new] == [
         "lower", "input+order_by", "drain", "fetch_wait", "fetch_copy",
-        "decode", "collect"]
+        "decode", "drop", "drop", "collect"]
     root = new[-1]["span_id"]
     assert all(e["parent_id"] == root for e in new[:-1])
     copy = next(e for e in new if e["name"] == "fetch_copy")
     assert copy["bytes"] == recorded["requery_counters"]["d2h_bytes"]
+
+
+def test_what_a_span_learns_before_it_closes_rides_its_annotation(recorded):
+    events = {e["span_id"]: e for e in recorded["spans"]}
+    # ``add()``ed after open: the tokenizer's rows, the plan's stages
+    (_, _, _, stats), = _by_name(recorded, "dryad:ingest:tokenize")
+    assert stats["rows"] == 5000 == events[stats["span_id"]]["rows"]
+    assert stats["bytes_out"] == 4 * 4 * 5000  # h0, h1, r0, r1
+    for _, _, _, stats in _by_name(recorded, "dryad:plan:lower"):
+        assert stats["stages"] == events[stats["span_id"]]["stages"] >= 1
+    # every numeric field of every event, with the value it had at close
+    for name, _, _, stats in recorded["annotations"]:
+        ev = events[stats["span_id"]]
+        for field, value in ev.items():
+            if isinstance(value, (int, float)) and field not in (
+                    "ts", "mono", "dur", "span_id", "parent_id"):
+                assert stats[field] == value, (name, field)
+
+
+def test_a_changed_field_is_sent_again_once(recorded):
+    # the dispatch that traces opens with 0 bytes on the wire and learns
+    # its program's constants inside: the last value once, not twice
+    first = _by_name(recorded, "dryad:dispatch:input+order_by")[0]
+    sent = recorded["sent"][first[3]["span_id"]]
+    values = [v for k, v in sent if k == "xchg_ici_bytes"]
+    assert len(values) == 2 and values[0] == 0 < values[1] == first[3]["xchg_ici_bytes"]
+    # an unchanged one (``stage``, ``boost``, the id) is sent at open alone
+    for field in ("stage", "boost", "span_id", "parent_id", "xchg_elided"):
+        assert [k for k, _ in sent].count(field) == 1, field
+    # the next dispatch knew them at open: nothing is sent again
+    again = _by_name(recorded, "dryad:dispatch:input+order_by")[1]
+    sent = recorded["sent"][again[3]["span_id"]]
+    assert [k for k, _ in sent].count("xchg_ici_bytes") == 1
+    assert again[3]["xchg_ici_bytes"] == first[3]["xchg_ici_bytes"]
+
+
+def test_every_host_pass_accounts_for_itself(recorded):
+    events = {e["span_id"]: e for e in recorded["spans"]}
+    seen = set()
+    for name, _, _, stats in recorded["annotations"]:
+        if name in HOST_PASSES:
+            seen.add(name)
+            ev = events[stats["span_id"]]
+            for field in ("user_s", "sys_s"):
+                assert stats[field] == ev[field] >= 0, (name, field)
+            # and states the bytes it wrote
+            made = "bytes" if name.endswith("fetch_copy") else "bytes_out"
+            assert stats[made] == ev[made] > 0, name
+        else:  # the plan, the compiles, the dispatches, the waits and
+            # the frees: seconds alone
+            assert not {"user_s", "sys_s", "bytes_out"} & set(stats), name
+    assert seen == HOST_PASSES
+    # inclusive of the children, like the seconds
+    by_id = {a[3]["span_id"]: a for a in recorded["annotations"]}
+    for name in ("dryad:ingest:pack", "dryad:decode:unpack"):
+        for _, _, _, stats in _by_name(recorded, name):
+            outer = by_id[stats["parent_id"]][3]
+            assert stats["user_s"] <= outer["user_s"] + 1e-6
+            assert stats["sys_s"] <= outer["sys_s"] + 1e-6
+
+
+def test_bytes_out_is_the_arrays_a_pass_made(recorded):
+    _, inside = _job(recorded, 0)
+    table = recorded["table"]
+    schema, pad = [a[3] for a in inside if a[0] == "dryad:ingest:encode"]
+    # the physical columns; then P x capacity slots of them and ``valid``
+    assert "capacity" not in schema and pad["capacity"] == ROWS
+    assert schema["bytes_out"] == table["k"].nbytes + table["v"].nbytes
+    assert pad["bytes_out"] == schema["bytes_out"] + ROWS
+    (_, _, _, decode), = [a for a in inside if a[0] == "dryad:decode:decode"]
+    assert decode["bytes_out"] == table["k"].nbytes + table["v"].nbytes
+    # BYTES columns: 10 bytes are 3 words, 6 are 2; ``pack`` lies inside
+    # the schema pass, whose bytes are the words'
+    records = recorded["records"]
+    np.testing.assert_array_equal(
+        recorded["sorted_records"]["key"],
+        records["key"][np.lexsort(records["key"].T[::-1])])
+    packs = _by_name(recorded, "dryad:ingest:pack")
+    assert sorted((a[3]["bytes"], a[3]["bytes_out"]) for a in packs) == [
+        (4096 * 6, 4096 * 8), (4096 * 10, 4096 * 12)]
+    by_id = {a[3]["span_id"]: a for a in recorded["annotations"]}
+    outer = by_id[packs[0][3]["parent_id"]]
+    assert outer[0] == "dryad:ingest:encode"
+    assert outer[3]["bytes_out"] == 4096 * (12 + 8)
+    unpacks = _by_name(recorded, "dryad:decode:unpack")
+    assert sorted(a[3]["bytes_out"] for a in unpacks) == [4096 * 6, 4096 * 10]
+    outer = by_id[unpacks[0][3]["parent_id"]]
+    assert outer[0] == "dryad:decode:decode"
+    assert outer[3]["bytes_out"] == 4096 * 16
+
+
+def test_the_sample_is_a_child_of_the_span_that_paid_for_it(recorded):
+    samples = _by_name(recorded, SAMPLE)
+    assert samples
+    by_id = {a[3]["span_id"]: a for a in recorded["annotations"]}
+    for name, start, end, stats in samples:
+        parent = by_id[stats["parent_id"]]
+        assert parent[1] <= start <= end <= parent[2]
+
+
+def test_an_accounted_span_over_a_first_touched_array(tmp_path):
+    from dryad_tpu.exec.events import EventLog
+    from dryad_tpu.obs.span import Tracer
+
+    log = EventLog(None)
+    tracer = Tracer(log)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tracer.span("encode", cat="ingest", account=True, rows=1) as sp:
+            made = np.zeros(64 << 20, np.uint8)
+            made[:] = 1  # every page of it, for the first time
+            sp.add(bytes_out=made.nbytes)
+    finally:
+        jax.profiler.stop_trace()
+    (_, _, _, stats, _), = _annotations(str(tmp_path))
+    ev, = log.filter("span")
+    for seen in (stats, ev):
+        assert seen["bytes_out"] == 64 << 20  # to the byte
+        # 16,384 pages touched for the first time: CPU seconds of the
+        # process, the kernel's share under whichever of the two the
+        # host books it (no fault is counted: the chip's host has none)
+        assert seen["user_s"] >= 0 and seen["sys_s"] >= 0
+        assert seen["user_s"] + seen["sys_s"] > 0
+        assert not {"minflt", "majflt"} & set(seen)
+    assert stats["user_s"] == ev["user_s"] and stats["sys_s"] == ev["sys_s"]
+
+
+def test_without_a_session_nothing_is_sent_and_nothing_is_asked(monkeypatch):
+    from dryad_tpu.exec.events import EventLog
+    from dryad_tpu.obs import span as span_module
+
+    sent, asked = [], []
+    monkeypatch.setattr(span_module.TraceAnnotation, "set_metadata",
+                        lambda self, **kw: sent.append(kw), raising=True)
+    real = span_module.resource.getrusage
+    monkeypatch.setattr(span_module.resource, "getrusage",
+                        lambda who: asked.append(who) or real(who))
+    log = EventLog(None)
+    tracer = span_module.Tracer(log)
+    assert not span_module.TraceAnnotation.is_enabled()
+    with tracer.span("lower", cat="plan") as sp:
+        sp.add(stages=2)
+    assert asked == []  # a span not opened with ``account`` asks nothing
+    with tracer.span("encode", cat="ingest", account=True, rows=3) as sp:
+        sp.add(bytes_out=24)
+    assert len(asked) == 2 and sent == []
+    lower, encode = log.filter("span")
+    assert lower["stages"] == 2 and "user_s" not in lower
+    assert encode["bytes_out"] == 24 and encode["rows"] == 3
+    assert {"user_s", "sys_s"} <= set(encode)
 
 
 def test_a_disabled_tracer_opens_no_annotation():

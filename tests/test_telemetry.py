@@ -301,6 +301,54 @@ def test_tap_paces_samples_and_ignores_its_own_events():
     assert len(log.filter("resource_sample")) == 2
 
 
+def test_the_sample_is_a_span_under_the_span_whose_event_found_it_due():
+    from dryad_tpu.obs import critpath
+    from dryad_tpu.obs.span import Tracer
+
+    class Ticking:  # every reading a minute later: a sample is always due
+        t = 0.0
+
+        def __call__(self):
+            self.t += 60.0
+            return self.t
+
+    log = EventLog(None)
+    tracer = Tracer(log)
+    mon = ResourceMonitor(
+        interval_s=1.0, events=log, clock=Ticking(),
+        device_memory_fn=lambda: (1, 2),
+    )
+    mon.tracer = tracer  # as ``DryadContext`` hands it its own
+    log.add_tap(mon.observe)
+    with tracer.span("collect", cat="job") as job:
+        with tracer.span("drain", cat="readback"):
+            pass
+    # drain's event sampled while ``collect`` was the thread's open span;
+    # collect's own event sampled with no span open.  Neither the sample's
+    # event nor its span's re-entered the sampler, due as it always was.
+    assert [(e["kind"], e.get("name")) for e in log.events()] == [
+        ("span", "drain"), ("resource_sample", None), ("span", "resource_sample"),
+        ("span", "collect"), ("resource_sample", None), ("span", "resource_sample"),
+    ]
+    first, second = [e for e in log.filter("span") if e["name"] == "resource_sample"]
+    assert first["parent_id"] == job.span_id and second["parent_id"] is None
+    assert first["cat"] == "obs"
+    assert critpath.phase_of("resource_sample", "obs") == "other"
+    assert "user_s" not in first  # it writes no table: seconds alone
+    # a monitor with no event log samples under no span and emits nothing
+    quiet = ResourceMonitor(interval_s=1.0, device_memory_fn=lambda: (1, 2))
+    assert quiet.sample()["source"] == "device"
+    assert len(log.events()) == 6
+    # left with its own tracer, the monitor opens its span on that:
+    # under no span of another tracer's
+    alone = EventLog(None)
+    mon = ResourceMonitor(
+        interval_s=1.0, events=alone, device_memory_fn=lambda: (1, 2))
+    with Tracer(alone).span("collect", cat="job"):
+        mon.sample()
+    assert alone.filter("span")[0]["parent_id"] is None
+
+
 def test_sample_ring_is_bounded():
     mon = ResourceMonitor(
         interval_s=1.0, clock=FakeClock(), history=4,
