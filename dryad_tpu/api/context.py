@@ -135,6 +135,9 @@ class DryadContext:
         # an ingest has handed host arrays to device_put and no fetch
         # has let go of them since (see _release_ingested)
         self._ingest_unreleased = False
+        # the host memory every ingest lays its table out in, warm from
+        # the job before (parallel.distribute.StagingPool)
+        self.staging = D.StagingPool()
         self.diagnosis: Optional[DiagnosisEngine] = None
         self.rewriter = None
         # Continuous telemetry plane (obs.telemetry): the tap-paced
@@ -259,6 +262,7 @@ class DryadContext:
         }
         # Cached ingests are sharded over the OLD mesh — drop them.
         self._device_cache.clear()
+        self.staging.clear()
         self.executor = GraphExecutor(
             self.mesh, self.config, self.events,
             subquery_runner=self._run_subquery,
@@ -266,6 +270,14 @@ class DryadContext:
         )
         self.executor.rewriter = self.rewriter
         self.executor.headroom = self.headroom
+
+    def close(self) -> None:
+        """Let go of what the context keeps between jobs for speed
+        alone: the staging pool's host memory and the device-resident
+        ingest cache.  The context stays usable; its next job ingests
+        again, into newly mapped memory."""
+        self._device_cache.clear()
+        self.staging.clear()
 
     # -- ingestion ----------------------------------------------------------
     def from_arrays(
@@ -702,6 +714,7 @@ class DryadContext:
                 node.schema, arrays, self.mesh,
                 partition_capacity=cap, dictionary=self.dictionary,
                 tracer=self.tracer, metrics=self.executor.metrics,
+                pool=self.staging,
             )
         if kind == "host_physical":
             phys, *opt = rest
@@ -709,11 +722,11 @@ class DryadContext:
             return D.from_physical_table(
                 phys, self.mesh, partition_capacity=cap,
                 tracer=self.tracer, metrics=self.executor.metrics,
+                pool=self.staging,
             )
         if kind == "store":
             parts, schema = rest
             P = num_partitions(self.mesh)
-            phys = schema.device_names()
 
             # Fold store partitions onto mesh partitions (store partition
             # i concatenates into mesh partition i % P) so a store written
@@ -726,29 +739,20 @@ class DryadContext:
                 for group in folded
             ]
             cap = math.ceil(max(max(rows_per, default=1), 1) / 8) * 8
-            # Host-side (P * cap) layout + one device_put per column
-            # (same no-jitted-ingest policy as from_physical_table).
-            with self.tracer.span(
-                "encode", cat="ingest", account=True,
-                rows=sum(rows_per), capacity=P * cap,
-            ) as span:
-                data = {
-                    c: np.zeros(P * cap, _phys_dtype(c, schema))
-                    for c in phys
-                }
-                valid = np.zeros(P * cap, np.bool_)
+
+            def fill(out) -> None:
                 for p, group in enumerate(folded):
                     at = p * cap
                     for cols in group:
                         n = len(next(iter(cols.values()))) if cols else 0
-                        for c in phys:
-                            data[c][at : at + n] = cols[c]
-                        valid[at : at + n] = True
+                        for c, col in out.items():
+                            col[at : at + n] = cols[c]
                         at += n
-                span.add(bytes_out=_nbytes(data) + valid.nbytes)
-            return D.shard_host_padded(
-                data, valid, self.mesh,
+
+            return D.lay_out(
+                schema.device_dtypes(), rows_per, cap, fill, self.mesh,
                 tracer=self.tracer, metrics=self.executor.metrics,
+                pool=self.staging,
             )
         if kind == "stream":
             raise RuntimeError(
@@ -990,31 +994,36 @@ class DryadContext:
         return table
 
     def _release_ingested(self, done: bool = False) -> None:
-        """Let go of the host arrays an ingest copied to the device, in
-        the job that made them.  jax keeps the source array of every
+        """Let go of what an ingest handed to ``device_put``, in the
+        job that made it.  jax keeps the source array of every
         ``device_put`` alive until its copy is done and cannot drop it
         from the runtime's thread: the array joins a list that the next
         call into jax on the calling thread, or the next Python
         collection (jax hooks ``gc.callbacks``, jax issue 14882),
-        empties (``PythonRefManager::CollectGarbage``).  Unmapping a
-        table's worth of arrays there takes milliseconds (302 MB: 25
-        ms; PERF.md section 6, PR 31 and 34), and they landed in
-        whichever call came next: the fetch's ``block_until_ready``
-        (``fetch_wait``), or the requery's dispatch.  One generation-0
-        collection empties the list under a ``release`` span instead,
-        twice a job that ingested: before the fetch, when a stage that
-        was waited for has used every copy (a job that dispatched and
-        did not wait finds nothing to drop yet), and when the job is
-        ``done``, the answer on the host, for what is left.  The second
-        also lets go of what the runtime still held of the answer's
-        host copies (``drop`` in :meth:`_run_to_host`), so they are
-        unmapped inside the span and not at the next job's first call
-        into jax."""
+        empties (``PythonRefManager::CollectGarbage``).  One
+        generation-0 collection empties the list under a ``release``
+        span, twice a job that ingested: before the fetch, when a stage
+        that was waited for has used every copy (a job that dispatched
+        and did not wait finds nothing to drop yet), and when the job
+        is ``done``, the answer on the host, for what is left.
+
+        The sources are views of the staging pool's arenas
+        (``parallel.distribute.StagingPool``), so what goes here is
+        the views: the memory stays mapped for the next table (through
+        PR 35 a table's worth of arrays was unmapped here, 25 - 31 ms
+        for 302 MB; PERF.md section 6, PR 31, 34 and 36).  The second
+        collection still lets go of what the runtime held of the
+        ANSWER's host copies (``drop`` in :meth:`_run_to_host`), which
+        are the user's and no pool's, so they are unmapped inside the
+        span and not at the next job's first call into jax; and it
+        trims the pool to the arenas this job's ingests used."""
         if not self._ingest_unreleased:
             return
         self._ingest_unreleased = not done
         with self.tracer.span("release", cat="ingest"):
             gc.collect(0)
+            if done:
+                self.staging.trim()
 
     def run_to_host_async(self, query: Query):
         """Dispatch the device job NOW; return a zero-arg ``fetch``
@@ -1204,15 +1213,3 @@ class DryadContext:
             vals = np.asarray(col)[np.asarray(valid)]
             return bool(vals[0]) if len(vals) else False
         return self._execute_device(out_q)
-
-
-def _phys_dtype(col: str, schema: Schema) -> np.dtype:
-    if "#" in col:
-        return np.dtype(np.uint32)
-    f = schema.field(col)
-    return {
-        ColumnType.INT32: np.dtype(np.int32),
-        ColumnType.FLOAT32: np.dtype(np.float32),
-        ColumnType.BOOL: np.dtype(np.bool_),
-        ColumnType.UINT32: np.dtype(np.uint32),
-    }[f.ctype]

@@ -78,14 +78,12 @@ def encode_table(
     schema: Schema,
     arrays: Dict[str, np.ndarray],
     dictionary: Optional[StringDictionary],
-    tracer: Tracer = UNTRACED,
 ) -> Tuple[Dict[str, np.ndarray], int]:
-    """Logical host table -> ``(physical host columns, row count)``.
-    Host-only (NumPy in, NumPy out): the sharded ingest edge
-    (``parallel.distribute.from_host_table``) places these columns
-    itself, so nothing here may touch a device.  Turning a BYTES
-    column into its words is a ``pack`` span of ``tracer`` (``bytes``
-    of the column as handed in, ``rows``)."""
+    """Logical host table -> ``(physical host columns at n rows, row
+    count)``.  Host-only (NumPy in, NumPy out).  The sharded ingest
+    edge does not come through here: ``parallel.distribute.
+    from_host_table`` writes a column's physical form straight into
+    its ``P * capacity`` layout."""
     n = None
     for name in schema.names:
         a = np.asarray(arrays[name])
@@ -95,16 +93,7 @@ def encode_table(
             raise ValueError("ragged input columns")
     phys: Dict[str, np.ndarray] = {}
     for f in schema.fields:
-        a = np.asarray(arrays[f.name])
-        if f.ctype.is_bytes:
-            with tracer.span(
-                "pack", cat="ingest", account=True, bytes=a.size, rows=len(a)
-            ) as sp:
-                words = encode_physical(f, a, dictionary)
-                sp.add(bytes_out=_nbytes(words))
-            phys.update(words)
-        else:
-            phys.update(encode_physical(f, a, dictionary))
+        phys.update(encode_physical(f, np.asarray(arrays[f.name]), dictionary))
     return phys, n or 0
 
 

@@ -168,10 +168,14 @@ def _over_row_blocks(rows: int, width: int, work) -> None:
         ))
 
 
-def bytes_to_words(values: np.ndarray, width: int) -> List[np.ndarray]:
+def bytes_to_words(
+    values: np.ndarray, width: int, out: Optional[Sequence[np.ndarray]] = None
+) -> List[np.ndarray]:
     """``[rows, width]`` uint8 -> ``ceil(width / 4)`` uint32 arrays of
-    big-endian words (the rows of one ``[words, rows]`` array), the
-    last zero-padded.  Array passes a block of rows, never a row."""
+    big-endian words, the last zero-padded; written into ``out`` (one
+    array of ``rows`` a word) where given, else into the rows of one
+    new ``[words, rows]`` array.  Array passes a block of rows, never a
+    row."""
     a = np.asarray(values)
     if a.dtype != np.uint8 or a.ndim != 2 or a.shape[1] != width:
         raise ValueError(
@@ -179,11 +183,22 @@ def bytes_to_words(values: np.ndarray, width: int) -> List[np.ndarray]:
             f"{a.dtype} {a.shape}"
         )
     rows = a.shape[0]
-    out = np.empty((-(-width // 4), rows), np.uint32)
+    words = -(-width // 4)
+    if out is None:
+        out = np.empty((words, rows), np.uint32)
+    elif len(out) != words or any(
+        w.dtype != np.uint32 or w.shape != (rows,) for w in out
+    ):
+        raise ValueError(
+            f"BYTES({width}) packs {rows} rows into {words} uint32 arrays "
+            f"of {rows}, got {[(str(w.dtype), w.shape) for w in out]}"
+        )
 
     def pack(block, lo, n):  # the block's padding bytes stay zero
         block[:n, :width] = a[lo : lo + n]
-        out[:, lo : lo + n] = block.view(">u4")[:n].T
+        as_words = block.view(">u4")
+        for i, w in enumerate(out):
+            w[lo : lo + n] = as_words[:n, i]
 
     _over_row_blocks(rows, width, pack)
     return list(out)
@@ -384,6 +399,14 @@ class Schema:
         for f in self.fields:
             out.extend(f.device_names)
         return out
+
+    def device_dtypes(self) -> Dict[str, np.dtype]:
+        """Physical device column -> its dtype: the words of a split
+        column are uint32, any other column keeps its own."""
+        return {
+            n: np.dtype(np.uint32) if f.ctype.is_split else f.ctype.numpy_dtype
+            for f in self.fields for n in f.device_names
+        }
 
     def with_field(self, name: str, ctype: ColumnType) -> "Schema":
         return Schema([(f.name, f.ctype) for f in self.fields] + [(name, ctype)])

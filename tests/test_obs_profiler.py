@@ -229,9 +229,9 @@ def test_h2d_bytes_are_the_arrays_and_the_counter(recorded):
     assert stats["bytes"] == table["k"].nbytes + table["v"].nbytes + ROWS
     assert recorded["fresh_counters"]["h2d_bytes"] == stats["bytes"]
     assert recorded["requery_counters"]["h2d_bytes"] == 0
-    encode = [a[3] for a in inside if a[0] == "dryad:ingest:encode"]
-    assert [s["rows"] for s in encode] == [ROWS, ROWS]
-    assert encode[-1]["capacity"] == ROWS
+    # ONE ``encode`` a table since PR 36: the layout, written in one pass
+    (encode,) = [a[3] for a in inside if a[0] == "dryad:ingest:encode"]
+    assert encode["rows"] == encode["capacity"] == ROWS
 
 
 def test_fetch_copy_bytes_are_the_arrays_and_the_counter(recorded):
@@ -327,15 +327,15 @@ def test_every_host_pass_accounts_for_itself(recorded):
 def test_bytes_out_is_the_arrays_a_pass_made(recorded):
     _, inside = _job(recorded, 0)
     table = recorded["table"]
-    schema, pad = [a[3] for a in inside if a[0] == "dryad:ingest:encode"]
-    # the physical columns; then P x capacity slots of them and ``valid``
-    assert "capacity" not in schema and pad["capacity"] == ROWS
-    assert schema["bytes_out"] == table["k"].nbytes + table["v"].nbytes
-    assert pad["bytes_out"] == schema["bytes_out"] + ROWS
+    (encode,) = [a[3] for a in inside if a[0] == "dryad:ingest:encode"]
+    # P x capacity slots of the physical columns and ``valid``, written once
+    assert encode["capacity"] == ROWS
+    assert encode["bytes_out"] == table["k"].nbytes + table["v"].nbytes + ROWS
+    assert 0 <= encode["warm_bytes"] <= encode["bytes_out"]
     (_, _, _, decode), = [a for a in inside if a[0] == "dryad:decode:decode"]
     assert decode["bytes_out"] == table["k"].nbytes + table["v"].nbytes
     # BYTES columns: 10 bytes are 3 words, 6 are 2; ``pack`` lies inside
-    # the schema pass, whose bytes are the words'
+    # the table's ``encode``, whose bytes are the words' and ``valid``'s
     records = recorded["records"]
     np.testing.assert_array_equal(
         recorded["sorted_records"]["key"],
@@ -346,7 +346,7 @@ def test_bytes_out_is_the_arrays_a_pass_made(recorded):
     by_id = {a[3]["span_id"]: a for a in recorded["annotations"]}
     outer = by_id[packs[0][3]["parent_id"]]
     assert outer[0] == "dryad:ingest:encode"
-    assert outer[3]["bytes_out"] == 4096 * (12 + 8)
+    assert outer[3]["bytes_out"] == 4096 * (12 + 8 + 1)
     unpacks = _by_name(recorded, "dryad:decode:unpack")
     assert sorted(a[3]["bytes_out"] for a in unpacks) == [4096 * 6, 4096 * 10]
     outer = by_id[unpacks[0][3]["parent_id"]]
