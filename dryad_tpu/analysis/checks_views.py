@@ -8,7 +8,8 @@ never finalizes partial state outside the snapshot path:
   (the serve driver imports the registry, not vice versa — a views ->
   serve import is a cycle through ``serve/__init__``);
 - views/ never calls an execution surface (``run_to_host`` /
-  ``run_to_host_async`` / ``collect`` / ``submit`` / ``to_store``) —
+  ``run_to_host_async`` / ``collect`` / ``collect_many`` / ``submit`` /
+  ``to_store``) —
   dispatching the finalize plan belongs to the serve driver, so a
   view read costs dispatches ONLY where the driver accounts for them;
 - partial state finalizes only inside :func:`finalize_query` in
@@ -44,6 +45,7 @@ _EXEC_SURFACES = (
     "run_to_host",
     "run_to_host_async",
     "collect",
+    "collect_many",
     "submit",
     "to_store",
     "_execute_device",

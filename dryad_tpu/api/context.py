@@ -18,7 +18,7 @@ import math
 import os
 import time
 from collections import OrderedDict
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -863,13 +863,20 @@ class DryadContext:
                 )
         return total
 
-    def _execute_device(self, query: Query, defer_miss: bool = False):
+    def _execute_roots(self, queries, defer_miss: bool = False):
+        """Lower ``queries`` together as ONE graph (a stage several of
+        them share is lowered, traced and run once), bind its inputs
+        and run it: the batch of each query, in the order given, and
+        the executor's ``DeferredFinish`` (None unless ``defer_miss``).
+        The one place a plan becomes device work: ``collect`` of one
+        query or of many, the asynchronous forms and ``cache`` all
+        come through here."""
         with self.tracer.span("lower", cat="plan") as span:
             graph = lower(
-                [query.node], self.config, self.dictionary,
+                [q.node for q in queries], self.config, self.dictionary,
                 P=num_partitions(self.mesh) if self.mesh is not None else None,
             )
-            span.add(stages=len(graph.stages))
+            span.add(stages=len(graph.stages), roots=len(queries))
         bindings = {
             nid: self._bind_device(n) for nid, n in graph.inputs.items()
         }
@@ -878,15 +885,17 @@ class DryadContext:
             binding_fps = {
                 nid: self._binding_fp(n) for nid, n in graph.inputs.items()
             }
+        results = self.executor.execute(
+            graph, bindings, binding_fps, defer_miss=defer_miss
+        )
+        deferred = None
         if defer_miss:
-            results, deferred = self.executor.execute(
-                graph, bindings, binding_fps, defer_miss=True
-            )
-            sid, oidx = graph.outputs[query.node.id]
-            return results[(sid, oidx)], deferred
-        results = self.executor.execute(graph, bindings, binding_fps)
-        sid, oidx = graph.outputs[query.node.id]
-        return results[(sid, oidx)]
+            results, deferred = results
+        return [results[graph.outputs[q.node.id]] for q in queries], deferred
+
+    def _execute_device(self, query: Query, defer_miss: bool = False):
+        (batch,), deferred = self._execute_roots([query], defer_miss)
+        return (batch, deferred) if defer_miss else batch
 
     def _trace_ctx(self):
         """The active trace context, or a fresh mint for a non-serve
@@ -899,13 +908,57 @@ class DryadContext:
         return ctx
 
     def run_to_host(self, query: Query) -> Dict[str, np.ndarray]:
+        """``Query.collect()``: the job of one output."""
+        return self.collect_many([query])[0]
+
+    def collect_many(self, queries) -> Tuple[Dict[str, np.ndarray], ...]:
+        """Run ``queries`` (of this context) as ONE job and return
+        their host tables, in the order given (the reference's
+        ``SubmitAndWait(q1, q2, ...)``).  The roots lower into
+        one graph, so what they share (an ``apply`` + ``fork`` that
+        feeds three pipelines) is traced and run once, and ``plan_fuse``
+        may fold all of it into one dispatched program.  One ``collect``
+        span (``outputs``), one ``lower`` (``roots``), one ``drain``;
+        then a fetch, a decode and a drop an output (``output``), the
+        dictionary-miss check riding the first fetch, so a miss in ANY
+        output raises before any table is handed out.  An answer's
+        device arrays go as soon as it is on the host.
+        ``Query.collect()`` is this with one query.
+
+        A context in ``local_debug`` mode, or a query that draws on a
+        ``from_stream`` input, has no such graph: those run one by one,
+        each by the rule a single ``collect()`` follows (the NumPy
+        interpreter; the ``StreamExecutor``; a stream input under
+        ``local_debug`` raises), inside the one ``collect`` span."""
+        queries = list(queries)
+        if not queries:
+            raise ValueError("collect_many needs at least one query")
+        for q in queries:
+            if q.ctx is not self:
+                raise ValueError(
+                    "collect_many takes queries of ONE context; "
+                    f"query over node {q.node.id} belongs to another"
+                )
         # every span / exchange_round / dispatch_gap below carries the
         # minted (or inherited) context's qid
         with tracectx.activate(self._trace_ctx()):
-            with self.tracer.span("collect", cat="job"):
-                return self._run_to_host(query)
+            with self.tracer.span("collect", cat="job", outputs=len(queries)):
+                return tuple(self._run_to_host(queries))
 
-    def _run_to_host(self, query: Query) -> Dict[str, np.ndarray]:
+    def _run_to_host(self, queries) -> list:
+        from dryad_tpu.exec.outofcore import has_stream_input
+
+        if self.local_debug or any(
+            has_stream_input(self, q.node) for q in queries
+        ):
+            return [self._run_one(q) for q in queries]
+        return self._run_device_job(queries)
+
+    def _run_one(self, query: Query) -> Dict[str, np.ndarray]:
+        """One query by the rule of a single ``collect()``: a chunk
+        stream goes through the ``StreamExecutor``, a ``local_debug``
+        context through the NumPy interpreter, anything else is a
+        device job of one output."""
         from dryad_tpu.exec.outofcore import StreamExecutor, has_stream_input
 
         if has_stream_input(self, query.node):
@@ -919,31 +972,42 @@ class DryadContext:
         if self.local_debug:
             from dryad_tpu.exec.localdebug import LocalDebugInterpreter
 
-            interp = LocalDebugInterpreter(self)
-            return interp.run_to_logical(query.node)
-        # The dict-miss counters ride the SAME device_get as the job
-        # outputs (one device->host round-trip instead of two); the
+            return LocalDebugInterpreter(self).run_to_logical(query.node)
+        return self._run_device_job([query])[0]
+
+    def _run_device_job(self, queries) -> list:
+        # The dict-miss counters ride the SAME device_get as the first
+        # output (one device->host round-trip instead of two); the
         # deferred check still raises before any result reaches the
         # caller.
-        batch, deferred = self._execute_device(query, defer_miss=True)
-        table = self._fetch_table(query, batch, deferred, done=False)
-        # the answer's device arrays, and what jax cached on them
-        with self.tracer.span("drop", cat="readback"):
-            del batch, deferred
+        batches, deferred = self._execute_roots(queries, defer_miss=True)
+        self._release_ingested()
+        tables = []
+        for i, query in enumerate(queries):
+            # which answer it is, on every span of its fetch
+            with self.tracer.stamped(output=i):
+                tables.append(self._fetch_table(
+                    query, batches[i], deferred, done=False
+                ))
+                # the answer's device arrays, and what jax cached on them
+                with self.tracer.span("drop", cat="readback"):
+                    batches[i] = deferred = None
         self._release_ingested(done=True)
-        return table
+        return tables
 
     def _fetch_table(self, query: Query, batch, deferred=None, done=True):
         """A result batch as the user's logical host table: the fetch
         (``deferred``'s miss counters riding it; the byte accounting is
         the fetch's own) and the decode of the valid rows, which are a
         slice a shard where the fetch measured the batch and found no
-        hole, and the mask's otherwise.  ``done``: the job ends with
-        this call (``_run_to_host`` says no: it drops the batch first
-        and then lets go of what the job ingested, see
-        :meth:`_release_ingested`)."""
+        hole, and the mask's otherwise.  ``done``: this call is a fetch
+        of its own, so it lets go of what the job ingested before and
+        after (:meth:`_release_ingested`; ``_run_device_job`` says no:
+        it does so itself, once before its first output's fetch and
+        once after its last's drop)."""
         metrics = self.executor.metrics if self.executor is not None else None
-        self._release_ingested()
+        if done:
+            self._release_ingested()
         if deferred is not None:
             valid, host_cols, rows = _fetch_with_miss(
                 batch, deferred, self.tracer, metrics
@@ -979,7 +1043,7 @@ class DryadContext:
 
                 table = collapse_table(table, self._codecs)
             span.add(bytes_out=_nbytes(table))
-        # The fetched host copies go here (and in ``_run_to_host`` the
+        # The fetched host copies go here (and in ``_run_device_job`` the
         # device arrays that may own them): a table's worth of memory
         # to unmap (15 ms for 302 MB, 45 ms for 881 MB, 56 ms for the
         # four shards' 718 MB; PERF.md section 6, PR 34), which fell at
@@ -1013,7 +1077,7 @@ class DryadContext:
         PR 35 a table's worth of arrays was unmapped here, 25 - 31 ms
         for 302 MB; PERF.md section 6, PR 31, 34 and 36).  The second
         collection still lets go of what the runtime held of the
-        ANSWER's host copies (``drop`` in :meth:`_run_to_host`), which
+        ANSWER's host copies (``drop`` in :meth:`_run_device_job`), which
         are the user's and no pool's, so they are unmapped inside the
         span and not at the next job's first call into jax; and it
         trims the pool to the arenas this job's ingests used."""
@@ -1031,72 +1095,50 @@ class DryadContext:
         streaming pipeline's dispatch/drain split: the driver launches
         bucket k+1's program while bucket k's results transfer
         (``exec.outofcore`` phase 2).  Not valid for stream-input
-        plans (those route through the StreamExecutor)."""
-        tctx = self._trace_ctx()
-        with tracectx.activate(tctx):
-            batch, deferred = self._execute_device(query, defer_miss=True)
-
-        def fetch() -> Dict[str, np.ndarray]:
-            # the closure carries its query's context: a fetch drained
-            # on another thread (DispatchWindow collector, serve
-            # driver) still stamps readback spans with the right qid
-            with tracectx.activate(tctx):
-                return self._fetch_table(query, batch, deferred)
-
-        return fetch
+        plans (those route through the StreamExecutor).
+        :meth:`run_many_to_host_async` of one query."""
+        return self.run_many_to_host_async([query])[0]
 
     def run_many_to_host_async(self, queries):
-        """Dispatch SEVERAL independent queries as ONE lowered program
+        """:meth:`collect_many`'s asynchronous form: dispatch SEVERAL
+        queries as ONE lowered program NOW and hand back a fetch each
         (cross-chunk plan fusion, ``config.chunk_fuse``): the roots
-        lower together, their stage chains land consecutively in the
-        graph, and ``plan_fuse`` folds them into a single dispatched
-        region — K dispatch round trips collapse into one.  Each query
-        stays its own computation inside the region (its reduction
-        order is untouched), so results are byte-identical to K
-        separate dispatches.
+        lower together (:meth:`_execute_roots`, the lowering, binding
+        and dispatch every ``collect`` goes through), their stage
+        chains land consecutively in the graph, and ``plan_fuse`` folds
+        them into a single dispatched region — K dispatch round trips
+        collapse into one.  Each query stays its own computation inside
+        the region (its reduction order is untouched), so results are
+        byte-identical to K separate dispatches.
 
         Returns one zero-arg ``fetch`` closure per query, resolving
-        that query's outputs from the shared execution.  The deferred
+        that query's output from the shared execution through
+        :meth:`_fetch_table`, as ``collect_many`` does.  The deferred
         dict-miss check rides the FIRST fetch's transfer (a miss
         anywhere in the group raises there, before any result of the
         group is committed)."""
+        queries = list(queries)
         tctx = self._trace_ctx()
         with tracectx.activate(tctx):
-            graph = lower(
-                [q.node for q in queries], self.config, self.dictionary,
-                P=num_partitions(self.mesh) if self.mesh is not None else None,
-            )
-            bindings = {
-                nid: self._bind_device(n) for nid, n in graph.inputs.items()
-            }
-            binding_fps = None
-            if self.config.checkpoint_dir:
-                binding_fps = {
-                    nid: self._binding_fp(n)
-                    for nid, n in graph.inputs.items()
-                }
-            results, deferred = self.executor.execute(
-                graph, bindings, binding_fps, defer_miss=True
-            )
-        state = {"deferred_done": False}
+            batches, deferred = self._execute_roots(queries, defer_miss=True)
 
-        def make_fetch(query, batch):
+        def make_fetch(output, query, batch):
             def fetch() -> Dict[str, np.ndarray]:
-                with tracectx.activate(tctx):
-                    first = not state["deferred_done"]
-                    table = self._fetch_table(
-                        query, batch, deferred if first else None
-                    )
-                    state["deferred_done"] = True
+                nonlocal deferred  # the first fetch to come resolves it
+                # the closure carries its job's context: a fetch drained
+                # on another thread (DispatchWindow collector, serve
+                # driver) still stamps readback spans with the right qid
+                with tracectx.activate(tctx), self.tracer.stamped(output=output):
+                    table = self._fetch_table(query, batch, deferred)
+                    deferred = None
                     return table
 
             return fetch
 
-        fetches = []
-        for q in queries:
-            sid, oidx = graph.outputs[q.node.id]
-            fetches.append(make_fetch(q, results[(sid, oidx)]))
-        return fetches
+        return [
+            make_fetch(i, q, batch)
+            for i, (q, batch) in enumerate(zip(queries, batches))
+        ]
 
     def submit(self, query: Query) -> JobHandle:
         return JobHandle(self.run_to_host(query))

@@ -1236,7 +1236,8 @@ class Query:
 
     def collect(self) -> Dict[str, np.ndarray]:
         """Execute and fetch host logical columns (reference
-        Submit+enumerate path, ``DryadLinqQuery.cs:608``)."""
+        Submit+enumerate path, ``DryadLinqQuery.cs:608``): the job of
+        one output (``DryadContext.collect_many``)."""
         return self.ctx.run_to_host(self)
 
     def collect_stream(self):

@@ -1080,11 +1080,28 @@ def _k_scalar_agg(ctx: StageContext, p) -> None:
 def _k_fork(ctx: StageContext, p) -> None:
     b = ctx.slots[p["slot"]]
     outs = p["fn"](b)
-    if len(outs) != p["n_out"]:
-        raise ValueError(f"fork fn returned {len(outs)} outputs, expected {p['n_out']}")
-    for slot, ob in zip(p["out_slots"], outs):
+    want = p["out_dtypes"]
+    if len(outs) != len(want):
+        raise ValueError(
+            f"fork fn returned {len(outs)} outputs, expected {len(want)}"
+        )
+    for i, (slot, ob, dtypes) in enumerate(zip(p["out_slots"], outs, want)):
         if not isinstance(ob, ColumnBatch):
             raise TypeError("fork fn must return ColumnBatches")
+        # against its schema here, where the output can be named: a
+        # wrong column otherwise fails inside whatever consumes it
+        got = tuple(sorted((n, a.dtype.name) for n, a in ob.data.items()))
+        if got != dtypes:
+            raise ValueError(
+                f"fork output {i} has columns {dict(got)}; its schema "
+                f"(out_schemas[{i}]) says {dict(dtypes)}"
+            )
+        if ob.capacity != b.capacity:
+            raise ValueError(
+                f"fork output {i} has capacity {ob.capacity}; a fork "
+                f"keeps its input's ({b.capacity}): filter rows, do not "
+                "cut them"
+            )
         ctx.slots[slot] = ob
 
 

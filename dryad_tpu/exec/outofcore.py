@@ -278,8 +278,11 @@ class _AsyncDispatcher:
 
     Queries queue up to ``fuse`` deep and dispatch in submit order —
     a fused batch lowers as ONE multi-root program
-    (``run_many_to_host_async``), collapsing K dispatch round trips
-    into one — and each chunk's readback fetch hands off to the
+    (``run_many_to_host_async``, the asynchronous form of the
+    context's ``collect_many``: one lowering, binding and dispatch
+    under both, ``DryadContext._execute_roots``), collapsing K
+    dispatch round trips into one — and each chunk's readback fetch
+    (``DryadContext._fetch_table``, every ``collect``'s) hands off to the
     window's collector thread.  Outcomes are delivered strictly in
     submit order, so the caller's commit body (spill / accumulate /
     combine) observes the exact serial sequence and results stay

@@ -23,7 +23,11 @@ may construct its own ``Tracer(events)`` over the same log and the
 hierarchy stays consistent.  The stack of open spans is a tracer's own:
 a module whose spans must nest under another's is handed that tracer
 (the telemetry sampler the context's, so ``resource_sample`` is a child
-of the span whose event found the sample due).
+of the span whose event found the sample due).  So are the fields of
+:meth:`Tracer.stamped`: what a layer knows about the work below it (which
+of a job's answers a fetch is) goes onto every span that this thread
+opens through the tracer meanwhile, and the layers below take no
+parameter for it.
 
 Every span is also a ``jax.profiler.TraceAnnotation`` over the same
 interval, named ``dryad:<phase>:<name>`` (``<phase>`` from
@@ -52,6 +56,7 @@ faults are not taken: the chip's host (gVisor) counts none.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import resource
 import threading
@@ -229,7 +234,23 @@ class Tracer:
         if not self.enabled:
             return _NULL
         pid = self.current_id() if parent is _UNSET else parent
-        return Span(self, name, cat, pid, dict(fields), account)
+        stamp = getattr(self._local, "stamp", None)
+        return Span(
+            self, name, cat, pid, {**stamp, **fields} if stamp else fields,
+            account,
+        )
+
+    @contextlib.contextmanager
+    def stamped(self, **fields: Any):
+        """Every span this thread opens through the tracer inside the
+        block carries ``fields`` (a span's own field of the same name
+        wins)."""
+        was = getattr(self._local, "stamp", None)
+        self._local.stamp = {**was, **fields} if was else fields
+        try:
+            yield
+        finally:
+            self._local.stamp = was
 
 
 # The default of a ``tracer=`` parameter (``columnar/batch.py``,

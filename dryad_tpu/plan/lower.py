@@ -53,6 +53,16 @@ class Stage:
     out_slots: List[int] = dataclasses.field(default_factory=lambda: [0])
     # growth: output capacity multiplier relative to base input capacity
     growth: float = 1.0
+    # slots taken beyond the inputs' (``take_slots``)
+    taken: int = 0
+
+    def take_slots(self, n: int) -> List[int]:
+        """``n`` slots that no input of the stage and no earlier taker
+        holds (an op works in its input's slot; a ``fork`` needs one an
+        output)."""
+        first = len(self.input_refs) + self.taken
+        self.taken += n
+        return list(range(first, first + n))
 
 
 @dataclasses.dataclass
@@ -346,14 +356,24 @@ class _Builder:
 
         elif k == "fork":
             stage, slot = self._continue_or_start(node, fanout.get(node.inputs[0].id, 1))
-            n_out = len(node.params["out_schemas"])
+            out_slots = stage.take_slots(len(node.params["out_schemas"]))
             stage.ops.append(
-                StageOp("fork", dict(slot=slot, fn=node.params["fn"], n_out=n_out))
+                StageOp(
+                    "fork",
+                    dict(
+                        slot=slot, fn=node.params["fn"], out_slots=out_slots,
+                        # what each output must look like, checked as
+                        # the fork is traced: physical column -> dtype
+                        out_dtypes=tuple(
+                            tuple(
+                                (n, dt.name)
+                                for n, dt in sorted(sch.device_dtypes().items())
+                            )
+                            for sch in node.params["out_schemas"]
+                        ),
+                    ),
+                )
             )
-            # fork outputs occupy fresh slots after existing inputs
-            base = len(stage.input_refs)
-            out_slots = [base + 100 + i for i in range(n_out)]
-            stage.ops[-1].params["out_slots"] = out_slots
             self._close(stage, out_slots)
             self.cursor[node.id] = ("closed", stage.id, -1)  # branches index it
 

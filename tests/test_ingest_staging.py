@@ -350,8 +350,10 @@ def test_a_smaller_table_after_a_larger_one_is_served_from_the_larger_buffer():
     e1, e2 = [e for e in log.events() if e["name"] == "encode"]
     assert e1["bytes_out"] == 9 * 4004 and e2["bytes_out"] == 9 * 1004
     # every column of the small table that found an idle buffer took it: the
-    # smallest that fits, each viewed at the column's own dtype and length
-    need = sorted([4 * 1004, 4 * 1004, 1004])
+    # smallest that fits, each viewed at the column's own dtype and length,
+    # in the order ``lay_out`` asks: the columns, then ``valid`` (sorted,
+    # this model was off whenever the backend had kept the smallest buffer)
+    need = [4 * 1004, 4 * 1004, 1004]
     served = 0
     for nbytes in need:
         fit = [h for h in held if h >= nbytes]

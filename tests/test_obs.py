@@ -154,6 +154,43 @@ class TestSpans:
         else:
             assert not stats & set(encode)
 
+    def test_stamped_fields_ride_every_span_of_the_block(self):
+        """What a layer knows of the work below it (which of a job's
+        answers a fetch is) is stamped once, on this thread's spans of
+        this tracer, and the layers below take no parameter for it."""
+        log = EventLog(None)
+        tr = Tracer(log)
+        seen = []
+
+        def elsewhere():
+            with tr.span("other_thread"):
+                pass
+            seen.append(log.filter("span")[-1])
+
+        with tr.span("collect", cat="job"):
+            with tr.stamped(output=1):
+                with tr.span("fetch_copy", cat="readback", bytes=9):
+                    with tr.span("inner"):
+                        pass
+                with tr.stamped(shard=2):
+                    with tr.span("decode", output=7):  # its own wins
+                        pass
+                with tr.span("drop"):
+                    pass
+                t = threading.Thread(target=elsewhere)
+                t.start()
+                t.join()
+            with tr.span("release"):
+                pass
+        by_name = {e["name"]: e for e in log.filter("span")}
+        assert by_name["fetch_copy"]["output"] == 1 == by_name["inner"]["output"]
+        assert by_name["fetch_copy"]["bytes"] == 9
+        assert (by_name["decode"]["output"], by_name["decode"]["shard"]) == (7, 2)
+        assert by_name["drop"]["output"] == 1 and "shard" not in by_name["drop"]
+        for name in ("release", "collect", "other_thread"):
+            assert "output" not in by_name[name], name
+        assert seen and Tracer(None).stamped(output=0)  # a disabled tracer too
+
     def test_error_recorded_on_exception(self):
         log = EventLog(None)
         tr = Tracer(log)

@@ -149,9 +149,12 @@ def test_terasort_shape(ctx, dbg):
 
 
 # -- config 4: Apply + Fork multi-output DAG --------------------------------
-def test_apply_fork_dag(ctx, dbg):
+@pytest.mark.parametrize("how", ["three_collects", "one_job"])
+def test_apply_fork_dag(ctx, dbg, how):
     """Per-partition apply, then a fork producing two branches consumed
-    by different downstream pipelines (multi-output DAG with a Tee)."""
+    by different downstream pipelines (multi-output DAG with a Tee);
+    the three outputs collected one by one, and as ONE job
+    (``collect_many``: the reference's ``SubmitAndWait`` of several)."""
     n = 800
     tbl = {"x": np.arange(n, dtype=np.int32)}
     s = Schema([("x", ColumnType.INT32)])
@@ -171,6 +174,8 @@ def test_apply_fork_dag(ctx, dbg):
         agg_m = mult.group_by(
             "x", {"c": ("count", None)}
         ).aggregate_as_query({"total": ("count", None)})
+        if how == "one_job":
+            return c.collect_many([mult, rest, agg_m])
         return mult.collect(), rest.collect(), agg_m.collect()
 
     am, ar, at = q(ctx)

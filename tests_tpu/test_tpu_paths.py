@@ -464,3 +464,40 @@ def test_the_third_key_word_decides_at_the_cells_rows(jaxmod):
     dispatched = [e for e in ctx.events.events()
                   if e["kind"] == "span" and e.get("cat") == "execute"]
     assert {e["row_words"] for e in dispatched} == {26}
+
+
+def test_the_multi_output_job_on_chip(jaxmod):
+    """``applyfork-1c``'s job at 2^21 rows (9 B a slot: both branches
+    are over the 16 MiB under which a fetch does not ask; 2^20 would be
+    under it) through the cell's own ``bind`` and ``compare``: one
+    ``collect`` of three answers, ONE dispatch, the sorted branch a
+    trimmed copy, the branch that is passed through copied whole."""
+    import importlib.util
+    import os
+
+    from dryad_tpu import DryadContext
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_job_applyfork",
+        os.path.join(root, "benchmarks", "jobs", "applyfork.py"))
+    job = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(job)
+    params = {"rows": 1 << 21, "hot_eighths": 3}
+    table = job.make_table(np.random.default_rng([38, 1]), params, None, 0)
+    ctx = DryadContext(num_partitions_=1)
+    bound = job.bind(ctx, table, params)
+    for answer in (bound.collect(), bound.collect()):
+        checks = job.compare(table, answer, params)
+        assert len(checks) == 7, checks
+        assert all(value <= limit for value, limit in checks.values()), checks
+    spans = [e for e in ctx.events.events() if e["kind"] == "span"]
+    collects = [e for e in spans if e["name"] == "collect"]
+    assert [e["outputs"] for e in collects] == [3, 3]
+    assert len([e for e in spans if e.get("cat") == "execute"]) == 2
+    trims = [e for e in spans if e["name"] == "fetch_trim"]
+    assert [(e["output"], e["trimmed"]) for e in trims] == [(0, 1), (1, 0)] * 2
+    decodes = [e for e in spans if e["name"] == "decode"][:3]
+    assert decodes[0]["fetched"] < 1.2 * decodes[0]["rows"]
+    assert decodes[1]["fetched"] == decodes[1]["capacity"] == params["rows"]
+    assert decodes[0]["rows"] + decodes[1]["rows"] == params["rows"]
