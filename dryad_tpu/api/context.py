@@ -17,6 +17,7 @@ import itertools
 import math
 import os
 import time
+import weakref
 from collections import OrderedDict
 from typing import Dict, Optional, Tuple
 
@@ -65,10 +66,23 @@ _NP_TYPE_MAP = {
 }
 
 
-def _word_vocab(h0: np.ndarray, h1: np.ndarray) -> np.ndarray:
-    """Unique 64-bit word hashes from split (#h0, #h1) columns."""
-    h = (h1.astype(np.uint64) << np.uint64(32)) | h0.astype(np.uint64)
-    return np.unique(h)
+def _word_vocab(distinct) -> np.ndarray:
+    """The sorted 64-bit word hashes of a table, from the lists of
+    distinct words its buffers' tokenizer passes found (never from the
+    tokens)."""
+    v = np.sort(np.concatenate(distinct)) if distinct else np.zeros(0, np.uint64)
+    keep = np.ones(len(v), bool)
+    keep[1:] = v[1:] != v[:-1]
+    return v[keep]
+
+
+def _forget_binding(ctx_ref, node_id: int) -> None:
+    """Finalizer of an input node whose host table the context made
+    itself: nothing can reach the table once its node is gone."""
+    ctx = ctx_ref()
+    if ctx is not None:
+        ctx._bindings.pop(node_id, None)
+        ctx._binding_fp_cache.pop(node_id, None)
 
 
 def _infer_schema(arrays: Dict[str, np.ndarray]) -> Schema:
@@ -424,21 +438,22 @@ class DryadContext:
         return old_fp
 
     def _tokenize_buf(self, buf: bytes):
-        """Tokenize one byte buffer, registering tokens in the context
-        dictionary; returns the (h0, h1, r0, r1) physical columns."""
+        """Tokenize one byte buffer and register its distinct words in
+        the context dictionary, each decoded from its first occurrence;
+        returns the tokenizer's :class:`~dryad_tpu.runtime.bindings.Tokens`."""
         from dryad_tpu.runtime import bindings as RB
 
-        h0, h1, r0, r1, starts, lens = RB.tokenize(buf)
-        hashes = (h1.astype(np.uint64) << np.uint64(32)) | h0.astype(np.uint64)
-        uniq, first_idx = np.unique(hashes, return_index=True)
-        for h, i in zip(uniq, first_idx):
-            s = int(starts[i])
-            tok = buf[s : s + int(lens[i])].decode("utf-8", "replace")
-            existing = self.dictionary._map.get(int(h))
+        toks = RB.tokenize(buf)
+        known = self.dictionary._map
+        for h, s, n in zip(
+            toks.hashes.tolist(), toks.starts.tolist(), toks.lens.tolist()
+        ):
+            tok = buf[s : s + n].decode("utf-8", "replace")
+            existing = known.get(h)
             if existing is not None and existing != tok:
                 raise ValueError(f"hash64 collision: {existing!r} vs {tok!r}")
-            self.dictionary._map[int(h)] = tok
-        return h0, h1, r0, r1
+            known[h] = tok
+        return toks
 
     def from_text(self, data, column: str = "word") -> Query:
         """Tokenize raw text into a one-STRING-column table using the
@@ -464,31 +479,34 @@ class DryadContext:
                 # Multi-file ingest: the native prefetch channel reads
                 # file i+1 while file i tokenizes (reference async
                 # channel buffer readers, channelbuffernativereader.cpp).
-                parts = []
                 with RB.PrefetchChannel(list(data), depth=4, threads=2) as ch:
-                    for fbuf in ch:
-                        parts.append(self._tokenize_buf(fbuf))
-                if not parts:
-                    cols = [np.zeros(0, np.uint32)] * 4
-                else:
-                    cols = [
-                        np.concatenate([p[i] for p in parts])
-                        for i in range(4)
-                    ]
-                h0, h1, r0, r1 = cols
+                    parts = [self._tokenize_buf(fbuf) for fbuf in ch]
             else:
                 if on_disk:
                     with open(data, "rb") as fh:
                         data = fh.read()
-                h0, h1, r0, r1 = self._tokenize_buf(data)
+                parts = [self._tokenize_buf(data)]
+            if len(parts) == 1:
+                h0, h1, r0, r1 = parts[0][:4]
+            elif parts:
+                h0, h1, r0, r1 = (
+                    np.concatenate([p[i] for p in parts]) for i in range(4)
+                )
+            else:
+                h0 = h1 = r0 = r1 = np.zeros(0, np.uint32)
+            # distinct: the words the tokenizer's table found, a buffer;
+            # runs: the cuts of a buffer hashed side by side (1 = the
+            # caller's thread alone)
             span.add(
                 rows=len(h0),
                 bytes_out=h0.nbytes + h1.nbytes + r0.nbytes + r1.nbytes,
+                distinct=sum(len(p.hashes) for p in parts),
+                runs=max((p.runs for p in parts), default=1),
             )
         with self.tracer.span(
             "vocab", cat="ingest", account=True, rows=len(h0)
         ) as span:
-            vocab = _word_vocab(h0, h1)
+            vocab = _word_vocab([p.hashes for p in parts])
             span.add(bytes_out=vocab.nbytes)
         schema = Schema([(column, ColumnType.STRING)])
         node = Node(
@@ -500,6 +518,12 @@ class DryadContext:
             {f"{column}#h0": h0, f"{column}#h1": h1,
              f"{column}#r0": r0, f"{column}#r1": r1},
         )
+        # the columns are the context's own copy of the text: they go
+        # when no query can reach the node any more (a derived query
+        # holds it through ``inputs``)
+        weakref.finalize(
+            node, _forget_binding, weakref.ref(self), node.id
+        ).atexit = False
         return Query(self, node)
 
     def from_stream(self, chunks, schema: Optional[Schema] = None) -> Query:
@@ -546,11 +570,11 @@ class DryadContext:
         schema = Schema([(column, ColumnType.STRING)])
 
         def phys(buf):
-            h0, h1, r0, r1 = self._tokenize_buf(buf)
+            toks = self._tokenize_buf(buf)
             return {
-                f"{column}#h0": h0, f"{column}#h1": h1,
-                f"{column}#r0": r0, f"{column}#r1": r1,
-                "#vocab": {column: _word_vocab(h0, h1)},
+                f"{column}#h0": toks.h0, f"{column}#h1": toks.h1,
+                f"{column}#r0": toks.r0, f"{column}#r1": toks.r1,
+                "#vocab": {column: _word_vocab([toks.hashes])},
             }
 
         def gen():

@@ -16,17 +16,19 @@ import os
 import subprocess
 import threading
 import zlib
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
-from dryad_tpu.columnar.schema import hash64_bytes
+from dryad_tpu.columnar.schema import hash64_bytes, split64
 from dryad_tpu.utils.logging import get_logger
 
 log = get_logger("dryad_tpu.runtime")
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libdryadnative.so")
+_U32P = ctypes.POINTER(ctypes.c_uint32)
+_U64P = ctypes.POINTER(ctypes.c_uint64)
 _lib = None
 _lib_tried = False
 _lock = threading.Lock()
@@ -65,15 +67,20 @@ def _load() -> Optional[ctypes.CDLL]:
             return None
         lib.dn_hash64.restype = ctypes.c_uint64
         lib.dn_hash64.argtypes = [ctypes.c_char_p, ctypes.c_size_t]
-        lib.dn_token_count.restype = ctypes.c_size_t
-        lib.dn_token_count.argtypes = [ctypes.c_char_p, ctypes.c_size_t]
-        lib.dn_tokenize.restype = ctypes.c_size_t
-        lib.dn_tokenize.argtypes = [
-            ctypes.c_char_p, ctypes.c_size_t, ctypes.c_size_t,
-            ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_uint32),
-            ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_uint32),
-            ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_uint32),
+        lib.dn_words_open.restype = ctypes.c_void_p
+        lib.dn_words_open.argtypes = [
+            ctypes.c_char_p, ctypes.c_size_t, _U64P, ctypes.c_size_t,
         ]
+        lib.dn_words_tokens.restype = ctypes.c_size_t
+        lib.dn_words_tokens.argtypes = [ctypes.c_void_p]
+        lib.dn_words_fill.restype = ctypes.c_size_t
+        lib.dn_words_fill.argtypes = [ctypes.c_void_p, _U32P, _U32P, _U32P, _U32P]
+        lib.dn_words_distinct.restype = None
+        lib.dn_words_distinct.argtypes = [
+            ctypes.c_void_p, _U64P, _U64P, _U64P, _U32P,
+        ]
+        lib.dn_words_close.restype = None
+        lib.dn_words_close.argtypes = [ctypes.c_void_p]
         lib.dn_channel_open.restype = ctypes.c_void_p
         lib.dn_channel_open.argtypes = [
             ctypes.POINTER(ctypes.c_char_p), ctypes.c_size_t,
@@ -172,41 +179,70 @@ def hash64(data: bytes) -> int:
     return hash64_bytes(data)
 
 
-def tokenize(
-    text: bytes,
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Whitespace-tokenize a byte buffer into columnar token arrays.
+class Tokens(NamedTuple):
+    """A text buffer's tokens as columns, and its distinct words."""
 
-    Returns (h0, h1, r0, r1, starts, lens): Hash64 word pairs, 8-byte
-    prefix rank words, and byte offsets/lengths for dictionary
-    construction.
-    """
+    h0: np.ndarray  # a token: Hash64 low / high word
+    h1: np.ndarray
+    r0: np.ndarray  # a token: prefix rank of bytes 0-4 / 4-8
+    r1: np.ndarray
+    # a distinct word, in order of first occurrence: its 64-bit hash,
+    # the index of its first token, that token's byte offset and length
+    hashes: np.ndarray
+    first: np.ndarray
+    starts: np.ndarray
+    lens: np.ndarray
+    runs: int  # cuts of the buffer tokenized side by side
+
+
+# The tokenizer takes a thread for every _TOKENIZE_RUN_BYTES of text, up
+# to _TOKENIZE_THREADS: a buffer of a few words (tests, the serving
+# tier) is one run on the caller's thread, a corpus is cut at whitespace
+# and its runs are hashed side by side, each writing its own stretch of
+# the columns (one thread writes newly mapped pages at 0.9 GB/s on the
+# chip's host, PERF.md section 6, PR 34) and finding its own distinct
+# words.
+_TOKENIZE_THREADS = 8
+_TOKENIZE_RUN_BYTES = 1 << 20
+
+
+def tokenize(text: bytes, cuts: Optional[Sequence[int]] = None) -> Tokens:
+    """Whitespace-tokenize a byte buffer in one pass: the four columns
+    a token and the distinct words, found in a hash table as the tokens
+    are hashed (nothing sorts).  ``cuts`` proposes the byte offsets at
+    which the buffer is cut into runs (each moves on to the next
+    whitespace); left out, the buffer's length decides how many."""
     lib = _load()
-    if lib is not None:
-        n = lib.dn_token_count(text, len(text))
-        h0 = np.empty(n, np.uint32)
-        h1 = np.empty(n, np.uint32)
-        r0 = np.empty(n, np.uint32)
-        r1 = np.empty(n, np.uint32)
-        starts = np.empty(n, np.uint64)
-        lens = np.empty(n, np.uint32)
-        got = lib.dn_tokenize(
-            text, len(text), n,
-            h0.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            h1.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            r0.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            r1.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            starts.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-            lens.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+    if lib is None:
+        return _tokenize_py(text)
+    if cuts is None:
+        runs = max(1, min(_TOKENIZE_THREADS, len(text) // _TOKENIZE_RUN_BYTES))
+        cuts = [len(text) * r // runs for r in range(1, runs)]
+    handle = lib.dn_words_open(
+        text, len(text), (ctypes.c_uint64 * len(cuts))(*cuts), len(cuts) + 1
+    )
+    try:
+        n = lib.dn_words_tokens(handle)
+        cols = [np.empty(n, np.uint32) for _ in range(4)]
+        distinct = lib.dn_words_fill(
+            handle, *(c.ctypes.data_as(_U32P) for c in cols)
         )
-        assert got == n
-        return h0, h1, r0, r1, starts, lens
+        hashes, first, starts = (
+            np.empty(distinct, np.uint64) for _ in range(3)
+        )
+        lens = np.empty(distinct, np.uint32)
+        lib.dn_words_distinct(
+            handle, hashes.ctypes.data_as(_U64P), first.ctypes.data_as(_U64P),
+            starts.ctypes.data_as(_U64P), lens.ctypes.data_as(_U32P),
+        )
+    finally:
+        lib.dn_words_close(handle)
+    return Tokens(*cols, hashes, first, starts, lens, len(cuts) + 1)
 
-    # Python fallback
-    from dryad_tpu.columnar.schema import string_prefix_rank
 
-    tokens = []
-    starts_l = []
+def _tokenize_py(text: bytes) -> Tokens:
+    """:func:`tokenize` without the native library: one run."""
+    hashes, ranks, words = [], [], {}
     i = 0
     while i < len(text):
         while i < len(text) and text[i : i + 1].isspace():
@@ -216,18 +252,17 @@ def tokenize(
         s = i
         while i < len(text) and not text[i : i + 1].isspace():
             i += 1
-        tokens.append(text[s:i])
-        starts_l.append(s)
-    hashes = np.array([hash64_bytes(t) for t in tokens], np.uint64)
-    h0 = (hashes & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-    h1 = (hashes >> np.uint64(32)).astype(np.uint32)
-    sarr = np.array([t.decode("utf-8", "replace") for t in tokens], object)
-    r0 = string_prefix_rank(sarr)
-    r1 = string_prefix_rank(sarr, offset=4)
-    return (
-        h0, h1, r0, r1,
-        np.array(starts_l, np.uint64),
-        np.array([len(t) for t in tokens], np.uint32),
+        token = text[s:i]
+        h = hash64_bytes(token)
+        words.setdefault(h, (len(hashes), s, i - s))
+        hashes.append(h)
+        ranks.append(int.from_bytes(token[:8].ljust(8, b"\0"), "big"))
+    h0, h1 = split64(np.array(hashes, np.uint64))
+    r1, r0 = split64(np.array(ranks, np.uint64))
+    where = np.array(list(words.values()), np.uint64).reshape(-1, 3)
+    return Tokens(
+        h0, h1, r0, r1, np.array(list(words), np.uint64),
+        where[:, 0], where[:, 1], where[:, 2].astype(np.uint32), 1,
     )
 
 

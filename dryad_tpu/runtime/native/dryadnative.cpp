@@ -14,6 +14,7 @@
 //
 // Exposed as a C ABI for ctypes; see runtime/bindings.py.
 
+#include <algorithm>
 #include <atomic>
 #include <condition_variable>
 #include <cstdint>
@@ -21,6 +22,7 @@
 #include <cstdlib>
 #include <cstring>
 #include <deque>
+#include <functional>
 #include <mutex>
 #include <string>
 #include <thread>
@@ -49,35 +51,84 @@ static inline int is_space(uint8_t c) {
          c == '\v';
 }
 
-// Count whitespace-separated tokens in buf.
-size_t dn_token_count(const uint8_t* buf, size_t len) {
+// One pass over the text is the only pass over the words: while a
+// token is hashed its 64-bit hash is looked up in an open-addressing
+// table, and a miss records the word (hash, index of the token, byte
+// offset, length).  The caller gets the four per-token columns (hash
+// lo/hi u32 words; 8-byte prefix rank words, r0 bytes 0-4, r1 bytes
+// 4-8) and, for the DISTINCT words only, what the dictionary and the
+// vocabulary need - nothing sorts the tokens.
+struct Word {
+  uint64_t hash;
+  uint64_t first;  // index of the word's first token
+  uint64_t start;  // byte offset of that token
+  uint32_t len;
+};
+
+// hash -> index into `words`, linear probing, doubled at half full:
+// 2^14 words take 512 KiB of slots, which a core's L2 holds.
+struct WordTable {
+  struct Slot {
+    uint64_t hash;
+    uint64_t at;  // index into words + 1; 0 = empty
+  };
+  std::vector<Slot> slots;
+  std::vector<Word> words;  // in order of first occurrence
+  size_t mask;
+
+  WordTable() : slots(1 << 10, Slot{0, 0}), mask((1 << 10) - 1) {}
+
+  static inline size_t home(uint64_t h) { return (size_t)(h ^ (h >> 32)); }
+
+  void grow() {
+    std::vector<Slot> wider(slots.size() * 2, Slot{0, 0});
+    mask = wider.size() - 1;
+    for (const Slot& s : slots) {
+      if (!s.at) continue;
+      size_t i = home(s.hash) & mask;
+      while (wider[i].at) i = (i + 1) & mask;
+      wider[i] = s;
+    }
+    slots.swap(wider);
+  }
+
+  // Add `w` unless a word with its hash is there already.
+  inline void add(const Word& w) {
+    size_t i = home(w.hash) & mask;
+    while (slots[i].at) {
+      if (slots[i].hash == w.hash) return;
+      i = (i + 1) & mask;
+    }
+    words.push_back(w);
+    slots[i] = Slot{w.hash, (uint64_t)words.size()};
+    if (words.size() * 2 > slots.size()) grow();
+  }
+};
+
+static size_t count_run(const uint8_t* buf, size_t i, size_t end) {
   size_t n = 0;
-  size_t i = 0;
-  while (i < len) {
-    while (i < len && is_space(buf[i])) ++i;
-    if (i >= len) break;
+  while (i < end) {
+    while (i < end && is_space(buf[i])) ++i;
+    if (i >= end) break;
     ++n;
-    while (i < len && !is_space(buf[i])) ++i;
+    while (i < end && !is_space(buf[i])) ++i;
   }
   return n;
 }
 
-// Tokenize: fill per-token hash (lo/hi u32 words), 8-byte prefix rank
-// words (r0 bytes 0-4, r1 bytes 4-8), and byte offsets/lengths (for
-// host-side dictionary construction).
-// Returns the number of tokens written (<= max_tokens).
-size_t dn_tokenize(const uint8_t* buf, size_t len, size_t max_tokens,
-                   uint32_t* h0, uint32_t* h1, uint32_t* r0, uint32_t* r1,
-                   uint64_t* starts, uint32_t* lens) {
+// Tokenize buf[i, end) into the columns from slot 0 on; `first` of a
+// word counts from the run's own first token.
+static void tokenize_run(const uint8_t* buf, size_t i, size_t end,
+                         uint32_t* h0, uint32_t* h1, uint32_t* r0,
+                         uint32_t* r1, WordTable* table) {
   size_t n = 0;
-  size_t i = 0;
-  while (i < len && n < max_tokens) {
-    while (i < len && is_space(buf[i])) ++i;
-    if (i >= len) break;
+  while (i < end) {
+    while (i < end && is_space(buf[i])) ++i;
+    if (i >= end) break;
     size_t s = i;
     uint64_t h = FNV_OFFSET;
     uint32_t rank0 = 0, rank1 = 0;
-    while (i < len && !is_space(buf[i])) {
+    while (i < end && !is_space(buf[i])) {
       uint8_t c = buf[i];
       h ^= (uint64_t)c;
       h *= FNV_PRIME;
@@ -92,12 +143,96 @@ size_t dn_tokenize(const uint8_t* buf, size_t len, size_t max_tokens,
     h1[n] = (uint32_t)(h >> 32);
     r0[n] = rank0;
     r1[n] = rank1;
-    starts[n] = (uint64_t)s;
-    lens[n] = (uint32_t)(i - s);
+    table->add(Word{h, (uint64_t)n, (uint64_t)s, (uint32_t)(i - s)});
     ++n;
   }
-  return n;
 }
+
+// One text buffer cut into runs that are counted and tokenized side by
+// side, a thread and a table a run (run 0 on the caller's thread, so
+// one run starts no thread).
+struct Tokenizer {
+  const uint8_t* buf;
+  std::vector<size_t> cuts;     // runs + 1 byte offsets, 0 .. len
+  std::vector<size_t> offsets;  // runs + 1: a run's first token index
+  std::vector<Word> words;      // the buffer's distinct words
+
+  void each_run(const std::function<void(size_t)>& work) {
+    std::vector<std::thread> threads;
+    for (size_t r = 1; r + 1 < cuts.size(); ++r)
+      threads.emplace_back(work, r);
+    work(0);
+    for (auto& t : threads) t.join();
+  }
+};
+
+// Open a buffer with `runs - 1` proposed cuts (ascending byte offsets).
+// A cut moves forward to the next whitespace byte (or the end), so no
+// token straddles two runs; the runs' tokens are counted here.
+void* dn_words_open(const uint8_t* buf, size_t len, const uint64_t* cuts,
+                    size_t runs) {
+  auto* t = new Tokenizer{buf, {0}, {}, {}};
+  for (size_t r = 0; r + 1 < runs; ++r) {
+    size_t at = std::max((size_t)std::min<uint64_t>(cuts[r], len),
+                         t->cuts.back());
+    while (at < len && !is_space(buf[at])) ++at;
+    t->cuts.push_back(at);
+  }
+  t->cuts.push_back(len);
+  t->offsets.assign(t->cuts.size(), 0);
+  t->each_run([t](size_t r) {
+    t->offsets[r + 1] = count_run(t->buf, t->cuts[r], t->cuts[r + 1]);
+  });
+  for (size_t r = 1; r < t->offsets.size(); ++r)
+    t->offsets[r] += t->offsets[r - 1];
+  return t;
+}
+
+size_t dn_words_tokens(void* handle) {
+  return static_cast<Tokenizer*>(handle)->offsets.back();
+}
+
+// Fill the four columns (dn_words_tokens entries each), every run into
+// its own stretch, so the first touch of their pages is shared out
+// too; then merge the runs' tables in run order, which keeps `first`
+// the lowest index.  Returns the number of distinct words.
+size_t dn_words_fill(void* handle, uint32_t* h0, uint32_t* h1, uint32_t* r0,
+                     uint32_t* r1) {
+  auto* t = static_cast<Tokenizer*>(handle);
+  std::vector<WordTable> tables(t->cuts.size() - 1);
+  t->each_run([&](size_t r) {
+    size_t at = t->offsets[r];
+    tokenize_run(t->buf, t->cuts[r], t->cuts[r + 1], h0 + at, h1 + at,
+                 r0 + at, r1 + at, &tables[r]);
+  });
+  if (tables.size() == 1) {
+    t->words.swap(tables[0].words);
+    return t->words.size();
+  }
+  WordTable merged;
+  for (size_t r = 0; r < tables.size(); ++r)
+    for (Word w : tables[r].words) {
+      w.first += t->offsets[r];
+      merged.add(w);
+    }
+  t->words.swap(merged.words);
+  return t->words.size();
+}
+
+// The distinct words in order of first occurrence (dn_words_fill's
+// count of entries each).
+void dn_words_distinct(void* handle, uint64_t* hash, uint64_t* first,
+                       uint64_t* start, uint32_t* len) {
+  auto* t = static_cast<Tokenizer*>(handle);
+  for (size_t i = 0; i < t->words.size(); ++i) {
+    hash[i] = t->words[i].hash;
+    first[i] = t->words[i].first;
+    start[i] = t->words[i].start;
+    len[i] = t->words[i].len;
+  }
+}
+
+void dn_words_close(void* handle) { delete static_cast<Tokenizer*>(handle); }
 
 // ------------------------------------------------------ zlib transforms
 // Channel compression transform (reference TransformType gzip/deflate,

@@ -18,18 +18,25 @@ def test_hash64_native_matches_python():
 
 def test_tokenizer_native_matches_python():
     text = "  the quick\t brown\nfox  jumps over\r\nthe lazy dog "
-    h0, h1, r0, r1, starts, lens = B.tokenize(text.encode())
-    words = [
-        text.encode()[int(s) : int(s) + int(l)].decode()
-        for s, l in zip(starts, lens)
-    ]
-    assert words == text.split()
-    hashes = (h1.astype(np.uint64) << np.uint64(32)) | h0.astype(np.uint64)
-    assert all(hash64_str(w) == int(h) for w, h in zip(words, hashes))
-    assert np.array_equal(r0, string_prefix_rank(np.array(words, object)))
+    toks = B.tokenize(text.encode())
+    words = text.split()
+    hashes = (toks.h1.astype(np.uint64) << np.uint64(32)) | toks.h0.astype(np.uint64)
+    assert [hash64_str(w) for w in words] == hashes.tolist()
+    assert np.array_equal(toks.r0, string_prefix_rank(np.array(words, object)))
     assert np.array_equal(
-        r1, string_prefix_rank(np.array(words, object), offset=4)
+        toks.r1, string_prefix_rank(np.array(words, object), offset=4)
     )
+    # the distinct words, each where it first occurs ("the" once)
+    distinct = [
+        text.encode()[int(s) : int(s) + int(n)].decode()
+        for s, n in zip(toks.starts, toks.lens)
+    ]
+    assert distinct == words[:6] + words[7:]
+    assert [words[int(i)] for i in toks.first] == distinct
+    assert toks.hashes.tolist() == [hash64_str(w) for w in distinct]
+    assert toks.runs == 1
+    for a, b in zip(toks, B._tokenize_py(text.encode())):
+        assert np.array_equal(a, b)
 
 
 def test_prefetch_channel_order(tmp_path):

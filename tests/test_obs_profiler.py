@@ -206,6 +206,21 @@ def test_bind_time_spans_have_no_parent_and_no_query(recorded):
     assert stats["rows"] == 5000
 
 
+def test_the_tokenizer_says_what_it_found_and_vocab_is_bind_time_alone(recorded):
+    (_, _, _, stats), = _by_name(recorded, "dryad:ingest:tokenize")
+    # 37 words found in the tokenizer's table; a corpus of 23 KB is one
+    # run on the caller's thread
+    assert (stats["rows"], stats["distinct"], stats["runs"]) == (5000, 37, 1)
+    assert stats["bytes_out"] == 16 * 5000
+    # ``vocab`` sorts those 37: once a from_text, never in a collect()
+    (_, _, _, vocab), = _by_name(recorded, "dryad:ingest:vocab")
+    assert vocab["bytes_out"] == 8 * 37
+    for index in range(len(_by_name(recorded, "dryad:other:collect"))):
+        _, inside = _job(recorded, index)
+        assert not {a[0] for a in inside} & {
+            "dryad:ingest:tokenize", "dryad:ingest:vocab"}
+
+
 def test_every_annotation_has_its_span_event(recorded):
     events = {e["span_id"]: e for e in recorded["spans"]}
     assert len(recorded["annotations"]) >= 40
