@@ -12,6 +12,12 @@ against plain NumPy:
   B  GroupBy + aggregate, sort path    hash exchange           (shape 2)
   C  WordCount, dense MXU path         Pallas bucket kernel    (shape 1)
   D  Join + OrderBy                    broadcast join + top-k  (shape 5)
+  E  GroupBy, a user's combiner        ``Decomposable``, Zipf  (shape 2)
+
+Step E is the ``groupby-skew-4c`` cell's query and reference, loaded
+from ``benchmarks/jobs/groupby_skew.py``, at a quarter of the other
+steps' rows (2^24 a chip, the cell's): its scan carries six channels
+over every slot of the exchange's padded capacity.
 
 Each step runs its query twice on one context and prints ``rows``,
 ``first_s`` (ingest + compile + run) and ``repeat_s`` (resident table,
@@ -255,11 +261,60 @@ def step_join(ctx, rows: int, seed: int) -> None:
     _run_twice(ctx, "D_join", rows, q.collect, check, dim_rows=DIM_ROWS)
 
 
+# -- step E: GroupBy through a combiner the user writes, Zipf keys ------------
+
+def _skew_job():
+    """``benchmarks/jobs/groupby_skew.py``, by path: the cell's query,
+    table and reference are not copied here."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmarks", "jobs", "groupby_skew.py")
+    spec = importlib.util.spec_from_file_location("smoke_groupby_skew", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def step_decomposable(ctx, rows: int, seed: int) -> None:
+    job = _skew_job()
+    P = ctx.executor.P
+    params = {"rows": rows, "groups": max(1 << 6, rows >> 4), "zipf_theta": 0.99,
+              "partitions": P}
+    table = job.make_table(np.random.default_rng(seed + 4), params, None, 0)
+    q = job.bind(ctx, table, params)
+    kinds = _plan_kinds(ctx, q)
+    need(kinds.count("group_combine") == 2 and "group_reduce" not in kinds, kinds)
+    mark = [len(ctx.events.events())]
+
+    def check(out):
+        for name, (value, limit) in job.compare(table, out, params).items():
+            need(value <= limit, f"{name}: {value} over {limit}")
+        # what the job's exchange read back with its overflow flag
+        events = ctx.events.events()[mark[0]:]
+        mark[0] += len(events)
+        need(not [e for e in events if e["kind"] == "stage_overflow"],
+             "an exchange of combined rows overflowed at the default slack")
+        seen = [e for e in events if e["kind"] == "exchange_observed"]
+        need(len(seen) == int(P > 1), seen)
+        for e in seen:
+            need(e["combine_rows_in"] == rows and sum(e["recv_rows"]) ==
+                 e["combine_rows_out"] < rows, e)
+            say("E_exchange", combine_rows_in=e["combine_rows_in"],
+                combine_rows_out=e["combine_rows_out"],
+                recv_rows="/".join(map(str, e["recv_rows"])), boost=e["boost"])
+
+    _run_twice(ctx, "E_decomposable", rows, q.collect, check,
+               groups=int(np.count_nonzero(table["want"]["count"])),
+               hottest=int(table["want"]["count"].max()))
+
+
 STEPS = (
     ("A", step_sort),
     ("B", step_groupby),
     ("C", step_wordcount),
     ("D", step_join),
+    ("E", step_decomposable),
 )
 
 
@@ -308,8 +363,8 @@ def main(argv=None) -> int:
         help="rows per chip in every step, as a power of two "
              f"(default {LOG2_ROWS_PER_CHIP}; smaller is for debugging)",
     )
-    ap.add_argument("--steps", default="ABCD",
-                    help="which steps to run (default ABCD)")
+    ap.add_argument("--steps", default="ABCDE",
+                    help="which steps to run (default ABCDE)")
     args = ap.parse_args(argv)
     t_start = time.perf_counter()
 
@@ -360,6 +415,8 @@ def main(argv=None) -> int:
         if name not in args.steps:
             continue
         rows = (1 << n) * chips
+        if name == "E":
+            rows >>= 2  # the cell's rows a chip at the default size
         if name == "A":
             # per-device memory right after the ingest: job_start is
             # emitted once the inputs are bound and before any stage runs

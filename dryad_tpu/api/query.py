@@ -212,7 +212,15 @@ class Query:
         on the key alone) — the skew escape hatch for heavy-hitter keys,
         the analog of the reference's data-size-driven hash
         redistribution (``DrDynamicDistributor.h:26,79``).  Costs a
-        second shuffle; use when one key dominates.
+        second shuffle.  Builtin aggregates only.  A ``group_by``
+        combines on the chip BEFORE it exchanges (``plan/lower.py``), so
+        however hot a key is it crosses the mesh as one row a chip, and
+        what a destination receives is its share of the distinct keys:
+        a combine-first group-by that fits its chips cannot overflow a
+        bucket by skew, and ``salt`` buys it nothing.  It can matter
+        only where the combined rows bound for one destination would
+        not fit there (more distinct keys a destination than
+        ``shuffle_slack`` allows for).
 
         ``dense=K`` declares the single INT32 key lies in [0, K): the
         engine then skips the sort+shuffle pipeline and reduces on the

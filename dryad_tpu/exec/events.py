@@ -55,6 +55,9 @@ EVENT_KINDS: Dict[str, str] = {
     "stage_delay_injected": "fault-injection delay before the attempt",
     "exchange_round": "one planned exchange round; round/window/bytes/"
                       "ici_bytes/dcn_bytes (window 0 = flat all_to_all)",
+    "exchange_observed": "what a dispatch's exchanges saw, off the overflow "
+                         "flag's readback; combine_rows_in/combine_rows_out/"
+                         "recv_rows (a chip)/boost/overflows (of the job)",
     "dict_miss": "rows outside the dense key domain; stage_name/rows",
     # -- checkpointing (exec.checkpoint / executor) -----------------------
     "stage_checkpoint_hit": "stage served from the checkpoint store",
@@ -219,6 +222,11 @@ EVENT_PAYLOADS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
     "exchange_round": (
         ("bytes", "dcn_bytes", "ici_bytes", "round", "window"),
         ("name", "qid", "stage"),
+    ),
+    "exchange_observed": (
+        ("boost", "combine_rows_in", "combine_rows_out", "exchanges",
+         "name", "overflows", "recv_rows", "stage"),
+        ("qid",),
     ),
     "dict_miss": (("rows", "stage_name"), ()),
     "stage_checkpoint_hit": (("name", "stage"), ()),

@@ -28,6 +28,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import numpy as np
 
 from dryad_tpu.columnar.batch import ColumnBatch
 from dryad_tpu.exec import faults
@@ -235,8 +236,6 @@ class _CompileTimed:
 
 def _phys_np_dtype(col: str, schema):
     """numpy dtype of one physical device column."""
-    import numpy as np
-
     from dryad_tpu.columnar.schema import ColumnType
 
     if "#" in col:
@@ -808,6 +807,49 @@ class GraphExecutor:
             if capacities is not None and idx < len(capacities):
                 self.metrics.add("layout_rows", int(capacities[idx]))
 
+    def _exchange_observed(self, drain, dispatches, overflowed) -> None:
+        """What the exchanges of the drained dispatches saw
+        (``kernels._observe_exchange``), already on the host: it rode
+        the readback of the overflow flag.  ``dispatches``: ``(stage,
+        boost, seen)`` each, ``seen`` one ``(3, P)`` array an exchange
+        (rows the combiner before it was handed, rows sent, rows
+        received; a column a chip).  One ``exchange_observed`` event a
+        dispatch that ran an exchange, summed over its exchanges, and
+        the sums over all of them onto the ``drain`` span, with the
+        highest boost among them and the ``stage_overflow`` events of
+        the job so far, this drain's own counted."""
+        self._job_overflows += int(overflowed)
+
+        def fields(rows):  # of a (3, P) array of counts, a column a chip
+            return dict(
+                combine_rows_in=int(rows[0].sum()),
+                combine_rows_out=int(rows[1].sum()),
+                recv_rows=[int(r) for r in rows[2]],
+            )
+
+        ran = [
+            (stage, boost, len(seen),
+             sum(np.asarray(a, dtype=np.int64) for a in seen))
+            for stage, boost, seen in dispatches if seen
+        ]
+        for stage, boost, exchanges, rows in ran:
+            self.events.emit(
+                "exchange_observed", stage=stage.id, name=stage.name,
+                exchanges=exchanges, boost=boost,
+                overflows=self._job_overflows,
+                qid=tracectx.current_qid(), **fields(rows),
+            )
+        if ran:
+            total = fields(sum(rows for _, _, _, rows in ran))
+            # the list reaches the span's event; a profiler annotation
+            # keeps numbers only, so its largest entry goes beside it
+            drain.add(
+                exchanges=sum(n for _, _, n, _ in ran),
+                boost=max(boost for _, boost, _, _ in ran),
+                overflows=self._job_overflows,
+                recv_rows_max=max(total["recv_rows"]), **total,
+            )
+
     def _adapt_fan_for(self, stage: Stage) -> Optional[int]:
         """Reduced width for this stage from its inputs' OBSERVED rows;
         None = run as lowered (full width or static reduction)."""
@@ -864,6 +906,7 @@ class GraphExecutor:
 
     def _execute_stages(self, graph, bindings, results, binding_fps, stage_fps):
         depth = max(1, self.config.overflow_sync_depth)
+        self._job_overflows = 0  # drains of this job that saw the flag set
         # Speculative dispatch window (DrMessagePump.h:116-180 pump
         # concurrency): overflow-capable stages dispatch without their
         # per-stage host sync; flags drain in one batched readback when
@@ -930,9 +973,16 @@ class GraphExecutor:
         counted = [w for w in window if w.get("counts")]
         with self.tracer.span(
             "drain", cat="readback", inflight=len(window)
-        ):
-            combined_v, counts_v = jax.device_get(
-                (combined, [w["counts"] for w in counted])
+        ) as drain:
+            combined_v, counts_v, seen_v = jax.device_get(
+                (combined, [w["counts"] for w in counted],
+                 [w["seen"] for w in window])
+            )
+            self._exchange_observed(
+                drain,
+                [(w["stage"], w["boost"], sv)
+                 for w, sv in zip(window, seen_v)],
+                bool(combined_v),
             )
         count_of = {id(w): cv for w, cv in zip(counted, counts_v)}
         if not bool(combined_v):
@@ -1213,7 +1263,7 @@ class GraphExecutor:
                     # OPERAND params ride the replicated slot: current
                     # table content from the pool (uploaded/scattered
                     # once per content, reused across dispatches)
-                    outs, (overflow, dict_miss) = fn(
+                    outs, (overflow, dict_miss, seen) = fn(
                         inputs, self._stage_rep(stage)
                     )
                     # Static per-round exchange accounting (filled at
@@ -1251,7 +1301,7 @@ class GraphExecutor:
                             stage=stage, version=version, boost=boost,
                             fp=fp, flag=overflow if can_overflow else None,
                             miss=dict_miss, outs=outs, t0=t0,
-                            counts=counts_dev,
+                            counts=counts_dev, seen=seen,
                             fan=adapt_fan if boost < 4 else None,
                         ))
                         self.events.emit(
@@ -1265,24 +1315,24 @@ class GraphExecutor:
                     # and JAX async dispatch overlaps this stage's
                     # device time with independent stages (the GM
                     # message-pump concurrency, DrMessagePump.h:116).
-                    if can_overflow and counts_dev is not None:
-                        # ONE readback for flag + observed counts
+                    if can_overflow:
+                        # ONE readback for the flag, the observed
+                        # counts and what the exchanges saw
                         with self.tracer.span(
                             "drain", cat="readback", inflight=1
-                        ):
-                            overflow, host_counts = jax.device_get(
-                                (overflow, counts_dev)
+                        ) as drain:
+                            overflow, host_counts, seen = jax.device_get(
+                                (overflow, counts_dev, seen)
                             )
-                        overflow = bool(overflow)
-                        self._record_observed(
-                            stage, host_counts,
-                            [o.capacity for o in outs],
-                        )
-                    elif can_overflow:
-                        with self.tracer.span(
-                            "drain", cat="readback", inflight=1
-                        ):
                             overflow = bool(overflow)
+                            self._exchange_observed(
+                                drain, [(stage, boost, seen)], overflow
+                            )
+                        if host_counts is not None:
+                            self._record_observed(
+                                stage, host_counts,
+                                [o.capacity for o in outs],
+                            )
                     else:
                         overflow = False
             except faults.InjectedFault as e:
@@ -1502,7 +1552,6 @@ class GraphExecutor:
         perf cliff, SURVEY 7.3)."""
         import math
 
-        import numpy as np
         from dryad_tpu.parallel.mesh import partition_sharding
 
         p = stage.ops[0].params
@@ -1614,13 +1663,13 @@ class GraphExecutor:
 
                 def cond(state):
                     i, b, ovf, _miss = state
-                    couts, (covf, _cm) = cond_fn((b,), ())
+                    couts, (covf, _cm, _) = cond_fn((b,), ())
                     go = couts[0].data[cond_col][0].astype(jnp.bool_)
                     return (i < max_iter) & go & ~(ovf | covf)
 
                 def body(state):
                     i, b, ovf, miss = state
-                    bouts, (bovf, bmiss) = body_fn((b,), ())
+                    bouts, (bovf, bmiss, _) = body_fn((b,), ())
                     return (i + jnp.int32(1), bouts[0], ovf | bovf, miss + bmiss)
 
                 # DoWhile runs the body BEFORE checking cond (reference
@@ -1628,7 +1677,7 @@ class GraphExecutor:
                 # below mirrors it) — so seed the loop state with one body
                 # application rather than letting lax.while_loop evaluate
                 # cond on the un-iterated input.
-                bouts0, (bovf0, bmiss0) = body_fn((b0,), ())
+                bouts0, (bovf0, bmiss0, _) = body_fn((b0,), ())
                 it, bout, ovf, miss = jax.lax.while_loop(
                     cond, body, (jnp.int32(1), bouts0[0], bovf0, bmiss0)
                 )
@@ -1637,7 +1686,7 @@ class GraphExecutor:
                 # it by re-evaluating cond on the final state so the host
                 # retries with a larger boost instead of accepting a
                 # result whose termination decision overflowed.
-                _, (covf, _cm) = cond_fn((bout,), ())
+                _, (covf, _cm, _) = cond_fn((bout,), ())
                 return (bout,), (ovf | covf, it, miss)
 
             # split_operands=False: these fns were built WITHOUT

@@ -94,6 +94,15 @@ class StageContext:
         # decided at trace time; the executor emits each as a
         # ``join_plan`` event, once a compile.
         self.join_log: List[Dict[str, Any]] = []
+        # What each exchange of the trace saw (``_observe_exchange``):
+        # one replicated ``(3, P)`` int32 array an exchange, a third
+        # output of the stage fn beside the overflow flag.
+        self.xchg_seen: List[jax.Array] = []
+        # ``(slot, valid)`` of the batch a combiner (``COMBINERS``) was
+        # handed, while that combiner is the op just run (``apply_op``):
+        # the exchange that follows counts its rows as
+        # ``combine_rows_in``.
+        self.combined: Any = None
         self.slots: Dict[int, ColumnBatch] = {}
         self.entry_caps: Dict[int, int] = {}
         # id(param object) -> tuple of traced operand arrays (bound
@@ -120,13 +129,23 @@ class StageContext:
         return self.entry_caps.get(slot, max(self.entry_caps.values() or [64]))
 
 
+# The kernels that fold a partition's rows by key: when one is the op
+# just before a hash exchange it is that exchange's combiner.
+COMBINERS = frozenset({"group_reduce", "group_combine", "distinct"})
+
+
 def apply_op(ctx: StageContext, kind: str, p: Dict[str, Any]) -> None:
     fn = _KERNELS.get(kind)
     if fn is None:
         raise NotImplementedError(f"no kernel for stage op {kind!r}")
+    handed = (
+        (p["slot"], ctx.slots[p["slot"]].valid) if kind in COMBINERS
+        else None
+    )
     # names the operator in every device operation's ``tf_op`` path
     with jax.named_scope(f"dryad.{kind}"):
         fn(ctx, p)
+    ctx.combined = handed  # what the NEXT op finds: set by a combiner alone
 
 
 # -- row-wise --------------------------------------------------------------
@@ -244,6 +263,29 @@ def _exchange(
     return SH.exchange_staged(b, dest, P, B, axes, schedule)
 
 
+def _observe_exchange(ctx: StageContext, slot: int, sent: ColumnBatch) -> None:
+    """What an exchange knows and the host does not: the rows this
+    chip's combiner was handed (those it sent, where no combiner came
+    just before), the rows it sent, the rows it received.  Each chip
+    puts its three counts in its own column of a ``(3, P)`` array and
+    one ``psum`` replicates it; it leaves the program beside the
+    overflow flag and rides that flag's readback
+    (``GraphExecutor._exchange_observed``).  Kept a chip so that no
+    sum is formed in int32 on the device."""
+    rows_out = sent.count()
+    fed = ctx.combined
+    rows_in = (
+        jnp.sum(fed[1].astype(jnp.int32))
+        if fed is not None and fed[0] == slot else rows_out
+    )
+    mine = jnp.stack([rows_in, rows_out, ctx.slots[slot].count()])
+    me = jax.lax.axis_index(ctx.axes)
+    at = (jnp.arange(ctx.P, dtype=jnp.int32) == me).astype(jnp.int32)
+    ctx.xchg_seen.append(
+        jax.lax.psum(mine.astype(jnp.int32)[:, None] * at[None, :], ctx.axes)
+    )
+
+
 def _do_exchange_hash(
     ctx: StageContext, slot: int, keys, tree=None, nparts=None
 ) -> None:
@@ -252,13 +294,14 @@ def _do_exchange_hash(
     b = ctx.slots[slot]
     if tree is not None and len(ctx.axes) == 2:
         _tree_exchange_hash(ctx, slot, keys, tree)
-        return
-    P_eff = _fanout(ctx, nparts)
-    dest = partition_ids([b.data[k] for k in keys], P_eff)
-    B = SH.bucket_capacity(b.capacity, P_eff, ctx.slack * ctx.boost)
-    out, ovf = _exchange(ctx, b, dest, ctx.P, B, ctx.axes)
-    ctx.slots[slot] = out
-    ctx.overflow = ctx.overflow | ovf
+    else:
+        P_eff = _fanout(ctx, nparts)
+        dest = partition_ids([b.data[k] for k in keys], P_eff)
+        B = SH.bucket_capacity(b.capacity, P_eff, ctx.slack * ctx.boost)
+        out, ovf = _exchange(ctx, b, dest, ctx.P, B, ctx.axes)
+        ctx.slots[slot] = out
+        ctx.overflow = ctx.overflow | ovf
+    _observe_exchange(ctx, slot, b)
 
 
 def _tree_exchange_hash(ctx: StageContext, slot: int, keys, tree) -> None:
@@ -393,6 +436,7 @@ def _k_exchange_range(ctx: StageContext, p) -> None:
     out, ovf = _exchange(ctx, b, dest, ctx.P, B, ctx.axes)
     ctx.slots[p["slot"]] = out
     ctx.overflow = ctx.overflow | ovf
+    _observe_exchange(ctx, p["slot"], b)
 
 
 def _k_resize(ctx: StageContext, p) -> None:
@@ -1173,8 +1217,9 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
     statically by ``tests/test_fuse_lint.py``.
 
     Overflow/miss contract: the region's overflow flag is the OR over
-    every member's (already mesh-reduced) flag and the dict-miss count
-    is the sum — one seam overflowing retries the WHOLE region at the
+    every member's (already mesh-reduced) flag, the dict-miss count
+    is the sum and what the exchanges saw (``_observe_exchange``) is
+    every member's, in member order — one seam overflowing retries the WHOLE region at the
     next palette boost, the same bounded-palette contract as the
     single-stage path.
 
@@ -1223,6 +1268,7 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
         member_outs: List[Tuple] = []
         overflow = None
         miss = None
+        seen: Tuple = ()
         for i, mfn in enumerate(member_fns):
             ins = tuple(
                 ext[src[1]] if src[0] == "ext"
@@ -1232,10 +1278,11 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
             mrep = tuple(
                 a for obj in member_objs[i] for a in rep_map[id(obj)]
             )
-            outs, (m_ovf, m_miss) = mfn(ins, mrep)
+            outs, (m_ovf, m_miss, m_seen) = mfn(ins, mrep)
             member_outs.append(outs)
             overflow = m_ovf if overflow is None else (overflow | m_ovf)
             miss = m_miss if miss is None else (miss + m_miss)
+            seen += m_seen
         region_outs = tuple(
             member_outs[mi][oi] for mi, oi in fused.exports
         )
@@ -1247,7 +1294,7 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
             sort_cell[:] = [max((w for c in member_sorts for w in c), default=0)]
         if elided_cell is not None:
             elided_cell[:] = [sum(n for c in member_elided for n in c)]
-        return region_outs, (overflow, miss)
+        return region_outs, (overflow, miss, seen)
 
     return fn
 
@@ -1267,7 +1314,13 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
     ``stage_operand_objs`` order) whose arrays arrive flattened through
     the replicated input slot at call time instead of being baked as
     trace constants; empty = the legacy baked path (every caller that
-    passes operands must feed the matching arrays on every call)."""
+    passes operands must feed the matching arrays on every call).
+
+    The fn returns ``(outs, (overflow, dict_miss, seen))``: the output
+    batches, sharded, and three replicated values — the overflow flag
+    and the dictionary misses, reduced over the mesh, and one ``(3, P)``
+    array an exchange the trace kept (``_observe_exchange``; ``()`` for
+    a stage with none, or on one partition)."""
 
     def fn(sharded_inputs, replicated):
         ctx = StageContext(P, slack, boost, axes, axis_sizes, window)
@@ -1308,6 +1361,6 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
         if elided_cell is not None:
             # exchanges this trace skipped on a mesh of one partition
             elided_cell[:] = [ctx.xchg_elided]
-        return outs, (overflow, miss)
+        return outs, (overflow, miss, tuple(ctx.xchg_seen))
 
     return fn

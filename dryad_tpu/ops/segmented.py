@@ -48,25 +48,27 @@ class AggSpec:
     out: str
 
 
-@jax.named_scope("dryad.group_reduce.layout")
 def _segment_layout(
-    batch: ColumnBatch, key_cols: Sequence[str]
+    batch: ColumnBatch, key_cols: Sequence[str],
+    scope: str = "dryad.group_reduce.layout",
 ) -> Tuple[ColumnBatch, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Sort+compact by keys; return (sorted batch, valid, start, seg, nseg).
 
     ``seg`` maps each row to its segment id, with invalid rows mapped to
-    the sentinel segment ``capacity`` (dropped on slice).
+    the sentinel segment ``capacity`` (dropped on slice).  ``scope`` is
+    the caller's name for the pass in a device trace.
     """
     cap = batch.capacity
-    sb = sort_batch_by_operands(
-        batch, [to_sortable_u32(batch.data[k]) for k in key_cols]
-    )
-    v = sb.valid
-    eq = keys_equal_adjacent([sb.data[k] for k in key_cols])
-    start = v & ~eq
-    seg_id = jnp.cumsum(start.astype(jnp.int32)) - 1
-    seg = jnp.where(v, seg_id, cap)
-    nseg = jnp.sum(start.astype(jnp.int32))
+    with jax.named_scope(scope):
+        sb = sort_batch_by_operands(
+            batch, [to_sortable_u32(batch.data[k]) for k in key_cols]
+        )
+        v = sb.valid
+        eq = keys_equal_adjacent([sb.data[k] for k in key_cols])
+        start = v & ~eq
+        seg_id = jnp.cumsum(start.astype(jnp.int32)) - 1
+        seg = jnp.where(v, seg_id, cap)
+        nseg = jnp.sum(start.astype(jnp.int32))
     return sb, v, start, seg, nseg
 
 
@@ -500,6 +502,47 @@ def group_reduce_fused(
 MergeFn = Callable[[Dict[str, jax.Array], Dict[str, jax.Array]], Dict[str, jax.Array]]
 
 
+def segmented_scan(
+    start: jax.Array, vals: Dict[str, jax.Array], merge: MergeFn
+) -> Dict[str, jax.Array]:
+    """Inclusive segmented scan of ``vals`` under ``merge`` by doubling
+    (Hillis-Steele): after the pass at distance ``d`` every slot holds
+    its segment's reduction over the last ``2 d`` slots, so ``ceil(log2
+    n)`` passes, each ONE elementwise fusion over the whole array that
+    reads the state at ``i - d`` through a static slice.  ``merge(a, b)``
+    is always handed the earlier rows as ``a``.
+
+    Not ``lax.associative_scan``: its tree of odd/even slices is work-
+    efficient, but every level is a handful of fusions of a shape of its
+    own, and for the TPU the flagged six-channel scan of the
+    ``groupby-skew-4c`` cell came to 230 MB of generated code at 2^20
+    slots and no program at all at 2^23 (the compile was cut after
+    1,500 s on the chip's host; PERF.md section 6, PR 41).  These passes
+    compile in seconds at any size and move ``3 log2 n`` times the
+    state through HBM, which at 2^24 slots is a tenth of what the
+    scatters beside them cost."""
+    n = start.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+
+    def over(mask, x):  # ``mask`` against a column of any width
+        return mask.reshape((n,) + (1,) * (x.ndim - 1))
+
+    flag, d = start, 1
+    while d < n:
+        def back(x, d=d):  # x[i - d]; the first d slots are masked below
+            return jnp.concatenate([x[:d], x[:-d]])
+
+        reach = pos >= d
+        merged = merge({k: back(x) for k, x in vals.items()}, vals)
+        take = reach & ~flag  # no segment starts inside the slot's own window
+        vals = {
+            k: jnp.where(over(take, x), merged[k], x) for k, x in vals.items()
+        }
+        flag = flag | (reach & back(flag))
+        d *= 2
+    return vals
+
+
 def group_combine(
     batch: ColumnBatch,
     key_cols: Sequence[str],
@@ -511,41 +554,39 @@ def group_combine(
     ``state_cols`` name accumulator columns already produced by the
     user's Seed/Accumulate step; ``merge`` is RecursiveAccumulate
     (reference ``IDecomposable.cs:35-71``), applied pairwise and
-    vectorized over rows.  Implemented as a flagged segmented
-    ``associative_scan``: each segment's scan result at its last row is
-    the segment reduction.
+    vectorized over rows.  Three passes, each under a scope of its own
+    in a device trace: the rows sorted by key with the state carried
+    (``dryad.group_combine.layout``), a flagged segmented scan
+    (``.scan``, :func:`segmented_scan`) whose result at a segment's
+    last row is the segment's reduction, and one scatter-set a column
+    that puts it at the segment's slot (``.emit``).
     """
     cap = batch.capacity
-    sb, v, start, seg, nseg = _segment_layout(batch, key_cols)
+    sb, v, start, seg, nseg = _segment_layout(
+        batch, key_cols, scope="dryad.group_combine.layout"
+    )
 
-    flags = start
-    vals = {c: sb.data[c] for c in state_cols}
+    with jax.named_scope("dryad.group_combine.scan"):
+        scanned = segmented_scan(
+            start, {c: sb.data[c] for c in state_cols}, merge
+        )
 
-    def combine(a, b):
-        fa, va = a
-        fb, vb = b
-        merged = merge(va, vb)
-        out = {
-            k: jnp.where(fb, vb[k], merged[k]) for k in vals.keys()
-        }
-        return (fa | fb, out)
+    with jax.named_scope("dryad.group_combine.emit"):
+        # Last row of each segment: next row starts a new segment / is
+        # invalid / EOF.
+        nxt_start = jnp.concatenate([start[1:], jnp.array([True])])
+        nxt_valid = jnp.concatenate([v[1:], jnp.array([False])])
+        last = v & (nxt_start | ~nxt_valid)
 
-    _, scanned = jax.lax.associative_scan(combine, (flags, vals))
+        out: Dict[str, jax.Array] = {}
+        for k in key_cols:
+            out[k] = _first_scatter(sb.data[k], start, seg, cap)
+        idx = jnp.where(last, seg, cap)
+        for c in state_cols:
+            val = scanned[c]
+            out[c] = jnp.zeros((cap + 1,) + val.shape[1:], val.dtype).at[idx].set(val)[:cap]
 
-    # Last row of each segment: next row starts a new segment / is invalid / EOF.
-    nxt_start = jnp.concatenate([start[1:], jnp.array([True])])
-    nxt_valid = jnp.concatenate([v[1:], jnp.array([False])])
-    last = v & (nxt_start | ~nxt_valid)
-
-    out: Dict[str, jax.Array] = {}
-    for k in key_cols:
-        out[k] = _first_scatter(sb.data[k], start, seg, cap)
-    idx = jnp.where(last, seg, cap)
-    for c in state_cols:
-        val = scanned[c]
-        out[c] = jnp.zeros((cap + 1,) + val.shape[1:], val.dtype).at[idx].set(val)[:cap]
-
-    valid = jnp.arange(cap, dtype=jnp.int32) < nseg
+        valid = jnp.arange(cap, dtype=jnp.int32) < nseg
     return ColumnBatch(out, valid)
 
 

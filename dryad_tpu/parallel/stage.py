@@ -33,8 +33,9 @@ from dryad_tpu.parallel.mesh import mesh_axes
 # cached program carries: count it up here when a scope is added or
 # renamed anywhere, or a stale cache hands back a program without them
 # (benchmarks/TRACING.md).  ``dryad_stage``: PR 24's scopes; ``_2``:
-# ``dryad.join.{probe,materialize,exact}``.
-PROGRAM_NAME = "dryad_stage_2"
+# ``dryad.join.{probe,materialize,exact}``; ``_3``:
+# ``dryad.group_combine.{layout,scan,emit}``.
+PROGRAM_NAME = "dryad_stage_3"
 
 
 def compile_stage(mesh: Mesh, fn: Callable[[Any, Any], Tuple[Any, Any]]):

@@ -381,3 +381,51 @@ def test_do_while_growing_state_boosts_compaction(mesh8):
     assert len(out["x"]) == 128
     kinds = [e["kind"] for e in ctx.executor.events.events()]
     assert "do_while_state_boost" in kinds
+
+
+def test_the_exchanges_counts_ride_the_one_readback(mesh8, monkeypatch):
+    """What an exchange saw (rows into the combiner before it, rows it
+    sent, rows every chip received) comes back in the ``device_get``
+    that fetches the overflow flag: as many host transfers of the
+    executor as the stage has drains, with the plan fused into one
+    dispatch or not, and a retry's counts on the retry's own event."""
+    from dryad_tpu.exec import executor as EX
+
+    fetched = []  # the executor's own reads: (flag, counts, what was seen)
+    real = EX.jax.device_get
+
+    def spy(tree):
+        if isinstance(tree, tuple) and len(tree) == 3 and getattr(
+                tree[0], "shape", None) == ():
+            fetched.append(tree)
+        return real(tree)
+
+    monkeypatch.setattr(EX.jax, "device_get", spy)
+    n = 4096
+    table = {"k": (np.arange(n, dtype=np.int32) % 512) - 1}
+    for slack, boosts in ((2.0, [1]), (1.0, [1, 2])):
+        ctx = DryadContext(num_partitions_=8, config=DryadConfig(shuffle_slack=slack))
+        del fetched[:]
+        out = ctx.from_arrays(table).group_by("k", {"c": ("count", None)}).collect()
+        assert len(out["k"]) == 512 and (out["c"] == 8).all()
+        events = ctx.events.events()
+        drains = [e for e in events if e["kind"] == "span" and e["name"] == "drain"]
+        seen = [e for e in events if e["kind"] == "exchange_observed"]
+        overflows = [e for e in events if e["kind"] == "stage_overflow"]
+        assert [e["boost"] for e in seen] == boosts
+        assert len(drains) == len(seen) == len(fetched) == len(boosts)
+        assert len(overflows) == len(boosts) - 1
+        assert [e["overflows"] for e in seen] == list(range(1, len(boosts))) + [
+            len(boosts) - 1]
+        last = seen[-1]
+        # a chip holds 512 keys once each: the combiners leave every row
+        assert (last["combine_rows_in"], last["combine_rows_out"]) == (n, n)
+        assert sum(last["recv_rows"]) == n and len(last["recv_rows"]) == 8
+        assert drains[-1]["recv_rows"] == last["recv_rows"]
+        assert drains[-1]["overflows"] == len(overflows)
+        if len(seen) > 1:  # the dispatch that overflowed dropped rows on the way
+            assert sum(seen[0]["recv_rows"]) < seen[0]["combine_rows_out"]
+    # a stage with no exchange reads nothing back for it and says nothing
+    ctx = DryadContext(num_partitions_=8)
+    ctx.from_arrays(table).select(lambda c: {"k": c["k"] + 1}).collect()
+    assert not [e for e in ctx.events.events() if e["kind"] == "exchange_observed"]

@@ -504,3 +504,40 @@ def test_job_metrics_snapshot_from_live_context():
     for key in ("compile_s", "ingest_stall_s", "spill_bytes",
                 "padding_waste"):
         assert key in m.attribution()
+
+
+def test_the_drain_span_carries_what_the_exchange_saw():
+    """The counts that ride the overflow flag's readback are fields of
+    the job's ``drain`` span (the list a chip on the event; numbers
+    alone reach a profiler annotation, so its largest entry beside it)
+    and of one ``exchange_observed`` event a dispatch, both under the
+    job's qid; on one partition nothing is exchanged and neither says
+    anything."""
+    from dryad_tpu import DryadContext
+    from dryad_tpu.obs import critpath
+
+    rng = np.random.default_rng(41)
+    table = {"k": (rng.zipf(1.3, 6000) % 97 - 1).astype(np.int32),
+             "v": rng.standard_normal(6000).astype(np.float32)}
+    for P in (4, 1):
+        ctx = DryadContext(num_partitions_=P)
+        ctx.from_arrays(table).group_by("k", {"s": ("sum", "v")}).collect()
+        events = ctx.events.events()
+        (qid,) = critpath.query_ids(events)
+        drains = [e for e in events if e["kind"] == "span" and e["name"] == "drain"]
+        seen = [e for e in events if e["kind"] == "exchange_observed"]
+        assert len(drains) == 1 and len(seen) == (P > 1)
+        if P == 1:
+            assert "combine_rows_in" not in drains[0]
+            continue
+        drain, event = drains[0], seen[0]
+        assert drain["qid"] == event["qid"] == qid
+        assert drain["combine_rows_in"] == event["combine_rows_in"] == 6000
+        assert 97 <= drain["combine_rows_out"] < 4 * 97 + 1
+        assert drain["recv_rows"] == event["recv_rows"]
+        assert sum(drain["recv_rows"]) == drain["combine_rows_out"]
+        assert drain["recv_rows_max"] == max(drain["recv_rows"])
+        assert (drain["boost"], drain["overflows"], drain["exchanges"]) == (1, 0, 1)
+        numeric = {k for k, v in drain.items() if isinstance(v, (int, float))}
+        assert {"combine_rows_in", "combine_rows_out", "recv_rows_max", "boost",
+                "overflows"} <= numeric

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dryad_tpu.columnar.batch import ColumnBatch
 from dryad_tpu.columnar.schema import ColumnType, Schema
@@ -118,6 +119,42 @@ def test_group_combine_generic_merge():
     cnt = np.asarray(out["cnt"])[valid]
     got = {int(kk): (float(vv), float(cc)) for kk, vv, cc in zip(k, v, cnt)}
     assert got == {1: (42.0, 3.0), 2: (20.0, 1.0)}
+
+
+@pytest.mark.parametrize("n", [1, 2, 7, 64, 257])
+def test_segmented_scan_keeps_the_order_of_a_merge_that_does_not_commute(n):
+    """``segmented_scan`` (the doubling scan of ``group_combine``)
+    against a fold row by row, under a merge that is associative and
+    NOT commutative: affine maps composed, ``(a then b)(x) = b.m * (a.m
+    x + a.c) + b.c`` in integers mod 2^32, with a two-word column beside
+    it.  The earlier rows are always ``merge``'s first argument, and a
+    segment's reduction stops at its start whatever the distance."""
+    from dryad_tpu.ops.segmented import segmented_scan
+
+    rng = np.random.default_rng(n)
+    start = rng.random(n) < 0.2
+    start[0] = True
+    m = rng.integers(1, 1 << 16, n).astype(np.uint32)
+    c = rng.integers(0, 1 << 16, n).astype(np.uint32)
+    wide = rng.integers(0, 9, (n, 2)).astype(np.int32)
+
+    def merge(a, b):
+        return {"m": a["m"] * b["m"], "c": b["m"] * a["c"] + b["c"],
+                "w": a["w"] + b["w"]}
+
+    got = segmented_scan(jnp.asarray(start),
+                         {"m": jnp.asarray(m), "c": jnp.asarray(c),
+                          "w": jnp.asarray(wide)}, merge)
+    want_m, want_c, want_w = m.copy(), c.copy(), wide.copy()
+    with np.errstate(over="ignore"):  # mod 2^32, as on the device
+        for i in range(1, n):
+            if not start[i]:
+                want_m[i] = want_m[i - 1] * m[i]
+                want_c[i] = m[i] * want_c[i - 1] + c[i]
+                want_w[i] = want_w[i - 1] + wide[i]
+    assert np.array_equal(np.asarray(got["m"]), want_m)
+    assert np.array_equal(np.asarray(got["c"]), want_c)
+    assert np.array_equal(np.asarray(got["w"]), want_w)
 
 
 def test_distinct():

@@ -157,15 +157,17 @@ def test_the_cells_query_is_exact(job, shape, P):
 def test_the_stage_program_is_the_parents(job, monkeypatch):
     """The P = 4 program of the cell's query holds the range exchange's
     ``all_to_all`` under ``dryad.exchange.collective`` and the sample's
-    gather over the mesh under ``dryad.sort.splitters``, and this PR
-    added no scope: the program's name, which is in jax's compilation
-    cache key, is still the parent's."""
+    gather over the mesh under ``dryad.sort.splitters``.  The program's
+    name, which is in jax's compilation cache key, counts the
+    generations of scopes (``parallel/stage.py``): PR 30 added none and
+    kept ``dryad_stage_2``; PR 41's ``dryad.group_combine.*`` made it
+    ``dryad_stage_3``."""
     from dryad_tpu.parallel import stage
 
     program, = lowered_programs(
         job, monkeypatch, the_table(job, "uniform"), {"rows": ROWS}, 4)
-    assert stage.PROGRAM_NAME == "dryad_stage_2"
-    assert "module @jit_dryad_stage_2 " in program.as_text()
+    assert stage.PROGRAM_NAME == "dryad_stage_3"
+    assert "module @jit_dryad_stage_3 " in program.as_text()
     paths = re.findall(r'op_name="([^"]*)"', program.compile().as_text())
     under = "/dryad.exchange_range/"
     assert any(under + "dryad.exchange.collective/all_to_all" in p for p in paths)
@@ -182,7 +184,7 @@ def test_the_one_chip_program_is_the_local_sort_alone(job, monkeypatch):
     ``local_sort``'s and the answer has the slots the table had."""
     program, = lowered_programs(
         job, monkeypatch, the_table(job, "uniform"), {"rows": ROWS}, 1)
-    assert "module @jit_dryad_stage_2 " in program.as_text()
+    assert "module @jit_dryad_stage_3 " in program.as_text()
     compiled = program.compile().as_text()
     paths = re.findall(r'op_name="([^"]*)"', compiled)
     assert any("/dryad.local_sort/dryad.sort.carry/" in p for p in paths)

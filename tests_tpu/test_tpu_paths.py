@@ -501,3 +501,41 @@ def test_the_multi_output_job_on_chip(jaxmod):
     assert decodes[0]["fetched"] < 1.2 * decodes[0]["rows"]
     assert decodes[1]["fetched"] == decodes[1]["capacity"] == params["rows"]
     assert decodes[0]["rows"] + decodes[1]["rows"] == params["rows"]
+
+
+def test_the_user_defined_combiner_on_chip(jaxmod):
+    """``groupby-skew-4c``'s query at 2^20 rows a chip over every chip
+    there is, through the cell's own ``bind`` and ``compare``: the
+    scan that traces the user's ``merge`` runs over a hottest group of
+    6% of the rows, and across chips the exchange reads back with its
+    overflow flag what the combiner left and what every chip received
+    (no overflow at the default slack; nothing observed on one chip)."""
+    import importlib.util
+    import os
+
+    from dryad_tpu import DryadContext
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_job_groupby_skew",
+        os.path.join(root, "benchmarks", "jobs", "groupby_skew.py"))
+    job = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(job)
+    chips = len(jaxmod.devices())
+    params = {"rows": chips << 20, "groups": 1 << 17, "zipf_theta": 0.99,
+              "partitions": chips}
+    table = job.make_table(np.random.default_rng([41, 1]), params, None, 0)
+    ctx = DryadContext(num_partitions_=chips)
+    bound = job.bind(ctx, table, params)
+    for answer in (bound.collect(), bound.collect()):
+        checks = job.compare(table, answer, params)
+        assert len(checks) == 7, checks
+        assert all(value <= limit for value, limit in checks.values()), checks
+    events = ctx.events.events()
+    assert not [e for e in events if e["kind"] == "stage_overflow"]
+    seen = [e for e in events if e["kind"] == "exchange_observed"]
+    assert len(seen) == (2 if chips > 1 else 0)
+    for e in seen:
+        assert e["combine_rows_in"] == params["rows"] and e["boost"] == 1
+        assert sum(e["recv_rows"]) == e["combine_rows_out"] < params["rows"] // 2
+        assert max(e["recv_rows"]) * chips < 1.05 * e["combine_rows_out"]
