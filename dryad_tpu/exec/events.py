@@ -104,7 +104,10 @@ EVENT_KINDS: Dict[str, str] = {
     "xla_compile": "stage (re)compiled; stage/key/trace_s/compile_s",
     "join_plan": "one join kernel's trace-time decision, once a compile; "
                  "strategy/est_right/broadcast_limit/out_capacity/"
-                 "left_capacity/right_capacity",
+                 "left_capacity/right_capacity; slot_gathers = gathers "
+                 "over the pair slots, stacked_words = {li, ri}: words "
+                 "through ONE stacked gather an index (0: a gather a "
+                 "column)",
     "telemetry_merged": "driver absorbed worker span/counter batches",
     # -- diagnosis / flight recorder (obs.diagnose / exec.events) ---------
     "resource_sample": "continuous telemetry sample; hbm/rss/probes",
@@ -282,7 +285,8 @@ EVENT_PAYLOADS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
     "xla_compile": (("compile_s", "key", "stage", "trace_s"), ("qid",)),
     "join_plan": (
         ("broadcast_limit", "est_right", "key", "left_capacity",
-         "out_capacity", "right_capacity", "stage", "strategy"),
+         "out_capacity", "right_capacity", "slot_gathers", "stacked_words",
+         "stage", "strategy"),
         ("qid",),
     ),
     "telemetry_merged": (("events", "offsets"), ()),

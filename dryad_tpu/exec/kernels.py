@@ -709,7 +709,8 @@ def _apply_join_strategy(ctx: StageContext, p) -> int:
     candidate-pair buffer: ``expansion`` x ``boost`` x the larger side's
     capacity.  That base uses PRE-broadcast sizes: replicating the
     right side multiplies its capacity by P but not the match count.
-    What was decided goes on ``ctx.join_log``."""
+    What was decided goes on ``ctx.join_log``; :func:`_traced_join`
+    adds what the join kernel then traced."""
     base = max(
         ctx.slots[p["left_slot"]].capacity, ctx.slots[p["right_slot"]].capacity
     )
@@ -750,18 +751,31 @@ def _apply_join_strategy(ctx: StageContext, p) -> int:
     return out_cap
 
 
+def _traced_join(ctx: StageContext, join, *args, **kwargs):
+    """Trace one ``ops/join.py`` flavour and put what it gathered over
+    its pair slots (``slot_gathers``, ``stacked_words``) on the record
+    :func:`_apply_join_strategy` just opened for it."""
+    with J.slot_gather_log() as seen:
+        out = join(*args, **kwargs)
+    ctx.join_log[-1].update(seen)
+    return out
+
+
 def _k_join(ctx: StageContext, p) -> None:
     out_cap = _apply_join_strategy(ctx, p)
     left = ctx.slots[p["left_slot"]]
     right = ctx.slots[p["right_slot"]]
     if p.get("outer"):
-        out, ovf = J.hash_join_outer(
+        out, ovf = _traced_join(
+            ctx, J.hash_join_outer,
             left, right, p["left_keys"], p["right_keys"], out_cap,
             p.get("right_defaults") or {}, p.get("suffix", "_r"),
         )
     else:
-        out, ovf = J.hash_join(
-            left, right, p["left_keys"], p["right_keys"], out_cap, p.get("suffix", "_r")
+        out, ovf = _traced_join(
+            ctx, J.hash_join,
+            left, right, p["left_keys"], p["right_keys"], out_cap,
+            p.get("suffix", "_r"),
         )
     ctx.slots[p["left_slot"]] = out
     ctx.overflow = ctx.overflow | ovf
@@ -771,8 +785,8 @@ def _k_semi(ctx: StageContext, p) -> None:
     cap = _apply_join_strategy(ctx, p)
     left = ctx.slots[p["left_slot"]]
     right = ctx.slots[p["right_slot"]]
-    mask, ovf = J.exists_mask(
-        left, right, p["left_keys"], p["right_keys"], cap
+    mask, ovf = _traced_join(
+        ctx, J.exists_mask, left, right, p["left_keys"], p["right_keys"], cap
     )
     if p.get("negate"):
         mask = ~mask
@@ -791,8 +805,9 @@ def _k_group_join_count(ctx: StageContext, p) -> None:
     cap = _apply_join_strategy(ctx, p)
     left = ctx.slots[p["left_slot"]]
     right = ctx.slots[p["right_slot"]]
-    counts, ovf = J.group_join_counts(
-        left, right, p["left_keys"], p["right_keys"], cap
+    counts, ovf = _traced_join(
+        ctx, J.group_join_counts,
+        left, right, p["left_keys"], p["right_keys"], cap,
     )
     ctx.slots[p["left_slot"]] = left.with_column(p["out"], counts)
     ctx.overflow = ctx.overflow | ovf
@@ -807,7 +822,8 @@ def _k_join_ranked(ctx: StageContext, p) -> None:
     right = ctx.slots[p["right_slot"]]
     operands_fn = p.get("operands_fn")
     operands = operands_fn(right) if operands_fn is not None else ()
-    out, ovf = J.hash_join_ranked(
+    out, ovf = _traced_join(
+        ctx, J.hash_join_ranked,
         left, right, p["left_keys"], p["right_keys"], out_cap,
         p.get("suffix", "_r"), p["rank_out"], operands,
         rank_limit=p.get("rank_limit"), boost=ctx.boost,

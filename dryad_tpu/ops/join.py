@@ -15,11 +15,27 @@ one merge of the left hashes into the sorted right side
 (``ops/sort.py::sorted_ranks``), and a pair slot's left row from a
 scatter of the rows' first slots and a running maximum
 (:func:`_slot_owners`).
+
+What a pair slot gathers.  A slot has two indices, its left row ``li``
+and its right row ``ri``, and on the TPU a gather is priced by the
+index, not by the bytes it fetches.  So everything a slot needs from
+its left row goes through the gathers of ONE call by ``li`` -- the
+base of ``ri`` (``start - offsets``, one word a left row: ``ri`` is
+``base[li] + slot``) beside the left columns -- and everything from
+its right row through one call by ``ri`` (:func:`_materialize_pairs`;
+``ops/sort.py::take_rows`` sends the 4-byte columns of a call through
+one gather over their stacked words where that is the cheaper form).
+The key columns are gathered there once and the exact match compares
+what was gathered.  NO validity is gathered: the probe's contract
+(:func:`_probe_ranges`) already makes both sides of every live slot
+valid rows.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+import contextlib
+import threading
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +46,13 @@ from dryad_tpu.ops.sort import (
     sort_batch_by_operands,
     sort_carry,
     sorted_ranks,
+    stacked_words,
+    take_rows,
 )
+
+
+# No column's: a physical name has its logical base before any "#".
+_FIRST_SLOT = "#first_slot"
 
 
 def _suffixed(phys_name: str, suffix: str) -> str:
@@ -58,6 +80,13 @@ def _probe_ranges(
     (``sorted_ranks``).  Invalid right rows sort to the end with a
     sentinel hash that can never match a valid probe (probe hashes have
     their top bit cleared; the sentinel is 2^32-1).
+
+    The contract every flavour leans on, so that no pair slot reads a
+    validity: (1) ``counts[i] == 0`` for an invalid left row, so it
+    owns no slot (a caller may clamp ``counts`` down, never raise it);
+    (2) the sentinel hash sits on invalid right rows only and equals no
+    probe hash, and a candidate's hash EQUALS its left row's, so every
+    row of ``[start[i], start[i] + counts[i])`` is a valid right row.
     """
     rhash = hash_columns([right.data[k] for k in right_keys]) >> 1
     rhash = jnp.where(right.valid, rhash, jnp.uint32(0xFFFFFFFF))
@@ -92,16 +121,59 @@ def _slot_owners(
     return jax.lax.cummax(heads)
 
 
+class _SlotGathers(threading.local):
+    seen = None
+
+
+_slot_gathers = _SlotGathers()
+
+
+@contextlib.contextmanager
+def slot_gather_log() -> Iterator[Dict[str, object]]:
+    """Trace-time record of the gathers over the pair slots that the
+    joins traced under it emit: ``slot_gathers``, how many, and
+    ``stacked_words``, for each index (``li`` / ``ri``) the 4-byte
+    words that went through ONE stacked gather (0: a gather a column).
+    What the ``join_plan`` event says of the mechanism."""
+    before = _slot_gathers.seen
+    seen = _slot_gathers.seen = dict(
+        slot_gathers=0, stacked_words=dict(li=0, ri=0)
+    )
+    try:
+        yield seen
+    finally:
+        _slot_gathers.seen = before
+
+
+def _take_slots(
+    columns: Sequence[jax.Array], index: jax.Array, which: Optional[str] = None
+) -> List[jax.Array]:
+    """``columns`` at the pair slots' ``index`` (``take_rows``: one
+    stacked gather or a gather a column), counted for
+    :func:`slot_gather_log`."""
+    seen = _slot_gathers.seen
+    if seen is not None:
+        words = stacked_words(columns)
+        seen["slot_gathers"] += len(columns) - words + (words > 0)
+        if which is not None:
+            seen["stacked_words"][which] += words
+    return take_rows(columns, index)
+
+
 @jax.named_scope("dryad.join.expand_pairs")
 def _expand_pairs(
     start: jax.Array, counts: jax.Array, out_capacity: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Enumerate candidate (left_row, right_row) pairs into fixed slots.
 
-    Returns (left_idx, right_idx, pair_valid, overflow, offsets) where
+    Returns (left_idx, base, pair_valid, overflow, offsets) where
     ``offsets[i]`` is the first slot of left row i's candidate range
-    (slots for one left row are contiguous, rows in order) and
-    ``left_idx`` is :func:`_slot_owners`' answer.  Only the slots under
+    (slots for one left row are contiguous, rows in order),
+    ``left_idx`` is :func:`_slot_owners`' answer and ``base`` is
+    ``start - offsets``, a word a LEFT row: slot s's right row is
+    ``base[left_idx[s]] + s`` (``start[li] + (s - offsets[li])`` with
+    the subtraction done before the gather, so one gather a slot finds
+    it; int32 wraps the same either way).  Only the slots under
     ``pair_valid`` mean anything.
     """
     offsets = jnp.concatenate(
@@ -112,37 +184,49 @@ def _expand_pairs(
 
     slots = jnp.arange(out_capacity, dtype=jnp.int32)
     li = _slot_owners(offsets, counts, out_capacity)
-    within = slots - offsets[li].astype(jnp.int32)
+    base = (start - offsets).astype(jnp.int32)
     pair_valid = slots < total
-    ri = start[li].astype(jnp.int32) + within
-    return li, ri, pair_valid, overflow, offsets
+    return li, base, pair_valid, overflow, offsets
 
 
 @jax.named_scope("dryad.join.materialize")
 def _materialize_pairs(
-    left: ColumnBatch,
-    rs: ColumnBatch,
-    right_keys: Sequence[str],
+    lcols: Dict[str, jax.Array],
+    rcols: Dict[str, jax.Array],
     li: jax.Array,
-    ri: jax.Array,
+    base: jax.Array,
+) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
+    """Everything the pair slots read, in one call an index: by ``li``
+    the right row's ``base`` and the left columns ``lcols`` (any word a
+    left row has for its slots can ride here under a name of its own:
+    the ranked join's first slot does); then by ``ri = base[li] +
+    slot`` the right columns ``rcols``.  Returns both at the slots."""
+    by_li = _take_slots([base, *lcols.values()], li, "li")
+    ri = by_li[0] + jnp.arange(li.shape[0], dtype=jnp.int32)
+    by_ri = _take_slots(list(rcols.values()), ri, "ri")
+    return dict(zip(lcols, by_li[1:])), dict(zip(rcols, by_ri))
+
+
+def _joined_columns(
+    lcols: Dict[str, jax.Array],
+    rcols: Dict[str, jax.Array],
+    right_keys: Sequence[str],
     suffix: str,
 ) -> Tuple[Dict[str, jax.Array], Dict[str, str]]:
-    """Gather the pair slots' columns: every left column by ``li``,
-    every right column but the keys by ``ri`` (they equal the left's).
+    """The joined rows' columns: every left column, every right column
+    but the keys (they equal the left's).
 
-    Returns (data, right_out): ``right_out`` maps a gathered right
-    column's name to its name in ``data`` (a name clashing with a left
-    column's takes ``suffix``)."""
-    data: Dict[str, jax.Array] = {}
-    for name, col in left.data.items():
-        data[name] = col[li]
+    Returns (data, right_out): ``right_out`` maps a right column's name
+    to its name in ``data`` (a name clashing with a left column's takes
+    ``suffix``)."""
+    data = dict(lcols)
     rk = set(right_keys)
     right_out: Dict[str, str] = {}
-    for name, col in rs.data.items():
+    for name, col in rcols.items():
         if name in rk:
             continue
         right_out[name] = _suffixed(name, suffix) if name in data else name
-        data[right_out[name]] = col[ri]
+        data[right_out[name]] = col
     return data, right_out
 
 
@@ -161,26 +245,32 @@ def hash_join(
     with left names get ``suffix``).  Returns (batch, overflow).
     """
     rs, lhash, start, counts = _probe_ranges(left, right, left_keys, right_keys)
-    li, ri, pair_valid, overflow, _ = _expand_pairs(start, counts, out_capacity)
-    data, _ = _materialize_pairs(left, rs, right_keys, li, ri, suffix)
-    valid = _exact_pair_match(left, rs, left_keys, right_keys, li, ri, pair_valid)
+    li, base, pair_valid, overflow, _ = _expand_pairs(start, counts, out_capacity)
+    lcols, rcols = _materialize_pairs(left.data, rs.data, li, base)
+    valid = _exact_pair_match(lcols, rcols, left_keys, right_keys, pair_valid)
+    data, _ = _joined_columns(lcols, rcols, right_keys, suffix)
     return ColumnBatch(data, valid), overflow
 
 
 @jax.named_scope("dryad.join.exact")
 def _exact_pair_match(
-    left: ColumnBatch,
-    rs: ColumnBatch,
+    lcols: Dict[str, jax.Array],
+    rcols: Dict[str, jax.Array],
     left_keys: Sequence[str],
     right_keys: Sequence[str],
-    li: jax.Array,
-    ri: jax.Array,
     pair_valid: jax.Array,
 ) -> jax.Array:
-    """Candidate pairs that match on ALL key columns (kills collisions)."""
-    exact = pair_valid & left.valid[li] & rs.valid[ri]
+    """Candidate pairs that match on ALL key columns (kills collisions),
+    from the key columns as :func:`_materialize_pairs` gathered them.
+
+    Neither side's validity is read: under ``pair_valid`` both are true
+    by :func:`_probe_ranges`' contract.  A slot's left row owns it, so
+    its ``counts`` is positive and the row valid; its right row lies in
+    that row's candidate range, whose hashes equal a probe hash, which
+    the sentinel of the invalid right rows never does."""
+    exact = pair_valid
     for lk, rkey in zip(left_keys, right_keys):
-        exact = exact & (left.data[lk][li] == rs.data[rkey][ri])
+        exact = exact & (lcols[lk] == rcols[rkey])
     return exact
 
 
@@ -209,14 +299,15 @@ def hash_join_outer(
     the unmatched tail is statically reserved so it can never overflow.
     """
     rs, lhash, start, counts = _probe_ranges(left, right, left_keys, right_keys)
-    li, ri, pair_valid, overflow, _ = _expand_pairs(start, counts, out_capacity)
-    exact = _exact_pair_match(left, rs, left_keys, right_keys, li, ri, pair_valid)
+    li, base, pair_valid, overflow, _ = _expand_pairs(start, counts, out_capacity)
+    lcols, rcols = _materialize_pairs(left.data, rs.data, li, base)
+    exact = _exact_pair_match(lcols, rcols, left_keys, right_keys, pair_valid)
 
     # Per-left-row exact-match count -> unmatched mask for the tail.
     matched = _exact_per_left(li, exact, left.capacity)
     unmatched = left.valid & (matched == 0)
 
-    data, right_out = _materialize_pairs(left, rs, right_keys, li, ri, suffix)
+    data, right_out = _joined_columns(lcols, rcols, right_keys, suffix)
     for name, col in left.data.items():
         data[name] = jnp.concatenate([data[name], col])
     for name, out_name in right_out.items():
@@ -240,8 +331,12 @@ def group_join_counts(
     """Per-left-row count of exactly-matching right rows (GroupJoin's
     shape; aggregations over the group compose on the joined output)."""
     rs, _lhash, start, counts = _probe_ranges(left, right, left_keys, right_keys)
-    li, ri, pair_valid, overflow, _ = _expand_pairs(start, counts, out_capacity)
-    exact = _exact_pair_match(left, rs, left_keys, right_keys, li, ri, pair_valid)
+    li, base, pair_valid, overflow, _ = _expand_pairs(start, counts, out_capacity)
+    lcols, rcols = _materialize_pairs(  # the keys alone
+        {k: left.data[k] for k in left_keys},
+        {k: rs.data[k] for k in right_keys}, li, base,
+    )
+    exact = _exact_pair_match(lcols, rcols, left_keys, right_keys, pair_valid)
     cnt = _exact_per_left(li, exact, left.capacity)
     return cnt, overflow
 
@@ -297,10 +392,15 @@ def hash_join_ranked(
     full_counts = counts
     if rank_limit is not None and not final_attempt:
         counts = jnp.minimum(counts, jnp.int32(rank_limit * boost))
-    li, ri, pair_valid, overflow, offsets = _expand_pairs(
+    li, base, pair_valid, overflow, offsets = _expand_pairs(
         start, counts, out_capacity
     )
-    exact = _exact_pair_match(left, rs, left_keys, right_keys, li, ri, pair_valid)
+    # a slot's row's first slot rides the gather by ``li`` with the columns
+    lcols, rcols = _materialize_pairs(
+        {**left.data, _FIRST_SLOT: offsets.astype(jnp.int32)}, rs.data, li, base
+    )
+    seg = lcols.pop(_FIRST_SLOT)
+    exact = _exact_pair_match(lcols, rcols, left_keys, right_keys, pair_valid)
 
     # Group-local rank among EXACT matches: a left row's candidate
     # slots are contiguous ([offsets[i], offsets[i]+counts[i])), so the
@@ -308,10 +408,8 @@ def hash_join_ranked(
     # Hash-collision candidates inside the range fail `exact` and are
     # skipped by the subtraction.
     cs = jnp.cumsum(exact.astype(jnp.int32))
-    seg = offsets[li].astype(jnp.int32)
-    before = jnp.where(
-        seg > 0, cs[jnp.clip(seg - 1, 0, out_capacity - 1)], 0
-    )
+    (at_seg,) = _take_slots([cs], jnp.clip(seg - 1, 0, out_capacity - 1))
+    before = jnp.where(seg > 0, at_seg, 0)
     rank = jnp.where(exact, cs - 1 - before, 0).astype(jnp.int32)
 
     if rank_limit is not None:
@@ -331,7 +429,7 @@ def hash_join_ranked(
         # the boost-widened window.
         exact = exact & (rank < jnp.int32(rank_limit))
 
-    data, _ = _materialize_pairs(left, rs, right_keys, li, ri, suffix)
+    data, _ = _joined_columns(lcols, rcols, right_keys, suffix)
     data[rank_name] = rank
     return ColumnBatch(data, exact), overflow
 

@@ -159,6 +159,14 @@ def sort_carry(
     return sorted_valid, list(res[1:len(ops)]), moved
 
 
+def _stacks(c: jax.Array) -> bool:
+    """A column whose rows are one 4-byte word each: what a stacked
+    gather can carry (a ``pred`` and a column with trailing dimensions,
+    a BYTES column's words, go by themselves; a split 64-bit column is
+    two such columns)."""
+    return c.ndim == 1 and c.dtype.itemsize == 4
+
+
 def _take_rows(
     columns: Sequence[jax.Array], order: jax.Array, stacked: bool
 ) -> List[jax.Array]:
@@ -167,9 +175,7 @@ def _take_rows(
     along its rows, which on the TPU costs a tenth of a gather a
     column (26 words over 2^24 slots: 0.663 s against 6.27 s;
     ``PERF.md`` section 6, PR 32)."""
-    stack = [
-        stacked and c.ndim == 1 and c.dtype.itemsize == 4 for c in columns
-    ]
+    stack = [stacked and _stacks(c) for c in columns]
     words = [
         jax.lax.bitcast_convert_type(c, jnp.uint32)
         for c, s in zip(columns, stack) if s
@@ -181,6 +187,78 @@ def _take_rows(
         jax.lax.bitcast_convert_type(next(taken), c.dtype) if s else c[order]
         for c, s in zip(columns, stack)
     ]
+
+
+# The narrowest row, in 4-byte words, whose columns share ONE stacked
+# gather when they share an index that is no sort's permutation
+# (:func:`take_rows`; the join's pair slots): every row of two words or
+# more.  Chip-measured on one v5e (PR 42, ``PERF.md`` section 6),
+# seconds a call over 10,485,760 slots, the stack and the unstack
+# included, a gather a column against one stacked gather:
+#
+#   words   ascending index into 2^23 rows   random index into 2^16 rows
+#     1     0.0910                           0.0908
+#     2     0.3891    0.0653                 0.1694    0.0345
+#     3     0.6868    0.2350                 0.2478    0.0418
+#     4     0.9849    0.2360                 0.3258    0.0489
+#     6     1.5797    0.2572                 0.4820    0.0633
+#
+# The stacked gather wins at every width and both kinds of index, so
+# the rule is a width test with no other term.  Two things the table
+# does not say by itself: gathers a column in one program cost more
+# than as many programs of one (0.389 s for two, 0.091 s for one), and
+# the stacked gather has a cliff at the TABLE's size, not at a width:
+# from a table of 64 MiB or less it costs 3.3 - 6.3 ns a slot, from a
+# larger one 12.6 - 21.7 (2 words x 2^23 rows 0.0653 s, 3 words 0.2273;
+# 4 words x 2^22 rows 0.0667), whatever the words beyond it.
+SHARED_GATHER_WORDS = 2
+
+
+def stacked_words(columns: Sequence[jax.Array]) -> int:
+    """How many of ``columns`` :func:`take_rows` sends through ONE
+    stacked gather; 0 where every column is gathered by itself."""
+    words = sum(_stacks(c) for c in columns)
+    return words if words >= SHARED_GATHER_WORDS else 0
+
+
+# The most slots one stacked gather of :func:`take_rows` covers.  Where
+# the table is small (2^18 rows or fewer on the v5e: its rows, padded
+# to the 128 lanes, then fit the chip's vector memory) the TPU's
+# compiler hands the stacked gather's result over with every slot's
+# words padded to 128 lanes, 512 B a slot whatever the words: 5.4 GB
+# of the program's temporaries for the 10,485,760 pair slots of the
+# ``join-topk-1c`` cell, which ``memory_stats()`` does not count and a
+# join a few times larger would not compile under.  A block at a time,
+# that buffer is 512 MiB and dies with its block.  Chip-measured (PR
+# 42; seconds a call and the compiled program's temporaries, MB, over
+# 10,485,760 slots): 2 words from 2^16 rows by a random index, whole
+# 0.0344 / 5,369, blocks of 2^20 0.0349 / 619, of 2^21 0.1031 / 1,152;
+# 3 words from 2^23 rows by an ascending index, whole 0.2272 / 302,
+# blocks of 2^20 0.2106 / 244; the join's pair-slot part at the cell's
+# shapes, whole 0.3078 / 5,369, blocks of 2^20 0.2944 / 626, of 2^21
+# 0.3645 / 1,182 (``tests/test_tpu_compile.py`` holds the bound).
+# :func:`sort_carry`'s own stacked gather needs no blocks: its slots
+# are its table's rows, so a table small enough to be padded makes a
+# result of at most 2^18 slots, 128 MiB.
+STACK_BLOCK_SLOTS = 1 << 20
+
+
+def take_rows(
+    columns: Sequence[jax.Array], index: jax.Array
+) -> List[jax.Array]:
+    """``[c[index] for c in columns]`` for columns that share an index
+    which is no sort's permutation (the join's pair slots): the form is
+    :func:`stacked_words`' rule, bit-exact either way, the stacked one
+    over ``STACK_BLOCK_SLOTS`` slots at a time."""
+    if not stacked_words(columns):
+        return _take_rows(columns, index, stacked=False)
+    blocks = [
+        _take_rows(columns, index[at:at + STACK_BLOCK_SLOTS], stacked=True)
+        for at in range(0, index.shape[0], STACK_BLOCK_SLOTS)
+    ]
+    if len(blocks) == 1:
+        return blocks[0]
+    return [jnp.concatenate(taken) for taken in zip(*blocks)]
 
 
 def sort_batch_by_operands(

@@ -153,6 +153,12 @@ def test_the_join_plan_record(job):
     assert plan["broadcast_limit"] == ctx.config.broadcast_limit == 1 << 16
     assert (plan["left_capacity"], plan["right_capacity"],
             plan["out_capacity"]) == (1024, 128, 1280)
+    # what the traced join gathers over its pair slots (PR 42): five
+    # columns (the right row's base, key, payload; dkey, weight), an
+    # index's through ONE gather where its words were stacked
+    stacked = plan["stacked_words"]
+    assert set(stacked) == {"li", "ri"} and stacked["li"] in (0, 3) and stacked["ri"] in (0, 2)
+    assert plan["slot_gathers"] == 5 - sum(w - 1 for w in stacked.values() if w)
     # a stage without a join says nothing of joins
     other = DryadContext(num_partitions_=4)
     other.from_arrays(table["fact"]).order_by([("payload", True)]).take(5).collect()
@@ -160,7 +166,7 @@ def test_the_join_plan_record(job):
     assert "xla_compile" in kinds and "join_plan" not in kinds
 
 
-def lowered_programs(job, monkeypatch, table, params, P):
+def lowered_programs(job, monkeypatch, table, params, P, ctx=None):
     """The cell's query collected once; every stage program it lowered."""
     from dryad_tpu.exec.executor import GraphExecutor
 
@@ -179,7 +185,7 @@ def lowered_programs(job, monkeypatch, table, params, P):
         return hit
 
     monkeypatch.setattr(GraphExecutor, "_get_compiled", spy)
-    job.bind(DryadContext(num_partitions_=P), table, params).collect()
+    job.bind(ctx or DryadContext(num_partitions_=P), table, params).collect()
     return lowered
 
 
@@ -212,6 +218,31 @@ def test_the_stage_program_names_the_joins_parts(job, monkeypatch):
     # new set of scopes takes a new name (benchmarks/TRACING.md)
     assert stage.PROGRAM_NAME != "dryad_stage"  # PR 24's and the parent's
     assert f"module @jit_{stage.PROGRAM_NAME} " in program.as_text()
+
+
+def test_the_join_plan_counts_the_programs_gathers(job, monkeypatch):
+    """``slot_gathers`` of the ``join_plan`` event is the number of
+    ``gather``s under ``dryad.join`` over the pair slots in the stage
+    program the executor lowered, none of them a validity's; the
+    parent's program had nine."""
+    params = {"rows": ROWS, "dim_rows": DIM_ROWS, "top": TOP, "expansion": 1.25}
+    table = job.make_table(np.random.default_rng(26), params, None, 0)
+    ctx = DryadContext(num_partitions_=1)
+    program, = lowered_programs(job, monkeypatch, table, params, 1, ctx)
+    plan, = [e for e in ctx.events.events() if e["kind"] == "join_plan"]
+    slots = plan["out_capacity"]
+    assert slots == 5120
+    text = program.as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = (.*)$", text, re.M))
+    found = []
+    for line in text.splitlines():
+        if '"stablehlo.gather"' not in line:
+            continue
+        result, where = re.search(r"-> tensor<([^>]*)> loc\((#loc\d+)\)", line).groups()
+        *dims, dtype = result.split("x")
+        if str(slots) in dims and "/dryad.join/" in locs[where]:
+            found.append(dtype)
+    assert len(found) == plan["slot_gathers"] <= 5 and "i1" not in found
 
 
 def whiles_by_scope(program):

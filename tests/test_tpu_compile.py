@@ -71,3 +71,39 @@ def test_the_user_defined_combiners_scan_compiles_small_for_the_chip(one_chip, u
     assert memory.generated_code_size_in_bytes < 32 << 20
     # the passes reuse their buffers: a few copies of the state, not one a pass
     assert memory.temp_size_in_bytes < 8 * 21 * slots
+
+
+def test_the_joins_pair_slots_compile_small_for_the_chip(one_chip, uncached):
+    """Everything of ``ops/join.py::hash_join`` after the probe (the
+    pair slots' owners, the two calls that gather them, the exact
+    match) at the ``join-topk-1c`` cell's widths: 2^23 fact rows x 2^16
+    dimension rows, 10,485,760 pair slots.  The stacked gather by ``ri``
+    reads a small table, and for one the TPU's compiler pads every
+    slot of the result to 128 lanes: over all the slots at once that
+    buffer alone is 5.4 GB (``ops/sort.py::STACK_BLOCK_SLOTS``; PERF.md
+    section 6, PR 42); a block of 2^20 slots at a time it is 512 MiB."""
+    from dryad_tpu.columnar.batch import ColumnBatch
+    from dryad_tpu.ops import join as J
+
+    rows, dim_rows, slots = 1 << 23, 1 << 16, 10_485_760
+
+    def col(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    left = ColumnBatch({"key": col(rows, jnp.int32), "payload": col(rows, jnp.float32)},
+                       col(rows, jnp.bool_))
+    rs = ColumnBatch({"dkey": col(dim_rows, jnp.int32), "weight": col(dim_rows, jnp.float32)},
+                     col(dim_rows, jnp.bool_))
+
+    def pair_slots(left, rs, start, counts):
+        li, base, pair_valid, overflow, _ = J._expand_pairs(start, counts, slots)
+        lcols, rcols = J._materialize_pairs(left.data, rs.data, li, base)
+        valid = J._exact_pair_match(lcols, rcols, ["key"], ["dkey"], pair_valid)
+        return J._joined_columns(lcols, rcols, ["dkey"], "_r")[0], valid, overflow
+
+    with J.slot_gather_log() as seen:
+        compiled = jax.jit(pair_slots).lower(
+            left, rs, col(rows, jnp.int32), col(rows, jnp.int32)).compile()
+    assert seen == {"slot_gathers": 2, "stacked_words": {"li": 3, "ri": 2}}
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1 << 30  # 626 MB; 5,369 MB in one block
