@@ -20,15 +20,20 @@ How rows move: one stable ``lax.sort`` by destination puts each
 bucket's rows side by side, and the columns go through that sort with
 the key (``ops.sort.sort_carry``: on the TPU as extra sort operands,
 elsewhere gathered by the sorted row index; the same permutation
-either way); one scatter a column then drops every row at
-``dest * B + position in bucket``.  ``resize`` compacts the same way,
-a stable sort on ``~valid`` alone with the columns carried.  No
-column is gathered by a sorted ``iota``: XLA's TPU ``gather`` runs at
-28 ns an element, which made that form 92.5% of the device time of a
-2^25-row ``order_by`` and 73% of a four-chip ``group_by``, against
-7.5% for the sort that carries every column of 2^26 slots
-(``PERF_LEDGER.jsonl``, PR 24, ``sort-1c`` and ``groupby-4c``:
-``gather_dev_share`` 77.3% and 61.8%).
+either way).  Bucket ``p`` is then the run of sorted rows that starts
+where ``p`` first stands among the sorted destinations (a binary
+search for ``0..P``; the counts are the distances between the starts),
+and row ``(p, j)`` of the send buffer is read by position: one
+``dynamic_slice`` a bucket a column, masked past the bucket's count.
+``resize`` compacts the same way, a stable sort on ``~valid`` alone
+with the columns carried.  No column is gathered by a sorted ``iota``
+and none is scattered into its slots: XLA's TPU ``gather`` runs at
+28 ns an element (92.5% of the device time of a 2^25-row ``order_by``
+and 73% of a four-chip ``group_by`` in that form; ``PERF_LEDGER.jsonl``,
+PR 24, ``gather_dev_share``) and its ``scatter`` at 4.9 ns an update,
+which with a ``scatter-add`` histogram beside it was a quarter to a
+half of the four-chip cells' device time (``PERF.md`` section 6,
+PR 43).
 
 Under whole-DAG fusion (``plan/fuse.py``) these exchanges also serve as
 the SEAMS between fused member stages: the whole multi-stage region
@@ -83,41 +88,53 @@ def row_bytes(batch: ColumnBatch) -> int:
 
 
 # Operator scopes (``jax.named_scope``) inside an exchange: the bucket
-# layout (sort by destination with the columns carried, scatter into
-# send buffers) and the collective apart, so a device trace splits one
-# from the other.
+# layout (sort by destination with the columns carried, slice the runs
+# into send buffers) and the collective apart, so a device trace splits
+# one from the other.
 LAYOUT_SCOPE = "dryad.exchange.layout"
 COLLECTIVE_SCOPE = "dryad.exchange.collective"
 
 
 def _bucket_layout(batch: ColumnBatch, dest: jax.Array, P: int, B: int):
-    """Rows stably sorted by destination, so each bucket's rows are
-    contiguous: ``(sorted batch, sorted dest, position within bucket,
-    ships, overflow)``.  Invalid rows take the sentinel ``P`` (after
-    every valid row, in their own order) and never ship; a valid row
-    past its bucket's ``B`` sets ``overflow``.  The columns ride the
-    sort (``ops.sort.sort_carry``)."""
-    cap = batch.capacity
+    """Rows stably sorted by destination, so bucket ``p`` is the run
+    that starts at ``offsets[p]``: ``(sorted columns, offsets, rows of
+    each bucket that ship, overflow)``.  Invalid rows take the sentinel
+    ``P`` (after every valid row, in their own order) and never ship; a
+    bucket of more than ``B`` rows ships its first ``B`` and sets
+    ``overflow``.  The columns ride the sort (``ops.sort.sort_carry``)
+    and come back ``B`` slots longer than the batch: ``dynamic_slice``
+    clamps its start to keep the slice inside its operand, which would
+    hand the last buckets the rows before their own, so ``offsets[p] +
+    B`` is made to fit."""
     names = batch.columns
-    svalid, (dsorted,), carried = sort_carry(
+    _, (dsorted,), carried = sort_carry(
         [jnp.where(batch.valid, dest, P)],
         batch.valid,
         [batch.data[n] for n in names],
     )
-    sb = ColumnBatch(dict(zip(names, carried)), svalid)
-    dsorted = dsorted.astype(jnp.int32)
-
-    counts = jnp.bincount(dsorted, length=P + 1)[:P]
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]]
+    offsets = jnp.searchsorted(
+        dsorted.astype(jnp.int32), jnp.arange(P + 1, dtype=jnp.int32)
     )
-    within = jnp.arange(cap, dtype=jnp.int32) - jnp.where(
-        dsorted < P, offsets[jnp.clip(dsorted, 0, P - 1)], 0
-    ).astype(jnp.int32)
+    counts = jnp.diff(offsets)
+    cols = {
+        n: jnp.pad(c, ((0, B),) + ((0, 0),) * (c.ndim - 1))
+        for n, c in zip(names, carried)
+    }
+    return cols, offsets, jnp.minimum(counts, B), jnp.any(counts > B)
 
-    in_range = (dsorted < P) & (within < B)
-    overflow = jnp.any((dsorted < P) & (within >= B))
-    return sb, dsorted, within, in_range, overflow
+
+def _bucket_block(cols, offsets, ships, p, B: int):
+    """The ``(B, ...)`` block a column of bucket ``p`` (static or
+    traced) and its ``valid``: the first ``B`` rows of the run, zeros
+    behind them, which is slot for slot what a scatter to
+    ``position in bucket`` over a zeroed block leaves."""
+    live = jnp.arange(B, dtype=jnp.int32) < ships[p]
+    blocks = {}
+    for name, col in cols.items():
+        blk = jax.lax.dynamic_slice_in_dim(col, offsets[p], B)
+        keep = live.reshape((B,) + (1,) * (col.ndim - 1))
+        blocks[name] = jnp.where(keep, blk, jnp.zeros((), col.dtype))
+    return blocks, live
 
 
 def exchange(
@@ -136,21 +153,15 @@ def exchange(
     """
     P, B = num_partitions, bucket_cap
     with jax.named_scope(LAYOUT_SCOPE):
-        sb, dsorted, within, in_range, overflow = _bucket_layout(
-            batch, dest, P, B
-        )
-        flat_idx = jnp.where(in_range, dsorted * B + within, P * B)
-
-        send = {}
-        for name, col in sb.data.items():
-            buf = jnp.zeros((P * B,) + col.shape[1:], col.dtype)
-            send[name] = buf.at[flat_idx].set(col, mode="drop").reshape((P, B) + col.shape[1:])
-        send_valid = (
-            jnp.zeros((P * B,), jnp.bool_)
-            .at[flat_idx]
-            .set(sb.valid & in_range, mode="drop")
-            .reshape(P, B)
-        )
+        cols, offsets, ships, overflow = _bucket_layout(batch, dest, P, B)
+        buckets = [
+            _bucket_block(cols, offsets, ships, p, B) for p in range(P)
+        ]
+        send = {
+            name: jnp.stack([blocks[name] for blocks, _ in buckets])
+            for name in cols
+        }
+        send_valid = jnp.stack([live for _, live in buckets])
 
     with jax.named_scope(COLLECTIVE_SCOPE):
         recv = {
@@ -197,33 +208,16 @@ def exchange_staged(
     assert P == schedule.num_partitions == D * ici
 
     with jax.named_scope(LAYOUT_SCOPE):
-        sb, dsorted, within, in_range, overflow = _bucket_layout(
-            batch, dest, P, B
-        )
+        cols, offsets, ships, overflow = _bucket_layout(batch, dest, P, B)
 
     me = jax.lax.axis_index(axis_name)  # flattened, slice-major
     md, mp = me // ici, me % ici
 
     out = {
         name: jnp.zeros((P * B,) + col.shape[1:], col.dtype)
-        for name, col in sb.data.items()
+        for name, col in cols.items()
     }
     out_valid = jnp.zeros((P * B,), jnp.bool_)
-
-    def bucket_block(tgt):
-        """The (B, ...) block of rows destined for device ``tgt``."""
-        sel = in_range & (dsorted == tgt)
-        idx = jnp.where(sel, within, B)
-        blocks = {}
-        for name, col in sb.data.items():
-            buf = jnp.zeros((B,) + col.shape[1:], col.dtype)
-            blocks[name] = buf.at[idx].set(col, mode="drop")
-        bv = (
-            jnp.zeros((B,), jnp.bool_)
-            .at[idx]
-            .set(sb.valid & sel, mode="drop")
-        )
-        return blocks, bv
 
     def place(blocks, bv, src):
         start = (src * B).astype(jnp.int32)
@@ -234,9 +228,9 @@ def exchange_staged(
             )
         return jax.lax.dynamic_update_slice(out_valid, bv, (start,))
 
-    # Local bucket: zero network bytes, scatter straight into my slot.
+    # Local bucket: zero network bytes, sliced straight into my slot.
     with jax.named_scope(LAYOUT_SCOPE):
-        blocks, bv = bucket_block(me)
+        blocks, bv = _bucket_block(cols, offsets, ships, me, B)
         out_valid = place(blocks, bv, me)
 
     for rnd in schedule.rounds:
@@ -248,7 +242,7 @@ def exchange_staged(
             tgt = ((md + sd) % D) * ici + (mp + sp) % ici
             src = ((md - sd) % D) * ici + (mp - sp) % ici
             with jax.named_scope(LAYOUT_SCOPE):
-                blocks, bv = bucket_block(tgt)
+                blocks, bv = _bucket_block(cols, offsets, ships, tgt, B)
             with jax.named_scope(COLLECTIVE_SCOPE):
                 blocks = {
                     name: jax.lax.ppermute(blk, axis_name, perm)

@@ -40,7 +40,7 @@ class ExchangeRound:
 
     ``hops`` are ``(sd, sp)`` offset pairs — slice offset and
     intra-slice offset — never including the local ``(0, 0)`` hop,
-    which ships zero network bytes and is scattered in place.
+    which ships zero network bytes and is placed where it stands.
     """
 
     index: int

@@ -11,7 +11,6 @@ from dryad_tpu.columnar.batch import ColumnBatch
 from dryad_tpu.columnar.schema import ColumnType, Schema
 from dryad_tpu.ops.hash import partition_ids
 from dryad_tpu.ops.segmented import AggSpec, group_reduce
-from dryad_tpu.ops import shuffle as SH
 from dryad_tpu.ops import sort as SORT
 from dryad_tpu.ops.shuffle import (
     bucket_capacity,
@@ -124,7 +123,9 @@ def test_resize_shrink_and_overflow():
 # ``lax.sort`` as on the TPU, or gathered by a sorted row index as on
 # the CPU), every slot of the output, the invalid ones included, holds
 # what ``batch.take(order)`` put there before PR 25, and the overflow
-# flag is the same.
+# flag is the same.  Since PR 43 the exchange reads its send buffers out
+# of the sorted rows by position; the form that scattered them there
+# (``_scatter_exchange``) is the oracle both exchange forms are held to.
 
 _P, _CAP = 8, 64
 _B = bucket_capacity(_CAP, _P, 1.5)  # 12: a bucket fills in the all-valid batch only
@@ -132,7 +133,8 @@ _B = bucket_capacity(_CAP, _P, 1.5)  # 12: a bucket fills in the all-valid batch
 
 def _take_order_layout(batch, dest, P, B):
     """``_bucket_layout`` as it was before PR 25: sort ``(dest, iota)``,
-    then one gather a column by the sorted ``iota``."""
+    then one gather a column by the sorted ``iota``; the histogram and
+    each row's position in its bucket as they were through PR 42."""
     cap = batch.capacity
     dest = jnp.where(batch.valid, dest, P)
     dsorted, order = jax.lax.sort(
@@ -149,6 +151,29 @@ def _take_order_layout(batch, dest, P, B):
     in_range = (dsorted < P) & (within < B)
     overflow = jnp.any((dsorted < P) & (within >= B))
     return sb, dsorted, within, in_range, overflow
+
+
+def _scatter_exchange(batch, dest, P, B, axis_name):
+    """The oracle: ``exchange`` as it stood through PR 42, every row
+    scattered to ``dest * B + position in bucket`` of a zeroed send
+    buffer.  ``exchange_staged`` promises the same bytes."""
+    sb, dsorted, within, in_range, overflow = _take_order_layout(
+        batch, dest, P, B
+    )
+    flat_idx = jnp.where(in_range, dsorted * B + within, P * B)
+
+    def ship(col, fill):
+        buf = jnp.zeros((P * B,) + col.shape[1:], col.dtype)
+        buf = buf.at[flat_idx].set(fill, mode="drop")
+        return jax.lax.all_to_all(
+            buf.reshape((P, B) + col.shape[1:]), axis_name,
+            split_axis=0, concat_axis=0, tiled=True,
+        ).reshape(buf.shape)
+
+    recv = {name: ship(col, col) for name, col in sb.data.items()}
+    recv_valid = ship(sb.valid, sb.valid & in_range)
+    overflow = jax.lax.psum(overflow.astype(jnp.int32), axis_name) > 0
+    return ColumnBatch(recv, recv_valid), overflow
 
 
 def _take_order_compact(batch):
@@ -178,19 +203,34 @@ def _permuted_batch(kind):
     return ColumnBatch(data, jnp.asarray(valid))
 
 
-def _run_exchange(mesh, batch, staged):
+def _staged_exchange(batch, dest, P, B, axis_name):
+    return exchange_staged(
+        batch, dest, P, B, (axis_name,), plan_exchange(P, 2, 1)
+    )
+
+
+_PATHS = {"exchange": exchange, "exchange_staged": _staged_exchange}
+
+
+def _exchange_stage(fn, to=None, B=_B):
+    """A stage that runs exchange form *fn*: every row to partition
+    *to*, or by the hash of ``k``."""
+
     def stage(sharded, _):
         (b,) = sharded
         dest = partition_ids([b["k"]], _P)
-        if staged:
-            out, ovf = exchange_staged(
-                b, dest, _P, _B, (AXIS,), plan_exchange(_P, 2, 1)
-            )
-        else:
-            out, ovf = exchange(b, dest, _P, _B, AXIS)
+        if to is not None:
+            dest = jnp.full_like(dest, to)
+        out, ovf = fn(b, dest, _P, B, AXIS)
         return (out,), (ovf,)
 
-    (out,), (ovf,) = compile_stage(mesh, stage)((batch,), ())
+    return stage
+
+
+def _run_exchange(mesh, batch, fn, to=None, B=_B):
+    (out,), (ovf,) = compile_stage(mesh, _exchange_stage(fn, to, B))(
+        (batch,), ()
+    )
     return out, bool(ovf)
 
 
@@ -207,20 +247,39 @@ _RESIZE_TO = {"resize_equal": _P * _CAP, "resize_shrink": 100,
               "resize_grow": _P * _CAP + 40}
 
 
-@pytest.mark.parametrize("kind", ["all_valid", "half_valid", "none_valid", "col2d"])
+# Exchange-only cases: kind -> (batch, where every row goes (None: by
+# hash), B, whether rows drop).  A shard holds ``_CAP`` rows.
+_EXCHANGE_CASES = {
+    # every row to the LAST bucket, which overflows
+    "last_dest_overflows": ("all_valid", _P - 1, _B, True),
+    "col2d_last_dest": ("col2d", _P - 1, _B, True),
+    # the valid rows all to one bucket in the middle
+    "one_dest": ("half_valid", 3, _B, True),
+    # B == capacity: ``offsets[p] + B`` passes the end of the sorted
+    # rows for every bucket but the first, where an unpadded
+    # ``dynamic_slice`` would clamp its start
+    "bucket_is_capacity": ("all_valid", None, _CAP, False),
+}
+_KINDS = ["all_valid", "half_valid", "none_valid", "col2d"]
+
+
 @pytest.mark.parametrize(
-    "path", ["exchange", "exchange_staged", "compact", *_RESIZE_TO]
+    "path,kind",
+    [(p, k) for k in _KINDS
+     for p in ["exchange", "exchange_staged", "compact", *_RESIZE_TO]]
+    + [(p, k) for k in _EXCHANGE_CASES for p in _PATHS],
 )
 @pytest.mark.parametrize("carry", [True, False], ids=["carry", "gather"])
 def test_permuted_batch_equals_take_order(mesh8, monkeypatch, carry, path, kind):
+    kind, to, B, drops = _EXCHANGE_CASES.get(
+        kind, (kind, None, _B, kind == "all_valid")  # rows drop at _B there
+    )
     batch = _permuted_batch(kind)
     monkeypatch.setattr(SORT, "_carry_profitable", lambda: carry)
-    if path.startswith("exchange"):
-        staged = path == "exchange_staged"
-        got, got_ovf = _run_exchange(mesh8, batch, staged)
-        monkeypatch.setattr(SH, "_bucket_layout", _take_order_layout)
-        want, want_ovf = _run_exchange(mesh8, batch, staged)
-        assert want_ovf == (kind == "all_valid")  # rows drop at _B there
+    if path in _PATHS:
+        got, got_ovf = _run_exchange(mesh8, batch, _PATHS[path], to, B)
+        want, want_ovf = _run_exchange(mesh8, batch, _scatter_exchange, to, B)
+        assert want_ovf == drops
     elif path == "compact":
         got, got_ovf = batch.compact(), None
         want, want_ovf = _take_order_compact(batch), None
@@ -236,12 +295,27 @@ def test_permuted_batch_equals_take_order(mesh8, monkeypatch, carry, path, kind)
     _assert_same_slots(got, want)
 
 
-def _gathers_by_result_rows(mesh, carry, monkeypatch):
-    """Leading dimension of every ``stablehlo.gather`` result that reads
-    a table of more than ``_P`` rows, in ``exchange`` + ``resize``
-    lowered over 1-D columns."""
+def _lowered(mesh, carry, monkeypatch, stage):
+    """*stage* lowered over the 1-D columns of a half-valid batch."""
     monkeypatch.setattr(SORT, "_carry_profitable", lambda: carry)
     batch = _permuted_batch("half_valid")
+    return compile_stage(mesh, stage).lower((batch,), ()).as_text()
+
+
+def _wide_gathers(text):
+    """Leading dimension of every ``stablehlo.gather`` result of more
+    than ``_P + 1`` rows (the bucket offsets' binary search reads
+    ``_P + 1`` of the sorted destinations a step)."""
+    found = re.findall(
+        r'stablehlo\.gather.*:\s*\(tensor<\d+[x>].*->\s*tensor<(\d+)[x>]', text
+    )
+    return [int(rows) for rows in found if int(rows) > _P + 1]
+
+
+def test_carried_exchange_and_resize_lower_to_no_column_gather(
+    mesh8, monkeypatch
+):
+    """With the carry forced, the program gathers no column."""
 
     def stage(sharded, _):
         (b,) = sharded
@@ -249,20 +323,21 @@ def _gathers_by_result_rows(mesh, carry, monkeypatch):
         out, o2 = resize(out, 2 * _CAP)
         return (out,), (o1 | o2,)
 
-    text = compile_stage(mesh, stage).lower((batch,), ()).as_text()
-    found = re.findall(
-        r'stablehlo\.gather.*:\s*\(tensor<(\d+)[x>].*->\s*tensor<(\d+)[x>]',
-        text,
-    )
-    return [int(rows) for table, rows in found if int(table) > _P]
-
-
-def test_carried_exchange_and_resize_lower_to_no_column_gather(
-    mesh8, monkeypatch
-):
-    """With the carry forced, the program gathers no column: the one
-    ``gather`` left reads the ``_P`` bucket offsets."""
     capacities = {_CAP, _P * _B}
-    gathered = _gathers_by_result_rows(mesh8, False, monkeypatch)
+    gathered = _wide_gathers(_lowered(mesh8, False, monkeypatch, stage))
     assert capacities <= set(gathered), gathered  # the check can see them
-    assert _gathers_by_result_rows(mesh8, True, monkeypatch) == []
+    assert _wide_gathers(_lowered(mesh8, True, monkeypatch, stage)) == []
+
+
+@pytest.mark.parametrize("path", list(_PATHS))
+def test_exchange_lowers_to_slices_not_scatters(mesh8, monkeypatch, path):
+    """A send buffer is sliced out of the destination-sorted rows (PR
+    43): with the carry forced, neither exchange form scatters anything
+    or gathers more than the ``_P + 1`` run starts; the oracle does
+    both, so the check can see them."""
+    oracle = _lowered(mesh8, True, monkeypatch, _exchange_stage(_scatter_exchange))
+    assert "stablehlo.scatter" in oracle and _wide_gathers(oracle)
+    text = _lowered(mesh8, True, monkeypatch, _exchange_stage(_PATHS[path]))
+    assert "stablehlo.scatter" not in text
+    assert "stablehlo.dynamic_slice" in text
+    assert _wide_gathers(text) == []

@@ -301,14 +301,18 @@ def test_exchange_carry_on_chip(jaxmod):
     dest = rng.integers(0, 3, n).astype(np.int32)
     batch = ColumnBatch(
         {c: jnp.asarray(a) for c, a in tbl.items()}, jnp.asarray(valid))
-    sb, dsorted, within, _, overflow = jax.jit(
+    cols, offsets, counts, overflow = jax.jit(
         lambda b, d: SH._bucket_layout(b, d, 3, n))(batch, jnp.asarray(dest))
     order = np.argsort(np.where(valid, dest, 3), kind="stable")
-    np.testing.assert_array_equal(np.asarray(dsorted), np.where(valid, dest, 3)[order])
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(dest[valid], minlength=3))
+    np.testing.assert_array_equal(
+        np.asarray(offsets), np.concatenate([[0], np.cumsum(np.asarray(counts))]))
     for name in ("k", "v"):
+        assert cols[name].shape == (2 * n,)  # B more slots than the batch
         np.testing.assert_array_equal(
-            np.asarray(sb.data[name])[: valid.sum()], tbl[name][order][: valid.sum()])
-    assert not bool(overflow) and int(np.asarray(within)[0]) == 0
+            np.asarray(cols[name])[: valid.sum()], tbl[name][order][: valid.sum()])
+    assert not bool(overflow)
     packed, dropped = jax.jit(lambda b: SH.resize(b, n // 2))(batch)
     assert bool(dropped) and packed.capacity == n // 2
     np.testing.assert_array_equal(
