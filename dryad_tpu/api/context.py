@@ -12,13 +12,9 @@ the GraphExecutor (replacing the GraphManager process tree).
 
 from __future__ import annotations
 
-import gc
 import itertools
-import math
 import os
 import time
-import weakref
-from collections import OrderedDict
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -34,11 +30,18 @@ from dryad_tpu.columnar.schema import (
 )
 from dryad_tpu.exec.events import EventLog
 from dryad_tpu.exec.executor import GraphExecutor
+from dryad_tpu.exec.inputs import (
+    ChunkStream,
+    DeviceTable,
+    HostTable,
+    Inputs,
+    PhysicalTable,
+    StoreParts,
+)
 from dryad_tpu.obs import flightrec, tracectx
 from dryad_tpu.obs.span import Tracer
 from dryad_tpu.obs.diagnose import DiagnosisEngine
 from dryad_tpu.rewrite.controller import RewriteController
-from dryad_tpu.parallel import distribute as D
 from dryad_tpu.parallel.mesh import make_mesh, num_partitions
 from dryad_tpu.plan.lower import lower
 from dryad_tpu.plan.nodes import Node, PartitionInfo
@@ -74,15 +77,6 @@ def _word_vocab(distinct) -> np.ndarray:
     keep = np.ones(len(v), bool)
     keep[1:] = v[1:] != v[:-1]
     return v[keep]
-
-
-def _forget_binding(ctx_ref, node_id: int) -> None:
-    """Finalizer of an input node whose host table the context made
-    itself: nothing can reach the table once its node is gone."""
-    ctx = ctx_ref()
-    if ctx is not None:
-        ctx._bindings.pop(node_id, None)
-        ctx._binding_fp_cache.pop(node_id, None)
 
 
 def _infer_schema(arrays: Dict[str, np.ndarray]) -> Schema:
@@ -130,28 +124,12 @@ class DryadContext:
         self.config.validate()
         self.local_debug = local_debug
         self.dictionary = StringDictionary()
-        self._bindings: Dict[int, tuple] = {}
-        # True once any from_stream binding exists: the fast gate for
-        # the per-collect stream check (has_stream_input)
-        self._any_stream = False
+        # what every input node is bound to, and all the context keeps
+        # of a table between jobs (exec.inputs.Inputs)
+        self.inputs = Inputs(self)
         # Column-name -> TypeCodec for custom user types (the
         # IDryadLinqSerializer hook, columnar/codecs.py).
         self._codecs: Dict[str, object] = {}
-        self._binding_fp_cache: Dict[int, Optional[str]] = {}
-        # Device-resident ingest cache: input node id -> (binding tuple
-        # the batch was ingested from, sharded batch, bytes), LRU by
-        # insertion order (see config.device_cache_bytes).  The stored
-        # binding identity self-invalidates the entry when a binding is
-        # rebound (worker _run_part rebinds per-part slices on a reused
-        # context); in-place mutation of arrays passed to from_arrays is
-        # NOT tracked — inputs snapshot at first execution.
-        self._device_cache: "OrderedDict[int, tuple]" = OrderedDict()
-        # an ingest has handed host arrays to device_put and no fetch
-        # has let go of them since (see _release_ingested)
-        self._ingest_unreleased = False
-        # the host memory every ingest lays its table out in, warm from
-        # the job before (parallel.distribute.StagingPool)
-        self.staging = D.StagingPool()
         self.diagnosis: Optional[DiagnosisEngine] = None
         self.rewriter = None
         # Continuous telemetry plane (obs.telemetry): the tap-paced
@@ -271,12 +249,7 @@ class DryadContext:
         from dryad_tpu.parallel.mesh import exclude_devices
 
         self.mesh = exclude_devices(self.mesh, exclude_device_ids)
-        self._bindings = {
-            nid: b for nid, b in self._bindings.items() if b[0] != "device"
-        }
-        # Cached ingests are sharded over the OLD mesh — drop them.
-        self._device_cache.clear()
-        self.staging.clear()
+        self.inputs.remesh()
         self.executor = GraphExecutor(
             self.mesh, self.config, self.events,
             subquery_runner=self._run_subquery,
@@ -290,8 +263,7 @@ class DryadContext:
         alone: the staging pool's host memory and the device-resident
         ingest cache.  The context stays usable; its next job ingests
         again, into newly mapped memory."""
-        self._device_cache.clear()
-        self.staging.clear()
+        self.inputs.close()
 
     # -- ingestion ----------------------------------------------------------
     def from_arrays(
@@ -356,7 +328,7 @@ class DryadContext:
             "input", [], schema, PartitionInfo.roundrobin(),
             source="host", col_stats=col_stats, str_vocab=str_vocab,
         )
-        self._bindings[node.id] = ("host", arrays, partition_capacity)
+        self.inputs.bind(node, HostTable(arrays, partition_capacity))
         return Query(self, node)
 
     def append_arrays(
@@ -373,12 +345,12 @@ class DryadContext:
         the append (None when unfingerprintable) — the invalidation
         key for any result cached against the old contents."""
         node = query.node
-        binding = self._bindings.get(node.id)
-        if node.kind != "input" or binding is None or binding[0] != "host":
+        binding = self.inputs.get(node.id)
+        if node.kind != "input" or not isinstance(binding, HostTable):
             raise ValueError(
                 "append_arrays() takes a from_arrays table; got a "
                 f"{node.kind!r} node bound as "
-                f"{binding[0] if binding else None!r}"
+                f"{binding.kind if binding else None!r}"
             )
         if self._codecs and any(c in self._codecs for c in arrays):
             from dryad_tpu.columnar.codecs import expand_arrays
@@ -387,13 +359,13 @@ class DryadContext:
                 arrays, {c: self._codecs[c] for c in arrays
                          if c in self._codecs}
             )
-        _, old_arrays, cap = binding
+        old_arrays = binding.arrays
         if set(arrays) != set(old_arrays):
             raise ValueError(
                 f"append columns {sorted(arrays)} != table columns "
                 f"{sorted(old_arrays)}"
             )
-        old_fp = self._binding_fp(node)
+        old_fp = self.inputs.fingerprint(node.id)
         merged = {}
         for name, old in old_arrays.items():
             old = np.asarray(old)
@@ -432,9 +404,7 @@ class DryadContext:
                         min(lo, int(a.min())), max(hi, int(a.max()))
                     )
             node.params["col_stats"] = stats
-        self._bindings[node.id] = ("host", merged, cap)
-        self._binding_fp_cache.pop(node.id, None)
-        self._device_cache.pop(node.id, None)
+        self.inputs.rebind(node.id, HostTable(merged, binding.cap))
         return old_fp
 
     def _tokenize_buf(self, buf: bytes):
@@ -513,17 +483,13 @@ class DryadContext:
             "input", [], schema, PartitionInfo.roundrobin(),
             source="host_physical", str_vocab={column: vocab},
         )
-        self._bindings[node.id] = (
-            "host_physical",
-            {f"{column}#h0": h0, f"{column}#h1": h1,
-             f"{column}#r0": r0, f"{column}#r1": r1},
-        )
         # the columns are the context's own copy of the text: they go
         # when no query can reach the node any more (a derived query
         # holds it through ``inputs``)
-        weakref.finalize(
-            node, _forget_binding, weakref.ref(self), node.id
-        ).atexit = False
+        self.inputs.bind(node, PhysicalTable({
+            f"{column}#h0": h0, f"{column}#h1": h1,
+            f"{column}#r0": r0, f"{column}#r1": r1,
+        }), owned=True)
         return Query(self, node)
 
     def from_stream(self, chunks, schema: Optional[Schema] = None) -> Query:
@@ -551,8 +517,7 @@ class DryadContext:
         node = Node(
             "input", [], schema, PartitionInfo.roundrobin(), source="stream"
         )
-        self._bindings[node.id] = ("stream", ChunkSource(it, schema))
-        self._any_stream = True
+        self.inputs.bind(node, ChunkStream(ChunkSource(it, schema)))
         return Query(self, node)
 
     def text_stream(
@@ -660,7 +625,7 @@ class DryadContext:
                 for f in schema.fields if f.ctype is ColumnType.STRING
             },
         )
-        self._bindings[node.id] = ("store", parts, schema)
+        self.inputs.bind(node, StoreParts(parts, schema))
         return Query(self, node)
 
     def _from_device_batch(
@@ -673,7 +638,7 @@ class DryadContext:
             "input", [], schema, partition or PartitionInfo(),
             source="device",
         )
-        self._bindings[node.id] = ("device", batch)
+        self.inputs.bind(node, DeviceTable(batch))
         return Query(self, node)
 
     def release(self, query: Query) -> None:
@@ -682,136 +647,19 @@ class DryadContext:
         stale-binding error rather than recomputing silently.  Only
         device-bound input queries qualify — releasing a source table
         or a derived query is a caller bug, surfaced loudly."""
-        binding = self._bindings.get(query.node.id)
+        binding = self.inputs.get(query.node.id)
         cached_marker = query.node.params.get("cached")  # local_debug pin
         if (
             query.node.kind != "input"
             or binding is None
-            or (binding[0] != "device" and not cached_marker)
+            or not (isinstance(binding, DeviceTable) or cached_marker)
         ):
             raise ValueError(
                 "release() takes the query returned by cache(); got a "
                 f"{query.node.kind!r} node bound as "
-                f"{binding[0] if binding else None!r}"
+                f"{binding.kind if binding else None!r}"
             )
-        del self._bindings[query.node.id]
-        self._device_cache.pop(query.node.id, None)
-
-    # -- execution ----------------------------------------------------------
-    def _bind_device(self, node: Node) -> ColumnBatch:
-        if node.id not in self._bindings:
-            raise RuntimeError(
-                f"input node {node.id} has no binding: its device-"
-                "resident table was dropped (rebuild_mesh clears cached "
-                "tables; release() drops them explicitly) — re-run "
-                ".cache() or re-ingest"
-            )
-        kind, *rest = self._bindings[node.id]
-        if kind == "device":
-            return rest[0]
-        binding = self._bindings[node.id]
-        budget = self.config.device_cache_bytes
-        if budget and node.id in self._device_cache:
-            src, batch, _ = self._device_cache[node.id]
-            if src is binding:  # rebound nodes miss (stale entry)
-                self._device_cache.move_to_end(node.id)
-                return batch
-            del self._device_cache[node.id]
-        with self.tracer.span("bind", cat="ingest", node=node.id):
-            batch = self._ingest_binding(kind, rest, node)
-        self._ingest_unreleased = True
-        if budget:
-            nbytes = sum(
-                a.size * a.dtype.itemsize for a in batch.data.values()
-            ) + batch.valid.size
-            self._device_cache[node.id] = (binding, batch, nbytes)
-            total = sum(e[2] for e in self._device_cache.values())
-            while total > budget and len(self._device_cache) > 1:
-                _, (_, _, freed) = self._device_cache.popitem(last=False)
-                total -= freed
-        return batch
-
-    def _ingest_binding(self, kind, rest, node: Node) -> ColumnBatch:
-        if kind == "host":
-            arrays, cap = rest
-            return D.from_host_table(
-                node.schema, arrays, self.mesh,
-                partition_capacity=cap, dictionary=self.dictionary,
-                tracer=self.tracer, metrics=self.executor.metrics,
-                pool=self.staging,
-            )
-        if kind == "host_physical":
-            phys, *opt = rest
-            cap = opt[0] if opt else None
-            return D.from_physical_table(
-                phys, self.mesh, partition_capacity=cap,
-                tracer=self.tracer, metrics=self.executor.metrics,
-                pool=self.staging,
-            )
-        if kind == "store":
-            parts, schema = rest
-            P = num_partitions(self.mesh)
-
-            # Fold store partitions onto mesh partitions (store partition
-            # i concatenates into mesh partition i % P) so a store written
-            # on a larger mesh loses nothing on a smaller one.
-            folded: list = [[] for _ in range(P)]
-            for i, cols in enumerate(parts):
-                folded[i % P].append(cols)
-            rows_per = [
-                sum(len(next(iter(c.values()))) if c else 0 for c in group)
-                for group in folded
-            ]
-            cap = math.ceil(max(max(rows_per, default=1), 1) / 8) * 8
-
-            def fill(out) -> None:
-                for p, group in enumerate(folded):
-                    at = p * cap
-                    for cols in group:
-                        n = len(next(iter(cols.values()))) if cols else 0
-                        for c, col in out.items():
-                            col[at : at + n] = cols[c]
-                        at += n
-
-            return D.lay_out(
-                schema.device_dtypes(), rows_per, cap, fill, self.mesh,
-                tracer=self.tracer, metrics=self.executor.metrics,
-                pool=self.staging,
-            )
-        if kind == "stream":
-            raise RuntimeError(
-                "a chunk-stream input cannot bind as a device table; "
-                "this operator needs the whole input resident (e.g. "
-                "cache/apply) — materialize with to_store() first"
-            )
-        raise RuntimeError(f"unknown binding kind {kind}")
-
-    def _binding_fp(self, node: Node):
-        """Content SHA-1 of a plan-input binding (checkpoint identity);
-        None for device-resident bindings, which can't be fingerprinted
-        without a host transfer.  Cached per input node."""
-        if node.id in self._binding_fp_cache:
-            return self._binding_fp_cache[node.id]
-        from dryad_tpu.exec.checkpoint import content_fingerprint
-
-        kind, *rest = self._bindings[node.id]
-        fp = None
-        if kind == "host":
-            arrays, cap = rest
-            fp = content_fingerprint({str(k): np.asarray(v) for k, v in arrays.items()}) + f":{cap}"
-        elif kind == "host_physical":
-            phys, *opt = rest
-            fp = content_fingerprint(phys) + (
-                f":{opt[0]}" if opt else ""
-            )
-        elif kind == "store":
-            parts, schema = rest
-            merged = {
-                f"p{i}/{c}": v for i, cols in enumerate(parts) for c, v in cols.items()
-            }
-            fp = content_fingerprint(merged)
-        self._binding_fp_cache[node.id] = fp
-        return fp
+        self.inputs.forget(query.node.id)
 
     # -- serving-tier surface ----------------------------------------------
     def is_stream_query(self, query: Query) -> bool:
@@ -837,7 +685,7 @@ class DryadContext:
         queries.  The output is identified by its stage's POSITION in
         the lowered graph (stage ids are fresh per lowering and would
         defeat every repeat).  Ingest content is the per-binding SHA-1
-        fingerprint (``_binding_fp``) of every plan input, in plan
+        fingerprint (``Inputs.fingerprint``) of every plan input, in plan
         creation order."""
         if self.local_debug or self.is_stream_query(query):
             return None
@@ -847,7 +695,7 @@ class DryadContext:
         )
         fps = []
         for nid in sorted(graph.inputs):
-            fp = self._binding_fp(graph.inputs[nid])
+            fp = self.inputs.fingerprint(nid)
             if fp is None:
                 return None
             fps.append(fp)
@@ -868,23 +716,9 @@ class DryadContext:
                 continue
             seen.add(node.id)
             stack.extend(node.inputs)
-            binding = self._bindings.get(node.id)
-            if binding is None:
-                continue
-            kind, *rest = binding
-            if kind == "host":
-                arrays, _cap = rest
-                total += sum(np.asarray(v).nbytes for v in arrays.values())
-            elif kind == "host_physical":
-                phys = rest[0]
-                total += sum(np.asarray(v).nbytes for v in phys.values())
-            elif kind == "store":
-                parts, _schema = rest
-                total += sum(
-                    np.asarray(v).nbytes
-                    for cols in parts
-                    for v in cols.values()
-                )
+            binding = self.inputs.get(node.id)
+            if binding is not None:
+                total += binding.host_bytes()
         return total
 
     def _execute_roots(self, queries, defer_miss: bool = False):
@@ -902,12 +736,12 @@ class DryadContext:
             )
             span.add(stages=len(graph.stages), roots=len(queries))
         bindings = {
-            nid: self._bind_device(n) for nid, n in graph.inputs.items()
+            nid: self.inputs.device_batch(n) for nid, n in graph.inputs.items()
         }
         binding_fps = None
         if self.config.checkpoint_dir:
             binding_fps = {
-                nid: self._binding_fp(n) for nid, n in graph.inputs.items()
+                nid: self.inputs.fingerprint(nid) for nid in graph.inputs
             }
         results = self.executor.execute(
             graph, bindings, binding_fps, defer_miss=defer_miss
@@ -1005,7 +839,7 @@ class DryadContext:
         # deferred check still raises before any result reaches the
         # caller.
         batches, deferred = self._execute_roots(queries, defer_miss=True)
-        self._release_ingested()
+        self.inputs.release()
         tables = []
         for i, query in enumerate(queries):
             # which answer it is, on every span of its fetch
@@ -1016,7 +850,7 @@ class DryadContext:
                 # the answer's device arrays, and what jax cached on them
                 with self.tracer.span("drop", cat="readback"):
                     batches[i] = deferred = None
-        self._release_ingested(done=True)
+        self.inputs.release(done=True)
         return tables
 
     def _fetch_table(self, query: Query, batch, deferred=None, done=True):
@@ -1026,12 +860,12 @@ class DryadContext:
         slice a shard where the fetch measured the batch and found no
         hole, and the mask's otherwise.  ``done``: this call is a fetch
         of its own, so it lets go of what the job ingested before and
-        after (:meth:`_release_ingested`; ``_run_device_job`` says no:
+        after (``Inputs.release``; ``_run_device_job`` says no:
         it does so itself, once before its first output's fetch and
         once after its last's drop)."""
         metrics = self.executor.metrics if self.executor is not None else None
         if done:
-            self._release_ingested()
+            self.inputs.release()
         if deferred is not None:
             valid, host_cols, rows = _fetch_with_miss(
                 batch, deferred, self.tracer, metrics
@@ -1078,40 +912,8 @@ class DryadContext:
         with self.tracer.span("drop", cat="readback"):
             del valid, host_cols
         if done:
-            self._release_ingested(done=True)
+            self.inputs.release(done=True)
         return table
-
-    def _release_ingested(self, done: bool = False) -> None:
-        """Let go of what an ingest handed to ``device_put``, in the
-        job that made it.  jax keeps the source array of every
-        ``device_put`` alive until its copy is done and cannot drop it
-        from the runtime's thread: the array joins a list that the next
-        call into jax on the calling thread, or the next Python
-        collection (jax hooks ``gc.callbacks``, jax issue 14882),
-        empties (``PythonRefManager::CollectGarbage``).  One
-        generation-0 collection empties the list under a ``release``
-        span, twice a job that ingested: before the fetch, when a stage
-        that was waited for has used every copy (a job that dispatched
-        and did not wait finds nothing to drop yet), and when the job
-        is ``done``, the answer on the host, for what is left.
-
-        The sources are views of the staging pool's arenas
-        (``parallel.distribute.StagingPool``), so what goes here is
-        the views: the memory stays mapped for the next table (through
-        PR 35 a table's worth of arrays was unmapped here, 25 - 31 ms
-        for 302 MB; PERF.md section 6, PR 31, 34 and 36).  The second
-        collection still lets go of what the runtime held of the
-        ANSWER's host copies (``drop`` in :meth:`_run_device_job`), which
-        are the user's and no pool's, so they are unmapped inside the
-        span and not at the next job's first call into jax; and it
-        trims the pool to the arenas this job's ingests used."""
-        if not self._ingest_unreleased:
-            return
-        self._ingest_unreleased = not done
-        with self.tracer.span("release", cat="ingest"):
-            gc.collect(0)
-            if done:
-                self.staging.trim()
 
     def run_to_host_async(self, query: Query):
         """Dispatch the device job NOW; return a zero-arg ``fetch``
@@ -1260,7 +1062,7 @@ class DryadContext:
             q0 = self._from_device_batch(current, schema)
             cached[cache_key] = (q0.node.id, plan_fn(q0))
         input_node_id, out_q = cached[cache_key]
-        self._bindings[input_node_id] = ("device", current)
+        self.inputs.rebind(input_node_id, DeviceTable(current))
         if scalar:
             # The cond output is ROW-SHARDED (its one valid row lives on
             # one partition); in a multi-controller gang a plain host

@@ -59,6 +59,7 @@ from dryad_tpu.exec.failure import (
     RetryPolicy,
     classify,
 )
+from dryad_tpu.exec.inputs import HostTable, RoutedTable, StoreParts
 from dryad_tpu.exec.jobpackage import pack_query
 from dryad_tpu.exec.stats import StageStatistics
 from dryad_tpu.obs import flightrec, tracectx
@@ -1904,10 +1905,10 @@ class LocalJobSubmission:
             cur = cur.inputs[0]
         if cur.kind != "input":
             return None
-        b = ctx._bindings.get(cur.id)
-        if not b or b[0] != "host":
+        b = ctx.inputs.get(cur.id)
+        if not isinstance(b, HostTable):
             return None
-        return cur, b[1]
+        return cur, b.arrays
 
     def _route_for_vertices(self, gate_node, ctx, nparts):
         """Driver-side routing that makes a shuffle-bearing plan
@@ -1917,7 +1918,7 @@ class LocalJobSubmission:
         ``DrDynamicRangeDistributor.cpp:28-100`` executed at the
         driver).  On the vertex's one-device mesh the plan's exchanges
         are identity, so each vertex computes exactly its partition of
-        the answer.  Returns ``(kind, {input_node_id: host_routed
+        the answer.  Returns ``(kind, {input_node_id: RoutedTable
         binding})`` or None when the plan shape doesn't qualify."""
         from dryad_tpu.exec.outofcore import (
             _host_hash_buckets,
@@ -1987,10 +1988,8 @@ class LocalJobSubmission:
         offsets = np.concatenate(
             [[0], np.cumsum(counts)]
         ).astype(np.int64)
-        return (
-            "host_routed",
-            {k: np.asarray(v)[order] for k, v in arrays.items()},
-            offsets,
+        return RoutedTable(
+            {k: np.asarray(v)[order] for k, v in arrays.items()}, offsets
         )
 
     # mergeable builtin aggregates for the partial-vertex rewrite
@@ -2022,15 +2021,14 @@ class LocalJobSubmission:
         if any(op == "first" for op, _c, _o in agg_list):
             # "first" merges by part-id-concat order, which equals
             # engine order only for HOST bindings (np.array_split is
-            # contiguous); slice_binding deals STORE partitions
+            # contiguous); StoreParts.part deals STORE partitions
             # round-robin, where that order diverges from
             # submit()/collect() — refuse rather than return an
             # nparts-dependent answer (code-review r4).
             from dryad_tpu.plan.nodes import walk as _walk
 
             for nd in _walk([node]):
-                b = query.ctx._bindings.get(nd.id)
-                if b and b[0] == "store":
+                if isinstance(query.ctx.inputs.get(nd.id), StoreParts):
                     return None
         if node.kind == "group_by":
             inner = Query(query.ctx, node.inputs[0])
@@ -2245,20 +2243,9 @@ class LocalJobSubmission:
 
         rows = 0
         for n in walk([query.node]):
-            b = query.ctx._bindings.get(n.id)
-            if not b:
-                continue
-            kind, *rest = b
-            if kind in ("host", "host_physical"):
-                arrays = rest[0]
-                rows += max(
-                    (len(np.asarray(v)) for v in arrays.values()), default=0
-                )
-            elif kind == "store":
-                parts = rest[0]
-                rows += sum(
-                    len(next(iter(c.values()))) if c else 0 for c in parts
-                )
+            b = query.ctx.inputs.get(n.id)
+            if b is not None:
+                rows += b.rows()
         per = max(query.ctx.config.rows_per_vertex, 1)
         fanout = max(self.n, -(-rows // per))
         return min(fanout, self.n * 8)
@@ -2273,10 +2260,10 @@ class LocalJobSubmission:
         from dryad_tpu.plan.nodes import walk
 
         for n in walk([query.node]):
-            b = query.ctx._bindings.get(n.id)
-            if not b or b[0] != "host":
+            b = query.ctx.inputs.get(n.id)
+            if not isinstance(b, HostTable):
                 continue
-            arrays = b[1]
+            arrays = b.arrays
             for f in n.schema.fields:
                 if f.ctype is ColumnType.STRING and f.name in arrays:
                     for s in np.unique(np.asarray(arrays[f.name], object)):

@@ -75,8 +75,18 @@ class _PackageCache:
             os.unlink(pkg_path)
         self.key = rel
         self.query = q
-        self.pristine = dict(q.ctx._bindings)
+        self.pristine = q.ctx.inputs.snapshot()
         return q, self.pristine
+
+
+def _bind_part(q, pristine: Dict, part: int, nparts: int) -> None:
+    """Rebind every input of the package to its part ``part`` of
+    ``nparts`` (``Inputs.restore`` drops each node's fingerprint and
+    device entry: a stale part-0 fingerprint would make checkpointing
+    restore part 0 for every part)."""
+    q.ctx.inputs.restore(
+        {nid: b.part(part, nparts) for nid, b in pristine.items()}
+    )
 
 
 def _run_part(cmd: Dict, args, client, pkgs: _PackageCache,
@@ -90,15 +100,10 @@ def _run_part(cmd: Dict, args, client, pkgs: _PackageCache,
 
     from dryad_tpu.cluster.partcache import content_fp
     from dryad_tpu.columnar.io import write_partition_file
-    from dryad_tpu.exec.jobpackage import slice_binding
 
     q, pristine = pkgs.load(cmd["package"], client)
     part, nparts = int(cmd["part"]), int(cmd["nparts"])
-    for nid, binding in pristine.items():
-        q.ctx._bindings[nid] = slice_binding(binding, part, nparts)
-    # rebinding invalidates cached binding fingerprints — a stale part-0
-    # fingerprint would make checkpointing restore part 0 for every part
-    q.ctx._binding_fp_cache.clear()
+    _bind_part(q, pristine, part, nparts)
     batch = q.ctx._execute_device(q)
     valid = np.asarray(batch.valid)
     cols = {c: np.asarray(v)[valid] for c, v in batch.data.items()}
@@ -234,19 +239,13 @@ def _run_coded(cmd: Dict, args, client, pkgs: _PackageCache) -> Dict:
     shard's work; a parity vertex pays the full-support redundancy
     work that buys any-k-of-n reconstruction."""
     from dryad_tpu.columnar.io import write_partition_file
-    from dryad_tpu.exec.jobpackage import slice_binding
     from dryad_tpu.exec.partial import coded_combine
 
     q, pristine = pkgs.load(cmd["package"], client)
     nparts = int(cmd["nparts"])
     tables = []
     for part in cmd["parts"]:
-        for nid, binding in pristine.items():
-            q.ctx._bindings[nid] = slice_binding(
-                binding, int(part), nparts
-            )
-        # stale fingerprints would restore another part's checkpoint
-        q.ctx._binding_fp_cache.clear()
+        _bind_part(q, pristine, int(part), nparts)
         batch = q.ctx._execute_device(q)
         tables.append(batch.to_numpy(q.schema, q.ctx.dictionary))
     combined = coded_combine(

@@ -6,7 +6,8 @@ captured by lambdas (``LinqToDryad/DryadLinqObjectStore.cs:173``,
 resource staging ``DryadLinqQueryGen.cs:950-955``).  The TPU-native
 equivalent: the logical plan IS Python objects, so a job package is one
 pickle blob holding the node DAG, the input bindings (host tables /
-store partitions), the string dictionary, and the config.  A remote
+store partitions, as ``exec.inputs`` types them), the string
+dictionary, and the config.  A remote
 driver process (or a ControlPlane worker told the package path over the
 mailbox) loads and executes it against its own mesh.
 
@@ -22,6 +23,7 @@ from __future__ import annotations
 import pickle
 from typing import Any, Dict, Optional
 
+from dryad_tpu.exec.inputs import Binding
 from dryad_tpu.plan.nodes import fresh_id, walk
 
 try:
@@ -29,35 +31,27 @@ try:
 except ImportError:  # pragma: no cover - cloudpickle present in-tree
     _pickler = pickle
 
-PACKAGE_VERSION = 1
+PACKAGE_VERSION = 2  # 2: bindings are exec.inputs types, not tuples
 
 
 def pack_query(
-    query, path: str, binding_overrides: Optional[Dict[int, tuple]] = None
+    query, path: str, binding_overrides: Optional[Dict[int, Binding]] = None
 ) -> Dict[str, Any]:
     """Serialize a lazy Query (plan + reachable input bindings +
     dictionary + config) to ``path``.  Returns the manifest summary.
 
     ``binding_overrides``: node id -> replacement binding shipped in
-    place of the context's (the driver-routed ``host_routed`` layouts
+    place of the context's (the driver-routed ``RoutedTable`` layouts
     of co-partitioned vertex submissions) — the live context's
     bindings stay untouched."""
     ctx = query.ctx
     nodes = walk([query.node])
-    bindings: Dict[int, tuple] = {}
+    bindings: Dict[int, Binding] = {}
     overrides = binding_overrides or {}
     for n in nodes:
-        if n.id in overrides:
-            bindings[n.id] = overrides[n.id]
-            continue
-        if n.id in ctx._bindings:
-            kind = ctx._bindings[n.id][0]
-            if kind == "device":
-                raise ValueError(
-                    "cannot pack a query over device-resident bindings; "
-                    "materialize to host or a store first"
-                )
-            bindings[n.id] = ctx._bindings[n.id]
+        binding = overrides.get(n.id) or ctx.inputs.get(n.id)
+        if binding is not None:
+            bindings[n.id] = binding.packed()
     blob = {
         "version": PACKAGE_VERSION,
         "node": query.node,
@@ -109,52 +103,10 @@ def load_query(path: str, ctx=None, mesh=None):
     remap: Dict[int, int] = {}
     for n in walk([blob["node"]]):
         remap[n.id] = n.id = fresh_id()
-    ctx._bindings.update(
+    ctx.inputs.restore(
         {remap[i]: b for i, b in blob["bindings"].items() if i in remap}
     )
     return Query(ctx, blob["node"])
-
-
-def slice_binding(binding: tuple, part: int, nparts: int) -> tuple:
-    """Restrict one packed input binding to vertex-task ``part`` of
-    ``nparts`` — the per-vertex input channel of the reference's
-    independent-vertex execution model (a ``DrStorageVertex`` holds one
-    input partition, ``GraphManager/vertex/DrVertex.h:146``).  Host rows
-    split into ``nparts`` contiguous blocks; store partitions deal
-    round-robin.  The union over parts is exactly the full input."""
-    import numpy as np
-
-    kind, *rest = binding
-    if kind == "host":
-        arrays, _cap = rest
-        return (
-            "host",
-            {k: np.array_split(np.asarray(v), nparts)[part]
-             for k, v in arrays.items()},
-            None,
-        )
-    if kind == "host_routed":
-        # driver-routed layout: rows pre-ordered by key bucket, part p
-        # owns [offsets[p], offsets[p+1]) — the co-partitioned input
-        # channels of a routed join/sort vertex submission
-        arrays, offsets = rest
-        lo, hi = int(offsets[part]), int(offsets[part + 1])
-        return (
-            "host",
-            {k: np.asarray(v)[lo:hi] for k, v in arrays.items()},
-            None,
-        )
-    if kind == "host_physical":
-        phys, *opt = rest
-        return (
-            "host_physical",
-            {k: np.array_split(np.asarray(v), nparts)[part]
-             for k, v in phys.items()},
-        )
-    if kind == "store":
-        parts, schema = rest
-        return ("store", parts[part::nparts], schema)
-    raise ValueError(f"cannot slice binding kind {kind!r}")
 
 
 def run_package(path: str, ctx=None):

@@ -96,33 +96,13 @@ class LocalDebugInterpreter:
 
     # -- inputs -------------------------------------------------------------
     def _n_input(self, node: Node) -> Table:
-        if node.id not in self.ctx._bindings:
+        binding = self.ctx.inputs.get(node.id)
+        if binding is None:
             raise RuntimeError(
                 f"input node {node.id} has no binding: the cached table "
                 "was released — re-run .cache() or re-ingest"
             )
-        kind, *rest = self.ctx._bindings[node.id]
-        if kind == "host":
-            arrays, _cap = rest
-            n = _rows({k: np.asarray(v) for k, v in arrays.items()})
-            b = ColumnBatch.from_numpy(
-                node.schema, arrays, capacity=max(n, 1),
-                dictionary=self.ctx.dictionary,
-            )
-            valid = np.asarray(b.valid)
-            return {k: np.asarray(v)[valid] for k, v in b.data.items()}
-        if kind == "store":
-            parts, _schema = rest
-            out: Table = {}
-            for c in parts[0].keys():
-                out[c] = np.concatenate([p[c] for p in parts])
-            return out
-        if kind == "host_physical":
-            (phys,) = rest
-            return {k: np.asarray(v) for k, v in phys.items()}
-        if kind == "table":  # bound by do_while recursion
-            return rest[0]
-        raise RuntimeError(f"localdebug: unsupported input binding {kind}")
+        return binding.table(node.schema, self.ctx.dictionary)
 
     # -- row-wise -----------------------------------------------------------
     def _n_select(self, node: Node) -> Table:
@@ -590,6 +570,7 @@ class LocalDebugInterpreter:
     # -- iteration -------------------------------------------------------------
     def _n_do_while(self, node: Node) -> Table:
         from dryad_tpu.api.query import Query
+        from dryad_tpu.exec.inputs import LoopTable
         from dryad_tpu.plan.nodes import Node as N, PartitionInfo
 
         current = self._in(node)
@@ -597,13 +578,13 @@ class LocalDebugInterpreter:
         cond = node.params["cond"]
         for _ in range(node.params.get("max_iter", 100)):
             inp = N("input", [], node.schema, PartitionInfo(), source="table")
-            self.ctx._bindings[inp.id] = ("table", current)
+            self.ctx.inputs.bind(inp, LoopTable(current))
             sub = LocalDebugInterpreter(self.ctx)
             out_q = body(Query(self.ctx, inp))
             current = sub.run(out_q.node)
 
             inp2 = N("input", [], node.schema, PartitionInfo(), source="table")
-            self.ctx._bindings[inp2.id] = ("table", current)
+            self.ctx.inputs.bind(inp2, LoopTable(current))
             sub2 = LocalDebugInterpreter(self.ctx)
             cond_q = cond(Query(self.ctx, inp2))
             cond_t = sub2.run(cond_q.node)

@@ -50,6 +50,7 @@ from dryad_tpu.exec.partial import (
 )
 from dryad_tpu.exec.failure import JobFailedError, StageFailedError
 from dryad_tpu.exec.faults import InjectedFault
+from dryad_tpu.exec.inputs import ChunkStream, HostTable, PhysicalTable
 from dryad_tpu.exec.pipeline import DispatchWindow, prefetched
 from dryad_tpu.exec.spill import SpillDir, SpillWriter
 from dryad_tpu.obs import telemetry
@@ -217,20 +218,19 @@ class _IngestScope:
 
         ctx = self.ctx
         node = q.node
-        binding = ctx._bindings.get(node.id)
+        binding = ctx.inputs.get(node.id)
         if binding is None:
             return q
         slot = self._slot_counter % self.slots
         self._slot_counter += 1
-        key = (self.cap, binding[0], slot)
+        key = (self.cap, binding.kind, slot)
         cached = self._cached_input.get(key)
         if cached is not None and cached[0] == self.version:
             cnode = cached[1]
-            # adopt the fresh chunk's binding under the cached node id;
-            # the content fingerprint is per-binding, so drop the stale
-            # cached one (checkpoint identity must follow the data)
-            ctx._bindings[cnode.id] = ctx._bindings.pop(node.id)
-            ctx._binding_fp_cache.pop(cnode.id, None)
+            # adopt the fresh chunk's binding under the cached node id
+            # (the move drops the id's stale fingerprint and device
+            # entry: checkpoint identity must follow the data)
+            ctx.inputs.move(node.id, cnode.id)
             # refresh the cached node's vocabulary metadata in place: a
             # within-tier widen reuses the node (and every chain/compiled
             # program built on it) but the NEXT lowering must code
@@ -267,7 +267,7 @@ class _IngestScope:
             source="host_physical",
             str_vocab={c: v.copy() for c, v in self.vocab.items()},
         )
-        ctx._bindings[node.id] = ("host_physical", table, self.cap)
+        ctx.inputs.bind(node, PhysicalTable(table, self.cap))
         return Query(ctx, node)
 
 
@@ -401,7 +401,7 @@ class StreamNotSupported(NotImplementedError):
 
 
 def has_stream_input(ctx, root: Node) -> bool:
-    if not getattr(ctx, "_any_stream", False):
+    if not ctx.inputs.any_stream:
         return False  # context never created a stream binding
     return bool(stream_reaching_ids(ctx, root))
 
@@ -411,8 +411,7 @@ def stream_reaching_ids(ctx, root: Node) -> set:
     in ONE topological walk (consulted per node during evaluation)."""
     ids: set = set()
     for n in walk([root]):
-        b = ctx._bindings.get(n.id)
-        if (b is not None and b[0] == "stream") or any(
+        if isinstance(ctx.inputs.get(n.id), ChunkStream) or any(
             i.id in ids for i in n.inputs
         ):
             ids.add(n.id)
@@ -671,8 +670,8 @@ class StreamExecutor:
         per-chunk jobs reuse the same binding instead of recomputing."""
         if node.id in self._small_nodes:
             return self._small_nodes[node.id]
-        if node.kind == "input" and self.ctx._bindings.get(node.id, ("",))[0] in (
-            "host", "host_physical",
+        if node.kind == "input" and isinstance(
+            self.ctx.inputs.get(node.id), (HostTable, PhysicalTable)
         ):
             self._small_nodes[node.id] = node  # already a cheap binding
             return node
@@ -746,9 +745,9 @@ class StreamExecutor:
         return node.id in self._stream_ids
 
     def _eval_inner(self, node: Node):
-        b = self.ctx._bindings.get(node.id)
-        if node.kind == "input" and b is not None and b[0] == "stream":
-            src: ChunkSource = b[1]
+        b = self.ctx.inputs.get(node.id)
+        if node.kind == "input" and isinstance(b, ChunkStream):
+            src: ChunkSource = b.source
             self._emit("stream_start", node=node.id)
             return "stream", _Stream(
                 src.schema, iter(src.chunks), _state=src.state

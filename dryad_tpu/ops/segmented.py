@@ -196,20 +196,7 @@ def group_reduce(
 
     Output batch holds one row per distinct key (rows 0..nseg-1 valid):
     the key columns plus one column per AggSpec.
-
-    Two strategies share this entry point:
-    - the per-agg path (segment_sum + shared start-position count
-      scatter) — the default;
-    - :func:`group_reduce_fused` (env ``DRYAD_TPU_SORT_FUSED=1``): one
-      multi-channel flagged scan + ONE stacked u32 scatter-set for
-      every output, attacking the one-random-access-op-per-output-
-      column floor.  Never timed on a chip: ROADMAP S3 flips the
-      default or deletes it.
     """
-    import os
-
-    if os.environ.get("DRYAD_TPU_SORT_FUSED") == "1":  # graftlint: disable=kernel-determinism -- opt-in experiment hatch, off by default; constant within a run
-        return group_reduce_fused(batch, key_cols, aggs)
     sb, v, start, seg, nseg = _segment_layout(batch, key_cols)
     return _segmented_fold(sb, v, start, seg, nseg, key_cols, aggs)
 
@@ -285,213 +272,6 @@ def _segmented_fold(
             out[a.out] = _first_scatter(col, start, seg, cap)
         else:
             raise ValueError(f"unknown agg op {a.op!r}")
-
-    valid = jnp.arange(cap, dtype=jnp.int32) < nseg
-    return ColumnBatch(out, valid)
-
-
-def _bitcast_u32(arr: jax.Array) -> jax.Array:
-    if arr.dtype == jnp.bool_:
-        return arr.astype(jnp.uint32)
-    if arr.dtype == jnp.uint32:
-        return arr
-    return jax.lax.bitcast_convert_type(arr, jnp.uint32)
-
-
-def _bitcast_from_u32(arr: jax.Array, dtype) -> jax.Array:
-    if dtype == jnp.bool_:
-        return arr.astype(jnp.bool_)
-    if dtype == jnp.uint32:
-        return arr
-    return jax.lax.bitcast_convert_type(arr, dtype)
-
-
-def group_reduce_fused(
-    batch: ColumnBatch,
-    key_cols: Sequence[str],
-    aggs: Sequence[AggSpec],
-) -> ColumnBatch:
-    """Sort-path group reduce with ONE multi-channel flagged scan and
-    ONE stacked u32 scatter-set for every output column.
-
-    The per-agg path pays one cap-sized random-access op per output
-    column.  Here every aggregate that needs per-segment state rides
-    a single segmented ``associative_scan`` (channels grouped by
-    combine kind and dtype), counts come free from last-row POSITIONS
-    (adjacent differences — segments are contiguous after the sort),
-    and all outputs (keys, aggregates, positions) bitcast to uint32
-    and land in one ``(cap, C)`` scatter-set at the segment-last rows.
-    """
-    cap = batch.capacity
-    sb, v, start, seg, nseg = _segment_layout(batch, key_cols)
-    nxt_start = jnp.concatenate([start[1:], jnp.array([True])])
-    nxt_valid = jnp.concatenate([v[1:], jnp.array([False])])
-    last = v & (nxt_start | ~nxt_valid)
-
-    # ---- scan channels, grouped so one combine handles a whole stack
-    elem_groups: Dict[Tuple[str, str], List[Tuple[str, jax.Array]]] = {}
-    pair_groups: Dict[str, List[Tuple[str, jax.Array, jax.Array]]] = {}
-
-    def elem(kind: str, name: str, arr: jax.Array) -> None:
-        elem_groups.setdefault((kind, str(arr.dtype)), []).append(
-            (name, arr)
-        )
-
-    post: List[Tuple[AggSpec, str]] = []  # (agg, channel name) finalize
-    need_count = any(a.op in ("count", "mean") for a in aggs)
-    for a in aggs:
-        if a.op == "count":
-            continue
-        if a.op in PAIR_OPS:
-            lo_col = a.col
-            hi_col = lo_col[: -len("#h0")] + "#h1"
-            pair_groups.setdefault(a.op, []).append(
-                (a.out, sb.data[lo_col], sb.data[hi_col])
-            )
-            continue
-        col = sb.data[a.col]
-        if a.op == "sum":
-            elem("sum", a.out, col)
-        elif a.op == "mean":
-            elem("sum", a.out, col.astype(jnp.float32))
-        elif a.op == "min":
-            elem("min", a.out, col)
-        elif a.op == "max":
-            elem("max", a.out, col)
-        elif a.op == "any":
-            elem("max", a.out, col.astype(jnp.int32))
-        elif a.op == "all":
-            elem("min", a.out, col.astype(jnp.int32))
-        elif a.op == "first":
-            elem("first", a.out, col)
-        else:
-            raise ValueError(f"unknown agg op {a.op!r}")
-        post.append((a, a.out))
-
-    ekeys = sorted(elem_groups)
-    pkeys = sorted(pair_groups)
-    scanned_elem: Dict[Tuple[str, str], jax.Array] = {}
-    scanned_pair: Dict[str, Tuple[jax.Array, jax.Array]] = {}
-    if ekeys or pkeys:
-        estacks = [
-            jnp.stack([arr for _n, arr in elem_groups[k]], axis=1)
-            for k in ekeys
-        ]
-        pstacks = [
-            (
-                jnp.stack([lo for _n, lo, _h in pair_groups[k]], axis=1),
-                jnp.stack([hi for _n, _l, hi in pair_groups[k]], axis=1),
-            )
-            for k in pkeys
-        ]
-
-        def combine(a, b):
-            fa = a[0]
-            fb = b[0]
-            keep_b = fb[:, None]
-            out = [fa | fb]
-            at = 1
-            for (kind, _dt) in ekeys:
-                ea, eb = a[at], b[at]
-                if kind == "sum":
-                    m = ea + eb
-                elif kind == "min":
-                    m = jnp.minimum(ea, eb)
-                elif kind == "max":
-                    m = jnp.maximum(ea, eb)
-                else:  # first: keep the left (earlier) value
-                    m = ea
-                out.append(jnp.where(keep_b, eb, m))
-                at += 1
-            for k in pkeys:
-                (alo, ahi), (blo, bhi) = a[at], b[at]
-                mlo, mhi = _pair_combine(k)(alo, ahi, blo, bhi)
-                out.append((
-                    jnp.where(keep_b, blo, mlo),
-                    jnp.where(keep_b, bhi, mhi),
-                ))
-                at += 1
-            return tuple(out)
-
-        res = jax.lax.associative_scan(
-            combine, tuple([start] + estacks + pstacks)
-        )
-        for i, k in enumerate(ekeys):
-            scanned_elem[k] = res[1 + i]
-        for j, k in enumerate(pkeys):
-            scanned_pair[k] = res[1 + len(ekeys) + j]
-
-    # ---- ONE stacked scatter at segment-last rows
-    chans: List[jax.Array] = []
-    names: List[Tuple[str, Any]] = []  # (out name, dtype to restore)
-
-    for k in key_cols:  # keys are constant within a segment
-        chans.append(_bitcast_u32(sb.data[k]))
-        names.append((k, sb.data[k].dtype))
-    for gk in ekeys:
-        stack = scanned_elem[gk]
-        for i, (name, arr) in enumerate(elem_groups[gk]):
-            chans.append(_bitcast_u32(stack[:, i]))
-            names.append((f"#chan/{gk[0]}/{name}", arr.dtype))
-    for pk in pkeys:
-        slo, shi = scanned_pair[pk]
-        for i, (name, _lo, _hi) in enumerate(pair_groups[pk]):
-            chans.append(slo[:, i])
-            names.append((f"{name}#h0", jnp.uint32))
-            chans.append(shi[:, i])
-            names.append((f"{name}#h1", jnp.uint32))
-    if need_count:
-        chans.append(
-            _bitcast_u32(jnp.arange(cap, dtype=jnp.int32))
-        )
-        names.append(("#chan/pos", jnp.int32))
-
-    stacked = jnp.stack(chans, axis=1)  # (cap, C)
-    # non-last rows take an OUT-OF-RANGE index and drop: a shared
-    # in-range sentinel would serialize ~cap same-address writes
-    idx = jnp.where(last, seg, cap + 1)
-    out2d = (
-        jnp.zeros((cap + 1, stacked.shape[1]), jnp.uint32)
-        .at[idx]
-        .set(stacked, mode="drop")[:cap]
-    )
-
-    fetched: Dict[str, jax.Array] = {}
-    for i, (name, dtype) in enumerate(names):
-        fetched[name] = _bitcast_from_u32(out2d[:, i], dtype)
-
-    out: Dict[str, jax.Array] = {k: fetched[k] for k in key_cols}
-    seg_count = None
-    if need_count:
-        pos_last = fetched["#chan/pos"]
-        prev = jnp.concatenate(
-            [jnp.array([-1], jnp.int32), pos_last[: cap - 1]]
-        )
-        seg_count = pos_last - prev
-
-    for a in aggs:
-        if a.op == "count":
-            out[a.out] = seg_count
-        elif a.op in PAIR_OPS:
-            out[f"{a.out}#h0"] = fetched[f"{a.out}#h0"]
-            out[f"{a.out}#h1"] = fetched[f"{a.out}#h1"]
-        elif a.op == "sum":
-            out[a.out] = fetched[f"#chan/sum/{a.out}"]
-        elif a.op == "mean":
-            s = fetched[f"#chan/sum/{a.out}"]
-            out[a.out] = s / jnp.maximum(
-                seg_count.astype(jnp.float32), 1.0
-            )
-        elif a.op == "min":
-            out[a.out] = fetched[f"#chan/min/{a.out}"]
-        elif a.op == "max":
-            out[a.out] = fetched[f"#chan/max/{a.out}"]
-        elif a.op == "any":
-            out[a.out] = fetched[f"#chan/max/{a.out}"].astype(jnp.bool_)
-        elif a.op == "all":
-            out[a.out] = fetched[f"#chan/min/{a.out}"].astype(jnp.bool_)
-        elif a.op == "first":
-            out[a.out] = fetched[f"#chan/first/{a.out}"]
 
     valid = jnp.arange(cap, dtype=jnp.int32) < nseg
     return ColumnBatch(out, valid)

@@ -34,6 +34,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from dryad_tpu.api.decomposable import delta_fold_reason
+from dryad_tpu.exec.inputs import ChunkStream, HostTable
 from dryad_tpu.exec.partial import (
     copy_physical,
     merge_state_rows,
@@ -106,17 +107,17 @@ def _eligibility(ctx, query):
             f"pre-aggregation operator {src.kind!r} between ingest and "
             "group_by; register the bare aggregation"
         )
-    binding = ctx._bindings.get(src.id)
+    binding = ctx.inputs.get(src.id)
     if binding is None:
         raise ViewIneligible("input binding was released")
-    if binding[0] == "stream":
+    if isinstance(binding, ChunkStream):
         raise ViewIneligible(
             "stream inputs re-drain their chunks; no resident table to "
             "fold deltas into"
         )
-    if binding[0] != "host":
+    if not isinstance(binding, HostTable):
         raise ViewIneligible(
-            f"{binding[0]!r}-bound input has no append path (views fold "
+            f"{binding.kind!r}-bound input has no append path (views fold "
             "host deltas)"
         )
     tail.reverse()
@@ -354,9 +355,7 @@ class MaterializedView:
         self.snap_ts = time.monotonic()
         self.snapshots_finalized += 1
         if node_id is not None and ctx is not None:
-            ctx._bindings.pop(node_id, None)
-            ctx._binding_fp_cache.pop(node_id, None)
-            ctx._device_cache.pop(node_id, None)
+            ctx.inputs.forget(node_id)
 
     def stats(self) -> Dict:
         return {
@@ -458,8 +457,7 @@ class ViewRegistry:
             self.fallbacks += 1
             self._emit("view_fallback", reason=e.reason, tenant=tenant)
             raise
-        _kind, arrays, _cap = self.ctx._bindings[src_node.id]
-        rows, _ = view.fold_delta(arrays)
+        rows, _ = view.fold_delta(self.ctx.inputs.get(src_node.id).arrays)
         self._views[(tenant, view.root_id)] = view
         self._emit(
             "view_register", tenant=tenant, view=view.name, rows=rows,

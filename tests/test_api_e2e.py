@@ -305,9 +305,13 @@ def test_device_ingest_cache_reuse_and_eviction(rng):
     ctx = DryadContext(num_partitions_=8)
     q = ctx.from_arrays(tbl)
     a = q.group_by("k", {"c": ("count", None)}).collect()
-    cached = ctx._device_cache[q.node.id][1]
+    assert ctx.inputs.holds(q.node.id)[2]
+    cached = ctx.inputs.device_batch(q.node)
+    encodes = [e for e in ctx.events.events() if e.get("name") == "encode"]
     b = q.group_by("k", {"s": ("count", None)}).collect()
-    assert ctx._device_cache[q.node.id][1] is cached  # reused, not re-ingested
+    # reused, not re-ingested
+    assert ctx.inputs.device_batch(q.node) is cached
+    assert encodes == [e for e in ctx.events.events() if e.get("name") == "encode"]
     assert sorted(a["k"].tolist()) == sorted(b["k"].tolist())
 
     small = DryadContext(
@@ -316,14 +320,16 @@ def test_device_ingest_cache_reuse_and_eviction(rng):
     q1 = small.from_arrays(tbl)
     q2 = small.from_arrays({"k": np.arange(512, dtype=np.int32)})
     q1.count(); q2.count()
-    assert len(small._device_cache) == 1  # budget of 1 byte keeps only newest
+    # budget of 1 byte keeps only newest
+    assert (small.inputs.holds(q1.node.id)[2], small.inputs.holds(q2.node.id)[2]) == (
+        False, True)
 
     off = DryadContext(
         num_partitions_=8, config=DryadConfig(device_cache_bytes=0)
     )
     q3 = off.from_arrays(tbl)
     q3.count()
-    assert len(off._device_cache) == 0
+    assert not off.inputs.holds(q3.node.id)[2]
 
 
 def test_device_cache_invalidated_on_rebinding(rng):
@@ -331,17 +337,16 @@ def test_device_cache_invalidated_on_rebinding(rng):
     must MISS the device cache — a stale part-0 ingest served for every
     part would duplicate rows (code-review regression)."""
     from dryad_tpu import DryadContext
-    from dryad_tpu.exec.jobpackage import slice_binding
 
     ctx = DryadContext(num_partitions_=8)
     k = np.arange(64, dtype=np.int32)
     q = ctx.from_arrays({"k": k})
-    pristine = dict(ctx._bindings)
+    pristine = ctx.inputs.snapshot()
     seen = []
     for part in range(2):
-        for nid, binding in pristine.items():
-            ctx._bindings[nid] = slice_binding(binding, part, 2)
-        ctx._binding_fp_cache.clear()
+        ctx.inputs.restore(
+            {nid: b.part(part, 2) for nid, b in pristine.items()}
+        )
         out = q.collect()
         seen.append(sorted(out["k"].tolist()))
     assert seen[0] == list(range(32))

@@ -443,7 +443,7 @@ def test_the_job_that_ingested_lets_go_of_its_host_arrays(how, monkeypatch):
     answer's arrays); a requery, which copied nothing, has none."""
     collected = []
     monkeypatch.setattr(
-        "dryad_tpu.api.context.gc.collect", lambda gen: collected.append(gen)
+        "dryad_tpu.exec.inputs.gc.collect", lambda gen: collected.append(gen)
     )
     ctx = DryadContext(num_partitions_=1)
     q = ctx.from_arrays({"k": np.arange(64, dtype=np.int32)}).order_by(["k"])

@@ -163,7 +163,7 @@ def test_a_second_table_is_warm_or_the_buffers_were_given_up(P, kind):
     ctx = DryadContext(num_partitions_=P)
     q1 = ctx.from_arrays(first, schema=schema).order_by([key])
     a1 = q1.collect()
-    held = ctx.staging.held_bytes()
+    held = ctx.inputs.staging.held_bytes()
     a2 = ctx.from_arrays(second, schema=schema).order_by([key]).collect()
     (e1, e2) = spans(ctx, "encode")
     assert e1["warm_bytes"] == 0 and e1["bytes_out"] == e2["bytes_out"]
@@ -298,9 +298,9 @@ def test_two_tables_in_flight_both_answer_right(P, cache_bytes):
     encodes = spans(ctx, "encode")
     assert len(encodes) == 9 and encodes[0]["warm_bytes"] == 0
     # what one job staged, and no more, outlives it
-    assert ctx.staging.held_bytes() <= 3 * encodes[0]["bytes_out"]
+    assert ctx.inputs.staging.held_bytes() <= 3 * encodes[0]["bytes_out"]
     # every program has run, so every copy is done and its array let go of
-    assert all(a.landed() and a.sent_to is None for a in ctx.staging._idle)
+    assert all(a.landed() and a.sent_to is None for a in ctx.inputs.staging._idle)
 
 
 def test_threads_share_the_pool_and_no_buffer_is_handed_out_twice():
@@ -376,21 +376,20 @@ def test_close_and_rebuild_mesh_leave_the_pool_empty_and_nothing_pins_a_device_a
     table = plain(np.random.default_rng(11), 4096)
     q = ctx.from_arrays(table).order_by(["k"])
     answer = q.collect()
-    (entry,) = ctx._device_cache.values()
-    ref = weakref.ref(entry[1].data["k"])
-    del entry
+    assert ctx.inputs.holds(q.node.inputs[0].id)[2]
+    ref = weakref.ref(ctx.inputs.device_batch(q.node.inputs[0]).data["k"])
     assert ref() is not None
     # the pool held the device arrays until their copies were done, the
     # job's end at the latest: with the device cache's entry gone nothing
     # of the context keeps the array
-    ctx._device_cache.clear()
+    ctx.inputs.evict()
     gc.collect()
     assert ref() is None
     ctx.close()
-    assert ctx.staging.held_bytes() == 0 and not ctx.staging._idle
+    assert ctx.inputs.staging.held_bytes() == 0 and not ctx.inputs.staging._idle
     np.testing.assert_array_equal(q.collect()["k"], answer["k"])  # still usable
     ctx.rebuild_mesh([jax.devices()[3].id])
-    assert ctx.staging.held_bytes() == 0
+    assert ctx.inputs.staging.held_bytes() == 0
     np.testing.assert_array_equal(
         ctx.from_arrays(table).order_by(["k"]).collect()["k"], answer["k"])
 

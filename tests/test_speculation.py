@@ -265,7 +265,7 @@ def test_partitioned_group_by_first_merges_engine_order(submission):
 
 def test_store_backed_first_refuses_partial_merge(submission, tmp_path):
     """'first' over a STORE-backed input must not partial-merge:
-    slice_binding deals store partitions round-robin, so part-id-concat
+    StoreParts.part deals store partitions round-robin, so part-id-concat
     order is not engine order there (code-review r4)."""
     src = DryadContext(num_partitions_=1)
     src.from_arrays(

@@ -131,8 +131,9 @@ def ingest(monkeypatch, buf: bytes, native: bool, cuts=None):
 
 
 def check(ctx, query, span, want: dict) -> None:
-    kind, phys = ctx._bindings[query.node.id]
-    assert kind == "host_physical"
+    binding = ctx.inputs.get(query.node.id)
+    assert binding.kind == "host_physical"
+    phys = binding.arrays
     for name, col in zip(("word#h0", "word#h1", "word#r0", "word#r1"), want["cols"]):
         assert phys[name].dtype == np.uint32
         assert phys[name].tobytes() == col.tobytes(), name
@@ -226,9 +227,9 @@ def test_text_stream_chunks_carry_their_vocabulary(mesh8, tmp_path):
     path.write_bytes(text)
     ctx = DryadContext(num_partitions_=8)
     query = ctx.text_stream(str(path), chunk_bytes=1 << 12)
-    kind, source = ctx._bindings[query.node.id]
-    assert kind == "stream"
-    chunks = list(source.chunks)
+    binding = ctx.inputs.get(query.node.id)
+    assert binding.kind == "stream"
+    chunks = list(binding.source.chunks)
     assert len(chunks) > 3
     whole = reference(text)
     for i, col in enumerate(("word#h0", "word#h1", "word#r0", "word#r1")):
@@ -253,7 +254,7 @@ TEXT = "to be or not to be that is the question " * 50
 
 
 def _held(ctx, node_id):
-    return node_id in ctx._bindings, node_id in ctx._binding_fp_cache
+    return ctx.inputs.holds(node_id)[:2]
 
 
 def test_a_dead_text_query_leaves_no_binding_and_no_fingerprint(mesh8):
@@ -261,13 +262,13 @@ def test_a_dead_text_query_leaves_no_binding_and_no_fingerprint(mesh8):
     q = ctx.from_text(TEXT)
     node_id = q.node.id
     assert len(q.collect()["word"]) == 500
-    ctx._binding_fp(q.node)
+    ctx.inputs.fingerprint(q.node.id)
     assert _held(ctx, node_id) == (True, True)
     del q
     gc.collect()
     assert _held(ctx, node_id) == (False, False)
     # nothing but the device cache's own bounded entry is left of it
-    assert [k for k in ctx._bindings] == []
+    assert ctx.inputs.snapshot() == {}
 
 
 def test_a_dead_query_that_never_ran_leaves_nothing(mesh8):
@@ -283,12 +284,12 @@ def test_a_live_derived_query_keeps_its_table(mesh8):
     node_id = q.node.id
     counts = q.group_by("word", {"n": ("count", None)}).order_by([("word", False)])
     first = counts.collect()
-    ctx._binding_fp(q.node)
+    ctx.inputs.fingerprint(q.node.id)
     del q
     gc.collect()
     assert _held(ctx, node_id) == (True, True)
     # re-ingested from the host columns, not from the device cache
-    ctx._device_cache.clear()
+    ctx.inputs.evict()
     again = counts.collect()
     assert first["word"].tolist() == again["word"].tolist()
     assert first["n"].tolist() == again["n"].tolist() == [100, 50, 50, 50, 50, 50, 50, 100]
@@ -313,11 +314,11 @@ def test_the_users_arrays_stay_bound_as_they_did(mesh8):
     q = ctx.from_arrays(table)
     node_id = q.node.id
     q.collect()
-    ctx._binding_fp(q.node)
+    ctx.inputs.fingerprint(q.node.id)
     del q
     gc.collect()
     assert _held(ctx, node_id) == (True, True)
-    assert ctx._bindings[node_id][1]["k"] is table["k"]
+    assert ctx.inputs.get(node_id).arrays["k"] is table["k"]
 
 
 def test_a_context_that_died_first_is_no_error(mesh8):
