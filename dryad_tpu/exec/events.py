@@ -58,6 +58,9 @@ EVENT_KINDS: Dict[str, str] = {
     "exchange_observed": "what a dispatch's exchanges saw, off the overflow "
                          "flag's readback; combine_rows_in/combine_rows_out/"
                          "recv_rows (a chip)/boost/overflows (of the job)",
+    "join_observed": "what a dispatch's join kernels saw, off the same "
+                     "readback; joins/pairs (candidate pairs in the pair "
+                     "buffers, a chip)/slots (out_capacity, a chip)",
     "dict_miss": "rows outside the dense key domain; stage_name/rows",
     # -- checkpointing (exec.checkpoint / executor) -----------------------
     "stage_checkpoint_hit": "stage served from the checkpoint store",
@@ -230,6 +233,9 @@ EVENT_PAYLOADS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         ("boost", "combine_rows_in", "combine_rows_out", "exchanges",
          "name", "overflows", "recv_rows", "stage"),
         ("qid",),
+    ),
+    "join_observed": (
+        ("joins", "name", "pairs", "slots", "stage"), ("qid",),
     ),
     "dict_miss": (("rows", "stage_name"), ()),
     "stage_checkpoint_hit": (("name", "stage"), ()),

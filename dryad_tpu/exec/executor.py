@@ -173,12 +173,12 @@ class _CompileTimed:
 
     __slots__ = (
         "fn", "_exec", "_name", "_key", "_build_s", "_pending",
-        "xchg_rounds", "join_plans", "sorted_words", "elided",
+        "xchg_rounds", "join_plans", "sorted_words", "elided", "seen_log",
     )
 
     def __init__(self, fn, executor, name, key_hash, build_s,
                  xchg_rounds=None, join_plans=None, sorted_words=None,
-                 elided=None):
+                 elided=None, seen_log=None):
         self.fn = fn
         self._exec = executor
         self._name = name
@@ -201,6 +201,11 @@ class _CompileTimed:
         # partition] (kernels._elided), filled at trace time too: the
         # ``xchg_elided`` stat of every ``dispatch`` span.
         self.elided = elided if elided is not None else []
+        # Of each array of the program's third replicated output, what
+        # only the trace knows (``kernels.StageContext.seen_log``): its
+        # ``kind`` (``exchange`` / ``join``) and the ``capacity`` a chip
+        # holds the received rows, or the pairs, in.
+        self.seen_log = seen_log if seen_log is not None else []
 
     @property
     def row_words(self) -> int:
@@ -447,12 +452,13 @@ class GraphExecutor:
             joins: List[Dict[str, Any]] = []
             sorts: List[int] = []
             elided: List[int] = []
+            seen_log: List[Dict[str, Any]] = []
             if isinstance(run_stage, FusedStage):
                 fn = build_fused_fn(
                     run_stage, self.P, self.config.shuffle_slack, boost,
                     axes, sizes, operand_objs=objs,
                     window=window, xchg_cell=cell, join_cell=joins,
-                    sort_cell=sorts, elided_cell=elided,
+                    sort_cell=sorts, elided_cell=elided, seen_cell=seen_log,
                 )
                 compiled = compile_fused(self.mesh, fn)
             else:
@@ -460,14 +466,14 @@ class GraphExecutor:
                     run_stage, self.P, self.config.shuffle_slack, boost,
                     axes, sizes, operand_objs=objs,
                     window=window, xchg_cell=cell, join_cell=joins,
-                    sort_cell=sorts, elided_cell=elided,
+                    sort_cell=sorts, elided_cell=elided, seen_cell=seen_log,
                 )
                 compiled = compile_stage(self.mesh, fn)
             hit = _CompileTimed(
                 compiled, self, run_stage.name,
                 _lowering_key_hash(key), time.monotonic() - t0,
                 xchg_rounds=cell, join_plans=joins, sorted_words=sorts,
-                elided=elided,
+                elided=elided, seen_log=seen_log,
             )
             self._compiled[key] = hit
         return hit
@@ -808,16 +814,23 @@ class GraphExecutor:
                 self.metrics.add("layout_rows", int(capacities[idx]))
 
     def _exchange_observed(self, drain, dispatches, overflowed) -> None:
-        """What the exchanges of the drained dispatches saw
-        (``kernels._observe_exchange``), already on the host: it rode
-        the readback of the overflow flag.  ``dispatches``: ``(stage,
-        boost, seen)`` each, ``seen`` one ``(3, P)`` array an exchange
-        (rows the combiner before it was handed, rows sent, rows
-        received; a column a chip).  One ``exchange_observed`` event a
-        dispatch that ran an exchange, summed over its exchanges, and
-        the sums over all of them onto the ``drain`` span, with the
-        highest boost among them and the ``stage_overflow`` events of
-        the job so far, this drain's own counted."""
+        """What the exchanges and join kernels of the drained dispatches
+        saw (``kernels._observe_exchange``, ``kernels._traced_join``),
+        already on the host: it rode the readback of the overflow flag.
+        ``dispatches``: ``(stage, boost, seen, seen_log)`` each, ``seen``
+        one array an exchange (``(3, P)``: rows the combiner before it
+        was handed, rows sent, rows received; a column a chip) or a join
+        (``(1, P)``: candidate pairs in the pair buffer), ``seen_log``
+        the trace's record of each (``kind``, ``capacity``).  One
+        ``exchange_observed`` event a dispatch that ran an exchange,
+        summed over its exchanges, and one ``join_observed`` a dispatch
+        that ran a join; onto the ``drain`` span the sums over all of
+        them, the highest boost among them, the ``stage_overflow``
+        events of the job so far (this drain's own counted), and beside
+        the sums, which an even exchange flattens, the worst single
+        exchange: ``recv_balance_max`` (fullest chip's rows x chips /
+        rows sent) and ``recv_fill_max`` (fullest chip's rows / the
+        capacity its ``resize`` leaves)."""
         self._job_overflows += int(overflowed)
 
         def fields(rows):  # of a (3, P) array of counts, a column a chip
@@ -827,27 +840,57 @@ class GraphExecutor:
                 recv_rows=[int(r) for r in rows[2]],
             )
 
-        ran = [
-            (stage, boost, len(seen),
-             sum(np.asarray(a, dtype=np.int64) for a in seen))
-            for stage, boost, seen in dispatches if seen
-        ]
-        for stage, boost, exchanges, rows in ran:
+        ran, joined = [], []
+        for stage, boost, seen, seen_log in dispatches:
+            of = {"exchange": [], "join": []}
+            for rows, said in zip(seen, seen_log):
+                of[said["kind"]].append(
+                    (np.asarray(rows, dtype=np.int64), said["capacity"])
+                )
+            if of["exchange"]:
+                ran.append((stage, boost, of["exchange"]))
+            if of["join"]:
+                joined.append((stage, of["join"]))
+        for stage, boost, exchanges in ran:
             self.events.emit(
                 "exchange_observed", stage=stage.id, name=stage.name,
-                exchanges=exchanges, boost=boost,
+                exchanges=len(exchanges), boost=boost,
                 overflows=self._job_overflows,
-                qid=tracectx.current_qid(), **fields(rows),
+                qid=tracectx.current_qid(),
+                **fields(sum(rows for rows, _ in exchanges)),
+            )
+        for stage, joins in joined:
+            self.events.emit(
+                "join_observed", stage=stage.id, name=stage.name,
+                joins=len(joins), slots=sum(slots for _, slots in joins),
+                pairs=[int(n) for n in sum(rows[0] for rows, _ in joins)],
+                qid=tracectx.current_qid(),
             )
         if ran:
-            total = fields(sum(rows for _, _, _, rows in ran))
+            each = [x for _, _, exchanges in ran for x in exchanges]
+            total = fields(sum(rows for rows, _ in each))
             # the list reaches the span's event; a profiler annotation
             # keeps numbers only, so its largest entry goes beside it
             drain.add(
-                exchanges=sum(n for _, _, n, _ in ran),
-                boost=max(boost for _, boost, _, _ in ran),
+                exchanges=len(each),
+                boost=max(boost for _, boost, _ in ran),
                 overflows=self._job_overflows,
                 recv_rows_max=max(total["recv_rows"]), **total,
+                recv_balance_max=max(
+                    float(rows[2].max() * len(rows[2]) / max(rows[1].sum(), 1))
+                    for rows, _ in each
+                ),
+            )
+            fills = [float(rows[2].max() / capacity)
+                     for rows, capacity in each if capacity]
+            if fills:  # an exchange no ``resize`` follows has no room to fill
+                drain.add(recv_fill_max=max(fills))
+        if joined:
+            each = [x for _, joins in joined for x in joins]
+            drain.add(
+                join_pairs=int(sum(rows.sum() for rows, _ in each)),
+                join_pairs_max=int(max(rows.max() for rows, _ in each)),
+                join_slots=sum(slots for _, slots in each),
             )
 
     def _adapt_fan_for(self, stage: Stage) -> Optional[int]:
@@ -980,7 +1023,7 @@ class GraphExecutor:
             )
             self._exchange_observed(
                 drain,
-                [(w["stage"], w["boost"], sv)
+                [(w["stage"], w["boost"], sv, w["seen_log"])
                  for w, sv in zip(window, seen_v)],
                 bool(combined_v),
             )
@@ -1302,6 +1345,7 @@ class GraphExecutor:
                             fp=fp, flag=overflow if can_overflow else None,
                             miss=dict_miss, outs=outs, t0=t0,
                             counts=counts_dev, seen=seen,
+                            seen_log=fn.seen_log,
                             fan=adapt_fan if boost < 4 else None,
                         ))
                         self.events.emit(
@@ -1326,7 +1370,8 @@ class GraphExecutor:
                             )
                             overflow = bool(overflow)
                             self._exchange_observed(
-                                drain, [(stage, boost, seen)], overflow
+                                drain, [(stage, boost, seen, fn.seen_log)],
+                                overflow
                             )
                         if host_counts is not None:
                             self._record_observed(

@@ -94,10 +94,15 @@ class StageContext:
         # decided at trace time; the executor emits each as a
         # ``join_plan`` event, once a compile.
         self.join_log: List[Dict[str, Any]] = []
-        # What each exchange of the trace saw (``_observe_exchange``):
-        # one replicated ``(3, P)`` int32 array an exchange, a third
-        # output of the stage fn beside the overflow flag.
-        self.xchg_seen: List[jax.Array] = []
+        # What the trace's exchanges and join kernels saw, a third
+        # output of the stage fn beside the overflow flag: one
+        # replicated int32 array each, a column a chip, ``(3, P)`` an
+        # exchange (``_observe_exchange``) and ``(1, P)`` a join
+        # (``_traced_join``).  ``seen_log`` says of each array, in the
+        # same order, what only the trace knows: its ``kind``, and the
+        # capacity a chip holds the rows or the pairs in.
+        self.seen: List[jax.Array] = []
+        self.seen_log: List[Dict[str, Any]] = []
         # ``(slot, valid)`` of the batch a combiner (``COMBINERS``) was
         # handed, while that combiner is the op just run (``apply_op``):
         # the exchange that follows counts its rows as
@@ -263,15 +268,24 @@ def _exchange(
     return SH.exchange_staged(b, dest, P, B, axes, schedule)
 
 
+def _a_column_a_chip(ctx: StageContext, mine: jax.Array) -> jax.Array:
+    """This chip's counts ``mine`` in its own column of a ``(len(mine),
+    P)`` array, replicated by one ``psum``.  Kept a chip so that no sum
+    is formed in int32 on the device."""
+    me = jax.lax.axis_index(ctx.axes)
+    at = (jnp.arange(ctx.P, dtype=jnp.int32) == me).astype(jnp.int32)
+    return jax.lax.psum(mine.astype(jnp.int32)[:, None] * at[None, :], ctx.axes)
+
+
 def _observe_exchange(ctx: StageContext, slot: int, sent: ColumnBatch) -> None:
     """What an exchange knows and the host does not: the rows this
     chip's combiner was handed (those it sent, where no combiner came
-    just before), the rows it sent, the rows it received.  Each chip
-    puts its three counts in its own column of a ``(3, P)`` array and
-    one ``psum`` replicates it; it leaves the program beside the
-    overflow flag and rides that flag's readback
-    (``GraphExecutor._exchange_observed``).  Kept a chip so that no
-    sum is formed in int32 on the device."""
+    just before), the rows it sent, the rows it received, a column a
+    chip (:func:`_a_column_a_chip`); the array leaves the program
+    beside the overflow flag and rides that flag's readback
+    (``GraphExecutor._exchange_observed``).  The capacity a chip holds
+    the received rows in is the ``resize``'s to say
+    (:func:`_do_resize`)."""
     rows_out = sent.count()
     fed = ctx.combined
     rows_in = (
@@ -279,11 +293,8 @@ def _observe_exchange(ctx: StageContext, slot: int, sent: ColumnBatch) -> None:
         if fed is not None and fed[0] == slot else rows_out
     )
     mine = jnp.stack([rows_in, rows_out, ctx.slots[slot].count()])
-    me = jax.lax.axis_index(ctx.axes)
-    at = (jnp.arange(ctx.P, dtype=jnp.int32) == me).astype(jnp.int32)
-    ctx.xchg_seen.append(
-        jax.lax.psum(mine.astype(jnp.int32)[:, None] * at[None, :], ctx.axes)
-    )
+    ctx.seen.append(_a_column_a_chip(ctx, mine))
+    ctx.seen_log.append(dict(kind="exchange", slot=slot, capacity=None))
 
 
 def _do_exchange_hash(
@@ -371,6 +382,10 @@ def _do_resize(
     out, ovf = SH.resize(b, target)
     ctx.slots[slot] = out
     ctx.overflow = ctx.overflow | ovf
+    for said in reversed(ctx.seen_log):  # the exchange this one follows
+        if said.get("slot") == slot:
+            said["capacity"] = target
+            break
 
 
 # Op kinds whose kernels never set the overflow flag: a stage composed
@@ -691,16 +706,18 @@ def _join_strategy(ctx: StageContext, p, right: ColumnBatch) -> bool:
     return False
 
 
+@jax.named_scope("dryad.join.copartition")
 def _co_partition_for_join(ctx: StageContext, p) -> None:
     """Hash-exchange whichever sides the plan says are not already
     partitioned on the join keys (deferred from lowering when the
-    strategy decision is trace-time)."""
-    if p.get("need_left_exchange"):
-        _do_exchange_hash(ctx, p["left_slot"], p["left_keys"])
-        _do_resize(ctx, p["left_slot"], 1.0)
-    if p.get("need_right_exchange"):
-        _do_exchange_hash(ctx, p["right_slot"], p["right_keys"])
-        _do_resize(ctx, p["right_slot"], 1.0)
+    strategy decision is trace-time).  Under a scope of its own: the
+    exchanges' ``exchange.layout``, ``exchange.collective`` and
+    ``resize`` nest there, apart from the join proper."""
+    for side in ("left", "right"):
+        if p.get(f"need_{side}_exchange"):
+            _do_exchange_hash(ctx, p[f"{side}_slot"], p[f"{side}_keys"])
+            with jax.named_scope("dryad.resize"):  # as the stage op's is named
+                _do_resize(ctx, p[f"{side}_slot"], 1.0)
 
 
 def _apply_join_strategy(ctx: StageContext, p) -> int:
@@ -754,9 +771,18 @@ def _apply_join_strategy(ctx: StageContext, p) -> int:
 def _traced_join(ctx: StageContext, join, *args, **kwargs):
     """Trace one ``ops/join.py`` flavour and put what it gathered over
     its pair slots (``slot_gathers``, ``stacked_words``) on the record
-    :func:`_apply_join_strategy` just opened for it."""
+    :func:`_apply_join_strategy` just opened for it.  The candidate
+    pairs in this chip's pair buffer leave the program as the
+    exchanges' counts do (:func:`_observe_exchange`): a column a chip,
+    on the overflow flag's readback."""
     with J.slot_gather_log() as seen:
         out = join(*args, **kwargs)
+        pairs = J.take_pairs()
+    with jax.named_scope("dryad.join.observe"):
+        ctx.seen.append(_a_column_a_chip(ctx, pairs[None]))
+    ctx.seen_log.append(
+        dict(kind="join", capacity=ctx.join_log[-1]["out_capacity"])
+    )
     ctx.join_log[-1].update(seen)
     return out
 
@@ -1222,7 +1248,8 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
                    xchg_cell: "List[Dict[str, int]]" = None,
                    join_cell: "List[Dict[str, Any]]" = None,
                    sort_cell: "List[int]" = None,
-                   elided_cell: "List[int]" = None):
+                   elided_cell: "List[int]" = None,
+                   seen_cell: "List[Dict[str, Any]]" = None):
     """Compose a whole fused REGION (``plan.fuse.FusedStage``) into one
     per-partition function: the member stage fns chain device-resident
     — member i's output batches feed member j's slots directly in HBM,
@@ -1234,10 +1261,10 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
 
     Overflow/miss contract: the region's overflow flag is the OR over
     every member's (already mesh-reduced) flag, the dict-miss count
-    is the sum and what the exchanges saw (``_observe_exchange``) is
-    every member's, in member order — one seam overflowing retries the WHOLE region at the
-    next palette boost, the same bounded-palette contract as the
-    single-stage path.
+    is the sum and what the exchanges and joins saw
+    (``StageContext.seen``) is every member's, in member order — one
+    seam overflowing retries the WHOLE region at the next palette
+    boost, the same bounded-palette contract as the single-stage path.
 
     ``operand_objs``: the region's deduplicated OPERAND-registered
     param objects in ``stage_operand_objs(fused)`` order (the chained
@@ -1256,13 +1283,14 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
     member_joins = [[] for _ in members]
     member_sorts = [[] for _ in members]
     member_elided = [[] for _ in members]
+    member_seen = [[] for _ in members]
     member_fns = [
         build_stage_fn(
             m, P, slack, boost, axes, axis_sizes,
             operand_objs=member_objs[i],
             window=window, xchg_cell=member_cells[i],
             join_cell=member_joins[i], sort_cell=member_sorts[i],
-            elided_cell=member_elided[i],
+            elided_cell=member_elided[i], seen_cell=member_seen[i],
         )
         for i, m in enumerate(members)
     ]
@@ -1310,6 +1338,8 @@ def build_fused_fn(fused, P: int, slack: float, boost: int,
             sort_cell[:] = [max((w for c in member_sorts for w in c), default=0)]
         if elided_cell is not None:
             elided_cell[:] = [sum(n for c in member_elided for n in c)]
+        if seen_cell is not None:
+            seen_cell[:] = [r for c in member_seen for r in c]
         return region_outs, (overflow, miss, seen)
 
     return fn
@@ -1323,7 +1353,8 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
                    xchg_cell: "List[Dict[str, int]]" = None,
                    join_cell: "List[Dict[str, Any]]" = None,
                    sort_cell: "List[int]" = None,
-                   elided_cell: "List[int]" = None):
+                   elided_cell: "List[int]" = None,
+                   seen_cell: "List[Dict[str, Any]]" = None):
     """Compose the stage's ops into one per-partition function.
 
     ``operand_objs``: the stage's OPERAND-registered param objects (in
@@ -1334,9 +1365,11 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
 
     The fn returns ``(outs, (overflow, dict_miss, seen))``: the output
     batches, sharded, and three replicated values — the overflow flag
-    and the dictionary misses, reduced over the mesh, and one ``(3, P)``
-    array an exchange the trace kept (``_observe_exchange``; ``()`` for
-    a stage with none, or on one partition)."""
+    and the dictionary misses, reduced over the mesh, and one array an
+    exchange or join kernel the trace kept (``StageContext.seen``;
+    ``()`` for a stage with neither; an exchange on one partition is
+    not traced), of which ``seen_cell`` gets ``StageContext.seen_log``'s
+    record each."""
 
     def fn(sharded_inputs, replicated):
         ctx = StageContext(P, slack, boost, axes, axis_sizes, window)
@@ -1377,6 +1410,8 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
         if elided_cell is not None:
             # exchanges this trace skipped on a mesh of one partition
             elided_cell[:] = [ctx.xchg_elided]
-        return outs, (overflow, miss, tuple(ctx.xchg_seen))
+        if seen_cell is not None:
+            seen_cell[:] = list(ctx.seen_log)
+        return outs, (overflow, miss, tuple(ctx.seen))
 
     return fn

@@ -106,23 +106,42 @@ def _probe_ranges(
 
 
 def _slot_owners(
-    offsets: jax.Array, counts: jax.Array, out_capacity: int
+    offsets: jax.Array, counts: jax.Array, total: jax.Array, out_capacity: int
 ) -> jax.Array:
     """The left row each pair slot belongs to: the inverse of the prefix
     sum ``offsets``.  Every row that owns slots writes its index at its
     first one (the targets are distinct; a row with none, or with its
     first slot past the capacity, drops) and a running maximum fills
-    each row's range.  On the slots under ``sum(counts)`` this is
-    ``searchsorted(offsets, slot, "right") - 1``; past them it is the
-    last row that owns a slot (0 when none does)."""
-    rows = jnp.arange(counts.shape[0], dtype=jnp.int32)
+    each row's range.  On the slots under ``total = sum(counts)`` this
+    is ``searchsorted(offsets, slot, "right") - 1``.
+
+    A slot past them is dead: nothing reads what it gathers, but the
+    gathers run over every slot, and a gather costs by its addresses as
+    well as by its shape (``ops/sort.py``'s table by kind of index).
+    So a dead slot reads the row of its OWN number, wrapped into the
+    table (``slot % rows``): ascending addresses, the same on every
+    chip and for every table.  The running maximum alone would leave
+    the whole tail on ONE row, the last that owns a slot, which moves
+    with the data, and one row read by every slot costs 17 - 18 or 28
+    ns a slot BY THE ROW on the v5e (against 21.2 ascending): with 11 -
+    20 M of a chip's 2^25 slots dead the ``li`` gather of the
+    ``join-hash-4c`` cell read 0.634 - 0.857 s by chip and table that
+    way and reads 0.712 s on every chip and table this way, each of
+    its 32 blocks 22.22 - 22.28 ms (``PERF.md`` section 6, PR 45 and PR
+    46).  What a dead slot's ``ri = base[li] + slot`` then reads
+    follows from the row it landed on and stays the data's."""
+    rows = counts.shape[0]
     first = jnp.where(counts > 0, offsets, out_capacity)
-    heads = jnp.zeros((out_capacity,), jnp.int32).at[first].set(rows, mode="drop")
-    return jax.lax.cummax(heads)
+    heads = jnp.zeros((out_capacity,), jnp.int32).at[first].set(
+        jnp.arange(rows, dtype=jnp.int32), mode="drop"
+    )
+    slots = jnp.arange(out_capacity, dtype=jnp.int32)
+    return jnp.where(slots < total, jax.lax.cummax(heads), slots % rows)
 
 
 class _SlotGathers(threading.local):
     seen = None
+    pairs = None
 
 
 _slot_gathers = _SlotGathers()
@@ -143,6 +162,14 @@ def slot_gather_log() -> Iterator[Dict[str, object]]:
         yield seen
     finally:
         _slot_gathers.seen = before
+
+
+def take_pairs() -> Optional[jax.Array]:
+    """The candidate pairs in the pair buffer of the join traced last
+    under :func:`slot_gather_log`, a traced scalar
+    (:func:`_expand_pairs`), handed over once."""
+    pairs, _slot_gathers.pairs = _slot_gathers.pairs, None
+    return pairs
 
 
 def _take_slots(
@@ -174,7 +201,8 @@ def _expand_pairs(
     ``base[left_idx[s]] + s`` (``start[li] + (s - offsets[li])`` with
     the subtraction done before the gather, so one gather a slot finds
     it; int32 wraps the same either way).  Only the slots under
-    ``pair_valid`` mean anything.
+    ``pair_valid`` mean anything; what the others read is
+    :func:`_slot_owners`' to say.
     """
     offsets = jnp.concatenate(
         [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]]
@@ -182,10 +210,11 @@ def _expand_pairs(
     total = jnp.sum(counts)
     overflow = total > out_capacity
 
-    slots = jnp.arange(out_capacity, dtype=jnp.int32)
-    li = _slot_owners(offsets, counts, out_capacity)
+    li = _slot_owners(offsets, counts, total, out_capacity)
     base = (start - offsets).astype(jnp.int32)
-    pair_valid = slots < total
+    pair_valid = jnp.arange(out_capacity, dtype=jnp.int32) < total
+    if _slot_gathers.seen is not None:
+        _slot_gathers.pairs = jnp.minimum(total, out_capacity)
     return li, base, pair_valid, overflow, offsets
 
 

@@ -213,7 +213,8 @@ def test_at_one_partition_a_repartition_traces_nothing(name):
     if name.endswith("_partition"):  # nothing at all is left to run
         assert not _scopes(program) and "scatter" not in program.as_text()
     (slots_in,) = _slots(program.args_info) - {len(DIM["dk"])}
-    assert _slots(program.out_info) == {growth * slots_in}
+    # the batches: beside them a join says its pairs, a column a chip (PR 46)
+    assert _slots(program.out_info[0]) == {growth * slots_in}
     assert [e["xchg_elided"] for e in _dispatches(events)] == [exchanges]
 
 
