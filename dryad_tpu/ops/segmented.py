@@ -1,24 +1,31 @@
 """On-device segmented (group-by) reduction.
 
 The TPU-native replacement for the reference's GroupBy combiner machinery:
-sort rows by key, detect segment boundaries, reduce per segment with XLA
-scatter-adds / segmented scans — instead of hash tables inside vertex
-processes (reference ``LinqToDryad/DryadLinqVertex.cs`` GroupBy operators)
+sort rows by key, detect segment boundaries, reduce each segment by a
+segmented scan read at the segment's last row, and put those rows in
+their slots by ONE order-preserving compaction shared by every output
+column (no scatter, no scatter-add, no gather) — instead of hash tables
+inside vertex processes (reference ``LinqToDryad/DryadLinqVertex.cs`` GroupBy operators)
 and GM-built aggregation trees (``DrDynamicAggregateManager.h:35-168``).
 The machine→pod→overall tree becomes: per-chip partial reduce (this
 module, pre-shuffle) + post-shuffle final reduce — the
 Seed/Accumulate/RecursiveAccumulate/FinalReduce decomposition of
 ``LinqToDryad/IDecomposable.cs:35-71``.
 
-Kernel-strategy note: raw scatter-adds serialize on TPU, so the
-general path stays sort-based and the bounded-key fast path stays the
-MXU kernel (``group_by(dense=K)``, auto-selected for dictionary STRING
-and ingest-bounded INT32 keys).  Within the sort path, the sort
-carries all columns as ``lax.sort`` operands (``ops/sort.py``: moving
-rows by XLA ``gather`` instead was 62-77% of the exchange cells' device
-time, ``PERF.md`` section 6, PR 25) and counts come from one shared
-start-position scatter.  What the fold costs on the chip is in
-``PERF.md`` section 5 (``group_reduce.fold``).
+Kernel-strategy note: a TPU scatter pays by the update, not by the
+rows that land, and pays again for every column, so the general path
+stays sort-based and the bounded-key fast path stays the MXU kernel
+(``group_by(dense=K)``, auto-selected for dictionary STRING and
+ingest-bounded INT32 keys).  Within the sort path, the sort carries all
+columns as ``lax.sort`` operands (``ops/sort.py``: moving rows by XLA
+``gather`` instead was 62-77% of the exchange cells' device time,
+``PERF.md`` section 6, PR 25).  The rows being sorted, a group is a run
+and its slot is its run's number: placing the run-end rows is a
+monotone move (:func:`compact_rows`), where the scatter a column it
+replaced was 73% of ``groupby-4c``'s device time and 65% of
+``groupby-skew-4c``'s, ten times what reducing the rows cost
+(``PERF.md`` section 6, PR 47).  Counts are differences of the run
+ends' positions, which the compaction hands back for nothing.
 """
 
 from __future__ import annotations
@@ -51,33 +58,18 @@ class AggSpec:
 def _segment_layout(
     batch: ColumnBatch, key_cols: Sequence[str],
     scope: str = "dryad.group_reduce.layout",
-) -> Tuple[ColumnBatch, jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Sort+compact by keys; return (sorted batch, valid, start, seg, nseg).
-
-    ``seg`` maps each row to its segment id, with invalid rows mapped to
-    the sentinel segment ``capacity`` (dropped on slice).  ``scope`` is
-    the caller's name for the pass in a device trace.
+) -> Tuple[ColumnBatch, jax.Array]:
+    """Sort+compact by keys; return (sorted batch, start): valid rows
+    first, a segment a run of them, ``start`` its first row.  ``scope``
+    is the caller's name for the pass in a device trace.
     """
-    cap = batch.capacity
     with jax.named_scope(scope):
         sb = sort_batch_by_operands(
             batch, [to_sortable_u32(batch.data[k]) for k in key_cols]
         )
-        v = sb.valid
         eq = keys_equal_adjacent([sb.data[k] for k in key_cols])
-        start = v & ~eq
-        seg_id = jnp.cumsum(start.astype(jnp.int32)) - 1
-        seg = jnp.where(v, seg_id, cap)
-        nseg = jnp.sum(start.astype(jnp.int32))
-    return sb, v, start, seg, nseg
-
-
-def _first_scatter(
-    val: jax.Array, start: jax.Array, seg: jax.Array, cap: int
-) -> jax.Array:
-    """Per-segment value from the segment's first row."""
-    idx = jnp.where(start, seg, cap)
-    return jnp.zeros((cap + 1,) + val.shape[1:], val.dtype).at[idx].set(val)[:cap]
+        start = sb.valid & ~eq
+    return sb, start
 
 
 PAIR_OPS = ("sum64", "min64", "max64")
@@ -117,44 +109,6 @@ def _pair_identity(op: str) -> Tuple[jax.Array, jax.Array]:
     return jnp.uint32(0), jnp.uint32(0x80000000)  # max64: min signed-64
 
 
-def _segmented_pair_reduce(
-    op: str,
-    lo: jax.Array,
-    hi: jax.Array,
-    v: jax.Array,
-    start: jax.Array,
-    seg: jax.Array,
-    cap: int,
-) -> Tuple[jax.Array, jax.Array]:
-    """Per-segment 64-bit reduce over a split (low, high) uint32 column:
-    a flagged segmented ``associative_scan`` wrapping
-    :func:`_pair_combine`."""
-    flags = start
-    base = _pair_combine(op)
-
-    def combine(a, b):
-        fa, alo, ahi = a
-        fb, blo, bhi = b
-        mlo, mhi = base(alo, ahi, blo, bhi)
-        return (
-            fa | fb,
-            jnp.where(fb, blo, mlo),
-            jnp.where(fb, bhi, mhi),
-        )
-
-    _, slo, shi = jax.lax.associative_scan(combine, (flags, lo, hi))
-
-    # Segment results live at each segment's LAST valid row (invalid
-    # rows sort to the tail, so they never contaminate gathered rows).
-    nxt_start = jnp.concatenate([start[1:], jnp.array([True])])
-    nxt_valid = jnp.concatenate([v[1:], jnp.array([False])])
-    last = v & (nxt_start | ~nxt_valid)
-    idx = jnp.where(last, seg, cap)
-    out_lo = jnp.zeros((cap + 1,), lo.dtype).at[idx].set(slo)[:cap]
-    out_hi = jnp.zeros((cap + 1,), hi.dtype).at[idx].set(shi)[:cap]
-    return out_lo, out_hi
-
-
 def pair_to_f32(lo: jax.Array, hi: jax.Array) -> jax.Array:
     """Approximate f32 value of a split signed-64 word pair
     (hi signed * 2^32 + lo unsigned) — the ONE decode used by every
@@ -187,99 +141,42 @@ def pair_scalar_reduce(
     return slo[-1], shi[-1]
 
 
-def group_reduce(
-    batch: ColumnBatch,
-    key_cols: Sequence[str],
-    aggs: Sequence[AggSpec],
-) -> ColumnBatch:
-    """Group rows by key columns and reduce; output capacity == input.
-
-    Output batch holds one row per distinct key (rows 0..nseg-1 valid):
-    the key columns plus one column per AggSpec.
-    """
-    sb, v, start, seg, nseg = _segment_layout(batch, key_cols)
-    return _segmented_fold(sb, v, start, seg, nseg, key_cols, aggs)
-
-
-@jax.named_scope("dryad.group_reduce.fold")
-def _segmented_fold(
-    sb: ColumnBatch, v, start, seg, nseg,
-    key_cols: Sequence[str], aggs: Sequence[AggSpec],
-) -> ColumnBatch:
-    """The fold of :func:`group_reduce` over rows already laid out by
-    segment (:func:`_segment_layout`): one output row a segment."""
-    cap = sb.capacity
-    nsegments = cap + 1  # includes the invalid-row sentinel segment
-
-    out: Dict[str, jax.Array] = {}
-    for k in key_cols:
-        out[k] = _first_scatter(sb.data[k], start, seg, cap)
-
-    seg_count = None
-    if any(a.op in ("count", "mean") for a in aggs):
-        # Per-segment row counts WITHOUT a segment_sum: one shared
-        # scatter of segment-start row positions, then adjacent
-        # differences.  Scatter-ADD cost grows with same-address
-        # run length on the TPU, while a scatter-set of distinct
-        # segment ids does not.  Non-start rows get an out-of-range
-        # index and are dropped (mode="drop"); the surviving
-        # in-bounds writes go to distinct slots, so no
-        # unique_indices promise is needed.
-        nvalid = jnp.sum(v.astype(jnp.int32))
-        idx = jnp.where(start, seg, cap + 2)
-        start_pos = (
-            jnp.full((cap + 2,), nvalid, jnp.int32)
-            .at[idx]
-            .set(jnp.arange(cap, dtype=jnp.int32), mode="drop")[: cap + 1]
-        )
-        seg_count = start_pos[1:] - start_pos[:cap]
-
-    for a in aggs:
-        if a.op == "count":
-            out[a.out] = seg_count
-            continue
-        if a.op in PAIR_OPS:
-            # a.col names the LOW word of a split 64-bit column; the
-            # high word lives alongside it and the output writes both.
-            lo_col = a.col
-            hi_col = lo_col[: -len("#h0")] + "#h1"
-            out_lo, out_hi = _segmented_pair_reduce(
-                a.op, sb.data[lo_col], sb.data[hi_col], v, start, seg, cap
-            )
-            out[f"{a.out}#h0"] = out_lo
-            out[f"{a.out}#h1"] = out_hi
-            continue
-        col = sb.data[a.col]
-        if a.op == "sum":
-            out[a.out] = jax.ops.segment_sum(col, seg, nsegments)[:cap]
-        elif a.op == "min":
-            out[a.out] = jax.ops.segment_min(col, seg, nsegments)[:cap]
-        elif a.op == "max":
-            out[a.out] = jax.ops.segment_max(col, seg, nsegments)[:cap]
-        elif a.op == "mean":
-            s = jax.ops.segment_sum(col.astype(jnp.float32), seg, nsegments)[:cap]
-            c = seg_count.astype(jnp.float32)
-            out[a.out] = s / jnp.maximum(c, 1.0)
-        elif a.op == "any":
-            m = jax.ops.segment_max(col.astype(jnp.int32), seg, nsegments)[:cap]
-            out[a.out] = m.astype(jnp.bool_)
-        elif a.op == "all":
-            m = jax.ops.segment_min(
-                jnp.where(v, col, True).astype(jnp.int32), seg, nsegments
-            )[:cap]
-            out[a.out] = m.astype(jnp.bool_)
-        elif a.op == "first":
-            out[a.out] = _first_scatter(col, start, seg, cap)
-        else:
-            raise ValueError(f"unknown agg op {a.op!r}")
-
-    valid = jnp.arange(cap, dtype=jnp.int32) < nseg
-    return ColumnBatch(out, valid)
-
-
-# -- generic user decompositions ------------------------------------------
+# -- the two passes every fold is made of -----------------------------------
 
 MergeFn = Callable[[Dict[str, jax.Array], Dict[str, jax.Array]], Dict[str, jax.Array]]
+
+
+def _over(mask: jax.Array, x: jax.Array) -> jax.Array:
+    """A per-row ``mask`` against a column of any width."""
+    return mask.reshape(mask.shape + (1,) * (x.ndim - 1))
+
+
+def _doubling(n: int, one_pass, state):
+    """``state`` after ``ceil(log2 n)`` passes of ``one_pass(d, state)``
+    at distances ``d`` = 1, 2, 4, ...: ONE loop body with ``d`` traced,
+    not a body a pass.  Unrolled (a static slice a pass) the compaction
+    is no faster on the chip (six columns at 2^25 slots: 0.1956 s for
+    0.1922) and a six-channel scan 12% faster (0.1475 for 0.1671), and
+    both compile far slower everywhere: a group-by query 2.55 s for
+    0.75 on the CPU mesh, where tier-1 lives, and 52 MB of generated
+    code for 20 MB at 2^25 slots on the TPU (PERF.md section 6, PR 47)."""
+    def body(bit, state):
+        return one_pass(jnp.left_shift(jnp.int32(1), bit), state)
+
+    return jax.lax.fori_loop(0, max(n - 1, 0).bit_length(), body, state)
+
+
+def _shifted(x: jax.Array, d: jax.Array, fill) -> jax.Array:
+    """``x[i + d]`` for a traced ``d`` of either sign, ``fill`` where
+    that runs off the array: one dynamic slice of the padded column
+    (the pad fuses into the slice's consumer and is never made, and an
+    unaligned dynamic start costs nothing on the TPU: PERF.md section
+    6, PR 43)."""
+    n = x.shape[0]
+    padded = jax.lax.pad(
+        x, jnp.asarray(fill, x.dtype), [(n, n, 0)] + [(0, 0, 0)] * (x.ndim - 1)
+    )
+    return jax.lax.dynamic_slice_in_dim(padded, n + d, n)
 
 
 def segmented_scan(
@@ -288,9 +185,9 @@ def segmented_scan(
     """Inclusive segmented scan of ``vals`` under ``merge`` by doubling
     (Hillis-Steele): after the pass at distance ``d`` every slot holds
     its segment's reduction over the last ``2 d`` slots, so ``ceil(log2
-    n)`` passes, each ONE elementwise fusion over the whole array that
-    reads the state at ``i - d`` through a static slice.  ``merge(a, b)``
-    is always handed the earlier rows as ``a``.
+    n)`` passes (:func:`_doubling`), each elementwise over the whole
+    array, reading the state at ``i - d`` through a slice.  ``merge(a,
+    b)`` is always handed the earlier rows as ``a``.
 
     Not ``lax.associative_scan``: its tree of odd/even slices is work-
     efficient, but every level is a handful of fusions of a shape of its
@@ -298,29 +195,178 @@ def segmented_scan(
     ``groupby-skew-4c`` cell came to 230 MB of generated code at 2^20
     slots and no program at all at 2^23 (the compile was cut after
     1,500 s on the chip's host; PERF.md section 6, PR 41).  These passes
-    compile in seconds at any size and move ``3 log2 n`` times the
-    state through HBM, which at 2^24 slots is a tenth of what the
+    compile in seconds at any size and move a few times the state
+    through HBM a pass, which at 2^24 slots was a tenth of what the
     scatters beside them cost."""
-    n = start.shape[0]
-    pos = jnp.arange(n, dtype=jnp.int32)
+    pos = jnp.arange(start.shape[0], dtype=jnp.int32)
 
-    def over(mask, x):  # ``mask`` against a column of any width
-        return mask.reshape((n,) + (1,) * (x.ndim - 1))
-
-    flag, d = start, 1
-    while d < n:
-        def back(x, d=d):  # x[i - d]; the first d slots are masked below
-            return jnp.concatenate([x[:d], x[:-d]])
-
-        reach = pos >= d
-        merged = merge({k: back(x) for k, x in vals.items()}, vals)
-        take = reach & ~flag  # no segment starts inside the slot's own window
+    def one_pass(d, state):
+        vals, flag = state
+        merged = merge({k: _shifted(x, -d, 0) for k, x in vals.items()}, vals)
+        take = (pos >= d) & ~flag  # no segment starts inside the slot's own window
         vals = {
-            k: jnp.where(over(take, x), merged[k], x) for k, x in vals.items()
+            k: jnp.where(_over(take, x), merged[k], x) for k, x in vals.items()
         }
-        flag = flag | (reach & back(flag))
-        d *= 2
-    return vals
+        return vals, flag | _shifted(flag, -d, False)
+
+    return _doubling(start.shape[0], one_pass, (vals, start))[0]
+
+
+def compact_rows(
+    keep: jax.Array, cols: Dict[str, jax.Array]
+) -> Tuple[Dict[str, jax.Array], jax.Array]:
+    """Order-preserving compaction: the rows of ``cols`` where ``keep``
+    at slots ``0 .. count-1`` in their order, zeros behind them; with
+    them ``origin``, the position each placed row came from (-1 behind).
+
+    A kept row moves left by ``shift``, the rows before it that are not
+    kept, one bit of it a pass (:func:`_doubling`), LOWEST bit first: in
+    the pass at distance ``d`` a slot takes the state of slot ``i + d``
+    if that slot is live and its shift has bit ``d`` set, keeps its own
+    if it is live with that bit clear, and is dead otherwise (a dead
+    slot's shift is -1: the live flag rides in the sign).  The move is
+    monotone (two kept rows ``i < j`` have ``shift_j - shift_i <= j - i
+    - 1``), so after any number of low bits they are still apart: no
+    slot is asked for twice.  Each pass is elementwise over (columns,
+    shift), shared by all columns; no scatter, no gather, no sort.
+    What it replaced and what each form costs on the chip: ``PERF.md``
+    section 6, PR 47."""
+    n = keep.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    shift = jnp.where(keep, pos - (jnp.cumsum(keep.astype(jnp.int32)) - 1), -1)
+
+    def one_pass(d, state):
+        cols, shift = state
+        ahead = _shifted(shift, d, -1)
+        inc = (ahead >= 0) & ((ahead & d) != 0)
+        stay = (shift >= 0) & ((shift & d) == 0)
+        cols = {
+            k: jnp.where(_over(inc, x), _shifted(x, d, 0), x)
+            for k, x in cols.items()
+        }
+        # a row's bits under d are spent, so its shift travels as it is
+        return cols, jnp.where(inc, ahead, jnp.where(stay, shift, -1))
+
+    cols, shift = _doubling(n, one_pass, (cols, shift))
+    live = shift >= 0
+    cols = {
+        k: jnp.where(_over(live, x), x, jnp.zeros((), x.dtype))
+        for k, x in cols.items()
+    }
+    return cols, jnp.where(live, pos + shift, -1)
+
+
+def _place_groups(
+    sb: ColumnBatch, start: jax.Array, key_cols: Sequence[str],
+    scanned: Dict[str, jax.Array],
+) -> Tuple[Dict[str, jax.Array], jax.Array, jax.Array]:
+    """One row a segment at the segment's slot: its keys and ``scanned``
+    as they stand at its last row (the next row starts a segment, is
+    invalid, for invalid rows sort to the tail, or does not exist).
+    Returns (columns, the last rows' positions, which slots hold a
+    segment)."""
+    v = sb.valid
+    nxt_start = jnp.concatenate([start[1:], jnp.array([True])])
+    nxt_valid = jnp.concatenate([v[1:], jnp.array([False])])
+    cols, origin = compact_rows(
+        v & (nxt_start | ~nxt_valid),
+        {**{k: sb.data[k] for k in key_cols}, **scanned},
+    )
+    return cols, origin, origin >= 0
+
+
+# -- built-in aggregates -----------------------------------------------------
+
+_MERGES = {
+    "sum": jnp.add,
+    "mean": jnp.add,
+    "min": jnp.minimum,
+    "max": jnp.maximum,
+    "any": jnp.logical_or,
+    "all": jnp.logical_and,
+    "first": lambda a, b: a,
+}
+
+
+def _agg_channels(
+    data: Dict[str, jax.Array], aggs: Sequence[AggSpec]
+) -> Tuple[Dict[str, jax.Array], MergeFn]:
+    """The scan's channels for ``aggs``, named as the outputs are, and
+    the merge over them (``count`` needs none: it is a difference of
+    run-end positions)."""
+    vals: Dict[str, jax.Array] = {}
+    ops: Dict[str, Callable] = {}
+    pairs: Dict[str, Callable] = {}
+    for a in aggs:
+        if a.op == "count":
+            continue
+        if a.op in PAIR_OPS:
+            # a.col names the LOW word of a split 64-bit column; the
+            # high word lives alongside it and the output writes both.
+            vals[f"{a.out}#h0"] = data[a.col]
+            vals[f"{a.out}#h1"] = data[a.col[: -len("#h0")] + "#h1"]
+            pairs[a.out] = _pair_combine(a.op)
+            continue
+        if a.op not in _MERGES:
+            raise ValueError(f"unknown agg op {a.op!r}")
+        col = data[a.col]
+        if a.op == "mean":
+            col = col.astype(jnp.float32)
+        elif a.op in ("any", "all"):
+            col = col.astype(jnp.bool_)
+        vals[a.out], ops[a.out] = col, _MERGES[a.op]
+
+    def merge(a, b):
+        out = {k: op(a[k], b[k]) for k, op in ops.items()}
+        for name, combine in pairs.items():
+            lo, hi = f"{name}#h0", f"{name}#h1"
+            out[lo], out[hi] = combine(a[lo], a[hi], b[lo], b[hi])
+        return out
+
+    return vals, merge
+
+
+def group_reduce(
+    batch: ColumnBatch,
+    key_cols: Sequence[str],
+    aggs: Sequence[AggSpec],
+) -> ColumnBatch:
+    """Group rows by key columns and reduce; output capacity == input.
+
+    Output batch holds one row per distinct key (rows 0..nseg-1 valid):
+    the key columns plus one column per AggSpec.  :func:`group_combine`
+    with a merge built from the AggSpecs, under scopes of its own
+    (``dryad.group_reduce.layout``, ``.fold`` with the scan and the
+    compaction as ``fold/scan`` and ``fold/place``).
+    """
+    sb, start = _segment_layout(batch, key_cols)
+    with jax.named_scope("dryad.group_reduce.fold"):
+        vals, merge = _agg_channels(sb.data, aggs)
+        with jax.named_scope("scan"):
+            scanned = segmented_scan(start, vals, merge)
+        with jax.named_scope("place"):
+            placed, origin, valid = _place_groups(sb, start, key_cols, scanned)
+        # valid rows come first, so a run's rows are those after the
+        # run end before it
+        before = jnp.concatenate([jnp.array([-1], jnp.int32), origin[:-1]])
+        count = jnp.where(valid, origin - before, 0)
+
+        out = {k: placed[k] for k in key_cols}
+        for a in aggs:
+            if a.op == "count":
+                out[a.out] = count
+            elif a.op in PAIR_OPS:
+                out[f"{a.out}#h0"] = placed[f"{a.out}#h0"]
+                out[f"{a.out}#h1"] = placed[f"{a.out}#h1"]
+            elif a.op == "mean":
+                c = count.astype(jnp.float32)
+                out[a.out] = placed[a.out] / jnp.maximum(c, 1.0)
+            else:
+                out[a.out] = placed[a.out]
+    return ColumnBatch(out, valid)
+
+
+# -- generic user decompositions ------------------------------------------
 
 
 def group_combine(
@@ -338,11 +384,11 @@ def group_combine(
     in a device trace: the rows sorted by key with the state carried
     (``dryad.group_combine.layout``), a flagged segmented scan
     (``.scan``, :func:`segmented_scan`) whose result at a segment's
-    last row is the segment's reduction, and one scatter-set a column
-    that puts it at the segment's slot (``.emit``).
+    last row is the segment's reduction, and one compaction of those
+    rows, key and state together, that puts each at its segment's slot
+    (``.emit``, :func:`compact_rows`).
     """
-    cap = batch.capacity
-    sb, v, start, seg, nseg = _segment_layout(
+    sb, start = _segment_layout(
         batch, key_cols, scope="dryad.group_combine.layout"
     )
 
@@ -352,21 +398,7 @@ def group_combine(
         )
 
     with jax.named_scope("dryad.group_combine.emit"):
-        # Last row of each segment: next row starts a new segment / is
-        # invalid / EOF.
-        nxt_start = jnp.concatenate([start[1:], jnp.array([True])])
-        nxt_valid = jnp.concatenate([v[1:], jnp.array([False])])
-        last = v & (nxt_start | ~nxt_valid)
-
-        out: Dict[str, jax.Array] = {}
-        for k in key_cols:
-            out[k] = _first_scatter(sb.data[k], start, seg, cap)
-        idx = jnp.where(last, seg, cap)
-        for c in state_cols:
-            val = scanned[c]
-            out[c] = jnp.zeros((cap + 1,) + val.shape[1:], val.dtype).at[idx].set(val)[:cap]
-
-        valid = jnp.arange(cap, dtype=jnp.int32) < nseg
+        out, _, valid = _place_groups(sb, start, key_cols, scanned)
     return ColumnBatch(out, valid)
 
 

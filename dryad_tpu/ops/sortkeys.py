@@ -82,8 +82,8 @@ def lexi_less(
 def keys_equal_adjacent(key_cols: Sequence[jax.Array]) -> jax.Array:
     """For sorted columns: row i equals row i-1 on all keys (row 0 -> False)."""
     n = key_cols[0].shape[0]
-    eq = jnp.ones((n,), jnp.bool_)
+    eq = jnp.arange(n, dtype=jnp.int32) > 0
     for col in key_cols:
         prev = jnp.roll(col, 1)
         eq = eq & (col == prev)
-    return eq.at[0].set(False)
+    return eq

@@ -73,6 +73,33 @@ def test_the_user_defined_combiners_scan_compiles_small_for_the_chip(one_chip, u
     assert memory.temp_size_in_bytes < 8 * 21 * slots
 
 
+def test_the_run_end_compaction_compiles_small_for_the_chip(one_chip, uncached):
+    """``ops/segmented.py::compact_rows`` over the ``groupby-skew-4c``
+    cell's six columns (the key and five state words), 2^20 slots: the
+    passes that put a fold's run-end rows in their slots are ONE loop
+    body of elementwise fusions over buffers it reuses (2.5 MB of
+    generated code; unrolled, a static slice a pass, 15 MB here and 21
+    MB at the cell's 2^25 slots), and the program names no scatter (the
+    twelve scatter-sets they replaced were 65% of the cell's device
+    time; PERF.md section 6, PR 47)."""
+    from dryad_tpu.ops.segmented import compact_rows
+
+    slots = 1 << 20
+
+    def col(dtype):
+        return jax.ShapeDtypeStruct((slots,), dtype, sharding=one_chip)
+
+    cols = {"k": col(jnp.int32), "n": col(jnp.int32), "ts": col(jnp.int32),
+            "last": col(jnp.float32), "mean": col(jnp.float32), "m2": col(jnp.float32)}
+    compiled = jax.jit(compact_rows).lower(col(jnp.bool_), cols).compile()
+    memory = compiled.memory_analysis()
+    assert memory.generated_code_size_in_bytes < 8 << 20
+    # six words and the shift a slot: a copy or two, not one a pass
+    assert memory.temp_size_in_bytes < 2 * 28 * slots
+    text = compiled.as_text()
+    assert "scatter" not in text and " gather(" not in text and " sort(" not in text
+
+
 def test_the_joins_pair_slots_compile_small_for_the_chip(one_chip, uncached):
     """Everything of ``ops/join.py::hash_join`` after the probe (the
     pair slots' owners, the two calls that gather them, the exact
