@@ -57,7 +57,8 @@ EVENT_KINDS: Dict[str, str] = {
                       "ici_bytes/dcn_bytes (window 0 = flat all_to_all)",
     "exchange_observed": "what a dispatch's exchanges saw, off the overflow "
                          "flag's readback; combine_rows_in/combine_rows_out/"
-                         "recv_rows (a chip)/boost/overflows (of the job)",
+                         "recv_rows (a chip)/boost/overflows (of the job)/"
+                         "resize_sorts (resizes that traced a compaction)",
     "join_observed": "what a dispatch's join kernels saw, off the same "
                      "readback; joins/pairs (candidate pairs in the pair "
                      "buffers, a chip)/slots (out_capacity, a chip)",
@@ -231,7 +232,7 @@ EVENT_PAYLOADS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
     ),
     "exchange_observed": (
         ("boost", "combine_rows_in", "combine_rows_out", "exchanges",
-         "name", "overflows", "recv_rows", "stage"),
+         "name", "overflows", "recv_rows", "resize_sorts", "stage"),
         ("qid",),
     ),
     "join_observed": (

@@ -821,7 +821,9 @@ class GraphExecutor:
         one array an exchange (``(3, P)``: rows the combiner before it
         was handed, rows sent, rows received; a column a chip) or a join
         (``(1, P)``: candidate pairs in the pair buffer), ``seen_log``
-        the trace's record of each (``kind``, ``capacity``).  One
+        the trace's record of each (``kind``, ``capacity``, and of an
+        exchange ``resize_sorts``: 1 where the ``resize`` after it
+        traced a compaction, ``kernels._reader_sorts``).  One
         ``exchange_observed`` event a dispatch that ran an exchange,
         summed over its exchanges, and one ``join_observed`` a dispatch
         that ran a join; onto the ``drain`` span the sums over all of
@@ -848,13 +850,14 @@ class GraphExecutor:
                     (np.asarray(rows, dtype=np.int64), said["capacity"])
                 )
             if of["exchange"]:
-                ran.append((stage, boost, of["exchange"]))
+                sorts = sum(said.get("resize_sorts", 0) for said in seen_log)
+                ran.append((stage, boost, of["exchange"], sorts))
             if of["join"]:
                 joined.append((stage, of["join"]))
-        for stage, boost, exchanges in ran:
+        for stage, boost, exchanges, sorts in ran:
             self.events.emit(
                 "exchange_observed", stage=stage.id, name=stage.name,
-                exchanges=len(exchanges), boost=boost,
+                exchanges=len(exchanges), resize_sorts=sorts, boost=boost,
                 overflows=self._job_overflows,
                 qid=tracectx.current_qid(),
                 **fields(sum(rows for rows, _ in exchanges)),
@@ -867,13 +870,14 @@ class GraphExecutor:
                 qid=tracectx.current_qid(),
             )
         if ran:
-            each = [x for _, _, exchanges in ran for x in exchanges]
+            each = [x for _, _, exchanges, _ in ran for x in exchanges]
             total = fields(sum(rows for rows, _ in each))
             # the list reaches the span's event; a profiler annotation
             # keeps numbers only, so its largest entry goes beside it
             drain.add(
                 exchanges=len(each),
-                boost=max(boost for _, boost, _ in ran),
+                resize_sorts=sum(sorts for _, _, _, sorts in ran),
+                boost=max(boost for _, boost, _, _ in ran),
                 overflows=self._job_overflows,
                 recv_rows_max=max(total["recv_rows"]), **total,
                 recv_balance_max=max(

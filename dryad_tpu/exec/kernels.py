@@ -11,7 +11,7 @@ capacities, stage growth, and the executor's retry ``boost``.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +108,17 @@ class StageContext:
         # the exchange that follows counts its rows as
         # ``combine_rows_in``.
         self.combined: Any = None
+        # The stage's ops after the one being traced
+        # (``build_stage_fn``): where a ``resize`` finds the kernel
+        # that reads its slot next (:func:`_next_reader`).
+        self.ahead: Sequence[Any] = ()
+        # The kind of the op being traced (``apply_op``): a join's own
+        # ``resize``s ask which flavour they are in (:func:`_join_side`).
+        self.kind: Optional[str] = None
+        # slot -> capacity: the cut a ``resize`` left to the kernel
+        # that reads the slot next, taken from that kernel's output
+        # (``apply_op``).
+        self.cuts: Dict[int, int] = {}
         self.slots: Dict[int, ColumnBatch] = {}
         self.entry_caps: Dict[int, int] = {}
         # id(param object) -> tuple of traced operand arrays (bound
@@ -138,6 +149,16 @@ class StageContext:
 # just before a hash exchange it is that exchange's combiner.
 COMBINERS = frozenset({"group_reduce", "group_combine", "distinct"})
 
+# The kernels whose first act is a stable sort of their slot that puts
+# valid rows first (``ops/sort.py::sort_carry``: the folds through
+# ``_segment_layout``), and whose output has them at the front.
+SORTS_VALID_FIRST = COMBINERS | {"local_sort"}
+
+# The kernels of two slots.  Each sorts its RIGHT side by key hash,
+# invalid rows last under a sentinel (``ops/join.py::_probe_ranges``);
+# its left rows are gathered where they lie (:func:`_join_side`).
+JOINS = frozenset({"join", "semi", "group_join_count", "join_ranked"})
+
 
 def apply_op(ctx: StageContext, kind: str, p: Dict[str, Any]) -> None:
     fn = _KERNELS.get(kind)
@@ -147,9 +168,15 @@ def apply_op(ctx: StageContext, kind: str, p: Dict[str, Any]) -> None:
         (p["slot"], ctx.slots[p["slot"]].valid) if kind in COMBINERS
         else None
     )
+    ctx.kind = kind
     # names the operator in every device operation's ``tf_op`` path
     with jax.named_scope(f"dryad.{kind}"):
         fn(ctx, p)
+        if kind in SORTS_VALID_FIRST and p["slot"] in ctx.cuts:
+            # the cut the ``resize`` before left to this kernel
+            ctx.slots[p["slot"]] = SH.cut(
+                ctx.slots[p["slot"]], ctx.cuts.pop(p["slot"])
+            )
     ctx.combined = handed  # what the NEXT op finds: set by a combiner alone
 
 
@@ -228,7 +255,9 @@ def _elided(ctx: StageContext, exchange: bool) -> bool:
     — no splitter sample, no bucket layout, no send buffer, no
     collective, no compaction, no growth of the capacity by the slack.
     The slot's batch goes on as it is (invalid rows where they were,
-    which every kernel downstream masks as it does after a ``where``),
+    which every kernel downstream masks as it does after a ``where``;
+    on more partitions a ``resize`` leaves them so only for a kernel
+    that sorts them away itself, :func:`_reader_sorts`),
     ``ctx.overflow`` untouched, and no ``exchange_round`` is accounted.
     Decided here, from the partition count the program is traced for,
     and nowhere else: ``lower()`` emits the same stage ops at every
@@ -340,7 +369,9 @@ def _tree_exchange_hash(ctx: StageContext, slot: int, keys, tree) -> None:
         ctx, b, dest_global(b) % P_in, P_in, B1, (ctx.axes[1],)
     )
     ctx.overflow = ctx.overflow | ovf
-    out, ovf = SH.resize(out, _round8(b.capacity * ctx.slack))
+    # the combine below sorts valid rows first itself (``SH.resize``)
+    per_slice = _round8(b.capacity * ctx.slack)
+    out, ovf = SH.resize(out, per_slice, reader_sorts=True)
     ctx.overflow = ctx.overflow | ovf
 
     # Per-slice combine (RecursiveAccumulate analog; idempotent specs).
@@ -352,6 +383,7 @@ def _tree_exchange_hash(ctx: StageContext, slot: int, keys, tree) -> None:
         )
     else:
         out = SEG.group_reduce(out, tree["keys"], tree["aggs"])
+    out = SH.cut(out, per_slice)
 
     # Hop 2: cross-slice exchange over DCN to slice g // P_ici.
     B2 = SH.bucket_capacity(out.capacity, D, slack)
@@ -362,13 +394,77 @@ def _tree_exchange_hash(ctx: StageContext, slot: int, keys, tree) -> None:
     ctx.slots[slot] = out2
 
 
+def _slots_of(p: Dict[str, Any]) -> Tuple[int, ...]:
+    """The slots a stage op's params name."""
+    named = [p.get(k) for k in ("slot", "left_slot", "right_slot", "out_slot")]
+    named += [*p.get("slots", ()), *p.get("out_slots", ())]
+    return tuple(s for s in named if s is not None)
+
+
+def _next_reader(ctx: StageContext, slot: int) -> Optional[str]:
+    """The kernel that reads ``slot`` next: the kind of the first op
+    ahead in the stage (the fused region's member) that names it,
+    ``join.left`` / ``join.right`` for the sides of a kernel of two
+    slots; None where the slot leaves the stage as it is."""
+    for op in ctx.ahead:
+        if slot not in _slots_of(op.params):
+            continue
+        if op.kind in JOINS:
+            return _join_side(op.kind, op.params, slot)
+        return op.kind
+    return None
+
+
+def _join_side(kind: str, p: Dict[str, Any], slot: int) -> str:
+    """What a join of ``kind`` is to ``slot`` as a reader: ``join.right``
+    (sorted by the probe), ``join.left`` where the left rows leave only
+    through the gathers over the pair slots (an inner ``join``, a
+    ``join_ranked``), else ``kind`` itself: a ``semi``, a
+    ``group_join_count`` and an outer ``join`` hand the left batch on
+    slot for slot, holes and all."""
+    if p["right_slot"] == slot:
+        return "join.right"
+    gathered = kind == "join_ranked" or (kind == "join" and not p.get("outer"))
+    return "join.left" if gathered else kind
+
+
+def _reader_sorts(reader: Optional[str], target: int, capacity: int) -> bool:
+    """The ONE rule for the ``resize`` after an exchange on more than
+    one partition: whether a compaction here would be lost on the kernel
+    that reads the slot next.  It is where that kernel sorts valid rows
+    first itself (``SORTS_VALID_FIRST``), whatever the target: a cut is
+    then taken from its output (``SH.resize``).  A join sorts its right
+    side so too (``JOINS``), and the left rows of one that puts out its
+    pair buffer alone (:func:`_join_side`) are read by a gather whose
+    indices ascend wherever the rows lie: without the left's compaction
+    a ``join-hash-4c`` job was 0.207 s shorter than with it, more than
+    the sort's own 0.147 s (``PERF.md`` section 6, PR 48).  But a join
+    puts out nothing of either table that could be cut afterwards, and
+    sizes its pair buffer from the capacities it is handed
+    (:func:`_apply_join_strategy`): it reads the slots as they are only
+    where none has to go.  Anything else - a row-wise kernel, another
+    exchange, a join that hands its left batch on, nothing at all (a
+    ``hash_partition`` that is read back) - takes the rows compacted:
+    holes cost at egress what the sort saves here.  Decided from what
+    the trace can see and nowhere else; ``lower()`` emits the same ops
+    whoever reads."""
+    if reader in SORTS_VALID_FIRST:
+        return True
+    return reader in ("join.left", "join.right") and target >= capacity
+
+
 def _do_resize(
-    ctx: StageContext, slot: int, factor: float, nparts=None
+    ctx: StageContext, slot: int, factor: float, nparts=None,
+    reader: Optional[str] = None,
 ) -> None:
     """The capacity after an exchange (every ``resize`` stage op follows
-    one, ``plan/lower.py``): compact, then entry capacity x growth x
-    boost x slack.  On one partition the exchange before it moved
-    nothing, so there is nothing to compact or to make room for."""
+    one, ``plan/lower.py``): entry capacity x growth x boost x slack,
+    the overflow flag a count of the valid rows against it, and a
+    compaction only where ``reader``, the kernel that reads the slot
+    next (looked up in the stage where the caller does not say), would
+    not sort them itself (:func:`_reader_sorts`).  On one partition the
+    exchange before it moved nothing, so there is nothing to count or
+    to make room for."""
     if _elided(ctx, exchange=False):
         return
     b = ctx.slots[slot]
@@ -379,12 +475,19 @@ def _do_resize(
     target = _round8(
         ctx.base_cap(slot) * factor * conc * ctx.boost * ctx.slack
     )
-    out, ovf = SH.resize(b, target)
+    skips = _reader_sorts(
+        reader or _next_reader(ctx, slot), target, b.capacity
+    )
+    out, ovf = SH.resize(b, target, reader_sorts=skips)
+    if out.capacity > target:
+        ctx.cuts[slot] = target  # from the reader's output (``apply_op``)
     ctx.slots[slot] = out
-    ctx.overflow = ctx.overflow | ovf
+    if target < b.capacity:  # else false as traced: nothing to OR in
+        ctx.overflow = ctx.overflow | ovf
     for said in reversed(ctx.seen_log):  # the exchange this one follows
         if said.get("slot") == slot:
             said["capacity"] = target
+            said["resize_sorts"] = int(not skips)
             break
 
 
@@ -646,9 +749,7 @@ def _k_topk(ctx: StageContext, p) -> None:
     # array would clamp and the gather arithmetic below would duplicate
     # the tail partition's rows
     n_pad = min(b.capacity, max(8, _round8(n)))
-    head = ColumnBatch(
-        {c: v[:n_pad] for c, v in sb.data.items()}, sb.valid[:n_pad]
-    )
+    head = SH.cut(sb, n_pad)
     gb = _gather_all(head, ctx.axes)  # every partition: all P heads
     # identical globally-sorted array everywhere
     gsb = SORT.sort_batch_by_operands(gb, p["operands_fn"](gb))
@@ -717,7 +818,10 @@ def _co_partition_for_join(ctx: StageContext, p) -> None:
         if p.get(f"need_{side}_exchange"):
             _do_exchange_hash(ctx, p[f"{side}_slot"], p[f"{side}_keys"])
             with jax.named_scope("dryad.resize"):  # as the stage op's is named
-                _do_resize(ctx, p[f"{side}_slot"], 1.0)
+                _do_resize(
+                    ctx, p[f"{side}_slot"], 1.0,
+                    reader=_join_side(ctx.kind, p, p[f"{side}_slot"]),
+                )
 
 
 def _apply_join_strategy(ctx: StageContext, p) -> int:
@@ -893,9 +997,10 @@ def _exchange_by_rank(
     B = SH.bucket_capacity(b.capacity, ctx.P, ctx.slack * ctx.boost)
     out, ovf = _exchange(ctx, b, dest, ctx.P, B, ctx.axes)
     ctx.overflow = ctx.overflow | ovf
-    out, ovf2 = SH.resize(out, per)
+    # the sort on ``#rank`` puts valid rows first itself (``SH.resize``)
+    out, ovf2 = SH.resize(out, per, reader_sorts=True)
     ctx.overflow = ctx.overflow | ovf2
-    return SORT.sort_batch_by_operands(out, [out.data["#rank"]])
+    return SH.cut(SORT.sort_batch_by_operands(out, [out.data["#rank"]]), per)
 
 
 def _k_zip(ctx: StageContext, p) -> None:
@@ -1386,11 +1491,12 @@ def build_stage_fn(stage, P: int, slack: float, boost: int,
                 f"arrays for {pos} registered operand slots"
             )
         with SORT.widest_row() as row_words:
-            for op in stage.ops:
+            for i, op in enumerate(stage.ops):
                 if op.kind == "do_while":
                     raise RuntimeError(
                         "do_while stages are driver-evaluated"
                     )
+                ctx.ahead = stage.ops[i + 1:]
                 apply_op(ctx, op.kind, op.params)
         outs = tuple(ctx.slots[s] for s in stage.out_slots)
         # Overflow flags from resize/join are per-device; reduce across the

@@ -25,8 +25,12 @@ where ``p`` first stands among the sorted destinations (a binary
 search for ``0..P``; the counts are the distances between the starts),
 and row ``(p, j)`` of the send buffer is read by position: one
 ``dynamic_slice`` a bucket a column, masked past the bucket's count.
-``resize`` compacts the same way, a stable sort on ``~valid`` alone
-with the columns carried.  No column is gathered by a sorted ``iota``
+``resize`` counts, and sorts only where nothing that reads the slots
+next would: it compacts the same way then, a stable sort on ``~valid``
+alone with the columns carried, and not at all before a kernel whose
+own sort puts valid rows first (a fifth of a four-chip group-by's
+device time was that second sort of the same slots; ``PERF.md``
+section 6, PR 48).  No column is gathered by a sorted ``iota``
 and none is scattered into its slots: XLA's TPU ``gather`` runs at
 28 ns an element (92.5% of the device time of a 2^25-row ``order_by``
 and 73% of a four-chip ``group_by`` in that form; ``PERF_LEDGER.jsonl``,
@@ -258,22 +262,45 @@ def exchange_staged(
 
 
 def resize(
-    batch: ColumnBatch, capacity: int
+    batch: ColumnBatch, capacity: int, reader_sorts: bool = False
 ) -> Tuple[ColumnBatch, jax.Array]:
-    """Compact valid rows to the front (``ColumnBatch.compact``: a
-    stable sort on ``~valid`` with the columns carried) and resize to
-    ``capacity``.
+    """The slots an exchange hands over, brought to ``capacity``.
 
-    Returns (batch, overflow) — overflow set when valid rows exceed the
-    new capacity (rows beyond it are dropped; the executor retries with
-    a larger shape).
+    Returns (batch, overflow).  ``overflow`` is a count and no sort:
+    more valid rows than ``capacity`` (the executor retries with a
+    larger shape), which no batch of at most ``capacity`` slots can
+    hold, so there it is false as the program is traced.
+
+    What moves the rows is the caller's to say.  ``reader_sorts``: the
+    kernel that reads the batch next sorts valid rows first itself, and
+    stably (a fold's ``_segment_layout``, a ``local_sort``, a join's
+    probe of its right side; a join's left side counts as such, its
+    rows are gathered where they lie), so nothing is compacted for it: the
+    holes stay where the exchange left them, as after a ``where``, the
+    valid rows reach the reader's sort in the order a compaction would
+    have handed them over in, and its output is the same slot for slot.
+    The batch grows to ``capacity`` (``pad_to``) and is never cut: where
+    it has more slots the reader runs over all of them and the caller
+    cuts the READER's output, whose valid rows are at the front
+    (:func:`cut`) - without an overflow nothing valid lies past
+    ``capacity`` there, with one the stage is run again.  Otherwise
+    (anything else reads the batch, or it leaves the program) valid
+    rows are compacted to the front (``ColumnBatch.compact``: a stable
+    sort on ``~valid`` with the columns carried), which is what keeps a
+    fetch's extent short, and the rows past ``capacity`` are dropped.
     """
-    compacted = batch.compact()
-    n = compacted.count()
-    overflow = n > capacity
-    if capacity == batch.capacity:
-        return compacted, overflow
     if capacity < batch.capacity:
-        data = {k: v[:capacity] for k, v in compacted.data.items()}
-        return ColumnBatch(data, compacted.valid[:capacity]), overflow
-    return compacted.pad_to(capacity), overflow
+        overflow = batch.count() > capacity
+    else:
+        overflow = jnp.zeros((), jnp.bool_)
+    if reader_sorts:
+        return batch.pad_to(max(capacity, batch.capacity)), overflow
+    return cut(batch.compact(), capacity).pad_to(capacity), overflow
+
+
+def cut(batch: ColumnBatch, capacity: int) -> ColumnBatch:
+    """The first ``capacity`` slots of a batch that has more."""
+    if capacity >= batch.capacity:
+        return batch
+    data = {k: v[:capacity] for k, v in batch.data.items()}
+    return ColumnBatch(data, batch.valid[:capacity])

@@ -288,6 +288,33 @@ def test_the_stage_program_splits_the_combiner_in_three(job, table, monkeypatch,
     assert bool(exchanged) == (P == 4)
 
 
+@pytest.mark.parametrize("cell", ["groupby-4c", "groupby-skew-4c"])
+def test_the_program_at_four_partitions_holds_three_sorts(job, table, monkeypatch, cell):
+    """The two group-by cells' P = 4 programs since PR 48: the combiner's
+    sort, the bucket layout's and the final fold's, THREE where the
+    parent had four, and nothing under ``dryad.resize``: the fold that
+    reads the received slots sorts valid rows first itself
+    (``exec/kernels.py::_reader_sorts``), so the ``resize`` between
+    them counts and does not sort; the job says so (``resize_sorts``)."""
+    if cell == "groupby-4c":
+        path = os.path.join(ROOT, "benchmarks", "jobs", "groupby.py")
+        spec = importlib.util.spec_from_file_location("bench_job_groupby", path)
+        job = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(job)
+        params = {"rows": 1 << 14, "groups": 1 << 10}
+        table = job.make_table(np.random.default_rng([48, 0]), params, None, 0)
+    else:
+        params = PARAMS
+    ctx = DryadContext(num_partitions_=4)
+    program, = lowered_programs(job, monkeypatch, table, params, 4, ctx)
+    assert len(re.findall(r"stablehlo\.sort", program.as_text())) == 3
+    paths = re.findall(r'loc\("([^"]*dryad\.[^"]*)"', program.as_text(debug_info=True))
+    assert any("dryad.exchange.layout/dryad.sort.carry" in p for p in paths)
+    assert not [p for p in paths if "dryad.resize" in p]
+    drain, = [e for e in ctx.events.events() if e["kind"] == "span" and e["name"] == "drain"]
+    assert drain["exchanges"] == 1 and drain["resize_sorts"] == 0
+
+
 def test_the_builtin_combiners_scopes_stay_what_they_were(monkeypatch):
     """``group_reduce`` keeps ``dryad.group_reduce.layout`` and
     ``.fold``: ``_segment_layout``'s scope is its caller's."""

@@ -522,8 +522,12 @@ def test_the_lowered_program_names_its_operators(shape, tmp_path, monkeypatch):
     assert len(lowered) == 1 and kinds
     paths = re.findall(r'op_name="([^"]*)"', lowered[0])
     scoped = [p for p in paths if "/dryad." in p]
-    for kind in kinds - {"project"}:  # picks columns: no operation
+    # ``project`` picks columns: no operation.  Nor has the ``resize`` one
+    # before a kernel that sorts valid rows first itself (PR 48).
+    for kind in kinds - {"project", "resize"}:
         assert any(f"/dryad.{kind}/" in p + "/" for p in scoped), kind
+    assert ("resize" in kinds) == (shape != "wordcount")
+    assert not [p for p in scoped if "/dryad.resize/" in p + "/"]
     for scope in INNER[shape]:
         under = [p for p in scoped if f"/{scope}/" in p + "/"]
         assert under, scope

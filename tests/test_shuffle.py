@@ -9,8 +9,11 @@ import pytest
 
 from dryad_tpu.columnar.batch import ColumnBatch
 from dryad_tpu.columnar.schema import ColumnType, Schema
+from dryad_tpu.exec.kernels import JOINS, build_stage_fn
 from dryad_tpu.ops.hash import partition_ids
 from dryad_tpu.ops.segmented import AggSpec, group_reduce
+from dryad_tpu.ops import join as JOIN
+from dryad_tpu.ops import segmented as SEG
 from dryad_tpu.ops import sort as SORT
 from dryad_tpu.ops.shuffle import (
     bucket_capacity,
@@ -19,8 +22,9 @@ from dryad_tpu.ops.shuffle import (
     resize,
 )
 from dryad_tpu.parallel.distribute import from_host_table, to_host_table
-from dryad_tpu.parallel.mesh import AXIS
+from dryad_tpu.parallel.mesh import AXIS, make_mesh
 from dryad_tpu.parallel.stage import compile_stage
+from dryad_tpu.plan.lower import Stage, StageOp
 from dryad_tpu.plan.xchgplan import plan_exchange
 
 from oracle import check
@@ -341,3 +345,224 @@ def test_exchange_lowers_to_slices_not_scatters(mesh8, monkeypatch, path):
     assert "stablehlo.scatter" not in text
     assert "stablehlo.dynamic_slice" in text
     assert _wide_gathers(text) == []
+
+
+# -- a resize counts; it sorts only where its slot's next reader would not (PR 48) --
+#
+# The stage programs are built as the executor builds them
+# (``build_stage_fn`` over ``resize`` + the reader), the batch a chip is
+# handed has holes as an exchange leaves them, and the reference is the
+# form every ``resize`` had through PR 47, written out here: compact,
+# cut or pad to the target, then the kernel.
+
+_SLOTS = 64  # a chip's, as the exchange hands them over; slack 1 and boost 1:
+_FACTORS = {"shrink": 0.5, "equal": 1.0, "grow": 1.5}  # the target is factor x _SLOTS
+_FILLS = {"sparse": 20, "dense": 44}  # valid rows a chip: only 44 > 32 overflows
+
+
+def _received(P, fill, seed=48):
+    """``P`` x ``_SLOTS`` slots, ``fill`` valid rows a chip at drawn
+    positions, few keys so that groups lie across the holes."""
+    rng = np.random.default_rng([seed, P, fill])
+    n = P * _SLOTS
+    valid = np.zeros(n, np.bool_)
+    for p in range(P):
+        valid[p * _SLOTS + rng.choice(_SLOTS, fill, replace=False)] = True
+    return ColumnBatch({
+        "k": jnp.asarray(rng.integers(-6, 6, n).astype(np.int32)),
+        "v": jnp.asarray(rng.standard_normal(n).astype(np.float32)),
+        "n": jnp.asarray(np.ones(n, np.int32)),
+    }, jnp.asarray(valid))
+
+
+def _merge(a, b):
+    return {"n": a["n"] + b["n"], "v": a["v"] + b["v"]}
+
+
+def _operands(b):
+    from dryad_tpu.ops.sortkeys import to_sortable_u32
+
+    return [to_sortable_u32(b.data["k"])]
+
+
+_AGGS = [AggSpec("count", None, "c"), AggSpec("sum", "v", "s"), AggSpec("first", "n", "n")]
+# reader -> (the stage op's params beside its slots, the kernel over (left, received))
+_READERS = {
+    "group_reduce": (
+        dict(keys=["k"], aggs=_AGGS),
+        lambda _l, b: group_reduce(b, ["k"], _AGGS)),
+    "group_combine": (
+        dict(keys=["k"], state_cols=["n", "v"], merge=_merge),
+        lambda _l, b: SEG.group_combine(b, ["k"], ["n", "v"], _merge)),
+    "distinct": (dict(keys=["k"]), lambda _l, b: SEG.distinct(b, ["k"])),
+    "local_sort": (
+        dict(operands_fn=_operands),
+        lambda _l, b: SORT.sort_batch_by_operands(b, _operands(b))),
+    "join": (
+        dict(left_keys=["k"], right_keys=["k"], expansion=8.0),
+        lambda l, b: JOIN.hash_join(
+            l, b, ["k"], ["k"], 8 * max(l.capacity, b.capacity), "_r")[0]),
+    # the received rows are the join's LEFT side (its right: the other input)
+    "join_left": (
+        dict(left_keys=["k"], right_keys=["k"], expansion=8.0, left_slot=1, right_slot=0),
+        lambda r, b: JOIN.hash_join(
+            b, r, ["k"], ["k"], 8 * max(r.capacity, b.capacity), "_r")[0]),
+}
+
+
+def _stage_after_resize(reader, factor, **named):
+    """``resize`` of slot 1 and then ``reader`` (of that slot, a join's
+    right, unless ``named`` says other slots), a stage of two inputs
+    whose output is the join's or the resized slot."""
+    ops = [StageOp("resize", dict(slot=1, factor=factor))]
+    out = 1
+    if reader is not None:
+        params = dict(_READERS.get(reader, ({},))[0])
+        kind = reader.split("_left")[0]  # ``join_left`` is a ``join``
+        if kind in JOINS:
+            params = dict(dict(left_slot=0, right_slot=1), **params)
+        elif "slots" not in named:
+            params["slot"] = 1
+        params.update(named)
+        ops.append(StageOp(kind, params))
+        out = params["left_slot"] if kind in JOINS else 1
+    return Stage(0, "resized", [("plan_input", 0), ("plan_input", 1)], ops=ops,
+                 out_slots=[out])
+
+
+def _parents_form(reader, target):
+    """compact, cut or pad, then the kernel: the stage through PR 47."""
+
+    def stage(sharded, _):
+        left, b = sharded
+        c = b.compact()
+        overflow = c.count() > target
+        if target < c.capacity:
+            c = ColumnBatch({k: v[:target] for k, v in c.data.items()}, c.valid[:target])
+        else:
+            c = c.pad_to(target)
+        overflow = jax.lax.psum(overflow.astype(jnp.int32), AXIS) > 0
+        return (_READERS[reader][1](left, c),), (overflow,)
+
+    return stage
+
+
+def _probe_side(P):
+    """The join's left table: every slot a row, the received keys' range."""
+    n = P * _SLOTS
+    k = np.random.default_rng([48, P]).integers(-6, 6, n).astype(np.int32)
+    return ColumnBatch({"k": jnp.asarray(k), "i": jnp.arange(n, dtype=jnp.int32)},
+                       jnp.ones(n, jnp.bool_))
+
+
+@pytest.mark.parametrize("P", [4, 8])
+@pytest.mark.parametrize("fill", list(_FILLS))
+@pytest.mark.parametrize("to", list(_FACTORS))
+@pytest.mark.parametrize("reader", list(_READERS))
+def test_a_resize_before_a_kernel_that_sorts_is_the_parents_form(reader, to, fill, P):
+    """Rule 2: whichever of the readers follows (a join as the reader
+    of either side) and whatever the target, the overflow flag is the parent's, and wherever it is false
+    (where it is true the stage is run again and its output dropped) so
+    is every row of the output, slot for slot and to the bit: the f32
+    sums and the user's ``merge`` fold the rows in the parent's order."""
+    mesh = make_mesh(P)
+    target = int(_FACTORS[to] * _SLOTS)
+    inputs = (_probe_side(P), _received(P, _FILLS[fill]))
+    fn = build_stage_fn(_stage_after_resize(reader, _FACTORS[to]), P, 1.0, 1)
+    (got,), (got_ovf, _, _) = compile_stage(mesh, fn)(inputs, ())
+    (want,), (want_ovf,) = compile_stage(mesh, _parents_form(reader, target))(inputs, ())
+    assert bool(got_ovf) == bool(want_ovf) == (_FILLS[fill] > target)
+    if bool(want_ovf):
+        return
+    assert got.capacity == want.capacity and got.columns == want.columns
+    valid = np.asarray(want.valid)
+    np.testing.assert_array_equal(np.asarray(got.valid), valid)
+    assert valid.any()
+    for name in want.columns:
+        np.testing.assert_array_equal(
+            np.asarray(got[name])[valid], np.asarray(want[name])[valid], err_msg=name)
+
+
+def _compacts(stage, P=4):
+    """Whether the stage's lowered program holds the compaction's sort,
+    and the sorts it holds."""
+    inputs = (_probe_side(P), _received(P, _FILLS["sparse"]))
+    lowered = compile_stage(make_mesh(P), build_stage_fn(stage, P, 1.0, 1)).lower(inputs, ())
+    paths = re.findall(r'loc\("([^"]*dryad\.[^"]*)"', lowered.as_text(debug_info=True))
+    sorts = len(re.findall(r"stablehlo\.sort", lowered.as_text()))
+    return any("dryad.resize/dryad.sort.carry" in p for p in paths), sorts
+
+
+_OTHER_READERS = {
+    # nothing reads the slot: it leaves the stage (a ``hash_partition`` read back)
+    "nothing": (None, {}),
+    "select": ("select", dict(fn=lambda cols: cols)),
+    "where": ("where", dict(fn=lambda cols: cols["k"] > 0)),
+    "project": ("project", dict(cols=["k", "v"])),
+    "apply": ("apply", dict(fn=lambda b: b)),
+    "concat": ("concat", dict(slots=[1, 1], out_slot=1)),
+    "another_exchange": ("exchange_hash", dict(keys=["k"])),
+    # a kernel that sorts, but another slot: this one leaves the stage as it is
+    "local_sort_of_another_slot": ("local_sort", dict(slot=0)),
+    # a join that hands its left batch on, slot for slot: holes would reach the egress
+    "semi_left": ("semi", dict(left_slot=1, right_slot=0, left_keys=["k"],
+                               right_keys=["k"], expansion=8.0)),
+    "outer_join_left": ("join", dict(left_slot=1, right_slot=0, outer=True,
+                                     **_READERS["join"][0])),
+}
+
+
+@pytest.mark.parametrize("reader", list(_OTHER_READERS))
+def test_a_resize_before_anything_else_still_compacts(reader):
+    """Rule 3: the compaction's sort stays in the lowered program, under
+    the ``resize``'s scope, whatever else reads the slot next."""
+    kind, named = _OTHER_READERS[reader]
+    compacts, _ = _compacts(_stage_after_resize(kind, 1.0, **named))
+    assert compacts
+
+
+@pytest.mark.parametrize("reader,to,compacts", [
+    (r, to, False) for r in list(_READERS)[:4] for to in _FACTORS
+] + [(j, to, to == "shrink") for j in ("join", "join_left") for to in _FACTORS])
+def test_what_a_resize_before_a_sorting_kernel_lowers_to(reader, to, compacts):
+    """Rule 2 in the program's text: no sort under ``dryad.resize`` and
+    ONE sort in the whole stage where a fold or a ``local_sort`` reads
+    the slot; before either side of a join one sort fewer than the
+    stage that cuts, which keeps its compaction (the probe sorts the
+    right side whatever was done to it and merges the left hashes into
+    it)."""
+    found, sorts = _compacts(_stage_after_resize(reader, _FACTORS[to]))
+    assert found == compacts
+    if reader.startswith("join"):
+        _, cutting = _compacts(_stage_after_resize(reader, _FACTORS["shrink"]))
+        assert sorts == cutting - (not compacts)
+    else:
+        assert sorts == 1
+
+
+@pytest.mark.parametrize("query,sorts", [("group_by", 0), ("order_by", 0), ("hash_partition", 1)])
+def test_a_job_says_how_many_resizes_sorted(query, sorts):
+    """``resize_sorts`` on the ``drain`` span and the ``exchange_observed``
+    event, a trace-time constant beside ``exchanges``: none where a fold
+    or a ``local_sort`` reads the received rows, one for the rows of a
+    ``hash_partition`` that go back to the user as they are."""
+    from dryad_tpu import DryadContext
+
+    rng = np.random.default_rng(48)
+    ctx = DryadContext(num_partitions_=4)
+    table = ctx.from_arrays({
+        "k": (rng.integers(0, 50, 4096) - 1).astype(np.int32),  # a negative key: no dense route
+        "v": rng.standard_normal(4096).astype(np.float32),
+    })
+    bound = {
+        "group_by": lambda: table.group_by("k", {"c": ("count", None), "s": ("sum", "v")}),
+        "order_by": lambda: table.order_by(["k"]),
+        "hash_partition": lambda: table.hash_partition("k"),
+    }[query]()
+    assert len(bound.collect()["k"]) == (50 if query == "group_by" else 4096)
+    events = ctx.events.events()
+    seen, = [e for e in events if e["kind"] == "exchange_observed"]
+    drain, = [e for e in events if e["kind"] == "span" and e["name"] == "drain"]
+    assert seen["exchanges"] == drain["exchanges"] == 1
+    assert seen["resize_sorts"] == drain["resize_sorts"] == sorts
+    assert type(drain["resize_sorts"]) is int  # a number: it rides the profiler annotation

@@ -161,7 +161,11 @@ def test_the_stage_program_is_the_parents(job, monkeypatch):
     name, which is in jax's compilation cache key, counts the
     generations of scopes (``parallel/stage.py``): PR 30 added none and
     kept ``dryad_stage_2``; PR 41's ``dryad.group_combine.*`` made it
-    ``dryad_stage_3``."""
+    ``dryad_stage_3``.  Since PR 48 nothing lies under ``dryad.resize``:
+    the ``local_sort`` that reads the slot next puts valid rows first
+    itself, so the program holds one sort fewer (the sample's on a chip,
+    the elected sample's, the bucket layout's and the ``local_sort``'s:
+    four where the parent had five, three of them over a chip's rows)."""
     from dryad_tpu.parallel import stage
 
     program, = lowered_programs(
@@ -174,8 +178,11 @@ def test_the_stage_program_is_the_parents(job, monkeypatch):
     assert any(under + "dryad.sort.splitters/all_gather" in p for p in paths)
     assert any(under + "dryad.sort.splitters/dryad.sort.carry/" in p for p in paths)
     assert any(under + "dryad.exchange.layout/dryad.sort.carry/" in p for p in paths)
-    assert any("/dryad.resize/" in p + "/" for p in paths)
+    assert not [p for p in paths if "dryad.resize" in p]
     assert any("/dryad.local_sort/dryad.sort.carry/" in p for p in paths)
+    assert len(re.findall(r"stablehlo\.sort", program.as_text())) == 4
+    lowered = re.findall(r'loc\("([^"]*dryad\.[^"]*)"', program.as_text(debug_info=True))
+    assert lowered and not [p for p in lowered if "dryad.resize" in p]
 
 
 def test_the_one_chip_program_is_the_local_sort_alone(job, monkeypatch):
