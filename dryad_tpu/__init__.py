@@ -23,7 +23,14 @@ Dryad + DryadLINQ (reference: wycharry/Dryad), re-designed TPU-first:
 """
 
 from dryad_tpu.utils.config import DryadConfig, StaticConfig
-from dryad_tpu.columnar.schema import BYTES, Schema, ColumnType, StringDictionary
+from dryad_tpu.columnar.schema import (
+    BYTES,
+    DECIMAL,
+    ColumnType,
+    Schema,
+    StringDictionary,
+    date,
+)
 from dryad_tpu.columnar.batch import ColumnBatch
 
 from dryad_tpu.api.decomposable import Decomposable
@@ -37,6 +44,8 @@ __all__ = [
     "StaticConfig",
     "Schema",
     "BYTES",
+    "DECIMAL",
+    "date",
     "ColumnType",
     "StringDictionary",
     "ColumnBatch",

@@ -25,6 +25,7 @@ from dryad_tpu.columnar.batch import ColumnBatch, _nbytes
 from dryad_tpu.columnar.schema import (
     BYTES,
     ColumnType,
+    DecimalType,
     Schema,
     StringDictionary,
 )
@@ -66,6 +67,7 @@ _NP_TYPE_MAP = {
     np.dtype(np.float64): ColumnType.FLOAT64,
     np.dtype(np.bool_): ColumnType.BOOL,
     np.dtype(np.uint32): ColumnType.UINT32,
+    np.dtype("datetime64[D]"): ColumnType.DATE,
 }
 
 
@@ -734,7 +736,15 @@ class DryadContext:
                 [q.node for q in queries], self.config, self.dictionary,
                 P=num_partitions(self.mesh) if self.mesh is not None else None,
             )
-            span.add(stages=len(graph.stages), roots=len(queries))
+            bound = [f for n in graph.inputs.values() for f in n.schema.fields]
+            span.add(
+                stages=len(graph.stages), roots=len(queries),
+                # the logical types of the bound columns: which of them
+                # the device holds as something else (DECIMAL, DATE)
+                types=",".join(f"{f.name}:{f.ctype.value}" for f in bound),
+                decimal_cols=sum(isinstance(f.ctype, DecimalType) for f in bound),
+                date_cols=sum(f.ctype is ColumnType.DATE for f in bound),
+            )
         bindings = {
             nid: self.inputs.device_batch(n) for nid, n in graph.inputs.items()
         }

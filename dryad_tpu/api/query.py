@@ -26,7 +26,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from dryad_tpu.api.decomposable import Decomposable
-from dryad_tpu.columnar.schema import ColumnType, Schema
+from dryad_tpu.columnar.schema import ColumnType, DecimalType, Schema
+from dryad_tpu.ops import wide
 from dryad_tpu.plan import infer
 from dryad_tpu.plan.nodes import Node, PartitionInfo
 
@@ -42,9 +43,24 @@ def _check_strategy(strategy: str) -> None:
             f"unknown join strategy {strategy!r}; expected one of {JOIN_STRATEGIES}"
         )
 
+def _sum_type(ct):
+    """A sum keeps its column's type, but a DECIMAL's is always wide:
+    a column of money fits 32 bits, its total does not (SQL's SUM of a
+    DECIMAL(p, s) is a DECIMAL(38, s) for the same reason)."""
+    return DecimalType(ct.scale, wide=True) if isinstance(ct, DecimalType) else ct
+
+
+def _agg_type(op: str, col: Optional[str], ct):
+    """The type of ``op`` over a column of type ``ct``, for both
+    engines: days do not add up."""
+    if ct is ColumnType.DATE and op in ("sum", "mean"):
+        raise ValueError(f"aggregate {op!r} unsupported on DATE column {col!r}")
+    return _AGG_TYPE_RULES[op](ct)
+
+
 _AGG_TYPE_RULES = {
     "count": lambda ct: ColumnType.INT32,
-    "sum": lambda ct: ct,
+    "sum": _sum_type,
     "min": lambda ct: ct,
     "max": lambda ct: ct,
     "first": lambda ct: ct,
@@ -92,6 +108,49 @@ class _Project:
 
     def __call__(self, cols: Dict) -> Dict:
         return {c: cols[c] for c in self.phys}
+
+
+class _Typed:
+    """A row function over LOGICAL columns, as a kernel calls it over
+    physical ones: each DECIMAL column of the input reaches ``fn`` as
+    one ``ops/wide.py::Dec`` under its logical name (scaled integers
+    whose scale the engine knows: ``price * (1 - discount)`` is exact
+    and comes out a DECIMAL), every other column as the device holds it
+    (a DATE as int32 days: compare it with ``dryad_tpu.date(...)``);
+    a ``Dec`` the function returns becomes its physical word or pair.
+    ``select`` and ``where`` wrap their function in one where the input
+    has a DECIMAL column, and only there, so every other plan is what
+    it was.  Picklable if ``fn`` is, and VALUE-equal so re-lowering a
+    rebuilt query hits the compiled-stage cache."""
+
+    def __init__(self, fn, schema: Schema):
+        self.fn = fn
+        self.decimals = tuple(
+            f for f in schema.fields if isinstance(f.ctype, DecimalType)
+        )
+
+    def __eq__(self, other) -> bool:
+        return (
+            type(other) is _Typed and other.fn == self.fn
+            and other.decimals == self.decimals
+        )
+
+    def __hash__(self) -> int:
+        return hash(("_Typed", self.fn, self.decimals))
+
+    def logical(self, cols: Dict):
+        """``fn``'s own answer: what schema inference reads the DECIMAL
+        types from."""
+        return self.fn(wide.wrap(cols, self.decimals))
+
+    def __call__(self, cols: Dict):
+        out = self.logical(cols)
+        return wide.unwrap(out) if isinstance(out, dict) else out
+
+
+def _typed(fn, schema: Schema):
+    has_decimal = any(isinstance(f.ctype, DecimalType) for f in schema.fields)
+    return _Typed(fn, schema) if has_decimal else fn
 
 
 _VOCAB_PRESERVING = frozenset({
@@ -150,7 +209,14 @@ class Query:
         even when the key *name* survives, which would make shuffle
         elision silently wrong.  Use ``project`` (name-only projection)
         or ``assume_*_partition`` to retain metadata.
+
+        A DECIMAL column reaches ``fn`` (and ``where``'s) as one exact
+        value under its logical name, not as physical words
+        (:class:`_Typed`), and an expression over such values is a
+        DECIMAL column of the output with the scale the arithmetic
+        gives; a DATE is its int32 days and stays a DATE under its name.
         """
+        fn = _typed(fn, self.schema)
         out_schema = schema or infer.infer_select_schema(self.schema, fn)
         node = Node("select", [self.node], out_schema, PartitionInfo(), fn=fn)
         return Query(self.ctx, node)
@@ -168,6 +234,7 @@ class Query:
         return Query(self.ctx, Node("select", [self.node], out_schema, keep, fn=fn))
 
     def where(self, fn: Callable[[Dict], Any]) -> "Query":
+        fn = _typed(fn, self.schema)
         node = Node("where", [self.node], self.schema, self.node.partition, fn=fn)
         return Query(self.ctx, node)
 
@@ -206,6 +273,24 @@ class Query:
         int64 range (numpy int64 semantics — C# long Average instead
         throws OverflowException there).  float64 supports
         min/max/first (totalOrder); cast to float32 for sums.
+        A DECIMAL column's ``sum`` is a wide (64-bit) DECIMAL of the
+        same scale, exact modulo 2^64 whichever width the column has,
+        its ``min`` / ``max`` / ``first`` keep its type and its ``mean``
+        is an f32 in units (the exact sum, one f32 division by the count
+        and one by 10^scale); a DATE takes ``min`` / ``max`` / ``first``.
+
+        **Which path a group-by takes.**  A sum that has to be exact
+        (INT64, DECIMAL), more than one key, or a key that is not a
+        bounded INT32 / a dictionary STRING goes down the SORT path:
+        the rows sorted by key with their columns carried, a segmented
+        scan whose 64-bit channels add with carry
+        (``ops/segmented.py``, ``ops/wide.py``), one compaction; cost
+        grows with the rows, not with the groups, so four groups cost
+        what four million do.  The dense MXU path below (``dense=K``,
+        or picked by the engine for ONE bounded INT32 / STRING key over
+        plain 32-bit columns) sums in f32 and refuses split and DECIMAL
+        columns: exact money goes down the sort path today, however
+        few the groups.
 
         ``salt=S`` spreads each key over S shuffle destinations
         (partial-reduce on (key, salt), exchange, reduce, then exchange
@@ -267,15 +352,18 @@ class Query:
                 raise ValueError(
                     f"dense group_by supports sum/count/mean, got {bad}"
                 )
-            wide = [
+            exact = [
                 c for _o, (_op, c) in aggs.items()
-                if c is not None and self.schema.field(c).ctype.is_split
+                if c is not None and (
+                    self.schema.field(c).ctype.is_split
+                    or isinstance(self.schema.field(c).ctype, DecimalType)
+                )
             ]
-            if wide:
+            if exact:
                 raise ValueError(
                     f"dense group_by aggregates f32 on the MXU; columns "
-                    f"{wide} are 64-bit/split types — use the default "
-                    f"sort-based path"
+                    f"{exact} are 64-bit/split or DECIMAL types — use the "
+                    f"default sort-based path"
                 )
         fields: List[Tuple[str, ColumnType]] = [
             (k, self.schema.field(k).ctype) for k in keys
@@ -294,7 +382,7 @@ class Query:
             if op not in _AGG_TYPE_RULES:
                 raise ValueError(f"unknown aggregate {op!r}")
             ct = self.schema.field(col).ctype if col is not None else ColumnType.INT32
-            fields.append((out_name, _AGG_TYPE_RULES[op](ct)))
+            fields.append((out_name, _agg_type(op, col, ct)))
             agg_list.append((op, col, out_name))
         if dense is not None:
             part = PartitionInfo.ranged(
@@ -1177,7 +1265,7 @@ class Query:
         fields = []
         for op, col, out in aggs:
             ct = self.schema.field(col).ctype if col else ColumnType.INT32
-            fields.append((out, _AGG_TYPE_RULES[op](ct)))
+            fields.append((out, _agg_type(op, col, ct)))
         return Node(
             "aggregate", [self.node], Schema(fields), PartitionInfo(), aggs=aggs
         )

@@ -63,7 +63,7 @@ def encode_physical(
             f"{field.name}#r0": string_prefix_rank(sarr),
             f"{field.name}#r1": string_prefix_rank(sarr, offset=4),
         }
-    if field.ctype == ColumnType.INT64:
+    if field.ctype.storage is ColumnType.INT64:  # INT64, a wide DECIMAL
         lo, hi = split64(a.astype(np.int64))
         return {f"{field.name}#h0": lo, f"{field.name}#h1": hi}
     if field.ctype == ColumnType.FLOAT64:
@@ -71,7 +71,16 @@ def encode_physical(
 
         lo, hi = split64(f64_to_ordered_i64(a))
         return {f"{field.name}#h0": lo, f"{field.name}#h1": hi}
-    return {field.name: a.astype(field.ctype.numpy_dtype)}
+    return {field.name: host_to_device(field.ctype, a)}
+
+
+def host_to_device(ctype, a: np.ndarray) -> np.ndarray:
+    """A one-word logical column in its device dtype.  A DATE's days
+    since 1970-01-01 are counted from whatever unit the array has; every
+    other type is a cast."""
+    if ctype is ColumnType.DATE and a.dtype.kind == "M":
+        a = a.astype("datetime64[D]").astype(np.int64)
+    return a.astype(ctype.storage.numpy_dtype)
 
 
 def encode_table(
@@ -526,7 +535,7 @@ def decode_physical_table(
                 out[f.name] = np.array(
                     dictionary.lookup_all(hashes), dtype=object
                 )
-        elif f.ctype == ColumnType.INT64:
+        elif f.ctype.storage is ColumnType.INT64:  # INT64, a wide DECIMAL
             out[f.name] = join64(
                 rows(f"{f.name}#h0"), rows(f"{f.name}#h1"), signed=True
             )
@@ -536,6 +545,8 @@ def decode_physical_table(
             out[f.name] = ordered_i64_to_f64(join64(
                 rows(f"{f.name}#h0"), rows(f"{f.name}#h1"), signed=True
             ))
+        elif f.ctype is ColumnType.DATE:
+            out[f.name] = rows(f.name).astype(np.int64).astype("datetime64[D]")
         else:
             out[f.name] = rows(f.name)
     return out

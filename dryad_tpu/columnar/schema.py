@@ -37,6 +37,11 @@ class ColumnType(enum.Enum):
     BOOL = "bool"
     UINT32 = "uint32"
     STRING = "string"  # dictionary-encoded: two uint32 hash words + host dict
+    # A calendar day.  Host form ``datetime64[D]``, in and out; device
+    # form ONE int32 column of days since 1970-01-01, so every int32
+    # kernel (compare, sort, min / max, group key) applies unchanged.
+    # In a row function it is that int32: compare it with :func:`date`.
+    DATE = "date"
 
     @property
     def is_split(self) -> bool:
@@ -48,6 +53,11 @@ class ColumnType(enum.Enum):
         return False
 
     @property
+    def storage(self) -> "ColumnType":
+        """The type whose device form and kernels this one shares."""
+        return ColumnType.INT32 if self is ColumnType.DATE else self
+
+    @property
     def numpy_dtype(self) -> np.dtype:
         return {
             ColumnType.INT32: np.dtype(np.int32),
@@ -57,6 +67,7 @@ class ColumnType(enum.Enum):
             ColumnType.BOOL: np.dtype(np.bool_),
             ColumnType.UINT32: np.dtype(np.uint32),
             ColumnType.STRING: np.dtype(object),
+            ColumnType.DATE: np.dtype("datetime64[D]"),
         }[self]
 
 
@@ -100,21 +111,86 @@ class BytesType:
         return f"BYTES({self.width})"
 
 
+    @property
+    def storage(self):
+        return self
+
+
 BYTES = BytesType
+
+
+@dataclasses.dataclass(frozen=True)
+class DecimalType:
+    """An exact fixed-point number, ``DECIMAL(scale)``: scaled integers
+    with ``scale`` digits after the point, the type carrying the scale
+    as :class:`BytesType` carries its width.
+
+    Host form: an integer array of the SCALED values (12.34 at scale 2
+    is 1234), in and out: ``int32`` for the narrow form (32 bits, one
+    int32 device column, the form a table's columns take where they
+    fit), ``int64`` for the wide one (``DECIMAL(scale, wide=True)``: 64
+    bits, two uint32 device words ``#h0`` / ``#h1`` as INT64 has them).
+    The engine keeps the scale through ``select`` (``ops/wide.py::Dec``:
+    a product's scale is the sum of its factors' and its form wide),
+    ``group_by`` (``sum`` is wide at the column's scale and exact
+    modulo 2^64, ``min`` / ``max`` / ``first`` keep the type, ``mean``
+    is an f32 in units) and ``order_by``; nothing rounds."""
+
+    scale: int
+    wide: bool = False
+
+    def __post_init__(self) -> None:
+        if not isinstance(self.scale, int) or not 0 <= self.scale <= 18:
+            raise ValueError(f"DECIMAL scale must be an int in 0..18, got {self.scale!r}")
+
+    is_bytes = False
+
+    @property
+    def is_split(self) -> bool:
+        return self.wide
+
+    @property
+    def value(self) -> str:
+        return f"decimal{64 if self.wide else 32}[{self.scale}]"
+
+    @property
+    def numpy_dtype(self) -> np.dtype:
+        return np.dtype(np.int64 if self.wide else np.int32)
+
+    @property
+    def storage(self) -> ColumnType:
+        return ColumnType.INT64 if self.wide else ColumnType.INT32
+
+    def __repr__(self) -> str:
+        return f"DECIMAL({self.scale}{', wide=True' if self.wide else ''})"
+
+
+DECIMAL = DecimalType
+
+
+def date(day) -> int:
+    """A calendar day (``"1998-09-02"``, a ``datetime.date``, a
+    ``datetime64``) as a DATE column holds it on the device: days since
+    1970-01-01.  What a ``where`` compares a DATE column with."""
+    return int(np.datetime64(day, "D").astype(np.int64))
 
 
 def parse_ctype(value: str):
     """The column type a manifest's string names: the inverse of
-    ``ctype.value`` (``"int32"`` ..., ``"bytes[10]"``)."""
+    ``ctype.value`` (``"int32"`` ..., ``"bytes[10]"``,
+    ``"decimal32[2]"``)."""
     if value.startswith("bytes[") and value.endswith("]"):
         return BytesType(int(value[6:-1]))
+    for prefix, wide in (("decimal32[", False), ("decimal64[", True)):
+        if value.startswith(prefix) and value.endswith("]"):
+            return DecimalType(int(value[len(prefix):-1]), wide)
     return ColumnType(value)
 
 
 def device_column_names(name: str, ctype) -> List[str]:
     """Physical device-column names backing one logical column.
 
-    INT64  -> ``#h0`` (low word), ``#h1`` (high word).
+    INT64 / a wide DECIMAL -> ``#h0`` (low word), ``#h1`` (high word).
     STRING -> ``#h0``/``#h1`` (Hash64 words, the identity) plus ``#r0``/``#r1``,
     an order-preserving uint32 rank of the first 4 UTF-8 bytes
     (big-endian), so range partitioning / OrderBy on strings is exact on
@@ -126,7 +202,7 @@ def device_column_names(name: str, ctype) -> List[str]:
         return [f"{name}#b{i}" for i in range(ctype.words)]
     if ctype == ColumnType.STRING:
         return [f"{name}#h0", f"{name}#h1", f"{name}#r0", f"{name}#r1"]
-    if ctype in (ColumnType.INT64, ColumnType.FLOAT64):
+    if ctype.storage in (ColumnType.INT64, ColumnType.FLOAT64):
         return [f"{name}#h0", f"{name}#h1"]
     return [name]
 
@@ -404,7 +480,8 @@ class Schema:
         """Physical device column -> its dtype: the words of a split
         column are uint32, any other column keeps its own."""
         return {
-            n: np.dtype(np.uint32) if f.ctype.is_split else f.ctype.numpy_dtype
+            n: np.dtype(np.uint32) if f.ctype.is_split
+            else f.ctype.storage.numpy_dtype
             for f in self.fields for n in f.device_names
         }
 

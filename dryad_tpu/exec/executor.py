@@ -153,6 +153,20 @@ def _lowering_key_hash(key) -> str:
     return format(zlib.crc32(repr(key).encode()) & 0xFFFFFFFF, "08x")
 
 
+def _fold_stats(stage) -> Dict[str, int]:
+    """Of a stage with a builtin-aggregate group-by, what its widest
+    fold carries a slot (``ops/segmented.py::fold_stats`` of the
+    ``group_reduce`` with the most state words); nothing for any other
+    stage.  Static: read from the stage's ops, not from a trace."""
+    from dryad_tpu.ops.segmented import fold_stats
+
+    folds = [
+        fold_stats(op.params["keys"], op.params["aggs"])
+        for op in stage.ops if op.kind == "group_reduce"
+    ]
+    return max(folds, key=lambda f: f["agg_state_words"], default={})
+
+
 def _ici_bytes(xchg_rounds) -> int:
     """Bytes one chip puts on the ICI in a dispatch: the sum over the
     program's ``exchange_round`` accounting."""
@@ -250,7 +264,7 @@ def _phys_np_dtype(col: str, schema):
         ColumnType.FLOAT32: np.dtype(np.float32),
         ColumnType.BOOL: np.dtype(np.bool_),
         ColumnType.UINT32: np.dtype(np.uint32),
-    }[schema.field(col).ctype]
+    }[schema.field(col).ctype.storage]
 
 
 class GraphExecutor:
@@ -1306,6 +1320,7 @@ class GraphExecutor:
                     xchg_ici_bytes=_ici_bytes(fn.xchg_rounds),
                     xchg_elided=fn.xchg_elided,
                     row_words=fn.row_words,
+                    **_fold_stats(stage),
                 ) as dispatch_span:
                     # OPERAND params ride the replicated slot: current
                     # table content from the pool (uploaded/scattered

@@ -1203,10 +1203,7 @@ def _global_pair_reduce(
     the gathered pairs the same way.  All-invalid partitions contribute
     the op identity (neutral), so the gathered reduce needs no
     validity."""
-    hi_col = lo_col[: -len("#h0")] + "#h1"
-    plo, phi = SEG.pair_scalar_reduce(
-        op, b.data[lo_col], b.data[hi_col], v
-    )
+    plo, phi = SEG.pair_scalar_reduce(op, *SEG.pair_words(b.data, lo_col), v)
     glo = jax.lax.all_gather(plo[None], ctx.axes, tiled=True)
     ghi = jax.lax.all_gather(phi[None], ctx.axes, tiled=True)
     return SEG.pair_scalar_reduce(
@@ -1244,10 +1241,8 @@ def _k_scalar_agg(ctx: StageContext, p) -> None:
         elif a.op == "mean64":
             # Average over long: exact global sum64, f32 divide
             tlo, thi = _global_pair_reduce(ctx, "sum64", b, a.col, v)
-            c = jax.lax.psum(jnp.sum(v.astype(jnp.float32)), ctx.axes)
-            out[a.out] = (
-                SEG.pair_to_f32(tlo, thi) / jnp.maximum(c, 1.0)
-            )[None]
+            c = jax.lax.psum(jnp.sum(v.astype(jnp.int32)), ctx.axes)
+            out[a.out] = SEG.pair_mean(tlo, thi, c, a.scale)[None]
         elif a.op in SEG.PAIR_OPS:
             # 64-bit scalar over a split column
             tlo, thi = _global_pair_reduce(ctx, a.op, b, a.col, v)

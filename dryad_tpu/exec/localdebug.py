@@ -50,6 +50,33 @@ def _join_split_col(t: Table, col: str) -> np.ndarray:
     )
 
 
+def _int64_view(t: Table, col, ctype, op: str):
+    """The column as int64 where the engine carries ``op`` over it in
+    64 bits, else None: a split INT64 or wide DECIMAL (``sum`` / ``min``
+    / ``max`` / ``mean``), a FLOAT64's ordered image (``min`` / ``max``:
+    they commute with the monotone transform), a narrow DECIMAL's
+    ``sum`` and ``mean`` (``plan/lower.py::_phys_aggs``)."""
+    from dryad_tpu.columnar.schema import ColumnType, DecimalType
+
+    if col is None:
+        return None
+    if col in t:
+        narrow = isinstance(ctype, DecimalType) and op in ("sum", "mean")
+        return np.asarray(t[col]).astype(np.int64) if narrow else None
+    ops = {
+        ColumnType.INT64: ("sum", "min", "max", "mean"),
+        ColumnType.FLOAT64: ("min", "max"),
+    }.get(ctype.storage, ())
+    return _join_split_col(t, col) if op in ops else None
+
+
+def _mean_in_units(total: int, n: int, ctype) -> np.float32:
+    """The engine's mean of a 64-bit sum: the WRAPPED int64 total (mod
+    2^64, the documented contract) over the count, a DECIMAL's in
+    units."""
+    return np.float32(np.float64(total) / n / 10.0 ** getattr(ctype, "scale", 0))
+
+
 def _key_tuples(t: Table, cols: List[str]) -> List[tuple]:
     arrs = [np.asarray(t[c]) for c in cols]
     return list(zip(*[a.tolist() for a in arrs])) if arrs else [()] * _rows(t)
@@ -200,39 +227,23 @@ class LocalDebugInterpreter:
                     f"aggregate {op!r} unsupported on float64 column "
                     f"{col!r}: cast to float32"
                 )
-            if (
-                col is not None
-                and col not in t
-                and (
-                    (ctype is ColumnType.INT64 and op in ("sum", "min", "max"))
-                    # FLOAT64 words are the order-preserving i64 image:
-                    # min/max commute with the monotone transform
-                    or (ctype is ColumnType.FLOAT64 and op in ("min", "max"))
-                )
-            ):
-                # split 64-bit column: independent numpy-int64 oracle for
-                # the engine's paired-word arithmetic (wrapping sum)
-                full = _join_split_col(t, col)
+            full = _int64_view(t, col, ctype, op)
+            if full is not None and op == "mean":
+                with np.errstate(over="ignore"):
+                    out[name] = np.array(
+                        [_mean_in_units(full[idx].sum(), len(idx), ctype)
+                         for idx in order],
+                        np.float32,
+                    )
+                continue
+            if full is not None:
+                # independent numpy-int64 oracle for the engine's
+                # paired-word arithmetic (wrapping sum)
                 with np.errstate(over="ignore"):
                     vals64 = np.array(
                         [getattr(full[idx], op)() for idx in order], np.int64
                     )
                 out[f"{name}#h0"], out[f"{name}#h1"] = split64(vals64)
-                continue
-            if (
-                col is not None and col not in t
-                and ctype is ColumnType.INT64 and op == "mean"
-            ):
-                full = _join_split_col(t, col)
-                # mirror the engine: WRAPPING int64 sum (mod 2^64, the
-                # documented contract) then f32 divide — a true-f64 mean
-                # here would diverge from the device on overflow
-                with np.errstate(over="ignore"):
-                    out[name] = np.array(
-                        [np.float64(full[idx].sum()) / len(idx)
-                         for idx in order],
-                        np.float32,
-                    )
                 continue
             if col is not None and col not in t and (
                 in_schema.field(col).ctype.is_split
@@ -468,15 +479,17 @@ class LocalDebugInterpreter:
                     f"aggregate {op!r} unsupported on float64 column "
                     f"{col!r}: cast to float32"
                 )
-            if col is not None and col not in t and (
-                (ctype is ColumnType.INT64 and op in ("sum", "min", "max"))
-                or (ctype is ColumnType.FLOAT64 and op in ("min", "max"))
-            ):
-                # split 64-bit scalar: numpy-int64 oracle on the word
-                # pairs (ordered image for f64; wrapping sum for i64).
-                # Empty input yields the op IDENTITY, matching the
-                # device engine's pair-identity semantics.
-                full = _join_split_col(t, col)
+            full = _int64_view(t, col, ctype, op)
+            if full is not None and op == "mean":
+                with np.errstate(over="ignore"):  # wrapping, as device
+                    val = _mean_in_units(full.sum(), n, ctype) if n else 0.0
+                out[name] = np.array([val], np.float32)
+                continue
+            if full is not None:
+                # numpy-int64 oracle on the word pairs (ordered image
+                # for f64; wrapping sum for i64).  Empty input yields
+                # the op IDENTITY, matching the device engine's
+                # pair-identity semantics.
                 if n == 0:
                     ident = {
                         "sum": 0,
@@ -488,15 +501,6 @@ class LocalDebugInterpreter:
                     with np.errstate(over="ignore"):
                         v64 = np.array([getattr(full, op)()], np.int64)
                 out[f"{name}#h0"], out[f"{name}#h1"] = split64(v64)
-                continue
-            if (
-                col is not None and col not in t
-                and ctype is ColumnType.INT64 and op == "mean"
-            ):
-                full = _join_split_col(t, col)
-                with np.errstate(over="ignore"):  # wrapping, as device
-                    val = np.float64(full.sum()) / n if n else 0.0
-                out[name] = np.array([val], np.float32)
                 continue
             if col is not None and col not in t and (
                 ctype is not None and ctype.is_split

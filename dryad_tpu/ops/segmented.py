@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from dryad_tpu.columnar.batch import ColumnBatch
+from dryad_tpu.ops import wide
 from dryad_tpu.ops.sort import sort_batch_by_operands
 from dryad_tpu.ops.sortkeys import keys_equal_adjacent, to_sortable_u32
 
@@ -45,14 +46,20 @@ from dryad_tpu.ops.sortkeys import keys_equal_adjacent, to_sortable_u32
 class AggSpec:
     """One built-in aggregation over a physical column.
 
-    op: sum | count | min | max | mean | any | all | first
-    col: input physical column (None for count)
+    op: sum | count | min | max | mean | any | all | first, and the
+        64-bit forms sum64 | min64 | max64 | mean64 (``plan/lower.py``)
+    col: input physical column (None for count); for a 64-bit form the
+        ``#h0`` word of a split column, or a 32-bit column to be carried
+        sign-extended (:func:`pair_words`)
     out: output physical column name
+    scale: of a DECIMAL input, the digits a ``mean64`` divides away so
+        that the mean comes out in units
     """
 
     op: str
     col: Optional[str]
     out: str
+    scale: int = 0
 
 
 def _segment_layout(
@@ -76,27 +83,21 @@ PAIR_OPS = ("sum64", "min64", "max64")
 
 
 def _pair_combine(op: str):
-    """The 64-bit word-pair combine for ``op`` — the ONE source of truth
-    for the paired-u32 arithmetic (carry-propagating add for ``sum64``;
-    signed-lexicographic select — high word signed, low word unsigned —
-    for ``min64``/``max64``), shared by the segmented and scalar
-    reducers.  jax x64 stays off: int64/float64 live as two u32 device
-    words (``columnar/schema.py``); the reference's numeric aggregate
-    surface is ``DryadLinqQueryGen.cs:3439ff``."""
+    """The 64-bit word-pair combine for ``op``, from the one
+    implementation of paired-u32 arithmetic (``ops/wide.py``: the
+    carry-propagating add for ``sum64``; the signed compare, high word
+    signed and low word unsigned, for ``min64`` / ``max64``), shared by
+    the segmented and scalar reducers.  jax x64 stays off: int64 /
+    float64 / wide DECIMAL live as two u32 device words
+    (``columnar/schema.py``); the reference's numeric aggregate surface
+    is ``DryadLinqQueryGen.cs:3439ff``."""
     if op == "sum64":
-        def combine(alo, ahi, blo, bhi):
-            slo = alo + blo  # uint32 wraps mod 2^32
-            carry = (slo < blo).astype(jnp.uint32)
-            return slo, ahi + bhi + carry
-    else:
-        def combine(alo, ahi, blo, bhi):
-            ahs, bhs = ahi.astype(jnp.int32), bhi.astype(jnp.int32)
-            a_less = (ahs < bhs) | ((ahs == bhs) & (alo < blo))
-            take_a = a_less if op == "min64" else ~a_less
-            return (
-                jnp.where(take_a, alo, blo),
-                jnp.where(take_a, ahi, bhi),
-            )
+        return wide.add64
+
+    def combine(alo, ahi, blo, bhi):
+        a_less = wide.less64(alo, ahi, blo, bhi)
+        take_a = a_less if op == "min64" else ~a_less
+        return jnp.where(take_a, alo, blo), jnp.where(take_a, ahi, bhi)
 
     return combine
 
@@ -109,14 +110,27 @@ def _pair_identity(op: str) -> Tuple[jax.Array, jax.Array]:
     return jnp.uint32(0), jnp.uint32(0x80000000)  # max64: min signed-64
 
 
-def pair_to_f32(lo: jax.Array, hi: jax.Array) -> jax.Array:
-    """Approximate f32 value of a split signed-64 word pair
-    (hi signed * 2^32 + lo unsigned) — the ONE decode used by every
-    mean64 finalize."""
-    return (
-        hi.astype(jnp.int32).astype(jnp.float32) * jnp.float32(4294967296.0)
-        + lo.astype(jnp.float32)
-    )
+# Approximate f32 value of a split signed-64 word pair: the ONE decode
+# used by every mean64 finalize.
+pair_to_f32 = wide.pair_to_f32
+
+
+def pair_mean(lo: jax.Array, hi: jax.Array, count: jax.Array, scale: int = 0) -> jax.Array:
+    """The f32 mean every ``mean64`` ends in: the exact 64-bit sum
+    rounded to f32, over the count (at least 1), over ``10^scale`` where
+    the sum is a DECIMAL's, so that the mean is in units."""
+    mean = pair_to_f32(lo, hi) / jnp.maximum(count.astype(jnp.float32), 1.0)
+    return mean / jnp.float32(10.0**scale) if scale else mean
+
+
+def pair_words(data: Dict[str, jax.Array], col: str) -> Tuple[jax.Array, jax.Array]:
+    """The (lo, hi) words a 64-bit aggregate reads for ``col``: the
+    ``#h0`` / ``#h1`` pair of a split column (``col`` names the low
+    word), or a 32-bit column (a narrow DECIMAL) sign-extended, so that
+    its sum is carried in 64 bits without the table holding them."""
+    if col.endswith("#h0"):
+        return data[col], data[col[: -len("#h0")] + "#h1"]
+    return wide.widen(data[col])
 
 
 def pair_scalar_reduce(
@@ -127,18 +141,16 @@ def pair_scalar_reduce(
     flags (Sum/Min/Max over int64/float64 columns without x64).
     Invalid rows are replaced by the op's identity, so an all-invalid
     input reduces to the identity pair (neutral under further
-    combining), and the scan's last element is the total.
+    combining).  A halving tree (``ops/wide.py::tree_reduce``), not
+    ``lax.associative_scan``: the scan builds every prefix to hand back
+    the last, and its tree of odd/even slices is the form that gave no
+    TPU program at 2^23 slots (:func:`segmented_scan`).
     """
     ilo, ihi = _pair_identity(op)
-    lo = jnp.where(valid, lo, ilo)
-    hi = jnp.where(valid, hi, ihi)
-    base = _pair_combine(op)
-
-    def combine(a, b):
-        return base(a[0], a[1], b[0], b[1])
-
-    slo, shi = jax.lax.associative_scan(combine, (lo, hi))
-    return slo[-1], shi[-1]
+    return wide.tree_reduce(
+        _pair_combine(op), (ilo, ihi),
+        jnp.where(valid, lo, ilo), jnp.where(valid, hi, ihi),
+    )
 
 
 # -- the two passes every fold is made of -----------------------------------
@@ -301,10 +313,7 @@ def _agg_channels(
         if a.op == "count":
             continue
         if a.op in PAIR_OPS:
-            # a.col names the LOW word of a split 64-bit column; the
-            # high word lives alongside it and the output writes both.
-            vals[f"{a.out}#h0"] = data[a.col]
-            vals[f"{a.out}#h1"] = data[a.col[: -len("#h0")] + "#h1"]
+            vals[f"{a.out}#h0"], vals[f"{a.out}#h1"] = pair_words(data, a.col)
             pairs[a.out] = _pair_combine(a.op)
             continue
         if a.op not in _MERGES:
@@ -324,6 +333,21 @@ def _agg_channels(
         return out
 
     return vals, merge
+
+
+def fold_stats(key_cols: Sequence[str], aggs: Sequence[AggSpec]) -> Dict[str, int]:
+    """What one :func:`group_reduce` carries a slot, from its arguments
+    alone: ``group_keys`` (physical key columns), ``agg_channels`` (the
+    scan's channels: a ``count`` needs none), ``agg64_channels`` (those
+    of them that are word pairs) and ``agg_state_words`` (4-byte words
+    of scan state: two a pair, one any other channel).  The stats of a
+    group-by stage's ``dispatch`` span."""
+    channels = [a for a in aggs if a.op != "count"]
+    pairs = sum(a.op in PAIR_OPS for a in channels)
+    return dict(
+        group_keys=len(key_cols), agg_channels=len(channels),
+        agg64_channels=pairs, agg_state_words=len(channels) + pairs,
+    )
 
 
 def group_reduce(

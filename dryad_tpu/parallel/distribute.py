@@ -16,7 +16,12 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from dryad_tpu.columnar.batch import ColumnBatch, _nbytes, encode_physical
+from dryad_tpu.columnar.batch import (
+    ColumnBatch,
+    _nbytes,
+    encode_physical,
+    host_to_device,
+)
 from dryad_tpu.columnar.schema import Schema, StringDictionary, bytes_to_words
 from dryad_tpu.obs.span import UNTRACED, Tracer
 from dryad_tpu.parallel.mesh import num_partitions, partition_sharding
@@ -305,6 +310,9 @@ def from_host_table(
                     sp.add(bytes_out=4 * len(words) * len(a))
             elif f.ctype.is_split:
                 _copy_in(out, encode_physical(f, a, dictionary), sizes, cap)
+            elif a.dtype.kind == "M" and a.dtype != np.dtype("datetime64[D]"):
+                # a DATE in another unit; days are cast as they are copied
+                _copy_in(out, {f.name: host_to_device(f.ctype, a)}, sizes, cap)
             else:
                 _copy_in(out, {f.name: a}, sizes, cap)
 

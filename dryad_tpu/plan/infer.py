@@ -5,7 +5,9 @@ the caller doesn't declare the output schema we trace it with
 ``jax.eval_shape`` on dummy columns and reconstruct logical fields from
 the physical names: ``x#h0``/``x#h1``/``x#r0``/``x#r1`` quads are STRING,
 ``x#h0``/``x#h1`` pairs are INT64, ``x#b0`` ... ``x#b<k-1>`` runs are
-BYTES, everything else maps by dtype.
+BYTES, everything else maps by dtype.  A DECIMAL value a typed function
+returns (``ops/wide.py::Dec``) says its own scale and width; a DATE
+survives under its name (it is an int32 on the device).
 """
 
 from __future__ import annotations
@@ -15,7 +17,8 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-from dryad_tpu.columnar.schema import BYTES, ColumnType, Schema
+from dryad_tpu.columnar.schema import BYTES, ColumnType, DecimalType, Schema
+from dryad_tpu.ops import wide
 
 _DEVICE_DTYPES = {
     ColumnType.INT32: jnp.int32,
@@ -32,7 +35,9 @@ def dummy_cols(schema: Schema, n: int = 4) -> Dict[str, jax.ShapeDtypeStruct]:
             for d in f.device_names:
                 out[d] = jax.ShapeDtypeStruct((n,), jnp.uint32)
         else:
-            out[f.name] = jax.ShapeDtypeStruct((n,), _DEVICE_DTYPES[f.ctype])
+            out[f.name] = jax.ShapeDtypeStruct(
+                (n,), _DEVICE_DTYPES[f.ctype.storage]
+            )
     return out
 
 
@@ -100,16 +105,34 @@ def schema_from_physical(
             dt = jnp.dtype(cols[name].dtype)
             if dt not in _DTYPE_TO_TYPE:
                 raise TypeError(f"column {name!r} has unsupported dtype {dt}")
-            fields.append((name, _DTYPE_TO_TYPE[dt]))
+            ctype = _DTYPE_TO_TYPE[dt]
+            if (
+                like is not None and name in like
+                and like.field(name).ctype is ColumnType.DATE
+                and ctype is ColumnType.INT32
+            ):
+                ctype = ColumnType.DATE  # days stay days under their name
+            fields.append((name, ctype))
     return Schema(fields)
 
 
 def infer_select_schema(schema: Schema, fn) -> Schema:
+    """The schema of ``fn``'s output.  A typed ``fn`` (``api/query.py``
+    wraps one where the input has DECIMAL columns) is traced over its
+    LOGICAL columns (``fn.logical``), so that each :class:`wide.Dec` it
+    returns names its own DECIMAL type."""
     shapes = dummy_cols(schema)
-    out = jax.eval_shape(lambda c: fn(c), shapes)
+    out = jax.eval_shape(lambda c: getattr(fn, "logical", fn)(c), shapes)
     if not isinstance(out, dict):
         raise TypeError("select fn must return a dict of physical columns")
-    return schema_from_physical(out, like=schema)
+    decimals = {
+        name: DecimalType(v.scale, v.wide)
+        for name, v in out.items() if isinstance(v, wide.Dec)
+    }
+    inferred = schema_from_physical(wide.unwrap(out), like=schema)
+    return Schema([
+        (f.name, decimals.get(f.name, f.ctype)) for f in inferred.fields
+    ])
 
 
 def infer_select_many_schema(schema: Schema, fn, factor: int) -> Schema:

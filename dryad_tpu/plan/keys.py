@@ -78,7 +78,7 @@ class OrderingOperands:
                 h1 = batch.data[f"{f.name}#h1"]
                 triple = [r0, r1, h1, h0]
                 ops.extend(~t if desc else t for t in triple)
-            elif f.ctype in (ColumnType.INT64, ColumnType.FLOAT64):
+            elif f.ctype.storage in (ColumnType.INT64, ColumnType.FLOAT64):
                 # FLOAT64 words are the order-preserving signed-int64
                 # image of the double, so the int64 operand transform
                 # orders both types correctly

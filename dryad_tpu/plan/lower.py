@@ -494,22 +494,37 @@ class _Builder:
         from dryad_tpu.ops.segmented import AggSpec
 
         out = []
-        from dryad_tpu.columnar.schema import ColumnType
+        from dryad_tpu.columnar.schema import ColumnType, DecimalType
 
         for op, col, name in aggs:
             if col is not None:
                 f = schema.field(col)
-                if f.ctype is ColumnType.INT64 and op in ("sum", "min", "max"):
+                if isinstance(f.ctype, DecimalType) and not f.ctype.wide:
+                    # a narrow DECIMAL: min / max / first are the int32
+                    # column's; a sum (and a mean's) is carried in 64
+                    # bits from the sign-extended word, so money adds
+                    # up exactly where a column of it fits 32 bits
+                    if op == "sum":
+                        out.append(AggSpec("sum64", col, name))
+                        continue
+                    if op == "mean":
+                        out.append(AggSpec("mean64", col, name, f.ctype.scale))
+                        continue
+                elif f.ctype.storage is ColumnType.INT64 and op in ("sum", "min", "max"):
                     # exact 64-bit arithmetic over the split (#h0, #h1)
                     # word pair (carry-propagating add / signed-lex
-                    # compare, ops/segmented.py; the reference's numeric
+                    # compare, ops/wide.py; the reference's numeric
                     # aggregate surface is DryadLinqQueryGen.cs:3439ff)
                     out.append(AggSpec(f"{op}64", f"{col}#h0", name))
                     continue
-                if f.ctype is ColumnType.INT64 and op == "mean":
+                elif f.ctype.storage is ColumnType.INT64 and op == "mean":
                     # Average over long: exact sum64 + count partials,
-                    # f32 divide at finalize
-                    out.append(AggSpec("mean64", f"{col}#h0", name))
+                    # f32 divide at finalize (a DECIMAL's by its scale
+                    # too: the mean is in units)
+                    out.append(AggSpec(
+                        "mean64", f"{col}#h0", name,
+                        getattr(f.ctype, "scale", 0),
+                    ))
                     continue
                 if f.ctype is ColumnType.FLOAT64:
                     if op in ("min", "max"):
@@ -702,7 +717,7 @@ class _Builder:
                 stage.ops.append(StageOp("project", dict(slot=slot, cols=want)))
         else:
             aggs = self._phys_aggs(in_schema, node.params["aggs"])
-            partial, final = _decompose_aggs(aggs)
+            partial, final, means = _decompose_aggs(aggs)
             from dryad_tpu.ops.segmented import AggSpec
 
             salt = node.params.get("salt")
@@ -746,9 +761,10 @@ class _Builder:
                 stage.ops.append(
                     StageOp("group_reduce", dict(slot=slot, keys=carry_cols, aggs=final))
                 )
-            fin = _finalize_fn(aggs)
-            if fin is not None:
-                stage.ops.append(StageOp("select", dict(slot=slot, fn=fin)))
+            if means:
+                stage.ops.append(StageOp(
+                    "select", dict(slot=slot, fn=_FinalizeMeans(means))
+                ))
             want = K.group_carry_cols(node.schema, node.schema.names)
             stage.ops.append(StageOp("project", dict(slot=slot, cols=want)))
         self.cursor[node.id] = ("open", stage, slot)
@@ -941,37 +957,54 @@ def _decompose_aggs(aggs):
     The Seed/Accumulate/RecursiveAccumulate split for builtin aggregates
     (reference ``DryadLinqDecomposition.cs:34``): count becomes local
     count + final sum; mean becomes (sum, count) partials + final divide.
+
+    A mean carries nothing another aggregate already carries: its count
+    is the query's ``count`` where there is one (else one count serves
+    every mean), and a ``mean64``'s sum is the ``sum64`` of the same
+    column where the query asks for that too.  What a fold moves a slot
+    is its channels (``agg_state_words`` on the stage's ``dispatch``
+    span), so TPC-H Q1's three averages beside its four sums and its
+    count cost one more 64-bit channel, not three and three counts.
+    Returns ``(partial, final, means)``: ``means`` is what
+    :class:`_FinalizeMeans` reads, ``(out, sum channel, count channel,
+    scale)`` with ``scale`` None for a sum of one word.
     """
     from dryad_tpu.ops.segmented import AggSpec
 
     partial, final = [], []
-    for a in aggs:
-        if a.op == "sum":
-            partial.append(AggSpec("sum", a.col, a.out))
-            final.append(AggSpec("sum", a.out, a.out))
-        elif a.op == "count":
-            partial.append(AggSpec("count", None, a.out))
-            final.append(AggSpec("sum", a.out, a.out))
-        elif a.op in ("min", "max", "first", "any", "all"):
-            partial.append(AggSpec(a.op, a.col, a.out))
-            final.append(AggSpec(a.op, a.out, a.out))
-        elif a.op in ("sum64", "min64", "max64"):
+    made = {}  # (op, col) -> the channel that carries it
+
+    def carry(op, col, out):
+        made.setdefault((op, col), out)
+        partial.append(AggSpec(op, col, out))
+        if op == "count":
+            final.append(AggSpec("sum", out, out))
+        elif op in ("sum64", "min64", "max64"):
             # partial writes out#h0/out#h1; final re-reduces that pair
-            partial.append(AggSpec(a.op, a.col, a.out))
-            final.append(AggSpec(a.op, f"{a.out}#h0", a.out))
-        elif a.op == "mean64":
-            partial.append(AggSpec("sum64", a.col, f"{a.out}#s"))
-            partial.append(AggSpec("count", None, f"{a.out}#c"))
-            final.append(AggSpec("sum64", f"{a.out}#s#h0", f"{a.out}#s"))
-            final.append(AggSpec("sum", f"{a.out}#c", f"{a.out}#c"))
-        elif a.op == "mean":
-            partial.append(AggSpec("sum", a.col, f"{a.out}#s"))
-            partial.append(AggSpec("count", None, f"{a.out}#c"))
-            final.append(AggSpec("sum", f"{a.out}#s", f"{a.out}#s"))
-            final.append(AggSpec("sum", f"{a.out}#c", f"{a.out}#c"))
+            final.append(AggSpec(op, f"{out}#h0", out))
         else:
+            final.append(AggSpec(op, out, out))
+
+    for a in aggs:
+        if a.op in ("sum", "count", "min", "max", "first", "any", "all",
+                    "sum64", "min64", "max64"):
+            carry(a.op, a.col, a.out)
+        elif a.op not in ("mean", "mean64"):
             raise ValueError(f"unknown agg op {a.op!r}")
-    return partial, final
+    means = []
+    for a in aggs:
+        if a.op not in ("mean", "mean64"):
+            continue
+        if ("count", None) not in made:
+            carry("count", None, f"{a.out}#c")
+        op = "sum64" if a.op == "mean64" else "sum"
+        if (op, a.col) not in made:
+            carry(op, a.col, f"{a.out}#s")
+        means.append((
+            a.out, made[(op, a.col)], made[("count", None)],
+            a.scale if a.op == "mean64" else None,
+        ))
+    return partial, final, tuple(means)
 
 
 class _AddSalt:
@@ -997,49 +1030,37 @@ class _AddSalt:
 
 
 class _FinalizeMeans:
-    """Post-shuffle mean finalize (sum/count -> mean; 64-bit sums
-    decode their word pair to f32 first); VALUE-equal so re-lowering
-    doesn't bust the compiled-stage cache."""
+    """Post-shuffle mean finalize (sum / count -> mean; a 64-bit sum
+    decodes its word pair to f32 first and a DECIMAL's divides its
+    scale away); VALUE-equal so re-lowering doesn't bust the
+    compiled-stage cache.  ``means``: :func:`_decompose_aggs`'s.  The
+    channels stay in the batch (another output may be one of them); the
+    ``project`` that follows keeps the schema's columns."""
 
-    def __init__(self, outs, outs64=()):
-        self.outs = tuple(outs)
-        self.outs64 = tuple(outs64)
+    def __init__(self, means):
+        self.means = tuple(means)
 
     def __eq__(self, other) -> bool:
-        return (
-            type(other) is _FinalizeMeans
-            and other.outs == self.outs
-            and other.outs64 == self.outs64
-        )
+        return type(other) is _FinalizeMeans and other.means == self.means
 
     def __hash__(self) -> int:
-        return hash(("_FinalizeMeans", self.outs, self.outs64))
+        return hash(("_FinalizeMeans", self.means))
 
     def __call__(self, cols):
         import jax.numpy as jnp
 
-        from dryad_tpu.ops.segmented import pair_to_f32
+        from dryad_tpu.ops.segmented import pair_mean
 
         out = dict(cols)
-        for name in self.outs:
-            s = out.pop(f"{name}#s").astype(jnp.float32)
-            c = out.pop(f"{name}#c").astype(jnp.float32)
-            out[name] = s / jnp.maximum(c, 1.0)
-        for name in self.outs64:
-            lo = out.pop(f"{name}#s#h0")
-            hi = out.pop(f"{name}#s#h1")
-            c = out.pop(f"{name}#c").astype(jnp.float32)
-            out[name] = pair_to_f32(lo, hi) / jnp.maximum(c, 1.0)
+        for name, s, c, scale in self.means:
+            if scale is None:
+                count = jnp.maximum(cols[c].astype(jnp.float32), 1.0)
+                out[name] = cols[s].astype(jnp.float32) / count
+            else:
+                out[name] = pair_mean(
+                    cols[f"{s}#h0"], cols[f"{s}#h1"], cols[c], scale
+                )
         return out
-
-
-def _finalize_fn(aggs):
-    """Post-shuffle finalize for aggs whose partials differ (mean)."""
-    means = [a.out for a in aggs if a.op == "mean"]
-    means64 = [a.out for a in aggs if a.op == "mean64"]
-    if not means and not means64:
-        return None
-    return _FinalizeMeans(means, means64)
 
 
 def _rewrite_topk(roots: Sequence[Node], limit: int) -> List[Node]:

@@ -277,3 +277,26 @@ def test_dense_group_by_rejects_wide_columns():
     ctx = DryadContext(num_partitions_=8)
     with pytest.raises(ValueError, match="sort-based"):
         ctx.from_arrays(tbl).group_by("k", {"m": ("mean", "w")}, dense=4)
+
+
+@pytest.mark.parametrize("op", ["sum", "min", "max", "mean"])
+@pytest.mark.parametrize("rows,partitions", [(1, 1), (5, 8), (1000, 4), (4097, 1), (4097, 8)])
+def test_whole_column_int64_reduce_is_exact_at_any_row_count(op, rows, partitions, rng):
+    """The whole-column 64-bit reduce (``ops/segmented.py::
+    pair_scalar_reduce``: a halving tree over the slots padded to a
+    power of two, the partitions' pairs gathered and reduced the same
+    way) over row counts that are no power of two, with rows a ``where``
+    made invalid, wrapping past 2^63 as NumPy's int64 does."""
+    v = rng.integers(-(2 ** 62), 2 ** 62, rows).astype(np.int64)
+    keep = rng.random(rows) < 0.7
+    keep[0] = True
+    ctx = DryadContext(num_partitions_=partitions)
+    q = ctx.from_arrays({"v": v, "keep": keep}).where(lambda c: c["keep"])
+    got = {"sum": q.sum_, "min": q.min_, "max": q.max_, "mean": q.mean}[op]("v")
+    with np.errstate(over="ignore"):
+        want = {"sum": v[keep].sum(), "min": v[keep].min(), "max": v[keep].max(),
+                "mean": np.float64(v[keep].sum()) / keep.sum()}[op]
+    if op == "mean":
+        assert got == pytest.approx(float(want), rel=1e-6, abs=1e-6)
+    else:
+        assert got == int(want)

@@ -134,3 +134,120 @@ def test_the_joins_pair_slots_compile_small_for_the_chip(one_chip, uncached):
     assert seen == {"slot_gathers": 2, "stacked_words": {"li": 3, "ri": 2}}
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 1 << 30  # 626 MB; 5,369 MB in one block
+
+
+def test_the_64_bit_fold_compiles_small_for_the_chip_at_the_q1_cells_slots(one_chip, uncached):
+    """``ops/segmented.py``'s fold of the ``tpch-q1-1c`` cell (five
+    ``sum64`` channels from four 32-bit columns and one pair, two key
+    columns) at the 2^26 slots SF 10 is bound at: the scan and the
+    compaction are each ONE loop body whatever the slots, and the
+    temporaries are a few copies of the state (ten words and the flag a
+    slot), far under the chip's HBM."""
+    from dryad_tpu.columnar.batch import ColumnBatch
+    from dryad_tpu.ops.segmented import AggSpec, _agg_channels, _place_groups, segmented_scan
+
+    slots = 1 << 26
+
+    def col(dtype):
+        return jax.ShapeDtypeStruct((slots,), dtype, sharding=one_chip)
+
+    narrow = ["l_quantity", "l_extendedprice", "l_discount", "disc_price"]
+    data = {"l_returnflag": col(jnp.int32), "l_linestatus": col(jnp.int32),
+            **{c: col(jnp.int32) for c in narrow},
+            "charge#h0": col(jnp.uint32), "charge#h1": col(jnp.uint32)}
+    aggs = [AggSpec("sum64", c, f"s_{c}") for c in narrow]
+    aggs += [AggSpec("sum64", "charge#h0", "s_charge"), AggSpec("count", None, "n")]
+    keys = ["l_returnflag", "l_linestatus"]
+
+    def fold(sb, start):
+        vals, merge = _agg_channels(sb.data, aggs)
+        assert len(vals) == 10
+        return _place_groups(sb, start, keys, segmented_scan(start, vals, merge))
+
+    compiled = jax.jit(fold).lower(
+        ColumnBatch(data, col(jnp.bool_)), col(jnp.bool_)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.generated_code_size_in_bytes < 16 << 20
+    assert memory.temp_size_in_bytes < 6 << 30  # under 6 of the chip's 15.75 GiB
+    text = compiled.as_text()
+    assert "scatter" not in text and " gather(" not in text and " sort(" not in text
+
+
+@pytest.mark.parametrize("op", ["sum64", "min64", "max64"])
+def test_the_whole_column_64_bit_reduce_compiles_for_the_chip_at_2_26(one_chip, uncached, op):
+    """``ops/segmented.py::pair_scalar_reduce`` over 2^26 slots (TPC-H
+    Q6's shape: one exact sum of a whole column): a halving tree of 26
+    elementwise levels.  The ``lax.associative_scan`` it replaced is the
+    form that gave no TPU program at 2^23 slots."""
+    from dryad_tpu.ops.segmented import pair_scalar_reduce
+
+    slots = 1 << 26
+
+    def col(dtype):
+        return jax.ShapeDtypeStruct((slots,), dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda lo, hi, valid: pair_scalar_reduce(op, lo, hi, valid)).lower(
+        col(jnp.uint32), col(jnp.uint32), col(jnp.bool_)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.generated_code_size_in_bytes < 8 << 20
+    assert memory.temp_size_in_bytes < 4 * 8 * slots  # a few copies of the pair
+    assert " while(" not in compiled.as_text()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("slots", [None, 1 << 26], ids=["the_cells_slots", "sf10_whole"])
+def test_the_q1_cells_stage_program_fits_the_chip(one_chip, uncached, monkeypatch, slots):
+    """The ``tpch-q1-1c`` cell's whole stage program
+    (``input+where+select+group_by+order_by``: three carried sorts, two
+    folds) compiled for the chip at the slots the cell is bound at and
+    at SF 10 whole's 2^26: its arguments, its answer and its temporaries
+    together stay under the chip's 15.75 GiB (at SF 10 whole, 2^26 slots, 1.95 + 3.83 + 8.86 =
+    14.63 GB, which ran on the chip and was set aside for its seconds
+    and for this; the cell holds half).  Slow for what it is: the
+    program's three carried sorts compile for minutes whatever the size
+    (260 s at 2^20 slots, 452 s at 2^26 here; 281 s cold on the chip's
+    host)."""
+    import json
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from dryad_tpu import DryadContext
+    from dryad_tpu.columnar.batch import ColumnBatch
+    from dryad_tpu.exec.kernels import build_stage_fn
+    from dryad_tpu.ops import pallas_bucket
+    from dryad_tpu.parallel.stage import compile_stage
+    from dryad_tpu.plan.lower import lower
+
+    # the program the chip traces: its sorts carry their columns
+    monkeypatch.setattr(pallas_bucket, "_on_tpu", lambda: True)
+    spec = importlib.util.spec_from_file_location(
+        "bench_job_tpch_q1_tpu", os.path.join(ROOT, "benchmarks", "jobs", "tpch_q1.py"))
+    job = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(job)
+    if slots is None:
+        with open(os.path.join(ROOT, "benchmarks", "traffic", "tpch_q1.json")) as fh:
+            slots = json.load(fh)["slots"]
+    params = {"orders": 100, "parts": 40, "slots": slots, "delta_days": 90,
+              "partitions": 1}
+    table = job.make_table(np.random.default_rng([49, 0]), params, None, 0)
+    ctx = DryadContext(num_partitions_=1)
+    query = job.bind(ctx, table, params)
+    graph = lower([query.node], ctx.config, ctx.dictionary, P=1)
+    (stage,) = graph.stages
+    (node,) = graph.inputs.values()
+    device = next(iter(one_chip.device_set))
+    mesh = Mesh(np.array([device]), ("p",))
+    sharded = NamedSharding(mesh, PartitionSpec("p"))
+    batch = ColumnBatch(
+        {n: jax.ShapeDtypeStruct((slots,), d, sharding=sharded)
+         for n, d in node.schema.device_dtypes().items()},
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=sharded))
+    words = []
+    fn = build_stage_fn(stage, 1, ctx.config.shuffle_slack, 1, ("p",), (1,),
+                        sort_cell=words)
+    memory = compile_stage(mesh, fn).lower((batch,), ()).compile().memory_analysis()
+    assert words == [14]
+    held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes)
+    assert memory.temp_size_in_bytes < 10 << 30 and held < int(15.75 * 2**30)

@@ -507,6 +507,44 @@ def test_the_multi_output_job_on_chip(jaxmod):
     assert decodes[0]["rows"] + decodes[1]["rows"] == params["rows"]
 
 
+def test_tpch_q1_on_chip(jaxmod):
+    """``tpch-q1-1c``'s job at 2^20 slots (250,000 orders, about a
+    million rows) through the cell's own ``bind`` and ``compare``: the
+    DATE predicate, the exact DECIMAL ``select`` (32 x 32 -> 64 and 64 x
+    32 -> 64 multiplies), two group keys and five 64-bit sum channels
+    folded on the chip, fresh and again, every sum equal to the NumPy
+    int64 reference to the unit; the stage's dispatch says what the
+    widest fold carries."""
+    import importlib.util
+    import os
+
+    from dryad_tpu import DryadContext
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_job_tpch_q1",
+        os.path.join(root, "benchmarks", "jobs", "tpch_q1.py"))
+    job = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(job)
+    params = {"orders": 250_000, "parts": 33_334, "slots": 1 << 20,
+              "delta_days": 90, "partitions": 1}
+    table = job.make_table(np.random.default_rng([49, 1]), params, None, 0)
+    assert len(table["arrays"]["l_quantity"]) < params["slots"]
+    ctx = DryadContext(num_partitions_=1)
+    bound = job.bind(ctx, table, params)
+    for answer in (bound.collect(), bound.collect()):
+        checks = job.compare(table, answer, params)
+        assert len(checks) == 9, checks
+        assert all(value <= limit for value, limit in checks.values()), checks
+        assert len(answer["count_order"]) == 4
+    dispatched = [e for e in ctx.events.events()
+                  if e["kind"] == "span" and e.get("cat") == "execute"]
+    assert len(dispatched) == 2
+    assert {(e["agg64_channels"], e["agg_state_words"], e["group_keys"])
+            for e in dispatched} == {(5, 11, 2)}
+    assert {e["xchg_elided"] for e in dispatched} == {2}
+
+
 def test_the_user_defined_combiner_on_chip(jaxmod):
     """``groupby-skew-4c``'s query at 2^20 rows a chip over every chip
     there is, through the cell's own ``bind`` and ``compare``: the
